@@ -234,7 +234,8 @@ class ExpertConfig:
     # capacity unknown never refuses
     admission_policy: str = "off"
     # opt into the persistent JAX compilation cache at host startup
-    # (hostenv.enable_compile_cache; DRAGONBOAT_TPU_COMPILE_CACHE=0
+    # (hostenv.enable_compile_cache: at JAX_COMPILATION_CACHE_DIR where
+    # set, else <checkout>/.jax_cache; DRAGONBOAT_TPU_COMPILE_CACHE=0
     # vetoes).  Off by default: the cache dir is process-global state
     compile_cache: bool = False
 

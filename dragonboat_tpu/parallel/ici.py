@@ -33,22 +33,12 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as PS
 
-try:
-    # jax >= 0.6: top-level shard_map, replication check kwarg is
-    # check_vma
-    _shard_map_impl = jax.shard_map
-    _SHARD_MAP_NOCHECK = {"check_vma": False}
-except AttributeError:
-    # jax 0.4/0.5: experimental namespace, kwarg is check_rep
-    from jax.experimental.shard_map import shard_map as _shard_map_impl
-
-    _SHARD_MAP_NOCHECK = {"check_rep": False}
-
 
 def shard_map(f, *, mesh, in_specs, out_specs):
-    """`jax.shard_map(..., check_vma=False)` across jax versions."""
-    return _shard_map_impl(f, mesh=mesh, in_specs=in_specs,
-                           out_specs=out_specs, **_SHARD_MAP_NOCHECK)
+    """``jax.shard_map`` without the replication check."""
+    return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
+                         out_specs=out_specs, check_vma=False)
+
 
 from dragonboat_tpu.core import params as KP
 from dragonboat_tpu.core.kernel import step

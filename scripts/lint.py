@@ -96,7 +96,7 @@ import os
 import subprocess
 import sys
 
-# lowering must never grab a TPU just to count ops
+# a CPU-only analysis tool: lowering must never grab a TPU just to count ops
 os.environ.setdefault("JAX_PLATFORMS", "cpu")
 # the partition pass's dynamic check needs a 2-device mesh; the flag
 # must be set before any child (or this process) initializes jax

@@ -14,6 +14,7 @@ import tempfile
 import time
 
 sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
+# a CPU-only analysis tool: host-path microbenchmarks, never a device number
 os.environ.setdefault("JAX_PLATFORMS", "cpu")
 
 

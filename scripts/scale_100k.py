@@ -43,10 +43,7 @@ def _enable_compile_cache() -> None:
     a warm cache."""
     from dragonboat_tpu import hostenv
 
-    try:
-        artifacts = len(os.listdir(hostenv.jax_cache_dir()))
-    except OSError:
-        artifacts = 0
+    artifacts = hostenv.cache_entry_count()
     cache_dir = hostenv.enable_compile_cache()
     if cache_dir is None:
         print("SCALE compile_cache: vetoed "
@@ -57,11 +54,19 @@ def _enable_compile_cache() -> None:
 
 
 def _budget_bytes(capacity_mod) -> int:
-    """Device HBM limit when the backend reports one, else the
-    SCALE_BUDGET_BYTES env (default 16 GiB — one v5e core)."""
+    """Device HBM limit as the backend reports it.  The CPU backend
+    reports none: there SCALE_BUDGET_BYTES stands in (default 16 GiB,
+    one v5e chip's HBM, so the headroom arithmetic can be rehearsed).
+    On any other backend an unknown limit is an error, not a guess."""
+    import jax
+
     for row in capacity_mod.device_memory_stats():
         if row.get("bytes_limit"):
             return int(row["bytes_limit"])
+    if jax.default_backend() != "cpu":
+        raise RuntimeError(
+            f"{jax.default_backend()} device reports no bytes_limit; "
+            "refusing to assume a memory budget")
     return int(os.environ.get("SCALE_BUDGET_BYTES", str(16 << 30)))
 
 

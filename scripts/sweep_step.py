@@ -16,8 +16,9 @@ sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 import jax
 import numpy as np
 
-jax.config.update("jax_compilation_cache_dir", "/tmp/dragonboat_tpu_jax_cache")
-jax.config.update("jax_persistent_cache_min_compile_time_secs", 1.0)
+from dragonboat_tpu.hostenv import enable_compile_cache
+
+enable_compile_cache()
 
 from dragonboat_tpu.bench_loop import elect_all, make_cluster, run_steps
 from dragonboat_tpu.core import params as KP

@@ -8,6 +8,7 @@ schema validator."""
 import importlib.util
 import json
 import os
+import sys
 import time
 import urllib.error
 import urllib.request
@@ -418,38 +419,109 @@ def test_steady_state_compiles_each_entry_once_per_geometry(depth):
     assert storms == [], storms
 
 
-def test_compile_cache_env_veto(monkeypatch):
-    """DRAGONBOAT_TPU_COMPILE_CACHE=0 vetoes the persistent compile
-    cache (scale_100k / tpu_pallas_ab / ExpertConfig.compile_cache all
-    route through this helper); the cache dir is CPU-fingerprinted and
-    stable within a box."""
+@pytest.mark.parametrize("case", ["env_set", "env_unset", "veto"])
+def test_compile_cache_placement(monkeypatch, tmp_path, case):
+    """The one compile-cache helper (hostenv; conftest, bench, the
+    scripts, chip_smoke and ExpertConfig.compile_cache all route through
+    it): JAX_COMPILATION_CACHE_DIR set -> that directory, and code sets
+    none; unset -> the fixed <checkout>/.jax_cache;
+    DRAGONBOAT_TPU_COMPILE_CACHE=0 -> None and nothing is set."""
+    import jax
+
     from dragonboat_tpu import hostenv
 
-    monkeypatch.setenv("DRAGONBOAT_TPU_COMPILE_CACHE", "0")
-    assert hostenv.enable_compile_cache() is None
-    assert hostenv.jax_cache_dir("/tmp/x") == hostenv.jax_cache_dir("/tmp/x")
-    assert hostenv.jax_cache_dir("/tmp/x").startswith("/tmp/x_")
+    placed = str(tmp_path / "placed")
+    monkeypatch.delenv("DRAGONBOAT_TPU_COMPILE_CACHE", raising=False)
+    if case == "env_unset":
+        monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
+    else:
+        monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", placed)
+    if case == "veto":
+        monkeypatch.setenv("DRAGONBOAT_TPU_COMPILE_CACHE", "0")
+    before = getattr(jax.config, hostenv.CACHE_DIR_OPTION)
+    try:
+        got = hostenv.enable_compile_cache()
+        after = getattr(jax.config, hostenv.CACHE_DIR_OPTION)
+    finally:
+        jax.config.update(hostenv.CACHE_DIR_OPTION, before)
+    checkout = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    if case == "env_set":
+        # the env var was set after jax started, so had code set a
+        # directory the config would have moved
+        assert got == placed and after == before
+    elif case == "env_unset":
+        assert got == after == os.path.join(checkout, ".jax_cache")
+    else:
+        assert got is None and after == before
+        assert hostenv.cache_entry_count() == 0
 
 
-def test_donated_cache_purge(tmp_path):
-    """Persisted executables for donated entries are purged whenever a
-    process points jax at the cache: jax 0.4.37's deserialization
-    breaks donated-buffer aliasing (wrong results, then a segfault on
-    the first result read), so donated entries must compile fresh in
-    every process.  Non-donated entries stay cached."""
-    from dragonboat_tpu import hostenv
+_RELOAD_CHILD = """
+import hashlib, sys
+import jax
+import numpy as np
+jax.config.update("jax_platforms", "cpu")
+from dragonboat_tpu import hostenv
+hits = []
+assert hostenv.enable_compile_cache(min_compile_secs=0.0) == sys.argv[1]
+jax.monitoring.register_event_listener(
+    lambda ev, **kw: hits.append(ev)
+    if ev == "/jax/compilation_cache/cache_hits" else None)
+import dataclasses, functools
+from dragonboat_tpu.bench_loop import _self_input, bench_params, make_cluster
+from dragonboat_tpu.core.kernel import step_donated
+from dragonboat_tpu.core.kstate import empty_inbox
+from dragonboat_tpu.core.router import route
+kp = dataclasses.replace(
+    bench_params(3, platform="cpu"), log_cap=64, msg_entries=4,
+    proposal_cap=4, apply_batch=16)
+state, box = make_cluster(kp, 4, 3), empty_inbox(kp, 12)
+feed = jax.jit(functools.partial(_self_input, kp), static_argnums=(3, 4))
+rt = jax.jit(functools.partial(route, kp, 3))
+for _ in range(40):
+    inp = feed(state, True, True, None, False, 0)
+    state, out = step_donated(kp, state, box, inp)
+    box = rt(out)
+h = hashlib.sha256()
+for leaf in jax.tree_util.tree_leaves(state):
+    h.update(np.asarray(leaf).tobytes())
+print("RELOAD", h.hexdigest(), int(np.asarray(state.committed).max()),
+      len(hits))
+"""
 
-    keep = tmp_path / "jit_step-aaaa-cache"
-    drop1 = tmp_path / "jit_step_donated-bbbb-cache"
-    drop2 = tmp_path / "jit_jit_serve_step_donated-cccc-atime"
-    for p in (keep, drop1, drop2):
-        p.write_bytes(b"x")
-    n = hostenv.purge_donated_cache_entries(str(tmp_path))
-    assert n == 2
-    assert keep.exists() and not drop1.exists() and not drop2.exists()
-    # idempotent on an already-clean (or missing) dir
-    assert hostenv.purge_donated_cache_entries(str(tmp_path)) == 0
-    assert hostenv.purge_donated_cache_entries(str(tmp_path / "nope")) == 0
+
+def test_donated_cache_reload(tmp_path):
+    """A DONATED executable round-trips through the persistent cache
+    soundly on the installed jax: process 1 compiles step_donated fresh
+    into an empty cache, process 2 runs it from the cache (cache hits
+    > 0) — both end 40 self-driving steps in bitwise the same state, and
+    every result buffer reads back.  The cache is placed through
+    JAX_COMPILATION_CACHE_DIR, so the children also show that the helper
+    then sets no directory of its own."""
+    import subprocess
+
+    checkout = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    cache = str(tmp_path / "cache")
+    env = dict(os.environ, PYTHONPATH=checkout, JAX_PLATFORMS="cpu",
+               JAX_COMPILATION_CACHE_DIR=cache)
+    env.pop("XLA_FLAGS", None)
+    env.pop("DRAGONBOAT_TPU_COMPILE_CACHE", None)
+
+    def run():
+        r = subprocess.run(
+            [sys.executable, "-c", _RELOAD_CHILD, cache],
+            env=env, capture_output=True, text=True, timeout=120)
+        assert r.returncode == 0, r.stderr[-2000:]
+        line = [ln for ln in r.stdout.splitlines()
+                if ln.startswith("RELOAD")][-1].split()
+        return line[1], int(line[2]), int(line[3])
+
+    fresh = run()
+    assert any("step_donated" in n for n in os.listdir(cache))
+    reloaded = run()
+    assert fresh[1] > 0, "no commits: the comparison would be vacuous"
+    assert fresh[2] == 0 and reloaded[2] > 0, (fresh, reloaded)
+    assert fresh[:2] == reloaded[:2], (fresh, reloaded)
 
 
 # ---------------------------------------------------------------------

@@ -29,7 +29,7 @@ FAST_SEEDS = (11, 23)
 
 @pytest.mark.chaos_fast
 @pytest.mark.parametrize("seed", FAST_SEEDS)
-def test_hotspot_drains_and_converges(seed):
+def test_hotspot_drains_and_converges(seed, one_core):
     r = run_hotspot(seed)
     assert r.report.ok, (seed, r.report.failures)
     assert r.transfers, (seed, "controller never planned a transfer")

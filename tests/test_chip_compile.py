@@ -1,0 +1,150 @@
+"""Compile the main path's device programs for a v5e that is described, not
+attached: what the TPU compiler refuses here costs no chip time.
+
+Covers the entries ``chip_smoke.py`` serves through (``kernel.step`` /
+``step_donated`` with the KernelParams a NodeHost picks on a TPU, the mesh
+serve step on a 1x3 mesh) and the three Pallas kernels at their bench
+shapes.  Nothing runs — these say nothing about results or times.
+
+One file on purpose: only one process may load the TPU's library, and the
+worker that is handed this file is the one that describes the topology
+(inside the fixture — never at import, never in a child process).
+"""
+
+from types import SimpleNamespace
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+from jax.sharding import Mesh, SingleDeviceSharding
+
+from dragonboat_tpu.config import ExpertConfig
+from dragonboat_tpu.core import kernel
+from dragonboat_tpu.core.kstate import empty_inbox, empty_input, init_state
+from dragonboat_tpu.nodehost import NodeHost
+from dragonboat_tpu.parallel import fabric_pallas, ici
+from dragonboat_tpu.rsm import device_kv_pallas
+from dragonboat_tpu.rsm.device_kv import DeviceKV
+
+ROWS = 3072          # 1024 groups x 3 replicas
+
+
+@pytest.fixture(scope="module")
+def topo():
+    import os
+
+    from jax.experimental import topologies
+
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    try:
+        return topologies.get_topology_desc(
+            platform="tpu", topology_name="v5e:2x2")
+    except Exception as e:
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
+    return SingleDeviceSharding(topo.devices[0])
+
+
+@pytest.fixture(autouse=True)
+def no_persistent_cache():
+    """A compile for a described chip is written to the persistent cache
+    but cannot be read back without the chip (the next run would warn and
+    compile again): keep these out of it."""
+    from jax.experimental.compilation_cache import compilation_cache as cc
+
+    jax.config.update("jax_enable_compilation_cache", False)
+    cc.reset_cache()
+    yield
+    jax.config.update("jax_enable_compilation_cache", True)
+    cc.reset_cache()
+
+
+@pytest.fixture
+def device_kp(monkeypatch):
+    """-> f(min_inbox): the KernelParams ``NodeHost._kernel_params`` picks
+    on a TPU with the default ExpertConfig (one-hot ring reads)."""
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    host = SimpleNamespace(config=SimpleNamespace(expert=ExpertConfig()))
+
+    def pick(min_inbox: int = 0):
+        kp = NodeHost._kernel_params(host, min_inbox)
+        assert kp.onehot_reads and kp.log_cap == 1024
+        return kp
+
+    return pick
+
+
+def _shapes(tree, sharding_of):
+    return jax.tree.map(
+        lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype,
+                                       sharding=sharding_of(x)), tree)
+
+
+def _step_args(kp, rows):
+    rids = np.tile(np.arange(1, 4, dtype=np.int32), rows // 3)
+    pids = np.zeros((rows, kp.num_peers), np.int32)
+    return jax.eval_shape(lambda: (init_state(kp, rows, rids, pids),
+                                   empty_inbox(kp, rows),
+                                   empty_input(kp, rows)))
+
+
+@pytest.mark.parametrize("entry", ["step", "step_donated"])
+def test_kernel_step_compiles_for_v5e(one_chip, device_kp, entry):
+    kp = device_kp()
+    args = _shapes(_step_args(kp, ROWS), lambda x: one_chip)
+    compiled = getattr(kernel, entry).lower(kp, *args).compile()
+    mem = compiled.memory_analysis()
+    assert mem.temp_size_in_bytes + mem.argument_size_in_bytes < 16e9
+
+
+@pytest.mark.parametrize("entry", ["jit_serve_step",
+                                   "jit_serve_step_donated"])
+def test_mesh_serve_step_compiles_for_v5e(topo, device_kp, entry):
+    """1x3 mesh of described chips, 48 group lanes per replica slot — the
+    geometry ``chip_smoke.py --chips 4`` serves — and the collectives are
+    in the program."""
+    kp = device_kp(min_inbox=10)    # as NodeHost._inject_mesh_shard asks
+    mesh = Mesh(np.array(topo.devices[:3]).reshape(1, 3), ("g", "r"))
+    cl = ici.IciCluster(kp=kp, mesh=mesh, replicas=3, n_local=48,
+                        num_groups=48)
+    state, box, inp = _shapes(_step_args(kp, cl.total_rows),
+                              lambda x: cl.sharding(x.ndim - 1))
+    cut = jax.ShapeDtypeStruct((cl.total_rows, kp.num_peers), bool,
+                               sharding=cl.sharding(1))
+    hlo = getattr(ici, entry).lower(
+        kp, cl, state, box, inp, cut).compile().as_text()
+    assert "all-gather" in hlo
+
+
+def _one(one_chip, shape, dtype=jnp.int32):
+    return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+
+def test_gather_pallas_compiles_for_v5e(one_chip):
+    hlo = fabric_pallas._gather_pallas.lower(
+        _one(one_chip, (ROWS, 15)), _one(one_chip, (ROWS, 10)), False,
+    ).compile().as_text()
+    assert "tpu_custom_call" in hlo
+
+
+def test_quorum_pallas_compiles_for_v5e(one_chip):
+    hlo = fabric_pallas._quorum_pallas.lower(
+        _one(one_chip, (ROWS, 3)), _one(one_chip, (ROWS, 3), bool),
+        _one(one_chip, (ROWS,)), False,
+    ).compile().as_text()
+    assert "tpu_custom_call" in hlo
+
+
+def test_device_kv_apply_pallas_compiles_for_v5e(one_chip):
+    kv = DeviceKV(table_cap=1024)
+    G, AB = 1024, 32
+    hlo = device_kv_pallas._apply_pallas.lower(
+        kv, False, _one(one_chip, (G, 1024)), _one(one_chip, (G, 1024)),
+        _one(one_chip, (G,)), _one(one_chip, (G, AB, 2)),
+        _one(one_chip, (G, AB), bool),
+    ).compile().as_text()
+    assert "tpu_custom_call" in hlo

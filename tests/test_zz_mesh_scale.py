@@ -23,7 +23,7 @@ N_MESH = 256
 REPLICAS = 4          # g_size 2 x replicas 4 = all 8 virtual devices
 
 
-def test_mesh_256_groups_8_devices_mixed_residency_concurrent_ops():
+def test_mesh_256_groups_8_devices_mixed_residency_concurrent_ops(one_core):
     prefix = f"m256-{time.monotonic_ns()}"
     spec = MeshSpec(name=prefix, g_size=2, replicas=REPLICAS, n_local=128)
     addrs = {i: f"{prefix}-{i}" for i in range(1, REPLICAS + 1)}
