@@ -53,9 +53,7 @@ from dragonboat_tpu.config import (
 from dragonboat_tpu.core.kstate import empty_input
 from dragonboat_tpu.nodehost import NodeHost
 from dragonboat_tpu.parallel import ici
-from dragonboat_tpu.request import (
-    RequestDroppedError, RequestTimeoutError,
-)
+from dragonboat_tpu.request import RequestDroppedError
 from dragonboat_tpu.statemachine import IStateMachine, Result
 
 REPLICAS = 3
@@ -86,10 +84,6 @@ def parse_args(argv):
                          "only scale knob — geometry, replicas, fsync and "
                          "read-back are never cut")
     ap.add_argument("--seed", type=int, default=23)
-    ap.add_argument("--pipeline-depth", type=int, default=None,
-                    help="ExpertConfig.kernel_pipeline_depth (default: the "
-                         "config's own; 1 dispatches through the donated "
-                         "entries)")
     ap.add_argument("--rehearse", action="store_true",
                     help="run on the CPU backend at a small size to find "
                          "wrong paths; prints no contract line")
@@ -156,14 +150,15 @@ def seeded_writes(seed: int, shards, per_shard: int):
 
 
 def retrying(fn, deadline_s: float = 60.0):
-    """Call ``fn`` until it stops raising the transient not-ready /
-    timed-out errors users must retry (SKILL.md flow 4); re-raises once
-    the deadline passes.  -> (result, retries)."""
+    """Call ``fn`` until it stops raising the transient not-ready error
+    users must retry (SKILL.md flow 4); re-raises once the deadline passes.
+    Any other error, a timeout included, fails the phase.
+    -> (result, retries)."""
     end, retries = time.time() + deadline_s, 0
     while True:
         try:
             return fn(), retries
-        except (RequestDroppedError, RequestTimeoutError):
+        except RequestDroppedError:
             if time.time() > end:
                 raise
             retries += 1
@@ -288,8 +283,8 @@ def drive_clients(label, hosts, leaders, writes) -> dict:
     for sid, key, val in writes:
         by_shard.setdefault(sid, []).append((key, val))
 
-    def write_shard(sid: int) -> int:
-        retries = 0
+    def write_shard(sid: int) -> tuple[int, int]:
+        acked = retries = 0
         sess = hosts[leaders[sid]].get_noop_session(sid)
         for key, val in by_shard[sid]:
             def propose(cmd=f"{key}={val}".encode()):
@@ -297,9 +292,10 @@ def drive_clients(label, hosts, leaders, writes) -> dict:
                 if ok and lid in hosts:
                     leaders[sid] = lid
                 return hosts[leaders[sid]].sync_propose(
-                    sess, cmd, timeout_s=10)
+                    sess, cmd, timeout_s=30)
             retries += retrying(propose)[1]
-        return retries
+            acked += 1      # sync_propose returned: quorum-committed, applied
+        return acked, retries
 
     def read_shard(sid: int) -> tuple[dict, int]:
         retries, got = 0, {}
@@ -307,7 +303,7 @@ def drive_clients(label, hosts, leaders, writes) -> dict:
         for key, val in by_shard[sid]:
             for rid in (lead, lead % REPLICAS + 1):
                 ans, r = retrying(lambda: hosts[rid].sync_read(
-                    sid, key, timeout_s=10))
+                    sid, key, timeout_s=30))
                 retries += r
                 check(ans == val,
                       f"{label}: shard {sid} {key}: host {rid} read "
@@ -318,8 +314,10 @@ def drive_clients(label, hosts, leaders, writes) -> dict:
     answers: dict = {}
     with ThreadPoolExecutor(max_workers=CLIENT_THREADS) as pool:
         t0 = time.time()
-        retries = sum(pool.map(write_shard, by_shard))
+        acked, retries = map(sum, zip(*pool.map(write_shard, by_shard)))
         write_s = time.time() - t0
+        check(acked == len(writes),
+              f"{label}: {acked} of {len(writes)} writes acknowledged")
         stages = stage_medians()    # before the reads crowd the ring
         t0 = time.time()
         for got, r in pool.map(read_shard, by_shard):
@@ -328,7 +326,7 @@ def drive_clients(label, hosts, leaders, writes) -> dict:
         read_s = time.time() - t0
     return answers, dict(
         writes_attempted=len(writes),
-        writes_acknowledged=len(writes),  # an unacked write raised above
+        writes_acknowledged=acked,
         reads_checked=2 * len(writes), transient_retries=retries,
         write_s=round(write_s, 3), read_s=round(read_s, 3),
         write_stage_us_median=stages)
@@ -419,8 +417,6 @@ def main() -> None:
     writes = seeded_writes(args.seed, shards, WRITES_PER_SHARD)
     reference = {(sid, key): val for sid, key, val in writes}
     knobs = {"trace_sample_every": 1}
-    if args.pipeline_depth is not None:
-        knobs["kernel_pipeline_depth"] = args.pipeline_depth
 
     root = tempfile.mkdtemp(prefix="chip-smoke-")
     try:

@@ -28,6 +28,17 @@ from dragonboat_tpu.hostenv import enable_compile_cache  # noqa: E402
 enable_compile_cache()
 
 
+def _set_affinity_all_threads(cpus) -> None:
+    """sched_setaffinity acts on ONE thread; apply it to every thread this
+    process has (a thread that exits between the listing and the call is
+    skipped)."""
+    for tid in os.listdir("/proc/self/task"):
+        try:
+            os.sched_setaffinity(int(tid), cpus)
+        except OSError:
+            pass
+
+
 @pytest.fixture
 def one_core():
     """Keep the test's threads on one core (under xdist, worker gwN takes
@@ -36,29 +47,45 @@ def one_core():
     cluster — convoy on the interpreter lock when the scheduler spreads
     them over many cores: the same 4-host mesh start-up took 48 s pinned to
     one core and 265 s on eight (PERF.md, PR 23).  Threads the test starts
-    inherit the pin; it is lifted when the test ends."""
+    inherit the pin from the thread that spawns them, and some outlive the
+    test (XLA's compile pool, process-global meters), so the teardown
+    restores the affinity of EVERY thread of the process, not only the
+    caller's."""
     if not hasattr(os, "sched_setaffinity"):
         yield
         return
     before = os.sched_getaffinity(0)
     cpus = sorted(before)
     gw = os.environ.get("PYTEST_XDIST_WORKER", "gw0")[2:]
-    os.sched_setaffinity(0, {cpus[int(gw) % len(cpus) if gw.isdigit() else 0]})
+    _set_affinity_all_threads(
+        {cpus[int(gw) % len(cpus) if gw.isdigit() else 0]})
     try:
         yield
     finally:
-        os.sched_setaffinity(0, before)
+        _set_affinity_all_threads(before)
 
 
-# -- one retry for a failed test ----------------------------------------------
+# -- one-retry for timing-sensitive E2E modules -------------------------------
 # The multi-NodeHost E2E tests run dozens of engine threads against
-# wall-clock deadlines and occasionally miss them under full-suite load —
-# most of all at the start of a run in a fresh checkout, where the compile
-# cache is cold and every xdist worker compiles at once.  Which module the
-# load lands on is chance (test_chaos_hotspot and test_durable_nodehost each
-# lost one test in PR 23's runs), so the retry covers every module rather
-# than a hand-kept list: a failed test is retried once — a deterministic
-# regression still fails twice and stays red.
+# wall-clock deadlines and occasionally miss them under full-suite load.  A
+# failed test from these modules is retried once — a deterministic
+# regression still fails twice and stays red.  Every other module (kernel
+# differentials, model check, lint, codecs) gets no second attempt: a test
+# there that fails one run in two is a bug to find, not load.
+
+_RETRY_MODULES = (
+    "test_nodehost", "test_node_ops", "test_tcp_transport", "test_gossip",
+    "test_durable_nodehost", "test_monkey", "test_vfs",
+    "test_snapshot_stream", "test_kernel_engine", "test_tools",
+    "test_history", "test_tan", "test_encoded", "test_examples",
+    "test_chaos_faults", "test_chaos_schedules", "test_health",
+    # added in PR 23: its control loop keys on a 30 ms step-latency EWMA and
+    # 30-45 s windows; beside five busy workers on an 8-core box seed 11
+    # failed 1 run in 3-4 (pinned or not), either with no transfer planned
+    # or with followers one entry behind for the whole 45 s convergence
+    # window (PERF.md, Open questions).  It also runs last (below).
+    "test_chaos_hotspot",
+)
 
 # module -> number of tests that needed the second attempt, THIS process.
 # The silent-rerun policy above hides flake from the pass/fail signal, so
@@ -75,6 +102,8 @@ _RETRY_REPORT = os.path.join(os.path.dirname(__file__),
 def pytest_runtest_protocol(item, nextitem):
     from _pytest.runner import runtestprotocol
 
+    if item.module.__name__ not in _RETRY_MODULES:
+        return None
     item.ihook.pytest_runtest_logstart(nodeid=item.nodeid,
                                        location=item.location)
     reports = runtestprotocol(item, nextitem=nextitem, log=False)
@@ -222,3 +251,7 @@ def pytest_collection_modifyitems(session, config, items):
     if big:
         rest = [it for it in items if "test_zz_" not in it.nodeid]
         items[:] = big + rest
+    # ...and the most load-sensitive file runs LAST: under ``--dist
+    # loadfile`` the last file goes to the first worker that runs dry, when
+    # most of the others are draining or idle, not beside five busy ones
+    items.sort(key=lambda it: "test_chaos_hotspot" in it.nodeid)

@@ -20,6 +20,9 @@ must re-arm from the sticky lease instead of being lost
 Budget: ~22 s per seed; two fixed seeds ride tier-1 as ``chaos_fast``.
 """
 
+import os
+import threading
+
 import pytest
 
 from dragonboat_tpu.chaos import run_hotspot
@@ -41,3 +44,30 @@ def test_hotspot_drains_and_converges(seed, one_core):
         ev = t.get("evidence", {})
         assert {"obs", "lane", "score", "lag", "streak",
                 "term"} <= set(ev), (seed, t)
+
+
+def test_one_core_pin_is_lifted_from_threads_born_under_it():
+    """``one_core``'s teardown must reach threads that were started while
+    the pin held (they inherit it, and sched_setaffinity on the caller
+    alone would leave them on one core for the rest of the xdist worker's
+    life)."""
+    import conftest
+
+    before = os.sched_getaffinity(0)
+    born, stop = [], threading.Event()
+    conftest._set_affinity_all_threads({min(before)})
+    try:
+        t = threading.Thread(target=lambda: (
+            born.append(threading.get_native_id()), stop.wait()))
+        t.start()
+        while not born:
+            pass
+        assert os.sched_getaffinity(born[0]) == {min(before)}
+    finally:
+        conftest._set_affinity_all_threads(before)
+    try:
+        assert os.sched_getaffinity(born[0]) == before
+        assert os.sched_getaffinity(0) == before
+    finally:
+        stop.set()
+        t.join()

@@ -22,6 +22,7 @@ from dragonboat_tpu.server.env import (
     NotOwnerError,
 )
 
+from test_kernel_engine import propose_retry
 from test_nodehost import KVStateMachine, wait_leader
 
 
@@ -148,9 +149,10 @@ def test_cluster_restart_from_disk(tmp_path):
         # recovered data (snapshot + log replay through the RSM)
         for i in range(25):
             assert hosts2[lead].stale_read(1, f"k{i}") == f"v{i}", i
-        # the cluster is live again
+        # the cluster is live again (a proposal right after the restart's
+        # election may be dropped once: retried, as users are told to)
         nh = hosts2[lead]
-        nh.sync_propose(nh.get_noop_session(1), b"post=restart")
+        propose_retry(nh, nh.get_noop_session(1), b"post=restart")
         assert nh.sync_read(1, "post") == "restart"
     finally:
         for h in hosts2.values():

@@ -155,12 +155,23 @@ def test_cluster_quiesces_and_wakes():
 def test_leader_transfer_future_completes():
     hosts = make_cluster(prefix="xfer")
     try:
-        lead = wait_leader(hosts)
-        target = next(r for r in hosts if r != lead)
-        node = hosts[lead].nodes[1]
-        rs = node.request_leader_transfer(target, 1000)
-        hosts[lead]._work.set()
-        r = rs.wait(10.0)
+        # raft abandons a transfer that does not land inside one election
+        # timeout (10 ticks of 5 ms here), and the future then times out:
+        # "the timeout is the failure signal" (node._on_leader_update), and
+        # the caller asks again, on whoever leads by then.  Under a loaded
+        # box the first attempt can miss its 50 ms; one attempt must
+        # complete with the target it named.
+        for _ in range(5):
+            lead = wait_leader(hosts)
+            target = next(r for r in hosts if r != lead)
+            rs = hosts[lead].nodes[1].request_leader_transfer(target, 400)
+            hosts[lead]._work.set()
+            # the book holds one request at a time and frees the slot at
+            # the 400-tick deadline: outwait it rather than race it
+            r = rs.wait(30.0)
+            if r.code.name == "COMPLETED":
+                break
+            time.sleep(0.1)     # let raft drop the abandoned transfer
         assert r.code.name == "COMPLETED", r.code
         assert r.result.value == target
         assert wait_leader(hosts) == target
