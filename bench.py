@@ -1237,7 +1237,7 @@ def run_elastic_ab() -> None:
 
     import jax
 
-    from dragonboat_tpu import flight
+    from dragonboat_tpu import flight, telemetry
     from dragonboat_tpu.chaos.runner import (
         _Cluster, HotspotKV, HOTSPOT_HOT_EWMA_US, HOTSPOT_MAX_PENDING,
         HOTSPOT_SKEW)
@@ -1455,12 +1455,10 @@ def run_elastic_ab() -> None:
             _t.sleep(1.0)
 
             def step_totals() -> tuple[int, int]:
-                steps = us = 0
-                for nh in hosts.values():
-                    snap = nh.events.metrics.snapshot()
-                    steps += snap.get("engine.kernel_step.steps", 0)
-                    us += snap.get("engine.kernel_step.total_us", 0)
-                return steps, us
+                # the round timer's histogram: every engine of the process
+                snap = telemetry.GLOBAL.snapshot()
+                return (snap.get("engine_round_us.count{phase=total}", 0),
+                        int(snap.get("engine_round_us.sum{phase=total}", 0)))
 
             def measure() -> dict:
                 s0, u0 = step_totals()
@@ -1750,7 +1748,7 @@ def run_trace_ab() -> None:
 
     import jax
 
-    from dragonboat_tpu import lifecycle
+    from dragonboat_tpu import lifecycle, telemetry
     from dragonboat_tpu.client import Session
     from dragonboat_tpu.config import Config, ExpertConfig, NodeHostConfig
     from dragonboat_tpu.nodehost import NodeHost
@@ -1836,12 +1834,10 @@ def run_trace_ab() -> None:
         _t.sleep(1.0)    # settle: windows full, elections over
 
         def step_totals() -> tuple[int, int]:
-            steps = us = 0
-            for nh in hosts.values():
-                snap = nh.events.metrics.snapshot()
-                steps += snap.get("engine.kernel_step.steps", 0)
-                us += snap.get("engine.kernel_step.total_us", 0)
-            return steps, us
+            # the round timer's histogram: every engine of the process
+            snap = telemetry.GLOBAL.snapshot()
+            return (snap.get("engine_round_us.count{phase=total}", 0),
+                    int(snap.get("engine_round_us.sum{phase=total}", 0)))
 
         def measure(sample_every: int) -> dict:
             lifecycle.TRACER.configure(sample_every=sample_every)
@@ -1920,7 +1916,7 @@ def run_fabric_ab() -> None:
 
     import jax
 
-    from dragonboat_tpu import fabric, lifecycle
+    from dragonboat_tpu import fabric, lifecycle, telemetry
     from dragonboat_tpu.client import Session
     from dragonboat_tpu.config import Config, ExpertConfig, NodeHostConfig
     from dragonboat_tpu.nodehost import NodeHost
@@ -2007,12 +2003,10 @@ def run_fabric_ab() -> None:
         _t.sleep(1.0)    # settle: windows full, elections over
 
         def step_totals() -> tuple[int, int]:
-            steps = us = 0
-            for nh in hosts.values():
-                snap = nh.events.metrics.snapshot()
-                steps += snap.get("engine.kernel_step.steps", 0)
-                us += snap.get("engine.kernel_step.total_us", 0)
-            return steps, us
+            # the round timer's histogram: every engine of the process
+            snap = telemetry.GLOBAL.snapshot()
+            return (snap.get("engine_round_us.count{phase=total}", 0),
+                    int(snap.get("engine_round_us.sum{phase=total}", 0)))
 
         def measure(sample_every: int, fabric_on: bool) -> dict:
             lifecycle.TRACER.configure(sample_every=sample_every)

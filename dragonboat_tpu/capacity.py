@@ -56,7 +56,7 @@ import jax
 
 from dragonboat_tpu import flight as _flight
 from dragonboat_tpu import telemetry as _telemetry
-from dragonboat_tpu.tracing import monotonic_us
+from dragonboat_tpu.tracing import current_phase, monotonic_us
 
 # ---------------------------------------------------------------------------
 # contracts-derived capacity model
@@ -413,6 +413,47 @@ class CompileTracker:
 #: one ring, so one export shows compiles across all engines
 TRACKER = CompileTracker()
 
+
+class CompileListener:
+    """Every XLA compile of the process, wrapped entry or not, off
+    ``jax.monitoring``'s duration event: the tracker above sees only the
+    jit entries an engine wraps, and jax also compiles small programs per
+    shape (the ``state.lt[idx]`` gather of a round's saved rows, the
+    per-lane constants of a lane injection) wherever the shape first
+    shows.  ``xla_compiles{phase}`` / ``xla_compile_us{phase}`` label
+    each with the engine-round phase of the compiling thread
+    (``phase_of``, ``tracing.current_phase``: ``none`` outside a round).
+    The event also fires for a program loaded from the persistent cache:
+    the duration is then the load."""
+
+    EVENT = "/jax/core/compile/backend_compile_duration"
+
+    def __init__(self, registry=None, phase_of=current_phase) -> None:
+        reg = registry if registry is not None else _telemetry.GLOBAL
+        self._phase_of = phase_of
+        self._count = reg.counter(
+            "xla_compiles",
+            help="XLA backend compiles (or cache loads) by the engine-"
+                 "round phase of the compiling thread",
+            labelnames=("phase",))
+        self._hist = reg.histogram(
+            "xla_compile_us",
+            help="XLA backend compile (or cache load) time by phase",
+            labelnames=("phase",))
+
+    def __call__(self, event: str, secs: float, **_kw) -> None:
+        if event != self.EVENT:
+            return
+        phase = self._phase_of()
+        self._count.labels(phase).inc()
+        self._hist.labels(phase).observe(secs * 1e6)
+
+
+#: registered once per process, at import (jax keeps listeners for the
+#: process's life; there is nothing to unregister at engine close)
+COMPILES = CompileListener()
+jax.monitoring.register_event_duration_secs_listener(COMPILES)
+
 #: flight-record kinds this rail emits (declared in flight.py beside the
 #: core transition kinds; re-exported here for callers of this module)
 RETRACE_STORM = _flight.RETRACE_STORM
@@ -425,16 +466,20 @@ MEMORY_PRESSURE = _flight.MEMORY_PRESSURE
 
 
 class _SanctionedCrossing:
-    """One declared boundary crossing: counts its tag, and — only while
-    a disallow guard is active — re-allows transfers for its extent so
+    """One declared boundary crossing: counts its tag, times its own
+    extent into ``device_crossing_us{tag=...}`` (a download blocks until
+    the device has the value, so this is where an engine round's wait
+    for the device shows, crossing by crossing), and — only while a
+    disallow guard is active — re-allows transfers for its extent so
     everything OUTSIDE a sanctioned scope keeps raising."""
 
-    __slots__ = ("_meter", "_tag", "_cm")
+    __slots__ = ("_meter", "_tag", "_cm", "_t0")
 
     def __init__(self, meter: "TransferMeter", tag: str) -> None:
         self._meter = meter
         self._tag = tag
         self._cm = None
+        self._t0 = 0
 
     def __enter__(self) -> "_SanctionedCrossing":
         m = self._meter
@@ -444,9 +489,12 @@ class _SanctionedCrossing:
         if guarding:
             self._cm = jax.transfer_guard("allow")
             self._cm.__enter__()
+        self._t0 = m._clock()
         return self
 
     def __exit__(self, *exc) -> bool:
+        m = self._meter
+        m._hist.labels(self._tag).observe(m._clock() - self._t0)
         cm, self._cm = self._cm, None
         if cm is not None:
             return bool(cm.__exit__(*exc))
@@ -489,10 +537,18 @@ class TransferMeter:
     static TRANSFER_LEDGER — an unsanctioned implicit transfer raises,
     a sanctioned one is tallied under its declared tag."""
 
-    def __init__(self) -> None:
+    def __init__(self, clock=None, registry=None) -> None:
         self.mu = threading.Lock()
         self._counts: dict = {}    # guarded-by: mu  (tag -> crossings)
         self._guard_depth = 0      # guarded-by: mu
+        # injected microsecond clock, as the tracker's
+        self._clock = clock if clock is not None else monotonic_us
+        self._hist = (registry if registry is not None
+                      else _telemetry.GLOBAL).histogram(
+            "device_crossing_us",
+            help="host time inside one sanctioned host<->device "
+                 "crossing, by its declared tag",
+            labelnames=("tag",))
 
     def sanctioned(self, tag: str) -> _SanctionedCrossing:
         """Context manager for one declared crossing (see class doc)."""

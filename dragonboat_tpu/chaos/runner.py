@@ -781,8 +781,9 @@ def run_detector_differential(seed: int, fault: str | None = None,
 # The elastic controller (control.py) is itself under chaos test: a
 # zipfian proposal skew (HOTSPOT_SKEW:1) lands on ONE seeded-choice
 # shard whose apply path is deliberately slow.  The engine retires
-# apply outputs inside its step-timer window, so the backlog throttles
-# the whole engine round and the hosts' step-latency EWMA
+# apply outputs inside the round its round timer times (tracing.
+# RoundTimer, phase finish), so the backlog throttles the whole engine
+# round and the hosts' round-time EWMA
 # (engine.kernel_step.ewma_us) climbs an order of magnitude — the
 # host_hot signal the controller keys on (device commit→apply lag
 # stays flow-controlled to a constant window, so lag_divergence is by

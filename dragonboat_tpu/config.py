@@ -220,9 +220,10 @@ class ExpertConfig:
     # observations during which the host-hot latency input is ignored
     # (jit compile inflates the step EWMA at process start)
     control_warmup_obs: int = 8
-    # host-hot gate for the controller: engine step-latency EWMA
-    # (engine.kernel_step.ewma_us — the measure() window includes
-    # output retirement, so apply backpressure shows up here) above
+    # host-hot gate for the controller: engine round EWMA
+    # (engine.kernel_step.ewma_us, fed by the round timer's total —
+    # stage to finish, so output retirement is in it and apply
+    # backpressure shows up here) above
     # this marks every led shard a drain candidate; 0 disables the
     # latency input
     control_hot_ewma_us: int = 0

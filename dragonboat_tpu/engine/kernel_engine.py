@@ -53,7 +53,13 @@ import numpy as np
 from dragonboat_tpu import capacity as _capacity
 from dragonboat_tpu import lifecycle
 from dragonboat_tpu import raftpb as pb
-from dragonboat_tpu.tracing import annotate, stop_env_trace
+from dragonboat_tpu import telemetry
+from dragonboat_tpu.tracing import (
+    RoundTimer,
+    maybe_start_from_env,
+    monotonic_us,
+    stop_env_trace,
+)
 from dragonboat_tpu.config import Config
 from dragonboat_tpu.core import params as KP
 from dragonboat_tpu.core.kernel import (
@@ -94,6 +100,35 @@ _KERNEL_MTYPES = frozenset({
 _F = {c: i for i, c in enumerate(FLAG_CLASSES)}
 _F_RESP, _F_REP, _F_HB, _F_VOTE = _F["resp"], _F["rep"], _F["hb"], _F["vote"]
 _F_TIMEOUT, _F_WITSNAP, _F_RTR = _F["timeout_now"], _F["wit_snap"], _F["rtr"]
+
+# admission at the staging boundary, all engines of the process
+# (telemetry.GLOBAL, where the round timer's histograms live)
+_PROPS_STAGED = telemetry.GLOBAL.counter(
+    "engine_props_staged",
+    help="proposals (config changes included) given a prop slot")
+_PROPS_DEFERRED = telemetry.GLOBAL.counter(
+    "engine_props_deferred",
+    help="proposals a staging pass put back for want of a prop slot")
+_PROP_SLOTS_OFFERED = telemetry.GLOBAL.counter(
+    "engine_prop_slots_offered",
+    help="prop slots (proposal_cap) of every row that staged at least "
+         "one proposal in a round")
+_READ_STAGE_WAIT_US = telemetry.GLOBAL.histogram(
+    "read_stage_wait_us",
+    help="one observation per ReadIndex context staged: its wait for "
+         "the staging, from the enqueue of its batch's first read (a "
+         "forwarded one: from its arrival at this host)")
+# lane admission, beside nodehost_start_replica_us: the caller's wait for
+# the engine lock in add_shard (a round holds it, flushes of earlier
+# lanes included; part of start_replica's ``stage``), and one
+# _flush_injections batch on the engine thread (inside a round's ``stage``)
+ADD_SHARD_LOCK_US = telemetry.GLOBAL.histogram(
+    "engine_add_shard_lock_us",
+    help="add_shard's wait for the engine lock, per call")
+_INJECT_FLUSH_US = telemetry.GLOBAL.histogram(
+    "engine_inject_flush_us",
+    help="one batch of queued lane injections written into the device "
+         "state, on the engine thread")
 
 
 class _LazyOut:
@@ -160,7 +195,8 @@ class KernelNode(Node):
         self._staged_props: list[tuple[pb.Entry, "KernelNode"]] = []
         self._staged_ri: pb.SystemCtx | None = None
         # remote ReadIndex ctxs forwarded from follower hosts, FIFO
-        self._remote_reads: list[tuple[int, pb.SystemCtx]] = []
+        # (sender, ctx, arrival at this host on tracing.monotonic_us)
+        self._remote_reads: list[tuple[int, pb.SystemCtx, int]] = []
         # ctx.low -> requesting replica, for remote reads riding the
         # quorum path (answered when the rtr lane lands, steps later)
         self._remote_ri_inflight: dict[int, int] = {}
@@ -320,7 +356,8 @@ class KernelEngine:
                  health_thresholds=None,
                  invariant_probe: bool = True,
                  capacity_watermark_pct: float = 10.0,
-                 capacity_budget_bytes: int = 0) -> None:
+                 capacity_budget_bytes: int = 0,
+                 label: str = "") -> None:
         self.kp = kp
         self.capacity = capacity
         self.send_message = send_message
@@ -408,11 +445,15 @@ class KernelEngine:
         # a previous step was still unretired at its staging
         self._pipe_steps = 0
         self._pipe_overlapped = 0
-        # step-latency accounting + opt-in jax.profiler capture
-        from dragonboat_tpu.tracing import StepTimer, maybe_start_from_env
-
-        self._step_timer = StepTimer(self.events.metrics,
-                                     "engine.kernel_step")
+        # the round timer (tracing.RoundTimer; ``label`` names this
+        # engine in its records and annotations: the owning host's id) +
+        # opt-in jax.profiler capture
+        self._round = RoundTimer(self.events.metrics, "engine.kernel_step",
+                                 engine=label or f"engine-{id(self):x}")
+        # staging counts of the round being staged (the round's record)
+        self._props_staged = 0
+        self._props_deferred = 0
+        self._reads_staged = 0
         maybe_start_from_env()
         self.events.metrics.set("engine.pipeline.depth", self.pipeline_depth)
         # decimated device-side fleet telemetry (core/fleet.py): every N
@@ -490,7 +531,9 @@ class KernelEngine:
         happens under the engine lock: a concurrent step must never run
         between registration and injection (it would write back a stepped
         pre-injection state, clobbering the lane)."""
+        t0 = monotonic_us()
         with self.mu:
+            ADD_SHARD_LOCK_US.observe(monotonic_us() - t0)
             if not self._free:
                 raise RuntimeError("kernel engine is at capacity")
             lane = self._free.pop()
@@ -549,6 +592,7 @@ class KernelEngine:
         O(n·capacity)."""
         if not self._pending_inject:
             return
+        t0 = monotonic_us()
         kp = self.kp
         items = sorted(self._pending_inject.items())
         self._pending_inject = {}
@@ -664,6 +708,7 @@ class KernelEngine:
                 quiesced=put(s.quiesced, False),
                 quiesce_epoch=put(s.quiesce_epoch, 0),
             )
+        _INJECT_FLUSH_US.observe(monotonic_us() - t0)
 
     def _clear_lane(self, lane: int) -> None:
         self._inv_dirty.add(lane)
@@ -768,7 +813,12 @@ class KernelEngine:
         the device, and it must run BEFORE (3) dispatches step N with
         donated buffers, because retiring reads previous-state leaves
         (lt rows, the wit-snap floor) that donation hands to XLA."""
-        with self.mu:
+        # the round timer (tracing.RoundTimer): ``stage`` starts once the
+        # lock is held; a pass that returns False, or raises, leaves the
+        # block with its round uncommitted and records nothing
+        with self.mu, self._round as rt:
+            self._props_staged = self._props_deferred = 0
+            self._reads_staged = 0
             nodes = dict(self.nodes)
             if not nodes:
                 if self._pending_ctx is not None:
@@ -781,8 +831,9 @@ class KernelEngine:
                             self._scrub_pending_ctx(n)
                             self._drop_staged_fates(n)
                     ctx, self._pending_ctx = self._pending_ctx, None
-                    with annotate("kernel_engine.process_outputs"):
+                    with rt.within("kernel_engine.process_outputs"):
                         self._process_outputs(ctx)
+                    self._commit_round(0, ())
                     return True
                 return False
             self._flush_injections()
@@ -847,8 +898,9 @@ class KernelEngine:
                     # and events, and retiring re-dirties its lanes so
                     # follow-on work stages next iteration
                     ctx, self._pending_ctx = self._pending_ctx, None
-                    with annotate("kernel_engine.process_outputs"):
+                    with rt.within("kernel_engine.process_outputs"):
                         self._process_outputs(ctx)
+                    self._commit_round(len(staged), ())
                     return True
                 return False
 
@@ -864,58 +916,73 @@ class KernelEngine:
                 ctx.traced = [e.key for fl in ctx.fates.values()
                               for e, _origin in fl
                               if e.key and lifecycle.TRACER.sampled(e.key)]
-            with self._step_timer.measure():
-                overlapped = self._pending_ctx is not None
-                if overlapped:
-                    # retire step N-1 BEFORE the donating dispatch of N
-                    pending, self._pending_ctx = self._pending_ctx, None
-                    with annotate("kernel_engine.process_outputs"):
-                        self._process_outputs(pending)
-                with annotate("kernel_engine.step"):
-                    if not self._compiled_once:
-                        # serialize FIRST calls across engines (incl. the
-                        # mesh override): concurrent jit compiles from
-                        # several engine threads have segfaulted XLA:CPU
-                        # (2026-07-31); once the executable is cached the
-                        # lock is never touched again
-                        with KernelEngine._first_compile_mu:
-                            state, out = self._kernel_call(inbox, inp)
-                        self._compiled_once = True
-                    else:
+            # ``stage`` runs on to the upload; ``within`` ends its
+            # annotation before either of the two older ones opens
+            overlapped = self._pending_ctx is not None
+            if overlapped:
+                # retire step N-1 BEFORE the donating dispatch of N
+                pending, self._pending_ctx = self._pending_ctx, None
+                with rt.within("kernel_engine.process_outputs"):
+                    self._process_outputs(pending)
+            with rt.within("kernel_engine.step"):
+                rt.enter("upload")
+                if not self._compiled_once:
+                    # serialize FIRST calls across engines (incl. the
+                    # mesh override): concurrent jit compiles from
+                    # several engine threads have segfaulted XLA:CPU
+                    # (2026-07-31); once the executable is cached the
+                    # lock is never touched again
+                    with KernelEngine._first_compile_mu:
                         state, out = self._kernel_call(inbox, inp)
-                self.state = state
-                ctx.out = out
-                for k in ctx.traced:
-                    lifecycle.TRACER.stamp(k, lifecycle.STAGE_DISPATCH)
-                self._pipe_steps += 1
-                if self.pipeline_depth > 0:
-                    # defer the fetch: the outputs are consumed one step
-                    # late, overlapping device step N+1 with this retire
-                    self._pending_ctx = ctx
-                    self._buf_idx ^= 1
-                    if overlapped:
-                        self._pipe_overlapped += 1
-                    m = self.events.metrics
-                    m.inc("engine.pipeline.steps")
-                    if overlapped:
-                        m.inc("engine.pipeline.overlapped")
-                    m.set("engine.pipeline.occupancy_pct",
-                          100 * self._pipe_overlapped
-                          // max(1, self._pipe_steps))
+                    self._compiled_once = True
                 else:
-                    with annotate("kernel_engine.process_outputs"):
-                        self._process_outputs(ctx)
+                    state, out = self._kernel_call(inbox, inp)
+            self.state = state
+            ctx.out = out
+            for k in ctx.traced:
+                lifecycle.TRACER.stamp(k, lifecycle.STAGE_DISPATCH)
+            self._pipe_steps += 1
+            if self.pipeline_depth > 0:
+                # defer the fetch: the outputs are consumed one step
+                # late, overlapping device step N+1 with this retire
+                self._pending_ctx = ctx
+                self._buf_idx ^= 1
+                if overlapped:
+                    self._pipe_overlapped += 1
+                m = self.events.metrics
+                m.inc("engine.pipeline.steps")
+                if overlapped:
+                    m.inc("engine.pipeline.overlapped")
+                m.set("engine.pipeline.occupancy_pct",
+                      100 * self._pipe_overlapped
+                      // max(1, self._pipe_steps))
+            else:
+                with rt.within("kernel_engine.process_outputs"):
+                    self._process_outputs(ctx)
             if self.fleet_stats_every > 0:
                 self._fleet_countdown -= 1
                 if self._fleet_countdown <= 0:
                     self._fleet_countdown = self.fleet_stats_every
+                    rt.enter("finish")
                     self._collect_fleet_stats()
                     if self.health_top_k > 0:
                         self._collect_health()
                     if self.invariant_probe:
                         self._collect_invariants()
                     self._collect_capacity()
+            self._commit_round(len(staged), ctx.traced)
             return True
+
+    def _commit_round(self, lanes_staged: int, keys) -> None:
+        """Close the round timer's round: the staging counts and the
+        sampled lifecycle keys the round dispatched (``ctx.traced``; none
+        where it only retired a step) ride its record, which is how a
+        write's span names its round."""
+        self._round.commit(
+            props_staged=self._props_staged,
+            props_deferred=self._props_deferred,
+            reads_staged=self._reads_staged,
+            lanes_staged=lanes_staged, keys=list(keys))
 
     def _is_registered(self, n: KernelNode) -> bool:
         # identity, not membership: with a deferred (pipelined) output
@@ -1221,13 +1288,17 @@ class KernelEngine:
             n._process_compaction(compact_key)
 
         requeue: list[pb.Message] = []
+        arrived_us = None
         for m in msgs:
             if m.type == MT.LOCAL_TICK:
                 ticks += 1
             elif m.type == MT.READ_INDEX:
                 # a follower host forwarded a read (hint carries its ctx)
+                if arrived_us is None:
+                    arrived_us = monotonic_us()    # once per staging pass
                 n._remote_reads.append(
-                    (m.from_, pb.SystemCtx(low=m.hint, high=m.hint_high)))
+                    (m.from_, pb.SystemCtx(low=m.hint, high=m.hint_high),
+                     arrived_us))
             elif m.type == MT.READ_INDEX_RESP:
                 n._local_ri_pending.pop(m.hint, None)
                 n.pending_reads.add_ready(
@@ -1252,8 +1323,9 @@ class KernelEngine:
         # read, else the local batch (node.go:1296)
         n._staged_ri = None
         ri_from = 0
+        ri_since_us = 0         # when the staged ctx began to wait
         if n._remote_reads:
-            ri_from, ctx = n._remote_reads.pop(0)
+            ri_from, ctx, ri_since_us = n._remote_reads.pop(0)
             n._staged_ri = ctx
             n._remote_ri_inflight[ctx.low] = ri_from
             inp.read(g, ctx)
@@ -1265,6 +1337,7 @@ class KernelEngine:
                     n._staged_ri = ctx
                     n._local_ri_pending[ctx.low] = ctx
                     inp.read(g, ctx)
+                    ri_since_us = n.pending_reads.peeped_since_us
                 elif n._leader_cache != 0:
                     # forward to the leader host (raft.go ReadIndex
                     # leader forwarding)
@@ -1277,6 +1350,9 @@ class KernelEngine:
                     n.pending_reads.dropped(ctx)
                 work = True
         n._staged_ri_from = ri_from
+        if n._staged_ri is not None:
+            self._reads_staged += 1
+            _READ_STAGE_WAIT_US.observe(monotonic_us() - ri_since_us)
 
         if transfer is not None:
             inp.transfer(g, transfer)
@@ -1319,17 +1395,20 @@ class KernelEngine:
         node per slot so fates (drop/mirror) land on the right books."""
         tg, tn = self._prop_target(n)
         self._staged_rows.add(tg)
-        slot = self._slot_cursor.get(tg, 0)
+        slot = first = self._slot_cursor.get(tg, 0)
+        deferred = 0
         if cc_entry is not None:
             if slot < inp.B:
                 inp.prop(tg, slot, True)
                 tn._staged_props.append((cc_entry, n))
                 slot += 1
             else:
+                deferred += 1
                 with n.mu:
                     n.config_change_entry = n.config_change_entry or cc_entry
         for e in props:
             if slot >= inp.B:
+                deferred += 1
                 with n.mu:
                     n.incoming_proposals.append(e)
                 continue
@@ -1339,6 +1418,14 @@ class KernelEngine:
                 lifecycle.TRACER.stamp(e.key, lifecycle.STAGE_STAGE)
             slot += 1
         self._slot_cursor[tg] = slot
+        # admission counters: what this pass admitted and turned away,
+        # and the row's slots the first time the round fills one
+        self._props_staged += slot - first
+        self._props_deferred += deferred
+        _PROPS_STAGED.inc(slot - first)
+        _PROPS_DEFERRED.inc(deferred)
+        if first == 0 and slot > 0:
+            _PROP_SLOTS_OFFERED.inc(inp.B)
 
     def _peers_of(self, n: KernelNode) -> dict[int, str]:
         m = n.sm.get_membership()
@@ -1359,8 +1446,12 @@ class KernelEngine:
         the eager 42-field np.asarray sweep was ~80% of step wall clock
         at 20k lanes."""
         nodes, out = ctx.nodes, ctx.out
+        rt = self._round
         for k in ctx.traced:
             lifecycle.TRACER.stamp(k, lifecycle.STAGE_RETIRE)
+        # fetch: the flag matrix, the [G] scalars of the activity mask and
+        # the saved rows' term ring — where the host waits for the device
+        rt.enter("fetch")
         with _capacity.METER.sanctioned("output_flags"):
             flags = np.asarray(output_row_flags(out))
         # the dispatch backend derives drain-pending from the same flags
@@ -1418,6 +1509,7 @@ class KernelEngine:
                 lt_rows = dict(zip(save_rows,
                                    np.asarray(self.state.lt[idx])))
 
+        rt.enter("resolve")
         for g, n in cand:
             # 1. proposal fates (origin holds the future's books — on a
             # mesh engine forwarded proposals stage on the leader row)
@@ -1454,6 +1546,7 @@ class KernelEngine:
         for sender, m in replicates:
             self._send(sender, m)
         if updates:
+            rt.enter("save")
             # one batched fsync per LogDB (nodes of a shared mesh engine
             # belong to different NodeHosts, each with its own LogDB)
             by_db: dict[int, tuple[object, list]] = {}
@@ -1466,9 +1559,11 @@ class KernelEngine:
                                 e.key, lifecycle.STAGE_SAVE)
             for db, uds in by_db.values():
                 db.save_raft_state(uds, worker_id=0)
+            rt.enter("resolve")
         for sender, m in others:
             self._send(sender, m)
 
+        rt.enter("finish")
         for g, n in cand:
             # a whole-group eviction earlier in THIS loop (mesh engine)
             # already handed the sibling rows to host-resident successor

@@ -45,6 +45,7 @@ from dragonboat_tpu.config import MeshSpec
 from dragonboat_tpu.core import params as KP
 from dragonboat_tpu.core.kstate import init_state
 from dragonboat_tpu.engine.kernel_engine import (
+    ADD_SHARD_LOCK_US,
     KernelEngine,
     KernelNode,
     _F_WITSNAP,
@@ -53,6 +54,7 @@ from dragonboat_tpu.engine.kernel_engine import (
 )
 from dragonboat_tpu.logger import get_logger
 from dragonboat_tpu.parallel.ici import IciCluster
+from dragonboat_tpu.tracing import monotonic_us
 
 _LOG = get_logger("mesh_engine")
 
@@ -98,7 +100,8 @@ class MeshEngine(KernelEngine):
                          health_thresholds=health_thresholds,
                          invariant_probe=invariant_probe,
                          capacity_watermark_pct=capacity_watermark_pct,
-                         capacity_budget_bytes=capacity_budget_bytes)
+                         capacity_budget_bytes=capacity_budget_bytes,
+                         label=f"mesh:{spec.name}")
         # replica ids are fixed by the mesh addressing (route() targets
         # rid 1..R); rows keep them even while ABSENT
         rids = np.empty((total,), np.int32)
@@ -147,7 +150,9 @@ class MeshEngine(KernelEngine):
             raise ValueError(
                 f"mesh-resident shard {node.shard_id}: witness members "
                 f"are host-engine only")
+        t0 = monotonic_us()
         with self.mu:
+            ADD_SHARD_LOCK_US.observe(monotonic_us() - t0)
             lane = self._lane_of.get(node.shard_id)
             if lane is None:
                 if not self._free_lanes:

@@ -35,11 +35,10 @@ completion verb of the proposal book ends its span.
 from __future__ import annotations
 
 import threading
-from collections import deque
 
 from dragonboat_tpu import flight
 from dragonboat_tpu import telemetry
-from dragonboat_tpu.tracing import monotonic_us
+from dragonboat_tpu.tracing import TraceRing, monotonic_us
 
 # -- stage taxonomy (canonical order along the commit path) -----------------
 
@@ -83,6 +82,9 @@ ANNOTATION_OF = {
 }
 
 DEFAULT_SAMPLE_EVERY = 64
+# completed traces kept for /trace and pollers: at 1-in-4 sampling and
+# ~1k ops/s a 4 Hz poll sees ~60 new traces, so this is ~15 s of slack
+DEFAULT_RING_SIZE = 4096
 
 
 class _Span:
@@ -102,18 +104,17 @@ class LifecycleTracer:
     """Process-wide span book + completed-trace ring + sinks."""
 
     def __init__(self, sample_every: int = 0, clock=None,
-                 ring_size: int = 256, max_active: int = 4096,
+                 ring_size: int = DEFAULT_RING_SIZE,
+                 max_active: int = 4096,
                  slow_commit_us: int = 0, registry=None,
                  recorder=None) -> None:
-        if ring_size <= 0:
-            raise ValueError(f"ring_size must be positive, got {ring_size}")
         self.mu = threading.Lock()
         self._clock = clock if clock is not None else monotonic_us
         self._every = max(0, int(sample_every))
         self._slow_us = max(0, int(slow_commit_us))
         self._max_active = max(1, int(max_active))
         self._spans: dict[int, _Span] = {}          # guarded-by: mu
-        self._ring: deque = deque(maxlen=ring_size)  # guarded-by: mu
+        self._ring = TraceRing(ring_size)           # guarded-by: mu
         self._dropped = 0        # spans refused at the active cap
         self._scrubbed = 0       # spans ended without an ack
         self._finished = 0       # spans completed through finish()
@@ -276,13 +277,23 @@ class LifecycleTracer:
     def counts(self) -> dict:
         with self.mu:
             return {"active": len(self._spans), "finished": self._finished,
-                    "scrubbed": self._scrubbed, "dropped": self._dropped}
+                    "scrubbed": self._scrubbed, "dropped": self._dropped,
+                    "overwritten": self._ring.overwritten}
 
     def completed(self) -> list[dict]:
         """Retained completed traces, oldest first (fresh copies)."""
         with self.mu:
-            return [dict(tr, stamps=list(tr["stamps"]))
-                    for tr in self._ring]
+            traces = self._ring.snapshot()
+        # a retired trace is never written again: copy outside the lock,
+        # the stamping threads do not wait for a poller
+        return [dict(tr, stamps=list(tr["stamps"])) for tr in traces]
+
+    def drain(self) -> list[dict]:
+        """Return the retained completed traces, oldest first, and clear
+        the ring: a poller that drains sees each trace once, and a trace
+        the ring pushed out unread shows in ``counts()["overwritten"]``."""
+        with self.mu:
+            return self._ring.drain()
 
     def reset(self) -> None:
         """Drop spans, traces and counters (test isolation)."""

@@ -39,7 +39,8 @@ from dragonboat_tpu.request import (
 )
 from dragonboat_tpu.rsm.statemachine import StateMachine
 from dragonboat_tpu.statemachine import Result
-from dragonboat_tpu import fabric
+from dragonboat_tpu import fabric, telemetry
+from dragonboat_tpu.tracing import monotonic_us
 from dragonboat_tpu.transport.chan import ChanTransportFactory
 from dragonboat_tpu.transport.chunks import ChunkSink
 from dragonboat_tpu.transport.hub import TransportHub, _msg_size
@@ -48,6 +49,18 @@ from dragonboat_tpu.logger import get_logger
 _LOG = get_logger("nodehost")
 
 DEFAULT_TIMEOUT_S = 5.0
+
+#: where one ``start_replica`` call spends its time.  The phases are
+#: contiguous and sum to ``total``: ``open`` (admission, the host lock,
+#: the bootstrap record in LogDB), ``build`` (state machine, node, log
+#: replay, registry), ``stage`` (durable lane init, then the engine's
+#: ``add_shard``: its wait for the engine lock is
+#: ``engine_add_shard_lock_us``)
+START_REPLICA_US = telemetry.GLOBAL.histogram(
+    "nodehost_start_replica_us",
+    help="NodeHost.start_replica by phase: open, build, stage, and "
+         "their sum total",
+    labelnames=("phase",))
 
 
 class ShardNotFoundError(RequestError):
@@ -641,6 +654,7 @@ class NodeHost:
                       create_sm, cfg: Config) -> None:
         """StartReplica (nodehost.go:499) for a regular/concurrent SM
         factory ``create_sm(shard_id, replica_id)``."""
+        t0 = monotonic_us()    # phases: START_REPLICA_US
         cfg.validate()
         self._admit_replica(cfg)
         with self.mu:
@@ -658,6 +672,7 @@ class NodeHost:
             elif bootstrap.addresses and initial_members and not join:
                 if bootstrap.addresses != initial_members:
                     raise RequestError("initial members mismatch")
+            t_open = monotonic_us()
             user_sm = create_sm(cfg.shard_id, cfg.replica_id)
             sm = StateMachine(cfg.shard_id, cfg.replica_id, user_sm,
                               cfg.ordered_config_change,
@@ -696,6 +711,7 @@ class NodeHost:
             self.nodes[cfg.shard_id] = node
             self._replica_specs[cfg.shard_id] = (
                 dict(initial_members), join, create_sm, cfg)
+        t_build = monotonic_us()
         if mesh:
             self._inject_mesh_shard(node, members)
         elif device:
@@ -704,6 +720,10 @@ class NodeHost:
             self._inject_kernel_shard(node, members)
         self.events.node_ready(NodeInfo(cfg.shard_id, cfg.replica_id))
         self._work.set()
+        t_end = monotonic_us()
+        for phase, us in (("open", t_open - t0), ("build", t_build - t_open),
+                          ("stage", t_end - t_build), ("total", t_end - t0)):
+            START_REPLICA_US.labels(phase).observe(us)
 
     def stop_replica(self, shard_id: int) -> None:
         with self.mu:
@@ -791,7 +811,8 @@ class NodeHost:
                 health_thresholds=self._health_thresholds(),
                 invariant_probe=ex.invariant_probe,
                 capacity_watermark_pct=ex.capacity_watermark_pct,
-                capacity_budget_bytes=ex.capacity_device_budget_bytes)
+                capacity_budget_bytes=ex.capacity_device_budget_bytes,
+                label=self.id)
             self.kernel_engine.on_evict = self._on_kernel_evict
         init = self._build_lane_init(node, members)
         self._inject_into_engine(self.kernel_engine, node, init,

@@ -17,6 +17,7 @@ from enum import IntEnum
 from dragonboat_tpu import lifecycle
 from dragonboat_tpu import raftpb as pb
 from dragonboat_tpu.statemachine import Result
+from dragonboat_tpu.tracing import monotonic_us
 
 
 class RequestResultCode(IntEnum):
@@ -264,11 +265,18 @@ class PendingReadIndex(_ClockedBook):
         self.waiting: list[tuple[int, RequestState]] = []  # guarded-by: mu — (index, rs)
         # raft shard id this book serves (Chrome-trace pid grouping)
         self.shard_id = shard_id                           # guarded-by: <init-only>
+        # when the open batch got its first read, and the same of the
+        # batch the last peep() took (tracing.monotonic_us, read once per
+        # batch): the engine's read_stage_wait_us starts there
+        self.batching_since_us = 0                         # guarded-by: mu
+        self.peeped_since_us = 0                           # guarded-by: mu
 
     def read(self, timeout_ticks: int) -> RequestState:
         key = next(PendingProposal._seq)
         rs = RequestState(key=key, deadline_tick=self.tick + timeout_ticks)
         with self.mu:
+            if not self.batching:
+                self.batching_since_us = monotonic_us()
             self.batching.append(rs)
         lifecycle.TRACER.begin_read(key, self.shard_id)
         return rs
@@ -281,6 +289,7 @@ class PendingReadIndex(_ClockedBook):
             ctx = pb.SystemCtx(low=next(self._ctx), high=1)
             self.pending[ctx.low] = self.batching
             self.batching = []
+            self.peeped_since_us = self.batching_since_us
             return ctx
 
     def add_ready(self, ctx: pb.SystemCtx, index: int) -> None:

@@ -13,10 +13,12 @@ NodeHostInfo-parity shard list) and ``/debug/group/<id>``
 ``/debug/capacity`` (capacity.py merged snapshot: live/peak bytes,
 headroom, per-entry compile counters), and ``/debug/fabric``
 (fabric.py: per-link transport telemetry + the commit-path hop
-census).  ``/trace`` merges the compile tracker's spans and the
-fabric meter's remote child spans into the lifecycle ring's, so one
-Perfetto timeline shows proposals beside the compiles that stalled
-them and the remote hosts their quorum rounds touched.
+census).  ``/trace`` merges the compile tracker's spans, the engine
+rounds (``tracing.ROUNDS``: one row per engine, one block per phase, on
+the spans' clock) and the fabric meter's remote child spans into the
+lifecycle ring's, so one Perfetto timeline shows proposals beside the
+rounds that carried them, the compiles that stalled them and the
+remote hosts their quorum rounds touched.
 
 ``/healthz`` is honest: with a ``health_source`` wired (core/health.py
 merged snapshot), any nonzero anomaly-class count turns it into a 503
@@ -39,6 +41,7 @@ from http.server import BaseHTTPRequestHandler, ThreadingHTTPServer
 
 from dragonboat_tpu import flight
 from dragonboat_tpu import lifecycle
+from dragonboat_tpu import tracing
 from dragonboat_tpu.logger import get_logger
 
 _LOG = get_logger("metrics_http")
@@ -109,7 +112,8 @@ class MetricsServer:
                     # share the proposal's tid, stitching the hosts)
                     trace = outer.tracer.export_chrome_trace()
                     events = (list(trace.get("traceEvents", ()))
-                              + outer.compile_tracker.chrome_events())
+                              + outer.compile_tracker.chrome_events()
+                              + tracing.ROUNDS.chrome_events())
                     if outer.fabric_trace_source is not None:
                         events += outer.fabric_trace_source()
                     trace["traceEvents"] = events

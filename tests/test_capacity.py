@@ -648,3 +648,78 @@ def test_trace_endpoint_merges_compile_spans():
         assert compiles[0]["name"] == "compile:step"
     finally:
         srv.close()
+
+
+# ---------------------------------------------------------------------
+# crossings timed per tag; every XLA compile counted with its phase
+
+def test_sanctioned_crossing_times_its_extent_per_tag():
+    """``device_crossing_us{tag=...}``: each sanctioned scope observes
+    its own extent on the injected clock, one histogram child per tag."""
+    ticks = iter([100, 130, 200, 290, 300, 305])
+    reg = telemetry.Registry()
+    meter = capacity.TransferMeter(clock=ticks.__next__, registry=reg)
+    for tag in ("lazy_out", "lazy_out", "input_up"):
+        with meter.sanctioned(tag):
+            pass
+    snap = reg.snapshot()
+    assert snap["device_crossing_us.count{tag=lazy_out}"] == 2
+    assert snap["device_crossing_us.sum{tag=lazy_out}"] == 120.0
+    assert snap["device_crossing_us.count{tag=input_up}"] == 1
+    assert snap["device_crossing_us.sum{tag=input_up}"] == 5.0
+    assert meter.counts() == {"lazy_out": 2, "input_up": 1}
+
+
+def test_crossing_is_timed_under_a_guard_too():
+    import jax.numpy as jnp
+    import numpy as np
+
+    reg = telemetry.Registry()
+    meter = capacity.TransferMeter(registry=reg)
+    with meter.guard():
+        with meter.sanctioned("output_flags"):
+            np.asarray(jnp.zeros((3,)))
+    assert reg.snapshot()["device_crossing_us.count{tag=output_flags}"] == 1
+
+
+def test_compile_listener_counts_an_untracked_eager_gather_by_phase():
+    """The engine's per-count ``state.lt[idx]`` gather is an eager jax
+    expression no ``TRACKER`` entry wraps: the process-wide listener
+    counts its compile and labels it with the round phase of the thread
+    that compiled (``none`` outside a round)."""
+    import jax.numpy as jnp
+    import numpy as np
+
+    from dragonboat_tpu import tracing
+
+    def compiles(phase):
+        return telemetry.GLOBAL.snapshot().get(
+            f"xla_compiles{{phase={phase}}}", 0)
+
+    table = jnp.arange(1237 * 3).reshape(1237, 3)
+    before = {p: compiles(p) for p in ("fetch", "none")}
+    tracker_before = capacity.TRACKER.snapshot()
+    tracing._thread.phase = "fetch"
+    try:
+        rows = np.asarray(table[jnp.asarray(np.arange(13, dtype=np.int32))])
+    finally:
+        tracing._thread.phase = "none"
+    assert rows.shape == (13, 3)
+    assert compiles("fetch") > before["fetch"]
+    assert capacity.TRACKER.snapshot() == tracker_before    # untracked
+    np.asarray(table[jnp.asarray(np.arange(17, dtype=np.int32))])
+    assert compiles("none") > before["none"]
+    us = telemetry.GLOBAL.snapshot()["xla_compile_us.sum{phase=fetch}"]
+    assert us > 0
+
+
+def test_compile_listener_ignores_other_events():
+    reg = telemetry.Registry()
+    listener = capacity.CompileListener(registry=reg,
+                                        phase_of=lambda: "upload")
+    listener("/jax/core/compile/jaxpr_trace_duration", 0.5)
+    assert "xla_compiles{phase=upload}" not in reg.snapshot()
+    listener(capacity.CompileListener.EVENT, 0.25, fun_name="step")
+    snap = reg.snapshot()
+    assert snap["xla_compiles{phase=upload}"] == 1
+    assert snap["xla_compile_us.sum{phase=upload}"] == 250000.0

@@ -76,8 +76,8 @@ def test_span_lifecycle_and_ring():
     ts = [x for _, x in tr["stamps"]]
     assert ts == sorted(ts)
     assert tr["total_us"] == ts[-1] - ts[0]
-    assert t.counts() == {"active": 0, "finished": 1,
-                          "scrubbed": 0, "dropped": 0}
+    assert t.counts() == {"active": 0, "finished": 1, "scrubbed": 0,
+                          "dropped": 0, "overwritten": 0}
 
 
 def test_ring_is_bounded():
@@ -87,6 +87,37 @@ def test_ring_is_bounded():
         t.finish(k)
     keys = [tr["key"] for tr in t.completed()]
     assert keys == [2, 3]          # oldest evicted
+    assert t.counts()["overwritten"] == 1      # ... and nobody had read it
+
+
+def test_default_ring_holds_a_polling_window():
+    assert lifecycle.TRACER._ring._q.maxlen == lifecycle.DEFAULT_RING_SIZE
+    assert lifecycle.DEFAULT_RING_SIZE == 4096
+
+
+def test_drain_returns_and_clears_and_loss_is_counted():
+    """A draining poller sees each trace once; ``overwritten`` counts
+    only traces the ring pushed out before any reader saw them."""
+    t = make_tracer(ring_size=3)
+
+    def commit(*keys):
+        for k in keys:
+            t.begin(k)
+            t.finish(k)
+
+    commit(1, 2)
+    assert [tr["key"] for tr in t.drain()] == [1, 2]
+    assert t.drain() == [] and t.completed() == []
+    commit(3, 4, 5)
+    assert [tr["key"] for tr in t.completed()] == [3, 4, 5]   # a poll
+    commit(6)                      # pushes out 3, which the poll had read
+    assert t.counts()["overwritten"] == 0
+    commit(7, 8, 9)                # 6 goes unread, 4 and 5 were read
+    assert t.counts()["overwritten"] == 1
+    assert [tr["key"] for tr in t.drain()] == [7, 8, 9]
+    assert t.counts()["finished"] == 9
+    t.reset()
+    assert t.counts()["overwritten"] == 0
 
 
 def test_active_cap_refuses_not_grows():
@@ -276,12 +307,60 @@ def test_trace_endpoint_serves_chrome_json():
     finally:
         srv.close()
     # the endpoint also merges compile spans from the process-wide
-    # capacity tracker — any earlier live engine in this process may
-    # have left some; the lifecycle spans must ride beside them
-    compiles = [e for e in obj["traceEvents"] if e.get("cat") == "compile"]
-    assert validate_chrome_trace(obj) == 3 + len(compiles)
+    # capacity tracker and the engines' rounds — any earlier live engine
+    # in this process may have left some; the lifecycle spans must ride
+    # beside them
+    others = [e for e in obj["traceEvents"]
+              if e.get("cat") in ("compile", "round")]
+    assert validate_chrome_trace(obj) == 3 + len(others)
     assert [e["name"] for e in obj["traceEvents"]
-            if e.get("cat") != "compile"] == ["propose", "dispatch", "ack"]
+            if e not in others] == ["propose", "dispatch", "ack"]
+
+
+def test_trace_endpoint_serves_rounds_beside_spans_on_one_clock():
+    """/trace carries the engines' rounds (one row per engine) beside the
+    proposal rows, on the spans' clock, and the strict validator accepts
+    the merged timeline."""
+    from dragonboat_tpu import tracing
+    from dragonboat_tpu.events import Metrics
+    from dragonboat_tpu.server.metrics_http import MetricsServer
+
+    ticks = iter(range(1_000, 10_000_000, 10))      # one clock, in us
+    t = make_tracer(clock=ticks.__next__)
+    rt = tracing.RoundTimer(Metrics(), "engine.t", engine="host-x",
+                            registry=telemetry.Registry(),
+                            clock_ns=lambda: next(ticks) * 1000,
+                            cpu_clock_ns=lambda: 0)
+    tracing.ROUNDS.reset()
+    try:
+        t.begin(1)
+        rt.begin()
+        t.stamp(1, lifecycle.STAGE_STAGE)
+        rt.enter("upload")
+        t.stamp(1, lifecycle.STAGE_DISPATCH)
+        rt.enter("fetch")
+        rt.commit(keys=[1])
+        t.finish(1)
+        srv = MetricsServer([telemetry.Registry()], tracer=t)
+        try:
+            with urllib.request.urlopen(
+                    f"http://{srv.address}/trace", timeout=5) as resp:
+                obj = json.loads(resp.read().decode("utf-8"))
+        finally:
+            srv.close()
+    finally:
+        tracing.ROUNDS.reset()
+    assert validate_chrome_trace(obj) == len(obj["traceEvents"])
+    rounds = [e for e in obj["traceEvents"] if e.get("cat") == "round"]
+    assert [(e["name"], e["pid"], e["tid"]) for e in rounds] == [
+        (p, "engine", "host-x") for p in ("stage", "upload", "fetch")]
+    assert rounds[0]["args"]["keys"] == [1]
+    spans = {e["name"]: e["ts"] for e in obj["traceEvents"]
+             if e.get("cat") == "proposal"}
+    # interleaved as they happened: both sides read the one clock
+    assert (spans["propose"] < rounds[0]["ts"] < spans["stage"]
+            < rounds[1]["ts"] < spans["dispatch"] < rounds[2]["ts"]
+            < spans["ack"])
 
 
 # -- end-to-end: spans across the engines ----------------------------------
@@ -361,6 +440,61 @@ def test_e2e_trace_spans_kernel_commit_path(depth):
     finally:
         close_all(hosts)
     assert lifecycle.TRACER.active_count() == 0
+
+
+#: a served write's and a linearizable read's stamp lists on a one-replica
+#: device-resident shard, as the tree before the round timer stamped them
+#: (PR 24's, read off that commit): the benchmark's dwell readers take
+#: "the stamp before", so a stamp added between two of these would move
+#: ``dispatch_ms`` or ``read_quorum_ms`` without a word
+GOLDEN_WRITE = {
+    "memory": ["propose", "stage", "dispatch", "retire", "save", "apply",
+               "ack"],
+    "durable": ["propose", "stage", "dispatch", "retire", "save", "fsync",
+                "apply", "ack"],
+}
+GOLDEN_READ = ["read_propose", "read_quorum", "read_serve"]
+
+
+@pytest.mark.parametrize("logdb", ["memory", "durable"])
+def test_stamp_lists_are_what_they_were_and_the_round_names_the_key(
+        logdb, tmp_path):
+    from dragonboat_tpu import tracing
+
+    addr = f"gold-{logdb}"
+    kw = {"node_host_dir": str(tmp_path)} if logdb == "durable" else {}
+    nh = NodeHost(NodeHostConfig(raft_address=addr, rtt_millisecond=5,
+                                 expert=_traced_expert(0), **kw))
+    try:
+        nh.start_replica({1: addr}, False, KVStateMachine, Config(
+            shard_id=1, replica_id=1, election_rtt=10, heartbeat_rtt=2,
+            compaction_overhead=5, device_resident=True))
+        wait_leader({1: nh}, timeout=30)
+        lifecycle.TRACER.reset()
+        tracing.ROUNDS.reset()
+        propose_retry(nh, nh.get_noop_session(1), b"a=1")
+        nh.sync_read(1, b"a", timeout_s=10)
+        deadline = time.time() + 10
+        while time.time() < deadline and len(
+                lifecycle.TRACER.completed()) < 2:
+            time.sleep(0.05)
+    finally:
+        nh.close()
+    by_kind = {tr["kind"]: tr for tr in lifecycle.TRACER.completed()}
+    write, read = by_kind["proposal"], by_kind["read"]
+    assert [s for s, _ in write["stamps"]] == GOLDEN_WRITE[logdb]
+    assert [s for s, _ in read["stamps"]] == GOLDEN_READ
+    assert all(len(stamp) == 2 for tr in (write, read)
+               for stamp in tr["stamps"])
+    # the causal link: the round that dispatched the write holds its key,
+    # and the write's dispatch stamp falls inside that round
+    rounds = [r for r in tracing.ROUNDS.rounds()
+              if write["key"] in r["keys"]]
+    assert len(rounds) == 1 and rounds[0]["engine"] == nh.id
+    assert rounds[0]["props_staged"] == 1
+    dispatched = dict(write["stamps"])["dispatch"]
+    assert rounds[0]["t0_us"] <= dispatched <= rounds[0]["phases"][-1][1]
+    assert any(r["reads_staged"] == 1 for r in tracing.ROUNDS.rounds())
 
 
 def test_e2e_disabled_sampling_records_nothing():

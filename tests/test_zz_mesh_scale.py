@@ -99,7 +99,6 @@ def test_mesh_256_groups_8_devices_mixed_residency_concurrent_ops(one_core):
         # the mesh is at full residency)
         m = hosts[1].metrics()
         print(f"\nMESH_STEP_US ewma={m.get('engine.kernel_step.ewma_us', 0)}"
-              f" max={m.get('engine.kernel_step.max_us', 0)}"
               f" at rows={spec.g_size * REPLICAS * spec.n_local}",
               flush=True)
 
