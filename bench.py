@@ -1100,8 +1100,8 @@ def run_transfer_ab() -> None:
     rail (capacity.METER + jax.transfer_guard) on the engine dispatch
     seam.
 
-    Arm A drives SerialDispatch + the staging builders + the per-step
-    flags fetch bare; arm B runs the identical loop inside
+    Arm A drives SerialDispatch + the round's staging + its one packed
+    download bare; arm B runs the identical loop inside
     ``METER.guard()`` — every declared crossing then enters a scoped
     ``transfer_guard("allow")`` and bumps its tag counter, which is
     exactly what the transfer lint pass's dynamic leg and the guarded
@@ -1121,7 +1121,6 @@ def run_transfer_ab() -> None:
     from dragonboat_tpu import capacity
     from dragonboat_tpu.analysis import transfer as transfer_pass
     from dragonboat_tpu.bench_loop import bench_params, make_cluster
-    from dragonboat_tpu.core.kernel import output_row_flags
     from dragonboat_tpu.engine import kernel_engine as _ke
     from dragonboat_tpu.engine.dispatch import SerialDispatch
 
@@ -1133,8 +1132,7 @@ def run_transfer_ab() -> None:
     state = make_cluster(kp, g, replicas)
     lanes = int(state.term.shape[0])
     disp = SerialDispatch(kp)
-    inbox = _ke._InboxBuilder(lanes, kp.inbox_cap, kp.msg_entries)
-    inp = _ke._InputBuilder(lanes, kp.proposal_cap)
+    staging = _ke._RoundStaging(kp, lanes)
 
     def window(guarded: bool) -> float:
         nonlocal state
@@ -1143,10 +1141,9 @@ def run_transfer_ab() -> None:
         t0 = time.time()
         with ctx:
             for _ in range(steps):
-                state, out = disp.dispatch(state, inbox, inp,
-                                           donate=False)
-                with capacity.METER.sanctioned("output_flags"):
-                    np.asarray(output_row_flags(out))
+                state, down = disp.dispatch(state, staging, donate=False)
+                with capacity.METER.sanctioned("round_down"):
+                    np.asarray(down)
         state.term.block_until_ready()
         return time.time() - t0
 

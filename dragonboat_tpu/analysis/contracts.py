@@ -80,6 +80,8 @@ DONATION_MODULES = (
     "dragonboat_tpu/core/kernel.py",
     "dragonboat_tpu/parallel/ici.py",
     "dragonboat_tpu/core/router.py",
+    "dragonboat_tpu/core/round.py",
+    "dragonboat_tpu/parallel/round.py",
 )
 
 # KernelParams attribute -> the symbolic axis it sizes

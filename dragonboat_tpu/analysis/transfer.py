@@ -23,7 +23,9 @@ Source of truth is ``engine/dispatch.py``'s two machine-read literals:
 Every row is sized in closed form from the CONTRACTS grammar
 (``capacity.bytes_for_contract`` — class names resolve through the
 merged kstate/fleet/health/invariants tables, inline ``"[G, K] i32"``
-strings directly), and the per-step up/down totals are gated against
+strings directly; a ``packed`` row is the tuple of values that rides
+one int32 array, every element 4 bytes, as kstate.py's column table
+lays it out), and the per-step up/down totals are gated against
 ``analysis/transfer_budget.json`` exactly like the hlo-budget gate.
 
 Rules:
@@ -34,10 +36,12 @@ Rules:
          layer, an unsizable row, or (dynamic) a METER tag observed
          live that no declaration carries
 - TB002  per-step upload/download bytes exceed the seeded budget
-- TB003  wide-field download outside the ``_LazyOut`` masked-fetch
-         path: an unmasked download row carrying a [G, axis] field, or
-         an eager ``np.asarray`` of a wide StepOutput field in engine
-         code (the 42-field sweep the masked fetch deleted)
+- TB003  wide-field download outside the round's one packed download:
+         a download row carrying a [G, axis] field that is neither
+         ``packed`` (the one fixed-shape array a round reads) nor
+         ``masked``, or an eager ``np.asarray`` of a wide StepOutput
+         field in engine code (a per-field pull is a crossing of its
+         own, and a round made 20-40 of them before they were packed)
 - TB004  upload not built through a staging builder: a
          ``jnp.asarray`` / ``jnp.array`` / ``jax.device_put`` in the
          engine layer outside every declared ledger site and every
@@ -52,7 +56,7 @@ Rules:
          donated, 2-device mesh)
 
 The dynamic leg drives the REAL seam objects (``SerialDispatch`` /
-``MeshDispatch`` + the staging builders) under
+``MeshDispatch`` + ``_RoundStaging``) under
 ``capacity.METER.guard()`` — ``jax.transfer_guard("disallow")`` with
 declared sync points re-allowed via scoped guards — so an implicit
 transfer raises at the JAX level while the tag counters prove the
@@ -128,7 +132,7 @@ TELEMETRY_ENTRIES = {
 
 #: entry parameters that are static/jit-metadata, never array crossings
 STATIC_PARAMS = frozenset({
-    "kp", "cluster", "cl", "replicas", "thresholds", "k",
+    "kp", "cluster", "cl", "replicas", "thresholds", "k", "step_fn",
 })
 
 #: conventional parameter name -> contract class (the partition pass's
@@ -140,10 +144,10 @@ from dragonboat_tpu.analysis.partition import (  # noqa: E402
 )
 from dragonboat_tpu.analysis import contracts as _ct  # noqa: E402
 
-#: engine-held device trees beyond the partition pass's set (the lazy
-#: output view and the telemetry digest carries)
+#: engine-held device trees beyond the partition pass's set (the
+#: telemetry digest carries)
 _SELF_ATTRS = frozenset(_DEVICE_SELF_ATTRS) | {
-    "_out", "_health_digest", "_inv_digest",
+    "_health_digest", "_inv_digest",
 }
 
 #: geometry the budget/ledger sizes at when no budget file declares one
@@ -308,13 +312,25 @@ def _axis_env(cfg: dict) -> dict:
     except ImportError:  # pragma: no cover - fixture environments
         env = dict(_AXIS_ENV_FALLBACK)
     env["TOPK"] = int(cfg.get("top_k", env["TOPK"]))
+    # S: the save window's ring entries in the packed download
+    from dragonboat_tpu.core.kstate import save_window
+
+    env["S"] = save_window(_Geom(cfg))
     return env
 
 
-def _field_bytes(fc, kp, num_groups: int, env: dict) -> int:
+def _row_values(row: dict) -> tuple:
+    """A row's value(s): one contract class / contract string, or on a
+    ``packed`` row the tuple of them riding one array."""
+    v = row.get("value", "")
+    return tuple(v) if isinstance(v, (tuple, list)) else (v,)
+
+
+def _field_bytes(fc, kp, num_groups: int, env: dict,
+                 packed: bool = False) -> int:
     from dragonboat_tpu import capacity as _capacity
 
-    n = _capacity.DTYPE_BYTES[fc.dtype]
+    n = 4 if packed else _capacity.DTYPE_BYTES[fc.dtype]
     for ax in fc.axes:
         if ax == "G":
             n *= int(num_groups)
@@ -330,28 +346,44 @@ def _field_bytes(fc, kp, num_groups: int, env: dict) -> int:
 
 
 def _value_bytes(value: str, contracts: dict, kp, num_groups: int,
-                 env: dict) -> int | None:
+                 env: dict, packed: bool = False) -> int | None:
     """Closed-form bytes of one ledger row value: a contract class name
-    (sum of its materialized fields) or an inline contract string."""
+    (sum of its materialized fields) or an inline contract string.
+    ``packed``: every element rides as int32, and an optional field has
+    columns whenever the geometry carries inline payloads (kstate.py
+    ``round_columns``)."""
     from dragonboat_tpu import capacity as _capacity
 
     fields = contracts.get(value)
     if fields is not None:
         total = 0
         for fname, fc in fields.items():
-            if fc.optional and not _capacity._optional_materialized(
-                    value, fname, kp):
+            if fc.optional and not (
+                    bool(kp.inline_payloads) if packed
+                    else _capacity._optional_materialized(
+                        value, fname, kp)):
                 continue
             try:
-                total += _field_bytes(fc, kp, num_groups, env)
+                total += _field_bytes(fc, kp, num_groups, env, packed)
             except ValueError:
                 return None
         return total
     try:
+        if packed:
+            return _field_bytes(parse_contract(value, "transfer"), kp,
+                                num_groups, env, packed)
         return _capacity.bytes_for_contract(value, kp, num_groups,
                                             axis_extra=env)
     except (ValueError, ContractError):
         return None
+
+
+def _row_bytes(row: dict, contracts: dict, kp, num_groups: int,
+               env: dict) -> int | None:
+    sizes = [_value_bytes(v, contracts, kp, num_groups, env,
+                          bool(row.get("packed")))
+             for v in _row_values(row)]
+    return None if None in sizes else sum(sizes)
 
 
 def _ledger_rows(ledger: dict):
@@ -390,8 +422,7 @@ def build_ledger(root: str, decl: dict | None = None,
 
     def size_row(row: dict) -> dict:
         out = dict(row)
-        out["bytes"] = _value_bytes(row.get("value", ""), contracts, kp,
-                                    num_groups, env)
+        out["bytes"] = _row_bytes(row, contracts, kp, num_groups, env)
         return out
 
     entries: dict = {}
@@ -596,15 +627,16 @@ def _check_masked(findings: list[Finding], decl: dict, lines: dict,
                   contracts: dict) -> None:
     line = lines.get("TRANSFER_LEDGER", 1)
     for entry, dirn, row in _ledger_rows(decl.get("TRANSFER_LEDGER", {})):
-        if dirn != "down" or row.get("masked"):
+        if dirn != "down" or row.get("masked") or row.get("packed"):
             continue
-        if _is_wide(row.get("value", ""), contracts):
+        if any(_is_wide(v, contracts) for v in _row_values(row)):
             findings.append(Finding(
                 PASS, DISPATCH_FILE, line, "TB003",
                 f"ledger row for {entry!r} downloads wide value "
-                f"{row.get('value')!r} unmasked — [G, axis] fetches "
-                "must ride the _LazyOut masked path (declare "
-                "masked=True and gate on the activity flags)"))
+                f"{row.get('value')!r} on its own — [G, axis] fetches "
+                "must ride the round's one packed download (declare "
+                "packed=True and add its columns to kstate.py's table) "
+                "or be lane-masked (masked=True)"))
 
 
 def _tb003_ast(findings: list[Finding], engine_trees: dict,
@@ -612,7 +644,7 @@ def _tb003_ast(findings: list[Finding], engine_trees: dict,
     wide = _wide_out_fields(contracts)
     if not wide:
         return
-    allowed = set(sync_points) | {"_LazyOut.__getitem__"}
+    allowed = set(sync_points)
     for relpath, tree in engine_trees.items():
         for qual, fn in _qual_funcs(tree):
             if qual in allowed:
@@ -630,9 +662,9 @@ def _tb003_ast(findings: list[Finding], engine_trees: dict,
                 findings.append(Finding(
                     PASS, relpath, node.lineno, "TB003",
                     f"eager np.{node.func.attr} of wide StepOutput "
-                    f"field .{node.args[0].attr} in {qual}() — the "
-                    "whole [G, axis] column crosses the boundary; "
-                    "fetch it through the _LazyOut masked path"))
+                    f"field .{node.args[0].attr} in {qual}() — a "
+                    "crossing of its own for one [G, axis] column; "
+                    "read it from the round's packed download"))
 
 
 # ---------------------------------------------------------------------------
@@ -982,7 +1014,6 @@ def _live_impl(root: str, decl: dict) -> list[Finding]:
 
     from dragonboat_tpu import capacity as _capacity
     from dragonboat_tpu.bench_loop import bench_params, make_cluster
-    from dragonboat_tpu.core.kernel import output_row_flags
     from dragonboat_tpu.engine import kernel_engine as _ke
     from dragonboat_tpu.engine.dispatch import MeshDispatch, SerialDispatch
 
@@ -990,42 +1021,39 @@ def _live_impl(root: str, decl: dict) -> list[Finding]:
     meter = _capacity.METER
     N = _LIVE_STEPS
 
-    def drain(out) -> None:
-        """Mirror the engine's per-step retire: the flags fetch (one
-        sanctioned download) plus one masked _LazyOut field."""
-        with meter.sanctioned("output_flags"):
-            np.asarray(output_row_flags(out))
-        _ = _ke._LazyOut(out)["s_commit"]
+    def drain(down) -> None:
+        """Mirror the engine's per-step retire: the one packed download
+        (everything after it is host work on that array)."""
+        with meter.sanctioned("round_down"):
+            np.asarray(down)
 
     # --- serial, depth 0 (non-donated oracle entry) --------------------
     kp = bench_params(3, platform="cpu")
     state = make_cluster(kp, 2, 3)
     G = int(state.term.shape[0])
     disp = SerialDispatch(kp)
-    inbox = _ke._InboxBuilder(G, kp.inbox_cap, kp.msg_entries)
-    inp = _ke._InputBuilder(G, kp.proposal_cap)
-    state, out = disp.dispatch(state, inbox, inp, donate=False)  # warm
-    np.asarray(output_row_flags(out))
+    staging = _ke._RoundStaging(kp, G)
+    state, down = disp.dispatch(state, staging, donate=False)  # warm
+    np.asarray(down)
     meter.reset()
     with meter.guard():
         for _ in range(N):
-            state, out = disp.dispatch(state, inbox, inp, donate=False)
-            drain(out)
+            state, down = disp.dispatch(state, staging, donate=False)
+            drain(down)
     _diff_counts(findings, "serial-depth0", "step", decl,
-                 meter.counts(), N, {"lazy_out": N})
+                 meter.counts(), N)
 
     # --- serial, depth 1 (donated entry, retire-before-dispatch) -------
     state = make_cluster(kp, 2, 3)
-    state, out = disp.dispatch(state, inbox, inp, donate=True)  # warm
-    np.asarray(output_row_flags(out))
+    state, down = disp.dispatch(state, staging, donate=True)  # warm
     meter.reset()
     with meter.guard():
         for _ in range(N):
-            drain(out)  # retire the previous step's outputs first
-            state, out = disp.dispatch(state, inbox, inp, donate=True)
-    # the drain above ran on the WARM step's output too: still N drains
+            drain(down)  # retire the previous step's download first
+            state, down = disp.dispatch(state, staging, donate=True)
+    # the drain above ran on the WARM step's download too: still N drains
     _diff_counts(findings, "serial-depth1", "step_donated", decl,
-                 meter.counts(), N, {"lazy_out": N})
+                 meter.counts(), N)
 
     # --- 2-device mesh (device-resident inbox, cached cut mask) --------
     if jax.device_count() < 2:
@@ -1040,20 +1068,21 @@ def _live_impl(root: str, decl: dict) -> list[Finding]:
     mesh = Mesh(np.asarray(jax.devices()[:2]).reshape(1, 2), ("g", "r"))
     cluster, mstate, _box = ici.make_ici_cluster(mkp, mesh, num_groups=2)
     mdisp = MeshDispatch(cluster)
-    minp = _ke._InputBuilder(cluster.total_rows, mkp.proposal_cap)
-    mstate, mout = mdisp.dispatch(mstate, None, minp, donate=False)  # warm
+    mstaging = _ke._RoundStaging(mkp, cluster.total_rows,
+                                 mesh_replicas=cluster.replicas)
+    mstate, mdown = mdisp.dispatch(mstate, mstaging, donate=False)  # warm
     mdisp.pending()
-    np.asarray(output_row_flags(mout))
+    np.asarray(mdown)
     mdisp.set_cut(0, False)  # invalidate so cut_up restages under guard
     meter.reset()
     with meter.guard():
         for _ in range(N):
-            mstate, mout = mdisp.dispatch(mstate, None, minp,
-                                          donate=False)
+            mstate, mdown = mdisp.dispatch(mstate, mstaging,
+                                           donate=False)
             mdisp.pending()
-            drain(mout)
+            drain(mdown)
     _diff_counts(findings, "mesh-2dev", "serve_step", decl,
-                 meter.counts(), N, {"lazy_out": N, "cut_up": 1})
+                 meter.counts(), N, {"cut_up": 1})
     return findings
 
 

@@ -67,15 +67,9 @@ from dragonboat_tpu.tracing import current_phase, monotonic_us
 DTYPE_BYTES = {"i32": 4, "u32": 4, "f32": 4, "bool": 1}
 
 #: symbolic contract axis -> the KernelParams field holding its extent
-#: (G is the free variable the model is *per*)
-AXIS_PARAMS = {
-    "P": "num_peers",
-    "CAP": "log_cap",
-    "K": "inbox_cap",
-    "E": "msg_entries",
-    "B": "proposal_cap",
-    "RI": "readindex_cap",
-}
+#: (G is the free variable the model is *per*); kstate.py's table, which
+#: also lays out the round's packed crossings
+from dragonboat_tpu.core.kstate import AXIS_PARAMS  # noqa: E402
 
 #: contract classes with a leading-G per-group footprint.  HealthReport /
 #: ShardRow are replicated O(K)/O(1) aggregates — not per-group cost

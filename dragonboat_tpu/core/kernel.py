@@ -1262,21 +1262,12 @@ def step_donated(kp: P.KernelParams, state: ShardState, inbox: Inbox,
     return jax.vmap(functools.partial(_shard_step, kp))(state, inbox, inp)
 
 
-# Message-class order of the [G, C] activity-flag matrix produced by
-# ``output_row_flags`` — the engine's masked output fetch keys on these
-# columns to decide which wide StepOutput fields to materialize at all.
-FLAG_CLASSES = ("resp", "rep", "hb", "vote", "timeout_now",
-                "need_snapshot", "wit_snap", "rtr")
-
-
 @jax.jit
 def output_row_flags(outs) -> jnp.ndarray:
-    """[G, C] bool: per-row any() over each message class of a StepOutput.
-
-    One tiny device reduction replaces the host-side per-field
-    ``np.asarray(...).any(axis=1)`` sweep that previously forced every
-    wide [G, K]/[G, P]/[G, RI] output field across the device boundary
-    every step.  Column order is ``FLAG_CLASSES``."""
+    """[G, C] bool: per-row any() over each message class of a StepOutput,
+    column order ``kstate.FLAG_CLASSES``.  The round's program (core/round.py)
+    writes it into the leading columns of the packed download, where the
+    engine reads which message classes a row has at all."""
     cols = (
         jnp.any(outs.r_type != 0, axis=1),
         jnp.any(outs.s_rep, axis=1),

@@ -12,6 +12,8 @@ host-side or in the device RSM's value lanes), and the ReadIndex book is a
 
 from __future__ import annotations
 
+import functools
+import math
 from typing import NamedTuple
 
 import jax.numpy as jnp
@@ -275,7 +277,7 @@ INVARIANTS = {
 # the caller MUST NOT read or re-pass the donated argument arrays — XLA
 # may have reused their memory for the outputs.  All host reads go
 # through the returned state/output (or host mirrors); the engine's
-# builders re-materialize fresh inbox/input device arrays every step.
+# staging re-materializes a fresh upload every step.
 # Backends that cannot donate (CPU) silently copy instead; the engine
 # keeps the same discipline regardless so behavior is backend-uniform.
 # ---------------------------------------------------------------------------
@@ -302,6 +304,27 @@ DONATION = {
         "params": ("state", "box", "inp"),
         "donor_classes": ("ShardState", "Inbox", "StepInput"),
         "result_classes": ("ShardState", "Inbox", "StepOutput"),
+    },
+    "round_step_donated": {
+        # the serial engine round (packed upload in, packed download
+        # out): only the state is donated — the upload matches no
+        # output's shape, the download is an output
+        "module": "dragonboat_tpu/core/round.py",
+        "function": "step_donated",
+        "argnums": (2,),
+        "params": ("state",),
+        "donor_classes": ("ShardState",),
+        "result_classes": ("ShardState",),
+    },
+    "round_serve_step_donated": {
+        # the mesh engine round: state and the carried device inbox are
+        # donated; the packed upload and the cached partition mask are not
+        "module": "dragonboat_tpu/parallel/round.py",
+        "function": "jit_serve_step_donated",
+        "argnums": (2, 3),
+        "params": ("state", "box"),
+        "donor_classes": ("ShardState", "Inbox"),
+        "result_classes": ("ShardState", "Inbox"),
     },
     "cluster_step_donated": {
         # router-layout twin used by the depth-1 differential arm: same
@@ -631,3 +654,133 @@ class StepOutput(NamedTuple):
     leader: jnp.ndarray
     leader_term: jnp.ndarray
     needs_host: jnp.ndarray
+
+
+# ---------------------------------------------------------------------------
+# The round's two crossings, as one column table.
+#
+# An engine round sends ONE [G, Wu] int32 array up (the staged Inbox and
+# StepInput) and reads ONE [G, Wd] int32 array down (the activity flags,
+# every StepOutput field, the save window's terms).  Both layouts are
+# derived here, from CONTRACTS and the geometry, so the host builders, the
+# jitted program's unpack/pack (core/round.py, parallel/round.py) and the
+# host view of the download cannot drift: a field is ``width`` consecutive
+# columns from ``start``, its trailing ``shape`` flattened row-major; a
+# bool rides as a 0/1 column.  Bytes are not the cost of a crossing, the
+# crossing is (PERF.md section 5): rows are not compacted, nothing is
+# bit-packed.
+# ---------------------------------------------------------------------------
+
+# Message-class order of the download's leading flag columns (what
+# core/kernel.py ``output_row_flags`` produces): the engine keys on them
+# to decide which of a row's message fields to read at all.
+FLAG_CLASSES = ("resp", "rep", "hb", "vote", "timeout_now",
+                "need_snapshot", "wit_snap", "rtr")
+
+#: symbolic contract axis -> the KernelParams field holding its extent
+#: (G is the free variable; the capacity model sizes by the same table)
+AXIS_PARAMS = {"P": "num_peers", "CAP": "log_cap", "K": "inbox_cap",
+               "E": "msg_entries", "B": "proposal_cap", "RI": "readindex_cap"}
+
+
+class Column(NamedTuple):
+    field: str       # name within its table
+    start: int       # first column
+    width: int       # columns: prod(shape)
+    dtype: str       # "i32" | "bool" (as the field is typed off the wire)
+    shape: tuple     # trailing shape after [G]
+
+
+class RoundColumns(NamedTuple):
+    up: tuple            # Inbox fields, then StepInput fields
+    up_width: int
+    down: tuple          # "flags", StepOutput fields, "save_terms"
+    down_width: int
+    save_window: int     # S
+
+
+def save_window(kp) -> int:
+    """S: the ring entries per lane whose terms ride the download.  A step
+    appends at most ``B + 1`` entries on a leader (a no-op on election,
+    then the proposals) and ``K * E`` on a follower (``save_first`` is the
+    lowest index written since ``stable``, core/kernel.py), rounded up to
+    the power of two the gather-free window read wants."""
+    s = getattr(kp, "save_window", 0)
+    if not s:
+        want = max(kp.proposal_cap + 1, kp.inbox_cap * kp.msg_entries)
+        s = 1 << (want - 1).bit_length()
+    return min(s, kp.log_cap)
+
+
+def _class_columns(cls, kp, start: int) -> tuple[list, int]:
+    cols = []
+    for f in cls._fields:
+        axes, rest = CONTRACTS[cls.__name__][f][1:].split("]", 1)
+        tags = rest.split()
+        if "optional" in tags and not kp.inline_payloads:
+            continue
+        shape = tuple(int(getattr(kp, AXIS_PARAMS[a.strip()]))
+                      for a in axes.split(",")[1:])
+        cols.append(Column(f, start, math.prod(shape), tags[0], shape))
+        start += cols[-1].width
+    return cols, start
+
+
+@functools.lru_cache(maxsize=None)
+def round_columns(kp) -> RoundColumns:
+    """The column table of both crossings at ``kp``'s geometry (``kp`` is
+    a KernelParams, or anything hashable with its attributes)."""
+    box, w = _class_columns(Inbox, kp, 0)
+    inp, wu = _class_columns(StepInput, kp, w)
+    s = save_window(kp)
+    flags = Column("flags", 0, len(FLAG_CLASSES), "bool",
+                   (len(FLAG_CLASSES),))
+    out, w = _class_columns(StepOutput, kp, flags.width)
+    terms = Column("save_terms", w, s, "i32", (s,))
+    return RoundColumns(up=tuple(box + inp), up_width=wu,
+                        down=(flags, *out, terms),
+                        down_width=w + s, save_window=s)
+
+
+def pack_columns(cols, values: dict):
+    """[G, W] int32 from ``values[field]`` for every column (device side:
+    jnp; the host builders write through ``column_views`` instead)."""
+    return jnp.concatenate(
+        [values[c.field].astype(jnp.int32).reshape(
+            values[c.field].shape[0], c.width) for c in cols], axis=1)
+
+
+def column_views(cols, packed) -> dict:
+    """field -> the [G, *shape] slice of a packed [G, W] array, still
+    int32 (numpy: a writable view of its columns, what the host builders
+    write through)."""
+    return {c.field: packed[:, c.start:c.start + c.width].reshape(
+        (packed.shape[0],) + c.shape) for c in cols}
+
+
+def column_value(c: Column, packed):
+    """Column ``c`` of a packed [G, W] array as its field: [G, *shape],
+    compared ``!= 0`` where the field is a bool (numpy or jnp)."""
+    x = column_views((c,), packed)[c.field]
+    return x != 0 if c.dtype == "bool" else x
+
+
+def unpack_columns(cls, cols, packed):
+    """Rebuild the NamedTuple ``cls`` from its columns of ``packed``, each
+    field with its contract dtype; a field without columns is None."""
+    fields = dict.fromkeys(cls._fields)
+    fields.update((c.field, column_value(c, packed))
+                  for c in cols if c.field in fields)
+    return cls(**fields)
+
+
+def unpack_upload(kp, up) -> tuple[Inbox, StepInput]:
+    cols = round_columns(kp).up
+    return (unpack_columns(Inbox, cols, up),
+            unpack_columns(StepInput, cols, up))
+
+
+def pack_download(kp, flags, out: StepOutput, save_terms):
+    return pack_columns(
+        round_columns(kp).down,
+        {**out._asdict(), "flags": flags, "save_terms": save_terms})

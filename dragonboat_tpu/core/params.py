@@ -55,10 +55,17 @@ class KernelParams:
     # Exists for the TPU A/B, where each rolled iteration is its own
     # serial launch of the full family body.
     unroll_scans: bool = False
+    # S: ring entries per lane that ride the round's packed download (the
+    # save window's terms, kstate.round_columns).  0 derives it from what
+    # one step can append; a smaller power of two is for tests of the
+    # engine's whole-row fallback
+    save_window: int = 0
 
     def __post_init__(self) -> None:
         assert self.log_cap & (self.log_cap - 1) == 0, "log_cap must be 2^n"
         assert self.readindex_cap & (self.readindex_cap - 1) == 0
+        assert self.save_window & (self.save_window - 1) == 0 \
+            and self.save_window <= self.log_cap, "save_window must be 2^n"
 
 
 def slot_families(K: int) -> tuple[str, ...]:

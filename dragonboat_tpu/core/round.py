@@ -1,0 +1,91 @@
+"""The serial engine round as ONE jitted program: packed upload in, new
+state and packed download out.
+
+``step`` / ``step_donated`` here are what ``SerialDispatch`` serves (their
+names are the program names a device capture shows, ``jit_step`` /
+``jit_step_donated``); ``core/kernel.py``'s entries of the same names stay
+the plain ``(state, Inbox, StepInput) -> (state, StepOutput)`` step the
+differentials and the benchmark's shape accounting call, and the
+``step_fn`` wrapped here (a static argument: the engine's kernel step, or
+a chaos test's mutated one).  The layouts of both packed arrays are
+kstate.py's column table.
+"""
+
+from __future__ import annotations
+
+import functools
+
+import jax
+import jax.numpy as jnp
+
+from dragonboat_tpu.core.kernel import output_row_flags
+from dragonboat_tpu.core.kstate import (
+    pack_download,
+    round_columns,
+    unpack_upload,
+)
+
+I32 = jnp.int32
+
+
+def ring_window(ring, first, size: int):
+    """[G, size]: ``ring[g, (first[g] + j) & (CAP - 1)]`` for ``j < size``
+    (``size`` a power of two dividing CAP).  Gather-free, like the kernel's
+    one-hot reads (a batched gather serialises over [G] on the TPU): the
+    window lies in two adjacent ``size``-blocks of the ring, picked by a
+    one-hot over the blocks, then rotated into place by the bits of the
+    offset."""
+    G, cap = ring.shape
+    nb = cap // size
+    start = first & (cap - 1)
+    b, r = start // size, start % size
+    blocks = ring.reshape(G, nb, size)
+    ids = jnp.arange(nb, dtype=I32)[None, :, None]
+
+    def pick(k):
+        return jnp.sum(jnp.where(ids == k[:, None, None], blocks, 0), axis=1)
+
+    two = jnp.concatenate([pick(b), pick((b + 1) % nb)], axis=1)
+    sh = 1
+    while sh < size:
+        two = jnp.where(((r & sh) != 0)[:, None],
+                        jnp.roll(two, -sh, axis=1), two)
+        sh <<= 1
+    return two[:, :size]
+
+
+def pack_round(kp, state, out):
+    """The round's download: the activity flags, every StepOutput field
+    and, from the state the step returned, the terms of the ``S`` ring
+    entries from ``save_first`` on (what ``_build_update`` persists)."""
+    terms = ring_window(state.lt, out.save_first, round_columns(kp).save_window)
+    return pack_download(kp, output_row_flags(out), out, terms)
+
+
+def _round(kp, step_fn, state, up):
+    inbox, inp = unpack_upload(kp, up)
+    state, out = step_fn(kp, state, inbox, inp)
+    return state, pack_round(kp, state, out)
+
+
+@functools.partial(jax.jit, static_argnums=(0, 1))
+def step(kp, step_fn, state, up):
+    """One round, non-donating (depth 0): ``up`` is the staged [G, Wu]
+    upload; returns ``(state, down)`` with ``down`` the [G, Wd] download."""
+    return _round(kp, step_fn, state, up)
+
+
+# The donating twin for the pipelined loop (kstate.DONATION
+# ``round_step_donated``): only the state is donated — the upload matches
+# no output's shape, and the download is an output, so a deferred retire
+# reads nothing XLA was handed.
+@functools.partial(jax.jit, static_argnums=(0, 1), donate_argnums=(2,))
+def step_donated(kp, step_fn, state, up):
+    return _round(kp, step_fn, state, up)
+
+
+@jax.jit
+def ring_row(ring, g):
+    """One lane's whole [CAP] ring row, ``g`` traced: the fixed-shape
+    fallback for a save window wider than ``S``."""
+    return ring[g]

@@ -1,17 +1,20 @@
 """Unified engine dispatch: ONE step loop, two jit backends.
 
-PR 6 gave the single-device engine donation + depth-1 software
-pipelining; the mesh engine's bespoke ``_kernel_call`` never caught up
-(no donation, no pipelined entry, its own telemetry wiring) — the exact
-engine-layer drift the engine-unity lint pass (analysis/engine_unity.py,
-EU001–EU006) now makes a failure.  This module is the refactor that
-makes the repo clean: ``KernelEngine.step_all`` remains the ONLY step
-loop, and the only thing a backend contributes is a ``dispatch()`` —
-serial jit (core/kernel.py ``step``/``step_donated``) or the
-``parallel/ici.py`` shard_map serving entries — each exposed as a
-donated + non-donated pair behind CompileTracker telemetry, so the
-pipelined retire-before-dispatch protocol and the masked output fetch
-work identically on both paths.
+``KernelEngine.step_all`` is the ONLY step loop; the only thing a backend
+contributes is a ``dispatch()`` — the serial round (core/round.py
+``step``/``step_donated``) or the shard_map round over a device mesh
+(parallel/round.py) — each a donated + non-donated pair behind
+CompileTracker telemetry, so the pipelined retire-before-dispatch
+protocol works identically on both paths (the engine-unity lint pass,
+analysis/engine_unity.py EU001–EU006, keeps it so).
+
+A round crosses the device boundary three times whatever it carries: ONE
+upload (the staged Inbox and StepInput, packed into one [G, Wu] int32
+array by ``_RoundStaging``), ONE call of the backend's jitted entry, and
+ONE download (the [G, Wd] int32 array that entry ends by writing: the
+activity flags, every StepOutput field and the save window's terms).
+Both layouts are kstate.py's column table; pack and unpack are generic
+over the leading [G] axis, so the backends differ only in ``dispatch()``.
 
 The module-level tuples/dicts below are the MACHINE-READ contract the
 engine-unity pass enforces (pure literals, parsed with
@@ -32,19 +35,18 @@ engine-unity pass enforces (pure literals, parsed with
 from __future__ import annotations
 
 import jax
-import jax.numpy as jnp
 import numpy as np
 
 from dragonboat_tpu import capacity as _capacity
 from dragonboat_tpu.core import params as KP
-from dragonboat_tpu.core.kernel import (
-    FLAG_CLASSES,
-    step as kernel_step,
-    step_donated as kernel_step_donated,
+from dragonboat_tpu.core.kernel import step as kernel_step
+from dragonboat_tpu.core.kstate import FLAG_CLASSES, empty_inbox
+from dragonboat_tpu.core.round import (
+    step as round_step,
+    step_donated as round_step_donated,
 )
-from dragonboat_tpu.core.kstate import empty_inbox
-from dragonboat_tpu.parallel.ici import (
-    IciCluster,
+from dragonboat_tpu.parallel.ici import IciCluster
+from dragonboat_tpu.parallel.round import (
     jit_serve_step,
     jit_serve_step_donated,
 )
@@ -60,6 +62,7 @@ STEP_LOOP_METHODS = (
     "_stage_lane",
     "_stage_props",
     "_process_outputs",
+    "_save_terms",
     "_kernel_call",
     "_capacity_entries",
     "_device_pending",
@@ -95,36 +98,36 @@ ENGINE_FEATURE_KNOBS = (
 )
 
 #: feature calls (not attributes) that must stay reachable from the
-#: step loop on every path — the masked output fetch is gated on the
-#: [G, C] activity matrix this produces
-ENGINE_FEATURE_CALLS = ("output_row_flags",)
+#: step loop on every path — the retired round's activity flags feed the
+#: backend's drain-pending (the mesh backend carries its inbox on device)
+ENGINE_FEATURE_CALLS = ("note_output_flags",)
 
 #: every jit entry a dispatch backend may call.  ``donated`` entries
 #: must be kstate.DONATION-declared (EU003 cross-checks via KC008);
 #: non-donated entries carry a waiver naming why donation is out.
 DISPATCH_ENTRIES = {
     "step": {
-        "module": "dragonboat_tpu/core/kernel.py",
+        "module": "dragonboat_tpu/core/round.py",
         "function": "step",
         "donated": False,
         "waiver": "depth-0 serial oracle: the differential reference "
                   "entry must leave its inputs readable",
     },
     "step_donated": {
-        "module": "dragonboat_tpu/core/kernel.py",
+        "module": "dragonboat_tpu/core/round.py",
         "function": "step_donated",
         "donated": True,
         "waiver": "",
     },
     "serve_step": {
-        "module": "dragonboat_tpu/parallel/ici.py",
+        "module": "dragonboat_tpu/parallel/round.py",
         "function": "jit_serve_step",
         "donated": False,
         "waiver": "depth-0 mesh oracle: the differential reference "
                   "entry must leave its inputs readable",
     },
     "serve_step_donated": {
-        "module": "dragonboat_tpu/parallel/ici.py",
+        "module": "dragonboat_tpu/parallel/round.py",
         "function": "jit_serve_step_donated",
         "donated": True,
         "waiver": "",
@@ -137,18 +140,19 @@ DISPATCH_ENTRIES = {
 #: The transfer pass (analysis/transfer.py TB005 — the engine-scope
 #: sharpening of PS006) fails any other engine-layer sync; the runtime
 #: leg counts each under ``tag`` via capacity.METER.  Declaring a site
-#: here is a REVIEWED claim that the sync is off the per-step critical
-#: path or deliberately masked/lazy.
+#: here is a REVIEWED claim that the sync is the round's one download or
+#: off the per-step critical path.
 SYNC_POINTS = {
-    "_LazyOut.__getitem__": {
-        "tag": "lazy_out",
-        "why": "memoized per-field StepOutput fetch — the masked-fetch "
-               "path that replaced the eager 42-field sweep",
-    },
     "KernelEngine._process_outputs": {
-        "tag": "output_flags",
-        "why": "the [G, 8] activity matrix gating the masked fetch, "
-               "plus the save-window lt rows for persisted lanes",
+        "tag": "round_down",
+        "why": "the round's ONE download: the packed [G, Wd] array the "
+               "jitted entry wrote (flags, StepOutput, save-window terms)",
+    },
+    "KernelEngine._save_terms": {
+        "tag": "save_window_row",
+        "why": "whole-ring-row fallback of a lane whose save window is "
+               "wider than the download's S entries (none expected; "
+               "counted in engine_save_window_overflow)",
     },
     "KernelEngine._emit_messages": {
         "tag": "wit_snap_floor",
@@ -162,12 +166,15 @@ SYNC_POINTS = {
 #: (analysis/transfer.py sizes each row in closed form from the
 #: CONTRACTS grammar and gates the per-step totals against
 #: analysis/transfer_budget.json).  Row schema:
-#:   value    contract class name or inline contract string
+#:   value    contract class name or inline contract string — or, on a
+#:            ``packed`` row, the tuple of them that rides one array
+#:   packed   the row is ONE int32 array laid out by kstate.py's column
+#:            table (every element 4 bytes, a bool a 0/1 column)
 #:   param    entry parameter the upload binds (classification cross-check)
 #:   site     host qualname performing the crossing
 #:   tag      capacity.METER tag the site counts under
 #:   per_step crossing happens on EVERY step of this entry's profile
-#:   masked   download is lane/field-masked (the _LazyOut discipline)
+#:   masked   download is lane-masked (only the lanes that need it)
 #:   cached   upload is memoized until invalidated (not per-step)
 #: ``_control`` rows are step-loop control-plane crossings (admissions,
 #: membership, telemetry) that belong to no single entry.
@@ -175,89 +182,71 @@ TRANSFER_LEDGER = {
     "step": {
         "resident": ("ShardState",),
         "up": (
-            {"value": "Inbox", "param": "inbox",
-             "site": "_InboxBuilder.to_device", "tag": "inbox_up",
-             "per_step": True},
-            {"value": "StepInput", "param": "inp",
-             "site": "_InputBuilder.to_device", "tag": "input_up",
-             "per_step": True},
+            {"value": ("Inbox", "StepInput"), "packed": True,
+             "param": "up", "site": "_RoundStaging.to_device",
+             "tag": "round_up", "per_step": True},
         ),
         "down": (
-            {"value": "[G, 8] bool",
-             "site": "KernelEngine._process_outputs",
-             "tag": "output_flags", "per_step": True},
-            {"value": "StepOutput", "site": "_LazyOut.__getitem__",
-             "tag": "lazy_out", "per_step": False, "masked": True},
-            {"value": "[G, CAP] i32",
-             "site": "KernelEngine._process_outputs", "tag": "lt_rows",
-             "per_step": False, "masked": True},
+            {"value": ("[G, 8] bool", "StepOutput", "[G, S] i32"),
+             "packed": True, "site": "KernelEngine._process_outputs",
+             "tag": "round_down", "per_step": True},
+            {"value": "[1, CAP] i32",
+             "site": "KernelEngine._save_terms",
+             "tag": "save_window_row", "per_step": False, "masked": True},
         ),
     },
     "step_donated": {
         "resident": ("ShardState",),
         "up": (
-            {"value": "Inbox", "param": "inbox",
-             "site": "_InboxBuilder.to_device", "tag": "inbox_up",
-             "per_step": True},
-            {"value": "StepInput", "param": "inp",
-             "site": "_InputBuilder.to_device", "tag": "input_up",
-             "per_step": True},
+            {"value": ("Inbox", "StepInput"), "packed": True,
+             "param": "up", "site": "_RoundStaging.to_device",
+             "tag": "round_up", "per_step": True},
         ),
         "down": (
-            {"value": "[G, 8] bool",
-             "site": "KernelEngine._process_outputs",
-             "tag": "output_flags", "per_step": True},
-            {"value": "StepOutput", "site": "_LazyOut.__getitem__",
-             "tag": "lazy_out", "per_step": False, "masked": True},
-            {"value": "[G, CAP] i32",
-             "site": "KernelEngine._process_outputs", "tag": "lt_rows",
-             "per_step": False, "masked": True},
+            {"value": ("[G, 8] bool", "StepOutput", "[G, S] i32"),
+             "packed": True, "site": "KernelEngine._process_outputs",
+             "tag": "round_down", "per_step": True},
+            {"value": "[1, CAP] i32",
+             "site": "KernelEngine._save_terms",
+             "tag": "save_window_row", "per_step": False, "masked": True},
         ),
     },
     "serve_step": {
         "resident": ("ShardState", "Inbox"),
         "up": (
-            {"value": "StepInput", "param": "inp",
-             "site": "_InputBuilder.to_device", "tag": "input_up",
-             "per_step": True},
+            {"value": ("Inbox", "StepInput"), "packed": True,
+             "param": "up", "site": "_RoundStaging.to_device",
+             "tag": "round_up", "per_step": True},
             {"value": "[G, P] bool", "param": "cut",
              "site": "MeshDispatch.dispatch", "tag": "cut_up",
              "per_step": False, "cached": True},
-            {"value": "Inbox", "site": "_InboxBuilder.to_device",
-             "tag": "inbox_up", "per_step": False},
         ),
         "down": (
-            {"value": "[G, 8] bool",
-             "site": "KernelEngine._process_outputs",
-             "tag": "output_flags", "per_step": True},
-            {"value": "StepOutput", "site": "_LazyOut.__getitem__",
-             "tag": "lazy_out", "per_step": False, "masked": True},
-            {"value": "[G, CAP] i32",
-             "site": "KernelEngine._process_outputs", "tag": "lt_rows",
-             "per_step": False, "masked": True},
+            {"value": ("[G, 8] bool", "StepOutput", "[G, S] i32"),
+             "packed": True, "site": "KernelEngine._process_outputs",
+             "tag": "round_down", "per_step": True},
+            {"value": "[1, CAP] i32",
+             "site": "KernelEngine._save_terms",
+             "tag": "save_window_row", "per_step": False, "masked": True},
         ),
     },
     "serve_step_donated": {
         "resident": ("ShardState", "Inbox"),
         "up": (
-            {"value": "StepInput", "param": "inp",
-             "site": "_InputBuilder.to_device", "tag": "input_up",
-             "per_step": True},
+            {"value": ("Inbox", "StepInput"), "packed": True,
+             "param": "up", "site": "_RoundStaging.to_device",
+             "tag": "round_up", "per_step": True},
             {"value": "[G, P] bool", "param": "cut",
              "site": "MeshDispatch.dispatch", "tag": "cut_up",
              "per_step": False, "cached": True},
-            {"value": "Inbox", "site": "_InboxBuilder.to_device",
-             "tag": "inbox_up", "per_step": False},
         ),
         "down": (
-            {"value": "[G, 8] bool",
-             "site": "KernelEngine._process_outputs",
-             "tag": "output_flags", "per_step": True},
-            {"value": "StepOutput", "site": "_LazyOut.__getitem__",
-             "tag": "lazy_out", "per_step": False, "masked": True},
-            {"value": "[G, CAP] i32",
-             "site": "KernelEngine._process_outputs", "tag": "lt_rows",
-             "per_step": False, "masked": True},
+            {"value": ("[G, 8] bool", "StepOutput", "[G, S] i32"),
+             "packed": True, "site": "KernelEngine._process_outputs",
+             "tag": "round_down", "per_step": True},
+            {"value": "[1, CAP] i32",
+             "site": "KernelEngine._save_terms",
+             "tag": "save_window_row", "per_step": False, "masked": True},
         ),
     },
     "fleet_stats": {
@@ -320,33 +309,33 @@ TRANSFER_LEDGER = {
 
 
 class SerialDispatch:
-    """Single-device backend: inbox re-staged from host every step."""
+    """Single-device backend: the whole inbox re-staged from host every
+    round, inside the one packed upload."""
 
-    def __init__(self, kp: KP.KernelParams,
-                 step_fn=None, donated_fn=None) -> None:
+    def __init__(self, kp: KP.KernelParams, step_fn=None) -> None:
         self.kp = kp
+        # the kernel step the round's program wraps: the engine binds ITS
+        # module global (chaos tests swap a mutated kernel in there)
+        self._step_fn = step_fn if step_fn is not None else kernel_step
         # per-instance telemetry wrappers (own counters): a first
         # compile at THIS engine's geometry is never mistaken for a
-        # retrace of another engine sharing the jitted function.
-        # step_fn/donated_fn let the engine bind ITS module globals
-        # (chaos tests swap in mutated kernels there)
+        # retrace of another engine sharing the jitted function
         self.entries = {
-            "step": _capacity.TRACKER.wrap(
-                "step", step_fn if step_fn is not None else kernel_step),
+            "step": _capacity.TRACKER.wrap("step", round_step),
             "step_donated": _capacity.TRACKER.wrap(
-                "step_donated",
-                donated_fn if donated_fn is not None
-                else kernel_step_donated),
+                "step_donated", round_step_donated),
         }
 
-    def dispatch(self, state, inbox, inp, donate: bool):
-        """One jitted step.  ``donate=True`` routes through the donating
-        entry (core/kernel.py ``step_donated``): XLA reuses the
-        state/inbox/input buffers, so after this call the host must not
-        read them again — step_all's retire-before-dispatch order
-        upholds that."""
+    def dispatch(self, state, staging, donate: bool):
+        """One round's device work: upload ``staging``, run the jitted
+        entry; returns ``(state, down)`` with ``down`` the packed download,
+        still on the device.  ``donate=True`` routes through the donating
+        entry (core/round.py ``step_donated``): XLA reuses the state's
+        buffers, so after this call the host must not read the passed-in
+        state again — step_all's retire-before-dispatch order upholds
+        that."""
         entry = self.entries["step_donated" if donate else "step"]
-        return entry(self.kp, state, inbox.to_device(), inp.to_device())
+        return entry(self.kp, self._step_fn, state, staging.to_device())
 
     def pending(self) -> bool:
         """No device-resident inbox: nothing carries between steps."""
@@ -384,7 +373,8 @@ class MeshDispatch:
     mesh inside the step (parallel/ici.py), the inbox is device-resident
     between steps, and a per-link cut mask decides which links the mesh
     serves — traffic for cut links (and off-mesh peers) rides the host
-    hub and is merged back into the carried inbox at its route() slot."""
+    hub and is merged back into the carried inbox at its route() slot
+    (parallel/round.py)."""
 
     def __init__(self, cluster: IciCluster) -> None:
         self.cluster = cluster
@@ -409,36 +399,26 @@ class MeshDispatch:
                 "serve_step_donated", jit_serve_step_donated),
         }
 
-    def dispatch(self, state, inbox, inp, donate: bool):
+    def dispatch(self, state, staging, donate: bool):
         """Advance the mesh: host-staged inputs, device-routed messages.
         Kernel-family traffic between mesh rows rides the exchange
-        inside the step; the host inbox builder is consulted ONLY for
+        inside the step; the upload's inbox columns carry ONLY
         hub-fallback deliveries (cut links, off-mesh senders), staged
         slot-exact by _InboxBuilder and merged into the carried inbox
-        before the entry runs.  ``donate=True`` hands state, the carried
-        inbox and the staged input to XLA (kstate.DONATION
-        ``serve_step_donated``); the cached cut mask is never donated."""
+        inside the entry, before the step.  ``donate=True`` hands state
+        and the carried inbox to XLA (kstate.DONATION
+        ``round_serve_step_donated``); the cached cut mask is never
+        donated.  Returns ``(state, down)`` like the serial backend."""
         cl = self.cluster
-        if inbox is not None and inbox.mtype.any():
-            staged_box = cl.shard(inbox.to_device())
-            if self.box.ent_val is not None and staged_box.ent_val is None:
-                staged_box = staged_box._replace(
-                    ent_val=jnp.zeros_like(self.box.ent_val))
-            live = staged_box.mtype != 0
-            self.box = jax.tree.map(
-                lambda s, b: jnp.where(
-                    live.reshape(live.shape + (1,) * (s.ndim - 2)), s, b),
-                staged_box, self.box)
-        staged = cl.shard(inp.to_device())
+        up = staging.to_device(cl.sharding(1))
         if self._cut_dev is None:
             with _capacity.METER.sanctioned("cut_up"):
-                self._cut_dev = cl.shard(jnp.asarray(self.cut))
+                self._cut_dev = jax.device_put(self.cut, cl.sharding(1))
         entry = self.entries["serve_step_donated" if donate
                              else "serve_step"]
-        state, box, out = entry(
-            cl.kp, cl, state, self.box, staged, self._cut_dev)
-        self.box = box
-        return state, out
+        state, self.box, down = entry(
+            cl.kp, cl, state, self.box, up, self._cut_dev)
+        return state, down
 
     def pending(self) -> bool:
         return self._pending_msgs

@@ -31,15 +31,20 @@ READ_INDEX_RESP — the kernel itself only ever sees leader-local reads.
 Pipelining (``pipeline_depth``): at depth 0 each ``step_all`` runs the
 serial loop — stage, dispatch, fetch, process — and is the differential
 oracle.  At depth 1 the loop is software-pipelined: staging for step N
-builds into the ALTERNATE half of a double-buffered inbox/input pair
-while the device still executes step N-1; step N-1's outputs are then
-retired (the async fetch is consumed one step late) BEFORE step N is
-dispatched through the donating jit entry (core/kernel.py
-``step_donated``) — the retire-before-dispatch order is the donation
-contract: dispatch hands the state/inbox/input buffers to XLA, so
-every read of the previous state (lt rows for the update batch, the
-wit-snap compaction floor) must complete first, and the host never
-touches a buffer again after its dispatch.
+builds into the ALTERNATE of two staging buffers while the device still
+executes step N-1; step N-1's outputs are then retired (its download is
+consumed one step late) BEFORE step N is dispatched through the donating
+jit entry (core/round.py ``step_donated``) — the retire-before-dispatch
+order is the donation contract: dispatch hands the state's buffers to
+XLA, so every read of the previous state (the wit-snap compaction floor,
+a whole ring row where a save window overflowed) must complete first.
+The download itself is an output of the step's program, carried on the
+step's ctx: retiring it reads nothing a donation has handed over.
+
+The device boundary (engine/dispatch.py): a round crosses it three times
+whatever it carries — one packed upload, one jitted entry, one packed
+download of fixed shape — and nothing in a round compiles after the
+first.
 """
 
 from __future__ import annotations
@@ -47,6 +52,7 @@ from __future__ import annotations
 import threading
 from dataclasses import dataclass, field, replace as _dc_replace
 
+import jax
 import jax.numpy as jnp
 import numpy as np
 
@@ -62,19 +68,17 @@ from dragonboat_tpu.tracing import (
 )
 from dragonboat_tpu.config import Config
 from dragonboat_tpu.core import params as KP
-from dragonboat_tpu.core.kernel import (
-    FLAG_CLASSES,
-    output_row_flags,
-    step as kernel_step,
-    step_donated as kernel_step_donated,
-)
+from dragonboat_tpu.core.kernel import step as kernel_step
 from dragonboat_tpu.core import router as _router
 from dragonboat_tpu.core.kstate import (
-    Inbox,
+    FLAG_CLASSES,
     ShardState,
-    StepInput,
+    column_value,
+    column_views,
     init_state,
+    round_columns,
 )
+from dragonboat_tpu.core.round import ring_row
 from dragonboat_tpu.events import EventHub
 from dragonboat_tpu.logger import get_logger
 from dragonboat_tpu.node import Node, _SnapshotRequest
@@ -95,8 +99,8 @@ _KERNEL_MTYPES = frozenset({
     MT.SNAPSHOT_STATUS,
 })
 
-# column per message class in the [G, C] output_row_flags matrix
-# (core/kernel.py FLAG_CLASSES order)
+# column per message class in the download's [G, C] activity flags
+# (kstate.FLAG_CLASSES order)
 _F = {c: i for i, c in enumerate(FLAG_CLASSES)}
 _F_RESP, _F_REP, _F_HB, _F_VOTE = _F["resp"], _F["rep"], _F["hb"], _F["vote"]
 _F_TIMEOUT, _F_WITSNAP, _F_RTR = _F["timeout_now"], _F["wit_snap"], _F["rtr"]
@@ -113,6 +117,26 @@ _PROP_SLOTS_OFFERED = telemetry.GLOBAL.counter(
     "engine_prop_slots_offered",
     help="prop slots (proposal_cap) of every row that staged at least "
          "one proposal in a round")
+# The engine's logical clock: one tick a step (the kernel ticks a lane at
+# most once a step), and no faster than the engine's own recent rounds
+# take (``KernelEngine._tick_floor_us``: it follows the longest recent
+# rounds, decays by a sixteenth a round, and is at most this cap).  A tick
+# stands for a message round trip (``rtt_millisecond``): election and
+# check-quorum windows are ~10 ticks and a heartbeat is due every 1-2,
+# which holds only while a tick is no shorter than a real round trip
+# between hosts, and a round trip through two engines' step loops takes
+# several of their rounds.  While a round cost 120-290 ms whatever it
+# carried, the step rate itself kept the clock that slow on every engine
+# alike; with rounds of 20-140 ms an engine between bursts of work ran its
+# clock 5-10x faster than a loaded one's: its followers deposed live
+# leaders, and its leaders flooded the slower engines with heartbeats
+# (PERF.md section 6, PR 26).
+_MAX_TICK_FLOOR_US = 100_000
+
+_SAVE_WINDOW_OVERFLOW = telemetry.GLOBAL.counter(
+    "engine_save_window_overflow",
+    help="lanes whose save window was wider than the download's S ring "
+         "entries and took the whole-ring-row fetch (none expected)")
 _READ_STAGE_WAIT_US = telemetry.GLOBAL.histogram(
     "read_stage_wait_us",
     help="one observation per ReadIndex context staged: its wait for "
@@ -131,25 +155,26 @@ _INJECT_FLUSH_US = telemetry.GLOBAL.histogram(
          "state, on the engine thread")
 
 
-class _LazyOut:
-    """Field-lazy host view of a ``StepOutput``: ``np.asarray`` per field
-    on FIRST access only.  The eager 42-field fetch blocked the host on
-    the whole async device step even when the activity mask would prove
-    most fields dead (PERF.md's ~80%%-of-wall-clock stall); lanes with no
-    replicates never pay for ``s_ent_term`` and friends."""
+class _RoundDown:
+    """Host view of one round's packed download (kstate.py's column
+    table): ``o["term"][g]``, ``o["prop_index"][g, slot]``,
+    ``o["s_ent_term"][g, p, j]`` read the one [G, Wd] int32 host array the
+    round fetched, each field with its StepOutput shape (a bool field is
+    compared ``!= 0`` once); ``o["flags"]`` is the [G, C] activity matrix
+    and ``o["save_terms"][g, j]`` the term of ring entry
+    ``save_first[g] + j``.  Nothing here touches the device."""
 
-    __slots__ = ("_out", "_np")
+    __slots__ = ("_host", "_cols", "_np")
 
-    def __init__(self, out) -> None:
-        self._out = out
+    def __init__(self, host: np.ndarray, cols: dict) -> None:
+        self._host = host
+        self._cols = cols           # field -> kstate.Column
         self._np: dict[str, np.ndarray] = {}
 
     def __getitem__(self, f: str) -> np.ndarray:
         v = self._np.get(f)
         if v is None:
-            with _capacity.METER.sanctioned("lazy_out"):
-                v = np.asarray(getattr(self._out, f))
-            self._np[f] = v
+            v = self._np[f] = column_value(self._cols[f], self._host)
         return v
 
 
@@ -165,7 +190,7 @@ class _StepCtx:
     fates: dict[int, list]                  # row -> [(entry, origin), ...]
     staged_ri: dict[int, pb.SystemCtx]      # row -> staged ReadIndex ctx
     staged_rows: set[int]
-    out: object = None                      # device StepOutput (async)
+    out: object = None                      # packed download, on device (async)
     dead: set[int] = field(default_factory=set)   # rows removed in flight
     # lifecycle-sampled proposal keys riding this step (dispatch/retire
     # stamps); keys of rows scrubbed in flight stay here harmlessly —
@@ -391,6 +416,10 @@ class KernelEngine:
         # must not pass it — the -1 triple sentinel vs device term 0
         # would make every empty lane "active" forever)
         self._occ_np = np.zeros((capacity,), bool)
+        # the applied cursor each lane's device state has been sent: the
+        # device gates campaigns and compaction on it, so a lane whose RSM
+        # has applied further is work for a round even when nothing else is
+        self._applied_sent_np = np.zeros((capacity,), np.int64)
         # rows that received staged proposals this step (bounds the
         # fate-reset and fate-processing loops)
         self._staged_rows: set[int] = set()
@@ -415,27 +444,32 @@ class KernelEngine:
         # Capped so a long no-node idle cannot bank a burst of rounds
         # that would fast-forward election timers on the first admission
         self._tick_rounds_pending = 0
+        self._last_tick_us = 0
+        self._tick_floor_us = 0     # see _MAX_TICK_FLOOR_US
+        self._round_t0_us = 0       # start of the pass being timed for it
         self._tick_mu = threading.Lock()
         # persistent staging buffers, zeroed per step (the jitted step
         # needs fixed [capacity] shapes anyway; reallocating every engine
         # iteration would cost ~G*K*E ints of fresh numpy per step).
-        # TWO pairs: at pipeline depth 1 staging for step N writes the
-        # alternate pair while step N-1 (whose device inbox may alias
-        # its numpy staging on CPU backends, and whose buffers are
-        # donated) is still in flight; a pair is only rewritten after
-        # the step that used it has retired
+        # TWO of them: at pipeline depth 1 staging for step N writes the
+        # alternate one while step N-1 (whose device upload may alias
+        # its numpy staging on CPU backends) is still in flight; a
+        # buffer is only rewritten after the step that used it has
+        # retired
         # mesh subclasses set _slot_exact_replicas BEFORE super().__init__
         # so hub-fallback staging lands at route()'s exact slot layout
         mesh_r = getattr(self, "_slot_exact_replicas", None)
+        # both crossings' layouts at this geometry (kstate.py's table)
+        self._cols = round_columns(kp)
+        self._down_cols = {c.field: c for c in self._cols.down}
         self._bufs = tuple(
-            (_InboxBuilder(capacity, kp.inbox_cap, kp.msg_entries,
-                           mesh_replicas=mesh_r),
-             _InputBuilder(capacity, kp.proposal_cap))
+            _RoundStaging(kp, capacity, mesh_replicas=mesh_r)
             for _ in range(2))
         self._buf_idx = 0
-        # aliases to the pair of the most recent dispatch (fleet stats
-        # and tests read the staged inbox through these)
-        self._inbox_buf, self._input_buf = self._bufs[0]
+        # aliases to the builders of the most recent dispatch (fleet
+        # stats and tests read the staged inbox through these)
+        self._inbox_buf = self._bufs[0].inbox
+        self._input_buf = self._bufs[0].inp
         # software pipeline: 0 = serial oracle (stage, dispatch, fetch,
         # process in one pass), 1 = retire step N-1 while N is staged,
         # dispatching N through the donating jit entry
@@ -582,6 +616,7 @@ class KernelEngine:
         self._lead_np[lane] = 0
         self._lead_term_np[lane] = 0
         self._occ_np[lane] = True
+        self._applied_sent_np[lane] = init.applied
         self._pending_inject[lane] = (node, init, pids, kinds)
         self._inv_dirty.add(lane)
         self.mark_dirty(lane)
@@ -817,6 +852,7 @@ class KernelEngine:
         # lock is held; a pass that returns False, or raises, leaves the
         # block with its round uncommitted and records nothing
         with self.mu, self._round as rt:
+            self._round_t0_us = monotonic_us()
             self._props_staged = self._props_deferred = 0
             self._reads_staged = 0
             nodes = dict(self.nodes)
@@ -836,11 +872,21 @@ class KernelEngine:
                     self._commit_round(0, ())
                     return True
                 return False
+            # between ticks the worker's passes mostly find nothing: leave
+            # before the staging buffer is cleared (an ingress that lands
+            # now has its own wake-up behind it)
+            tick_due = (self._tick_rounds_pending > 0
+                        and self._round_t0_us - self._last_tick_us
+                        >= self._tick_floor_us)
+            if not (tick_due or self._dirty or self._pending_inject
+                    or self._removed_nodes or self._pending_ctx is not None
+                    or self._device_pending()):
+                return False
             self._flush_injections()
-            inbox, inp = self._bufs[self._buf_idx]
+            staging = self._bufs[self._buf_idx]
+            inbox, inp = staging.inbox, staging.inp
             self._inbox_buf, self._input_buf = inbox, inp
-            inbox.reset()
-            inp.reset()
+            staging.reset()
             had_work = False
 
             # swap out the dirty set; arrivals during this step land in
@@ -865,10 +911,12 @@ class KernelEngine:
             # consume one queued engine-wide tick round: every
             # registered lane ticks via ONE vectorized bool write —
             # no per-lane Python, no dirty-marking the whole batch
-            with self._tick_mu:
-                tick_round = self._tick_rounds_pending > 0
-                if tick_round:
+            # (no faster than the tick floor: _MAX_TICK_FLOOR_US)
+            tick_round = tick_due
+            if tick_round:
+                with self._tick_mu:
                     self._tick_rounds_pending -= 1
+                self._last_tick_us = monotonic_us()
             if tick_round:
                 lanes = np.fromiter(nodes.keys(), np.int64, len(nodes))
                 inp._tick[lanes] = True
@@ -933,12 +981,14 @@ class KernelEngine:
                     # (2026-07-31); once the executable is cached the
                     # lock is never touched again
                     with KernelEngine._first_compile_mu:
-                        state, out = self._kernel_call(inbox, inp)
+                        state, out = self._kernel_call(staging)
                     self._compiled_once = True
                 else:
-                    state, out = self._kernel_call(inbox, inp)
+                    state, out = self._kernel_call(staging)
             self.state = state
             ctx.out = out
+            np.maximum(self._applied_sent_np, inp._applied,
+                       out=self._applied_sent_np)
             for k in ctx.traced:
                 lifecycle.TRACER.stamp(k, lifecycle.STAGE_DISPATCH)
             self._pipe_steps += 1
@@ -978,6 +1028,14 @@ class KernelEngine:
         sampled lifecycle keys the round dispatched (``ctx.traced``; none
         where it only retired a step) ride its record, which is how a
         write's span names its round."""
+        # the tick floor: up towards this round's length by at most a
+        # doubling (a lone long round, a compile or a stalled flush, moves
+        # it little), down by a sixteenth
+        floor = self._tick_floor_us
+        self._tick_floor_us = min(
+            _MAX_TICK_FLOOR_US,
+            max(min(monotonic_us() - self._round_t0_us, 2 * floor + 5_000),
+                floor * 15 // 16))
         self._round.commit(
             props_staged=self._props_staged,
             props_deferred=self._props_deferred,
@@ -1027,9 +1085,9 @@ class KernelEngine:
         loop.  Called once at the end of __init__."""
         from dragonboat_tpu.engine.dispatch import SerialDispatch
 
-        # bind THIS module's globals at construction: chaos tests swap
-        # kernel_step/kernel_step_donated for mutated kernels here
-        return SerialDispatch(self.kp, kernel_step, kernel_step_donated)
+        # bind THIS module's global at construction: chaos tests swap
+        # kernel_step for a mutated kernel here
+        return SerialDispatch(self.kp, kernel_step)
 
     def _device_pending(self) -> bool:
         """True while the dispatch backend carries undelivered messages
@@ -1225,14 +1283,16 @@ class KernelEngine:
                     thresholds=self.health_thresholds)
                 return _health.row_to_dict(row)
 
-    def _kernel_call(self, inbox: _InboxBuilder, inp: _InputBuilder):
+    def _kernel_call(self, staging: _RoundStaging):
+        # the round's device work, one upload and one jitted entry:
+        # -> (state, the packed download, still on the device).
         # depth > 0 routes through the backend's donating entry: XLA
-        # reuses the state/inbox/input buffers in place of per-step
-        # fresh allocations.  After a donating dispatch the host must
-        # not read the passed-in state again — step_all's
-        # retire-before-dispatch order upholds that on BOTH backends
+        # reuses the state's buffers in place of per-step fresh
+        # allocations.  After a donating dispatch the host must not read
+        # the passed-in state again — step_all's retire-before-dispatch
+        # order upholds that on BOTH backends
         return self._dispatch.dispatch(
-            self.state, inbox, inp, donate=self.pipeline_depth > 0)
+            self.state, staging, donate=self.pipeline_depth > 0)
 
     # -- staging ----------------------------------------------------------
 
@@ -1261,6 +1321,9 @@ class KernelEngine:
                     transfer = n._transfer_awaiting[0]
                 else:
                     n._transfer_awaiting = None    # timed out: lease over
+
+        if len(msgs) > 1:
+            msgs = _newest_heartbeats(msgs)
 
         # an InstallSnapshot forces eviction — restore everything drained
         # so the successor Node inherits it intact
@@ -1362,7 +1425,10 @@ class KernelEngine:
         if ticks:
             inp.tick(g)
             work = True
-        inp.applied(g, n.sm.get_last_applied())
+        applied = n.sm.get_last_applied()
+        inp.applied(g, applied)
+        if applied > self._applied_sent_np[g]:
+            work = True
         # anything left queued (inbox overflow requeues, extra remote
         # reads, an unserved local read batch) re-stages next step
         with n.mu:
@@ -1439,25 +1505,26 @@ class KernelEngine:
         mode calls this inline; pipelined mode one step late (the ctx
         carries the fates/read ctxs that staging has since rebound).
 
-        The fetch is MASKED: a [G, C] per-class activity matrix (one
-        tiny jitted reduction, core/kernel.py output_row_flags) plus the
-        cheap [G] scalars decide which lanes and which message classes
-        are live, and only those fields are pulled to host (_LazyOut) —
-        the eager 42-field np.asarray sweep was ~80% of step wall clock
-        at 20k lanes."""
-        nodes, out = ctx.nodes, ctx.out
+        The fetch is ONE download: the [G, Wd] int32 array the step's
+        program ended by writing (core/round.py ``pack_round``: activity
+        flags, every StepOutput field, the save window's terms), read
+        through ``_RoundDown``.  Everything below is host work on that
+        array; the 20-40 per-field pulls and the per-count ``lt`` gather
+        this replaced were half of a round (PERF.md, PR 25)."""
+        nodes = ctx.nodes
         rt = self._round
         for k in ctx.traced:
             lifecycle.TRACER.stamp(k, lifecycle.STAGE_RETIRE)
-        # fetch: the flag matrix, the [G] scalars of the activity mask and
-        # the saved rows' term ring — where the host waits for the device
+        # fetch: the download (where the host waits for the device) and
+        # the activity mask built from it
         rt.enter("fetch")
-        with _capacity.METER.sanctioned("output_flags"):
-            flags = np.asarray(output_row_flags(out))
+        with _capacity.METER.sanctioned("round_down"):
+            host = np.asarray(ctx.out)
+        o = _RoundDown(host, self._down_cols)
+        flags = o["flags"]
         # the dispatch backend derives drain-pending from the same flags
         # (MeshDispatch dropped its per-step pending-scalar download)
         self._dispatch.note_output_flags(flags)
-        o = _LazyOut(out)
         pid = self._pid_np
         kind = self._kind_np
         # shards whose witness peer needs a snapshot but have no recorded
@@ -1500,14 +1567,6 @@ class KernelEngine:
         # by re-examination, exactly as the full scan did
         for g, _n in cand:
             self._dirty.add(g)
-        save_rows = [g for g, n in cand
-                     if o["save_last"][g] >= o["save_first"][g]]
-        lt_rows = {}
-        if save_rows:
-            with _capacity.METER.sanctioned("lt_rows"):
-                idx = jnp.asarray(np.asarray(save_rows, np.int32))
-                lt_rows = dict(zip(save_rows,
-                                   np.asarray(self.state.lt[idx])))
 
         rt.enter("resolve")
         for g, n in cand:
@@ -1538,7 +1597,7 @@ class KernelEngine:
                                 replicates, others)
 
             # 3. persistence batch
-            ud = self._build_update(g, n, o, lt_rows.get(g))
+            ud = self._build_update(g, n, o)
             if ud is not None:
                 updates.append((n, ud))
 
@@ -1590,7 +1649,7 @@ class KernelEngine:
                        replicates, others) -> None:
         """Build this row's outgoing messages.  ``fl`` is the row of the
         [G, C] class-activity matrix: a class whose bit is clear is
-        never indexed, so its wide output field is never fetched."""
+        never indexed, so its per-slot Python loop never runs."""
         E = self.kp.msg_entries
         shard = n.shard_id
         # response lanes
@@ -1693,14 +1752,29 @@ class KernelEngine:
                     type=MT.TIMEOUT_NOW, to=to, from_=n.replica_id,
                     shard_id=shard, term=int(o["term"][g]))))
 
-    def _build_update(self, g, n, o, lt_row) -> pb.Update | None:
+    def _save_terms(self, g: int, first: int, last: int, o) -> np.ndarray:
+        """Terms of the ring entries ``first..last`` a step saved, indexed
+        from ``first``: the download's window, or for a lane whose window
+        is wider than its ``S`` entries (none expected; counted) one
+        fixed-shape fetch of the lane's whole ring row from the state
+        that step returned (still ``self.state``: a retire runs before
+        the next dispatch)."""
+        if last - first < self._cols.save_window:
+            return o["save_terms"][g]
+        _SAVE_WINDOW_OVERFLOW.inc()
+        with _capacity.METER.sanctioned("save_window_row"):
+            row = np.asarray(ring_row(self.state.lt, np.int32(g)))
+        return row[(first + np.arange(last - first + 1))
+                   & (self.kp.log_cap - 1)]
+
+    def _build_update(self, g, n, o) -> pb.Update | None:
         first, last = int(o["save_first"][g]), int(o["save_last"][g])
         triple = (int(o["term"][g]), int(o["vote"][g]), int(o["commit"][g]))
         entries: list[pb.Entry] = []
-        if lt_row is not None and last >= first:
-            cap = self.kp.log_cap
+        if last >= first:
+            terms = self._save_terms(g, first, last, o)
             for idx in range(first, last + 1):
-                term = int(lt_row[idx & (cap - 1)])
+                term = int(terms[idx - first])
                 e = n.mirror.get(idx)
                 if e is None or e.term != term:
                     e = (_dc_replace(e, term=term) if e is not None
@@ -1745,7 +1819,14 @@ class KernelEngine:
             if low in n._local_ri_pending:
                 n._local_ri_pending.pop(low)
                 n.pending_reads.dropped(staged_ri)
-            n._remote_ri_inflight.pop(low, None)
+            sender = n._remote_ri_inflight.pop(low, None)
+            if sender is not None and n.is_leader():
+                # a forwarded read this leader's kernel turned away (its
+                # ReadIndex book is full): the requester hears of nothing
+                # but an index, so the read waits its turn here instead of
+                # timing out over there.  One no longer led from here is
+                # let go, as before: the requester's timeout retries it
+                n._remote_reads.insert(0, (sender, staged_ri, monotonic_us()))
         n.pending_reads.applied(n.sm.get_last_applied())
 
     def _apply(self, g, n, o) -> None:
@@ -1869,7 +1950,7 @@ class KernelEngine:
 
 
 # ---------------------------------------------------------------------------
-# staging buffers (numpy first, one device transfer per step)
+# staging buffers (numpy first, ONE device transfer per step)
 # ---------------------------------------------------------------------------
 
 
@@ -1883,8 +1964,57 @@ _FAMILY_OF_TYPE = {
 # everything else (responses, NOOP, UNREACHABLE, SNAPSHOT_STATUS) -> "resp"
 
 
+def _newest_heartbeats(msgs: list) -> list:
+    """``msgs`` without the heartbeats a later one supersedes.  A lane
+    stages a few messages of a kind a step, so a follower whose engine
+    falls behind its leader's queues heartbeats faster than it takes
+    them, and its answers (a ReadIndex confirmation among them) come
+    ever later.  A heartbeat from the same sender in the same term
+    carries everything an earlier one did: its commit is monotone, and
+    its ReadIndex ctx is the newest pending one, whose ack confirms the
+    older."""
+    last: dict = {}
+    beats = 0
+    for i, m in enumerate(msgs):
+        if m.type == MT.HEARTBEAT:
+            last[(m.from_, m.term)] = i
+            beats += 1
+    if beats == len(last):
+        return msgs
+    return [m for i, m in enumerate(msgs)
+            if m.type != MT.HEARTBEAT or last[(m.from_, m.term)] == i]
+
+
+class _RoundStaging:
+    """One round's upload: a host array ``[G, Wu] int32`` laid out by
+    kstate.py's column table, written through the two builders (whose
+    fields are views of its columns) and sent up in ONE transfer."""
+
+    def __init__(self, kp: KP.KernelParams, G: int,
+                 mesh_replicas: int | None = None) -> None:
+        cols = round_columns(kp)
+        self.up = np.zeros((G, cols.up_width), np.int32)
+        views = column_views(cols.up, self.up)
+        self.inbox = _InboxBuilder(views, kp.inbox_cap, kp.msg_entries,
+                                   mesh_replicas=mesh_replicas)
+        self.inp = _InputBuilder(views, kp.proposal_cap)
+
+    def reset(self) -> None:
+        self.up.fill(0)
+
+    def to_device(self, sharding=None):
+        """The round's one upload (``sharding``: the mesh backend's
+        placement along G)."""
+        with _capacity.METER.sanctioned("round_up"):
+            return jax.device_put(self.up, sharding)
+
+
 class _InboxBuilder:
-    def __init__(self, G: int, K: int, E: int,
+    """Writes the Inbox columns of a round's upload (``views``:
+    ``_RoundStaging``'s field -> [G, ...] int32 view; a bool field is a
+    0/1 column)."""
+
+    def __init__(self, views: dict, K: int, E: int,
                  mesh_replicas: int | None = None) -> None:
         self.K, self.E = K, E
         # typed slot layout (params.slot_families): a message may only be
@@ -1900,24 +2030,18 @@ class _InboxBuilder:
         # used, so the merged carried inbox is bit-identical to a fully
         # resident exchange (core/router.py slot_candidates)
         self._mesh_R = mesh_replicas
-        self.mtype = np.zeros((G, K), np.int32)
-        self.from_ = np.zeros((G, K), np.int32)
-        self.term = np.zeros((G, K), np.int32)
-        self.log_term = np.zeros((G, K), np.int32)
-        self.log_index = np.zeros((G, K), np.int32)
-        self.commit = np.zeros((G, K), np.int32)
-        self.reject = np.zeros((G, K), bool)
-        self.hint = np.zeros((G, K), np.int32)
-        self.hint_high = np.zeros((G, K), np.int32)
-        self.n_ent = np.zeros((G, K), np.int32)
-        self.ent_term = np.zeros((G, K, E), np.int32)
-        self.ent_cc = np.zeros((G, K, E), bool)
-
-    def reset(self) -> None:
-        for a in (self.mtype, self.from_, self.term, self.log_term,
-                  self.log_index, self.commit, self.reject, self.hint,
-                  self.hint_high, self.n_ent, self.ent_term, self.ent_cc):
-            a.fill(0)
+        self.mtype = views["mtype"]
+        self.from_ = views["from_"]
+        self.term = views["term"]
+        self.log_term = views["log_term"]
+        self.log_index = views["log_index"]
+        self.commit = views["commit"]
+        self.reject = views["reject"]
+        self.hint = views["hint"]
+        self.hint_high = views["hint_high"]
+        self.n_ent = views["n_ent"]
+        self.ent_term = views["ent_term"]
+        self.ent_cc = views["ent_cc"]
 
     def add(self, g: int, m: pb.Message, n: KernelNode) -> bool:
         if self._mesh_R is not None:
@@ -1957,40 +2081,21 @@ class _InboxBuilder:
             n.mirror[e.index] = e
         return True
 
-    def to_device(self) -> Inbox:
-        with _capacity.METER.sanctioned("inbox_up"):
-            return Inbox(
-                mtype=jnp.asarray(self.mtype),
-                from_=jnp.asarray(self.from_),
-                term=jnp.asarray(self.term),
-                log_term=jnp.asarray(self.log_term),
-                log_index=jnp.asarray(self.log_index),
-                commit=jnp.asarray(self.commit),
-                reject=jnp.asarray(self.reject),
-                hint=jnp.asarray(self.hint),
-                hint_high=jnp.asarray(self.hint_high),
-                n_ent=jnp.asarray(self.n_ent),
-                ent_term=jnp.asarray(self.ent_term),
-                ent_cc=jnp.asarray(self.ent_cc),
-            )
-
 
 class _InputBuilder:
-    def __init__(self, G: int, B: int) -> None:
-        self.B = B
-        self.prop_valid = np.zeros((G, B), bool)
-        self.prop_cc = np.zeros((G, B), bool)
-        self.ri_valid = np.zeros((G,), bool)
-        self.ri_low = np.zeros((G,), np.int32)
-        self.ri_high = np.zeros((G,), np.int32)
-        self.transfer_to = np.zeros((G,), np.int32)
-        self._tick = np.zeros((G,), bool)
-        self._applied = np.zeros((G,), np.int32)
+    """Writes the StepInput columns of a round's upload (``quiesced``
+    stays 0: the device quiesces itself)."""
 
-    def reset(self) -> None:
-        for a in (self.prop_valid, self.prop_cc, self.ri_valid, self.ri_low,
-                  self.ri_high, self.transfer_to, self._tick, self._applied):
-            a.fill(0)
+    def __init__(self, views: dict, B: int) -> None:
+        self.B = B
+        self.prop_valid = views["prop_valid"]
+        self.prop_cc = views["prop_cc"]
+        self.ri_valid = views["ri_valid"]
+        self.ri_low = views["ri_low"]
+        self.ri_high = views["ri_high"]
+        self.transfer_to = views["transfer_to"]
+        self._tick = views["tick"]
+        self._applied = views["applied"]
 
     def prop(self, g: int, slot: int, is_cc: bool) -> None:
         self.prop_valid[g, slot] = True
@@ -2009,17 +2114,3 @@ class _InputBuilder:
 
     def applied(self, g: int, v: int) -> None:
         self._applied[g] = v
-
-    def to_device(self) -> StepInput:
-        with _capacity.METER.sanctioned("input_up"):
-            return StepInput(
-                prop_valid=jnp.asarray(self.prop_valid),
-                prop_cc=jnp.asarray(self.prop_cc),
-                ri_valid=jnp.asarray(self.ri_valid),
-                ri_low=jnp.asarray(self.ri_low),
-                ri_high=jnp.asarray(self.ri_high),
-                transfer_to=jnp.asarray(self.transfer_to),
-                tick=jnp.asarray(self._tick),
-                quiesced=jnp.zeros_like(self._tick),
-                applied=jnp.asarray(self._applied),
-            )

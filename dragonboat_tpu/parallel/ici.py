@@ -216,7 +216,7 @@ def _mask_incoming(box: Inbox, cut: jnp.ndarray) -> Inbox:
     )
 
 
-def _serve_body(kp: KP.KernelParams, replicas: int,
+def serve_body(kp: KP.KernelParams, replicas: int,
                 state: ShardState, box: Inbox, inp: StepInput,
                 cut: jnp.ndarray):
     """shard_map body for the SERVING path: host-staged StepInput, a
@@ -227,7 +227,8 @@ def _serve_body(kp: KP.KernelParams, replicas: int,
     (a per-step device->host crossing) is gone: the host derives
     drain-pending from the [G, C] activity flags it already fetches
     every step (MeshDispatch.note_output_flags), so the serving step
-    downloads nothing beyond the masked output path."""
+    downloads nothing beyond the round's one packed array
+    (parallel/round.py wraps this body)."""
     state, out = step(kp, state, box, inp)
     box = _exchange(kp, replicas, state.term.shape[0],
                     _mask_outgoing(out, cut))
@@ -244,7 +245,7 @@ def jit_serve_step(kp, cluster: IciCluster, state, box, inp, cut):
     engine dispatch layer wraps in compile telemetry.  ``cut`` is the
     per-link mask ``[G, num_peers] bool`` (see ``_mask_outgoing``)."""
     body = shard_map(
-        functools.partial(_serve_body, kp, cluster.replicas),
+        functools.partial(serve_body, kp, cluster.replicas),
         mesh=cluster.mesh,
         in_specs=(PS(("g", "r")), PS(("g", "r")), PS(("g", "r")),
                   PS(("g", "r"), None)),
@@ -261,7 +262,7 @@ def jit_serve_step_donated(kp, cluster: IciCluster, state, box, inp, cut):
     applies after dispatch).  ``cut`` is NOT donated — the engine caches
     the device copy of the per-link mask across steps."""
     body = shard_map(
-        functools.partial(_serve_body, kp, cluster.replicas),
+        functools.partial(serve_body, kp, cluster.replicas),
         mesh=cluster.mesh,
         in_specs=(PS(("g", "r")), PS(("g", "r")), PS(("g", "r")),
                   PS(("g", "r"), None)),

@@ -1,0 +1,64 @@
+"""The mesh engine round as ONE jitted program (the shard_map twin of
+core/round.py): packed upload in; new state, the carried inbox and the
+packed download out.
+
+``jit_serve_step`` / ``jit_serve_step_donated`` here are what
+``MeshDispatch`` serves (a device capture shows them under the same
+program names as parallel/ici.py's entries, which stay the unpacked
+``(state, box, StepInput, cut)`` serving step the differentials, the HLO
+budget and the benchmark's shape accounting call).  Everything added
+around ``ici.serve_body`` is per-row, so it runs inside the shard_map on
+each device's own rows: no collective beyond the step's own.
+"""
+
+from __future__ import annotations
+
+import functools
+
+import jax
+import jax.numpy as jnp
+from jax.sharding import PartitionSpec as PS
+
+from dragonboat_tpu.core.kstate import unpack_upload
+from dragonboat_tpu.core.round import pack_round
+from dragonboat_tpu.parallel.ici import IciCluster, serve_body, shard_map
+
+
+def _round_body(kp, replicas, state, box, up, cut):
+    # hub-fallback deliveries (cut links, off-mesh senders) were staged
+    # slot-exact by the host; a staged slot replaces the carried one
+    staged, inp = unpack_upload(kp, up)
+    live = staged.mtype != 0
+    box = jax.tree.map(
+        lambda s, b: jnp.where(
+            live.reshape(live.shape + (1,) * (s.ndim - 2)), s, b),
+        staged, box)
+    state, box, out = serve_body(kp, replicas, state, box, inp, cut)
+    return state, box, pack_round(kp, state, out)
+
+
+def _round(kp, cluster: IciCluster, state, box, up, cut):
+    rows = PS(("g", "r"))
+    body = shard_map(
+        functools.partial(_round_body, kp, cluster.replicas),
+        mesh=cluster.mesh,
+        in_specs=(rows, rows, rows, PS(("g", "r"), None)),
+        out_specs=(rows, rows, rows),
+    )
+    return body(state, box, up, cut)
+
+
+@functools.partial(jax.jit, static_argnums=(0, 1))
+def jit_serve_step(kp, cluster: IciCluster, state, box, up, cut):
+    """One mesh round, non-donating (depth 0): ``up`` is the staged
+    [G, Wu] upload sharded along G, ``cut`` the per-link mask; returns
+    ``(state, box, down)``."""
+    return _round(kp, cluster, state, box, up, cut)
+
+
+# The donating twin (kstate.DONATION ``round_serve_step_donated``): state
+# and the carried inbox are donated; the upload matches no output's shape
+# and the cached cut mask outlives the step.
+@functools.partial(jax.jit, static_argnums=(0, 1), donate_argnums=(2, 3))
+def jit_serve_step_donated(kp, cluster: IciCluster, state, box, up, cut):
+    return _round(kp, cluster, state, box, up, cut)

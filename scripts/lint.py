@@ -46,8 +46,8 @@ Passes (dragonboat_tpu/analysis/):
                   TRANSFER_LEDGER and sized in closed form from the
                   CONTRACTS grammar — undeclared crossings (TB001),
                   per-step byte budgets vs
-                  analysis/transfer_budget.json (TB002), unmasked wide
-                  downloads outside the _LazyOut path (TB003), uploads
+                  analysis/transfer_budget.json (TB002), wide downloads
+                  outside the round's packed download (TB003), uploads
                   bypassing the staging builders (TB004), syncs outside
                   the declared SYNC_POINTS (TB005, the engine-wide
                   sharpening of PS006), per-step crossing-count growth

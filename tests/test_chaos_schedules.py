@@ -110,9 +110,9 @@ def test_probe_catches_commit_without_quorum_mutation(monkeypatch):
     sys.modules[spec.name] = mc
     spec.loader.exec_module(mc)
     mut = mc.load_kernel_module("commit_without_quorum")
-    # the engine binds these module globals at construction time
+    # the engine binds this module global at construction time (both of
+    # its round entries wrap it, core/round.py)
     monkeypatch.setattr(ke, "kernel_step", mut.step)
-    monkeypatch.setattr(ke, "kernel_step_donated", mut.step_donated)
 
     hosts = make_cluster("mutq", expert=ExpertConfig(
         kernel_log_cap=256, kernel_capacity=8, kernel_apply_batch=16,

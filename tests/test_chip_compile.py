@@ -1,10 +1,13 @@
 """Compile the main path's device programs for a v5e that is described, not
 attached: what the TPU compiler refuses here costs no chip time.
 
-Covers the entries ``chip_smoke.py`` serves through (``kernel.step`` /
-``step_donated`` with the KernelParams a NodeHost picks on a TPU, the mesh
-serve step on a 1x3 mesh) and the three Pallas kernels at their bench
-shapes.  Nothing runs — these say nothing about results or times.
+Covers the entries an engine serves through (the packed rounds of
+``core/round.py`` and ``parallel/round.py`` with the KernelParams a NodeHost
+picks on a TPU, serial and on a 1x3 mesh), the unpacked steps they wrap
+(``kernel.step`` / ``step_donated``, ``ici.jit_serve_step``: the
+differentials' and ``chip_smoke.py``'s entries) and the three Pallas kernels
+at their bench shapes.  Nothing runs — these say nothing about results or
+times.
 
 One file on purpose: only one process may load the TPU's library, and the
 worker that is handed this file is the one that describes the topology
@@ -20,10 +23,15 @@ import pytest
 from jax.sharding import Mesh, SingleDeviceSharding
 
 from dragonboat_tpu.config import ExpertConfig
-from dragonboat_tpu.core import kernel
-from dragonboat_tpu.core.kstate import empty_inbox, empty_input, init_state
+from dragonboat_tpu.core import kernel, round as cround
+from dragonboat_tpu.core.kstate import (
+    empty_inbox,
+    empty_input,
+    init_state,
+    round_columns,
+)
 from dragonboat_tpu.nodehost import NodeHost
-from dragonboat_tpu.parallel import fabric_pallas, ici
+from dragonboat_tpu.parallel import fabric_pallas, ici, round as pround
 from dragonboat_tpu.rsm import device_kv_pallas
 from dragonboat_tpu.rsm.device_kv import DeviceKV
 
@@ -99,6 +107,56 @@ def test_kernel_step_compiles_for_v5e(one_chip, device_kp, entry):
     compiled = getattr(kernel, entry).lower(kp, *args).compile()
     mem = compiled.memory_analysis()
     assert mem.temp_size_in_bytes + mem.argument_size_in_bytes < 16e9
+
+
+@pytest.mark.parametrize("entry", ["step", "step_donated"])
+def test_round_step_compiles_for_v5e(one_chip, device_kp, entry):
+    """The serial round as served: one [G, Wu] upload in, the state and
+    one [G, Wd] download out, and no gather added around the step (the
+    save window is read by block select, core/round.py)."""
+    kp = device_kp()
+    rc = round_columns(kp)
+    state, _box, _inp = _shapes(_step_args(kp, ROWS), lambda x: one_chip)
+    up = jax.ShapeDtypeStruct((ROWS, rc.up_width), jnp.int32,
+                              sharding=one_chip)
+    compiled = getattr(cround, entry).lower(
+        kp, kernel.step, state, up).compile()
+    assert jax.eval_shape(
+        lambda s, u: getattr(cround, entry)(kp, kernel.step, s, u)[1],
+        state, up).shape == (ROWS, rc.down_width)
+    mem = compiled.memory_analysis()
+    assert mem.temp_size_in_bytes + mem.argument_size_in_bytes < 16e9
+    plain = kernel.step.lower(
+        kp, *_shapes(_step_args(kp, ROWS), lambda x: one_chip)
+    ).compile().as_text()
+    assert compiled.as_text().count(" gather(") <= plain.count(" gather(")
+
+
+@pytest.mark.parametrize("entry", ["jit_serve_step",
+                                   "jit_serve_step_donated"])
+def test_mesh_round_compiles_for_v5e(topo, device_kp, entry):
+    """The mesh round as served, on the geometry of the test below: the
+    step's collectives and no more (pack, unpack and the hub merge are
+    per row, inside the shard_map)."""
+    kp = device_kp(min_inbox=10)
+    mesh = Mesh(np.array(topo.devices[:3]).reshape(1, 3), ("g", "r"))
+    cl = ici.IciCluster(kp=kp, mesh=mesh, replicas=3, n_local=48,
+                        num_groups=48)
+    state, box, inp = _shapes(_step_args(kp, cl.total_rows),
+                              lambda x: cl.sharding(x.ndim - 1))
+    cut = jax.ShapeDtypeStruct((cl.total_rows, kp.num_peers), bool,
+                               sharding=cl.sharding(1))
+    up = jax.ShapeDtypeStruct((cl.total_rows, round_columns(kp).up_width),
+                              jnp.int32, sharding=cl.sharding(1))
+    hlo = getattr(pround, entry).lower(
+        kp, cl, state, box, up, cut).compile().as_text()
+    plain = ici.jit_serve_step.lower(
+        kp, cl, state, box, inp, cut).compile().as_text()
+    for collective in ("all-gather", "all-reduce", "all-to-all",
+                       "collective-permute"):
+        assert hlo.count(f" {collective}(") == plain.count(
+            f" {collective}("), collective
+    assert "all-gather" in hlo
 
 
 @pytest.mark.parametrize("entry", ["jit_serve_step",

@@ -32,7 +32,7 @@ STEP_LOOP_OWNER = "Owner"
 STEP_LOOP_METHODS = ("step_all", "_kernel_call", "_process_outputs")
 DISPATCH_SEAMS = ("_make_dispatch",)
 ENGINE_FEATURE_KNOBS = ("pipeline_depth",)
-ENGINE_FEATURE_CALLS = ("output_row_flags",)
+ENGINE_FEATURE_CALLS = ("note_output_flags",)
 DISPATCH_ENTRIES = {
     "step": {
         "module": "core/kernel.py",
@@ -86,7 +86,7 @@ class Owner:
             None, None, None, donate=self.pipeline_depth > 0)
 
     def _process_outputs(self, ctx):
-        return output_row_flags(ctx)
+        return self._dispatch.note_output_flags(ctx)
 
 
 class MeshSub(Owner):
@@ -291,7 +291,7 @@ class Owner:
             None, None, None, donate=self.pipeline_depth > 0)
 
     def _process_outputs(self, ctx):
-        return output_row_flags(ctx)
+        return self._dispatch.note_output_flags(ctx)
 '''
 
 
@@ -322,7 +322,7 @@ class Owner:
             None, None, None, donate=self.pipeline_depth > 0)
 
     def _process_outputs(self, ctx):
-        return output_row_flags(ctx)
+        return self._dispatch.note_output_flags(ctx)
 ''')
     fs = engine_unity.run(root)
     assert any(f.rule == "EU004" and "_pending_ctx" in f.message

@@ -6,7 +6,7 @@ Scenarios mirror test_nodehost.py / test_kernel_engine.py with
 ``Config.mesh_resident=True``: every NodeHost attaches to one shared
 MeshEngine, replicas of a shard live on different devices along mesh axis
 'r', and intra-group raft traffic rides the all_gather inside the jitted
-step instead of the chan transport (parallel/ici.py:_serve_body).
+step instead of the chan transport (parallel/ici.py:serve_body).
 """
 
 import time

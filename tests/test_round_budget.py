@@ -1,0 +1,321 @@
+"""A round's budget at the device boundary, on the CPU engine: one upload,
+one call, one download, and nothing compiles after the first round.
+
+The tests drive the engine's rounds themselves: they hold the engine lock
+(re-entrant; the engine's own worker waits for it), queue client work,
+call ``step_all`` and read ``capacity.METER`` / ``capacity.TRACKER`` / the
+registry around each round, so every crossing counted is that round's."""
+
+from __future__ import annotations
+
+import dataclasses
+import os
+import sys
+import time
+
+import pytest
+
+from dragonboat_tpu import capacity, telemetry
+from dragonboat_tpu.config import Config, ExpertConfig, NodeHostConfig
+from dragonboat_tpu.nodehost import NodeHost
+
+sys.path.insert(0, os.path.dirname(os.path.abspath(__file__)))
+
+from test_nodehost import KVStateMachine  # noqa: E402
+
+SHARDS = 48
+EVERY = 10      # fleet_stats_every: the every-tenth-round collections
+COLLECTIONS = {"fleet_down", "health_down", "invariants_down"}
+
+
+def _wait(cond, timeout):
+    deadline = time.time() + timeout
+    while time.time() < deadline:
+        if cond():
+            return True
+        time.sleep(0.05)
+    return cond()
+
+
+def _host(prefix, shards, depth=0, device=True):
+    """One NodeHost, ``shards`` single-replica shards: a proposal is
+    appended, committed, saved and applied in the round that stages it."""
+    nh = NodeHost(NodeHostConfig(
+        raft_address=f"{prefix}-1", rtt_millisecond=5,
+        expert=ExpertConfig(kernel_log_cap=64, kernel_capacity=64,
+                            fleet_stats_every=EVERY,
+                            kernel_pipeline_depth=depth)))
+    for sid in range(1, shards + 1):
+        nh.start_replica({1: f"{prefix}-1"}, False, KVStateMachine, Config(
+            shard_id=sid, replica_id=1, election_rtt=10, heartbeat_rtt=2,
+            device_resident=device))
+    assert _wait(lambda: all(nh.get_leader_id(sid)[1]
+                             for sid in range(1, shards + 1)), 90), \
+        "not every shard elected"
+    return nh
+
+
+def _settle(eng):
+    """Under the engine lock: run rounds until one finds nothing to do
+    (elections, bootstrap config changes and their peer-book uploads
+    are behind us)."""
+    for _ in range(200):
+        if not eng.step_all() and eng._pending_ctx is None:
+            return
+    raise AssertionError("the engine never went idle")
+
+
+def _xla_compiles() -> float:
+    return sum(v for k, v in telemetry.GLOBAL.snapshot().items()
+               if k.startswith("xla_compiles{"))
+
+
+def _tracked_compiles(eng) -> dict:
+    return {name: w.stats()["compiles"]
+            for name, w in eng._cap_entries.items()}
+
+
+@pytest.mark.parametrize("depth", [0, 1])
+def test_round_crosses_the_boundary_once_each_way(depth):
+    """Over 20 busy rounds ``METER.counts()`` grows by exactly one
+    ``round_up`` and one ``round_down`` a round, and by nothing else
+    outside the every-tenth-round collections (one of each then)."""
+    nh = _host(f"rb-cross{depth}", 4, depth=depth)
+    try:
+        eng = nh.kernel_engine
+        sessions = {sid: nh.get_noop_session(sid) for sid in range(1, 5)}
+        with eng.mu:
+            _settle(eng)
+            states, rounds, seen = [], 0, {}
+            for i in range(20):
+                for sid, s in sessions.items():
+                    states.append(nh.propose(s, f"k{i}={sid}".encode(), 30))
+                before = capacity.METER.counts()
+                countdown = eng._fleet_countdown
+                assert eng.step_all(), f"round {i} found nothing to do"
+                after = capacity.METER.counts()
+                delta = {t: after[t] - before.get(t, 0) for t in after
+                         if after[t] != before.get(t, 0)}
+                want = {"round_up": 1}
+                if depth == 0 or i > 0:
+                    want["round_down"] = 1      # depth 1 retires one late
+                if countdown == 1:
+                    want.update(dict.fromkeys(COLLECTIONS, 1))
+                assert delta == want, f"round {i}: {delta} != {want}"
+                rounds += 1
+                for t, n in delta.items():
+                    seen[t] = seen.get(t, 0) + n
+            assert seen["round_up"] == rounds == 20
+            assert seen.get("fleet_down", 0) == rounds // EVERY
+            _settle(eng)                    # depth 1: retire the last step
+        for rs in states:
+            assert rs.get(30) is not None
+    finally:
+        nh.close()
+
+
+def test_nothing_compiles_while_saved_rows_vary_1_to_48():
+    """Rounds that save 1, 2, ... 48 lanes' entries: the download is one
+    fixed shape, so after the first round no tracked entry compiles and
+    XLA compiles nothing at all (the per-count ``state.lt[idx]`` gather
+    compiled ~7 small programs for every new count), and no lane leaves
+    the download's save window."""
+    nh = _host("rb-compile", SHARDS)
+    try:
+        eng = nh.kernel_engine
+        sessions = {sid: nh.get_noop_session(sid)
+                    for sid in range(1, SHARDS + 1)}
+        overflow0 = telemetry.GLOBAL.snapshot().get(
+            "engine_save_window_overflow", 0)
+        with eng.mu:
+            _settle(eng)
+            # the first round, and one set of the every-tenth-round
+            # collections, may compile (the cluster's start already did)
+            rs = nh.propose(sessions[1], b"warm=1", 30)
+            for _ in range(EVERY):
+                eng.step_all()
+            tracked0, xla0 = _tracked_compiles(eng), _xla_compiles()
+            states = [rs]
+            for k in range(1, SHARDS + 1):
+                for sid in range(1, k + 1):
+                    states.append(nh.propose(
+                        sessions[sid], f"r{k}={sid}".encode(), 30))
+                calls0 = eng._cap_entries["step"].stats()["calls"]
+                assert eng.step_all()
+                assert eng._cap_entries["step"].stats()["calls"] == calls0 + 1
+                saved = sum(1 for sid in range(1, k + 1)
+                            if states[-sid].get(30) is not None)
+                assert saved == k, f"round {k} acknowledged {saved} writes"
+            assert _tracked_compiles(eng) == tracked0
+            assert _xla_compiles() == xla0, "a round compiled something"
+        assert telemetry.GLOBAL.snapshot().get(
+            "engine_save_window_overflow", 0) == overflow0
+        for sid in (1, SHARDS):
+            assert nh.sync_read(sid, f"r{SHARDS}", 30) == str(sid)
+    finally:
+        nh.close()
+
+
+def _persisted(nh, sid=1):
+    rs = nh.logdb.read_raft_state(sid, 1, 0)
+    ents = nh.logdb.iterate_entries(
+        sid, 1, rs.first_index, rs.first_index + rs.entry_count, 0)
+    return [(e.index, e.term, bytes(e.cmd)) for e in ents
+            if not e.is_config_change()]
+
+
+def test_lane_past_the_save_window_takes_its_ring_row(monkeypatch):
+    """A kernel parameter set whose download carries only 2 ring entries
+    per lane: a step that appends 8 leaves the window, the engine reads
+    that lane's whole ring row once (fixed shape) and counts it, and the
+    entries it persists are those the host-resident pycore node persists
+    for the same proposals."""
+    cmds = [f"w{i}={i}".encode() for i in range(8)]
+
+    oracle = _host("rb-oracle", 1, device=False)
+    try:
+        s = oracle.get_noop_session(1)
+        for rs in [oracle.propose(s, c, 30) for c in cmds]:
+            rs.get(30)
+        want = _persisted(oracle)
+    finally:
+        oracle.close()
+
+    picked = NodeHost._kernel_params
+
+    def small_window(self, min_inbox: int = 0):
+        return dataclasses.replace(picked(self, min_inbox), save_window=2)
+
+    monkeypatch.setattr(NodeHost, "_kernel_params", small_window)
+    counter = "engine_save_window_overflow"
+    before = telemetry.GLOBAL.snapshot().get(counter, 0)
+    nh = _host("rb-small", 1)
+    try:
+        eng = nh.kernel_engine
+        assert eng.kp.save_window == 2
+        s = nh.get_noop_session(1)
+        with eng.mu:
+            _settle(eng)
+            m0 = capacity.METER.counts()
+            states = [nh.propose(s, c, 30) for c in cmds]
+            assert eng.step_all()
+            m1 = capacity.METER.counts()
+        for rs in states:
+            rs.get(30)
+        assert m1.get("save_window_row", 0) - m0.get("save_window_row", 0) \
+            == 1
+        assert telemetry.GLOBAL.snapshot()[counter] == before + 1
+        got = _persisted(nh)
+    finally:
+        nh.close()
+    assert [c for _i, _t, c in got][-8:] == cmds
+    assert got == want
+
+
+def test_tick_no_faster_than_the_engines_recent_rounds():
+    """The logical clock: one tick a step, but no sooner after the last
+    than the engine's recent rounds took (capped): an engine between
+    bursts of work must not run its election and heartbeat clocks several
+    times faster than a loaded peer's."""
+    from dragonboat_tpu.engine import kernel_engine as ke
+
+    nh = _host("rb-tick", 1)
+    try:
+        eng = nh.kernel_engine
+        with eng.mu:
+            _settle(eng)
+            # the floor follows the rounds' length: up by at most a
+            # doubling a round (one 10 s compile round moves it 5 ms), down
+            # a sixteenth a round, never above the cap
+            eng._tick_floor_us = 0
+            eng._round_t0_us = ke.monotonic_us() - 10_000_000
+            eng._commit_round(0, ())
+            assert eng._tick_floor_us == 5_000
+            for _ in range(8):
+                eng._round_t0_us = ke.monotonic_us() - 40_000
+                eng._commit_round(0, ())
+            assert 40_000 <= eng._tick_floor_us < 45_000
+            for _ in range(8):
+                eng._round_t0_us = ke.monotonic_us() - 10_000_000
+                eng._commit_round(0, ())
+            assert eng._tick_floor_us == ke._MAX_TICK_FLOOR_US
+            eng._round_t0_us = ke.monotonic_us()
+            eng._commit_round(0, ())
+            assert eng._tick_floor_us == ke._MAX_TICK_FLOOR_US * 15 // 16
+
+            def ticked():
+                before = capacity.METER.counts().get("round_up", 0)
+                eng.tick_round()
+                eng.step_all()
+                return capacity.METER.counts().get("round_up", 0) - before
+
+            # a tick is pending, but the last one is 1 ms old: no round
+            eng._tick_floor_us = 50_000
+            eng._last_tick_us = ke.monotonic_us() - 1_000
+            assert ticked() == 0
+            # ...and once the floor has passed, the pending tick is a round
+            eng._last_tick_us = ke.monotonic_us() - 60_000
+            assert ticked() == 1
+    finally:
+        nh.close()
+
+
+def test_a_later_heartbeat_supersedes_the_queued_ones():
+    """Staging keeps, of the heartbeats queued from one sender in one
+    term, only the last (its commit is monotone and its ReadIndex ctx the
+    newest pending one): a lane that fell behind answers the newest, not
+    a backlog.  Everything else keeps its place and order."""
+    from dragonboat_tpu import raftpb as pb
+    from dragonboat_tpu.engine.kernel_engine import _newest_heartbeats
+
+    MT = pb.MessageType
+
+    def hb(frm, term, commit, hint=0):
+        return pb.Message(type=MT.HEARTBEAT, from_=frm, to=2, shard_id=1,
+                          term=term, commit=commit, hint=hint)
+
+    rep = pb.Message(type=MT.REPLICATE, from_=1, to=2, shard_id=1, term=3)
+    resp = pb.Message(type=MT.HEARTBEAT_RESP, from_=3, to=2, shard_id=1,
+                      term=3)
+    a, b, c = hb(1, 3, 10), hb(1, 3, 11, hint=7), hb(1, 3, 12, hint=9)
+    old_term, other = hb(1, 2, 5), hb(3, 3, 4)
+    msgs = [old_term, a, rep, b, other, resp, c]
+    assert _newest_heartbeats(msgs) == [old_term, rep, other, resp, c]
+    single = [a, rep, other]
+    assert _newest_heartbeats(single) is single      # nothing to drop
+
+
+def test_forwarded_read_the_leader_turned_away_waits_its_turn():
+    """A follower host's forwarded ReadIndex that the leader's kernel drops
+    (its ReadIndex book full) is staged again next round, ahead of later
+    ones: the requester would otherwise hear nothing until its timeout.
+    Once the shard is no longer led from here it is let go."""
+    from types import SimpleNamespace
+
+    import numpy as np
+
+    from dragonboat_tpu import raftpb as pb
+    from dragonboat_tpu.core import params as KP
+    from dragonboat_tpu.engine import kernel_engine as ke
+
+    eng = ke.KernelEngine(KP.KernelParams(), capacity=4, send_message=None)
+    ctx, later = pb.SystemCtx(low=7, high=1), pb.SystemCtx(low=9, high=1)
+    o = {"ri_dropped": np.array([False, True, False, False])}
+    flags = np.zeros((len(ke.FLAG_CLASSES),), bool)
+
+    def node(leader: bool):
+        return SimpleNamespace(
+            _local_ri_pending={}, _remote_ri_inflight={7: 3},
+            _remote_reads=[(2, later, 0)], is_leader=lambda: leader,
+            pending_reads=SimpleNamespace(applied=lambda i: None),
+            sm=SimpleNamespace(get_last_applied=lambda: 0))
+
+    n = node(leader=True)
+    eng._complete_reads(1, n, o, flags, ctx)
+    assert not n._remote_ri_inflight
+    assert [(s, c) for s, c, _t in n._remote_reads] == [(3, ctx), (2, later)]
+    n = node(leader=False)
+    eng._complete_reads(1, n, o, flags, ctx)
+    assert not n._remote_ri_inflight
+    assert [(s, c) for s, c, _t in n._remote_reads] == [(2, later)]
+    eng.close()

@@ -157,6 +157,7 @@ def test_a_pass_that_raises_in_step_all_leaves_no_round_open():
         raise RuntimeError("injection failed")
 
     eng._flush_injections = boom
+    eng.tick_round()            # a pass with nothing to do leaves at once
     with pytest.raises(RuntimeError, match="injection failed"):
         eng.step_all()
     assert tracing.current_phase() == "none"

@@ -27,7 +27,7 @@ def _load(path, name):
 
 
 # A minimal transfer-clean repo: one dispatch entry pair whose every
-# crossing is declared, staged through to_device builders, synced only
+# crossing is declared, staged through a to_device builder, synced only
 # at the declared SYNC_POINTS qualname, and sized through a CONTRACTS
 # literal carried in the engine fixture itself.
 DISPATCH_FIX = '''\
@@ -52,35 +52,31 @@ TRANSFER_LEDGER = {
     "step": {
         "resident": ("ShardState",),
         "up": (
-            {"value": "Inbox", "param": "inbox",
-             "site": "_InboxBuilder.to_device", "tag": "inbox_up",
-             "per_step": True},
-            {"value": "StepInput", "param": "inp",
-             "site": "_InputBuilder.to_device", "tag": "input_up",
-             "per_step": True},
+            {"value": ("Inbox", "StepInput"), "packed": True,
+             "param": "up", "site": "_RoundStaging.to_device",
+             "tag": "round_up", "per_step": True},
         ),
         "down": (
-            {"value": "[G, 8] bool", "site": "Engine._process_outputs",
-             "tag": "output_flags", "per_step": True},
-            {"value": "StepOutput", "site": "Engine.fetch_field",
-             "tag": "lazy_out", "masked": True},
+            {"value": ("[G, 8] bool", "StepOutput"), "packed": True,
+             "site": "Engine._process_outputs",
+             "tag": "round_down", "per_step": True},
+            {"value": "[1, CAP] i32", "site": "Engine.fetch_row",
+             "tag": "save_window_row", "masked": True},
         ),
     },
     "step_donated": {
         "resident": ("ShardState",),
         "up": (
-            {"value": "Inbox", "param": "inbox",
-             "site": "_InboxBuilder.to_device", "tag": "inbox_up",
-             "per_step": True},
-            {"value": "StepInput", "param": "inp",
-             "site": "_InputBuilder.to_device", "tag": "input_up",
-             "per_step": True},
+            {"value": ("Inbox", "StepInput"), "packed": True,
+             "param": "up", "site": "_RoundStaging.to_device",
+             "tag": "round_up", "per_step": True},
         ),
         "down": (
-            {"value": "[G, 8] bool", "site": "Engine._process_outputs",
-             "tag": "output_flags", "per_step": True},
-            {"value": "StepOutput", "site": "Engine.fetch_field",
-             "tag": "lazy_out", "masked": True},
+            {"value": ("[G, 8] bool", "StepOutput"), "packed": True,
+             "site": "Engine._process_outputs",
+             "tag": "round_down", "per_step": True},
+            {"value": "[1, CAP] i32", "site": "Engine.fetch_row",
+             "tag": "save_window_row", "masked": True},
         ),
     },
     "_control": (
@@ -115,14 +111,9 @@ CONTRACTS = {
 }
 
 
-class _InboxBuilder:
+class _RoundStaging:
     def to_device(self):
-        return jnp.asarray(self.buf)
-
-
-class _InputBuilder:
-    def to_device(self):
-        return jnp.asarray(self.buf)
+        return jnp.asarray(self.up)
 
 
 class Engine:
@@ -136,16 +127,16 @@ class Engine:
     def _process_outputs(self, out):
         return np.asarray(out)
 
-    def fetch_field(self, out, f):
-        return np.asarray(getattr(out, f))
+    def fetch_row(self, ring, g):
+        return np.asarray(ring[g])
 '''
 
 KERNEL_FIX = '''\
-def step(kp, state, inbox, inp):
+def step(kp, state, up):
     return state
 
 
-def step_donated(kp, state, inbox, inp):
+def step_donated(kp, state, up):
     return state
 '''
 
@@ -207,8 +198,8 @@ def test_tb001_uncovered_entry_parameter(tmp_path):
     # a fourth array parameter appears on the jit entry with no
     # resident/upload declaration covering it
     root = _mini_repo(tmp_path, kernel=KERNEL_FIX.replace(
-        "def step(kp, state, inbox, inp):",
-        "def step(kp, state, inbox, inp, sideband):"))
+        "def step(kp, state, up):",
+        "def step(kp, state, up, sideband):"))
     fs = _run_fix(root)
     assert any(f.rule == "TB001" and "'sideband'" in f.message
                and "undeclared host->device crossing" in f.message
@@ -282,14 +273,39 @@ def test_tb002_missing_budget_fires_on_real_run_only(tmp_path):
 # ------------------------------------------------------------------ TB003
 
 
-def test_tb003_unmasked_wide_download_row(tmp_path):
+def test_tb003_wide_download_row_on_its_own(tmp_path):
+    # a wide [G, axis] value fetched per step by a row that is neither
+    # the packed download nor lane-masked: a crossing of its own
     root = _mini_repo(tmp_path, dispatch=DISPATCH_FIX.replace(
-        '{"value": "StepOutput", "site": "Engine.fetch_field",\n'
-        '             "tag": "lazy_out", "masked": True},',
-        '{"value": "[G, CAP] i32", "site": "Engine.fetch_field",\n'
-        '             "tag": "lazy_out", "per_step": True},', 1))
+        '{"value": "[1, CAP] i32", "site": "Engine.fetch_row",\n'
+        '             "tag": "save_window_row", "masked": True},',
+        '{"value": "[G, CAP] i32", "site": "Engine.fetch_row",\n'
+        '             "tag": "save_window_row", "per_step": True},', 1))
     fs = _run_fix(root)
-    assert any(f.rule == "TB003" and "unmasked" in f.message for f in fs)
+    assert any(f.rule == "TB003" and "on its own" in f.message for f in fs)
+
+
+def test_tb003_packed_wide_download_is_clean(tmp_path):
+    # the round's one packed download carries every wide StepOutput
+    # field by design; its bytes are every element at 4 (a bool is a
+    # 0/1 int32 column)
+    root = _mini_repo(tmp_path)
+    assert "TB003" not in _rules(_run_fix(root))
+    sized = transfer.build_ledger(
+        root, decl=transfer._load_decl(root)[0],
+        cfg=dict(transfer.DEFAULT_CONFIG),
+        contracts=transfer._collect_contracts(
+            {"e": transfer._parse(os.path.join(
+                root, "dragonboat_tpu/engine/engine.py"))}, []))
+    cfg = transfer.DEFAULT_CONFIG
+    G, K, E, B = (cfg["num_groups"], cfg["inbox_cap"], cfg["msg_entries"],
+                  cfg["proposal_cap"])
+    step = sized["entries"]["step"]
+    assert step["up"][0]["bytes"] == 4 * G * (K + K * E + B)
+    assert step["down"][0]["bytes"] == 4 * G * (8 + K + 8)
+    assert sized["per_step"]["serial"] == {
+        "up_bytes": 4 * G * (K + K * E + B), "up_crossings": 1,
+        "down_bytes": 4 * G * (8 + K + 8), "down_crossings": 1}
 
 
 def test_tb003_eager_wide_field_fetch(tmp_path):
@@ -338,8 +354,8 @@ def sneak_upload2(rows):
 
 
 def test_tb004_declared_site_and_builder_are_clean(tmp_path):
-    # Engine.inject is a declared _control site and the builders are
-    # *.to_device — all three upload in the clean fixture
+    # Engine.inject is a declared _control site and the staging is
+    # *.to_device — both upload in the clean fixture
     fs = _run_fix(_mini_repo(tmp_path))
     assert "TB004" not in _rules(fs)
 
@@ -384,7 +400,7 @@ def stall(box):
 
 def test_tb006_tampered_crossing_budget_fires(tmp_path):
     tight = json.loads(json.dumps(_PERMISSIVE))
-    tight["budget"]["serial"]["up_crossings_per_step"] = 1
+    tight["budget"]["serial"]["up_crossings_per_step"] = 0
     fs = _run_fix(_mini_repo(tmp_path, budget=tight))
     assert any(f.rule == "TB006" and "transfer count grew" in f.message
                for f in fs)
@@ -427,18 +443,17 @@ def test_runtime_guard_catches_host_round_trip():
     state = make_cluster(kp, 1, 3)
     G = int(state.term.shape[0])
     disp = SerialDispatch(kp)
-    inbox = _ke._InboxBuilder(G, kp.inbox_cap, kp.msg_entries)
-    inp = _ke._InputBuilder(G, kp.proposal_cap)
-    state, _out = disp.dispatch(state, inbox, inp, donate=False)  # warm
+    staging = _ke._RoundStaging(kp, G)
+    state, _down = disp.dispatch(state, staging, donate=False)  # warm
 
     # the regression: state pulled to host numpy, fed straight back in
     state_np = jax.tree.map(np.array, state)
     with capacity.METER.guard():
         with pytest.raises(Exception, match="[Dd]isallow"):
-            disp.dispatch(state_np, inbox, inp, donate=False)
+            disp.dispatch(state_np, staging, donate=False)
     # sanctioned crossings still work inside the guard
     with capacity.METER.guard():
-        state, _out = disp.dispatch(state, inbox, inp, donate=False)
+        state, _down = disp.dispatch(state, staging, donate=False)
 
 
 # ------------------------------------- ledger vs live (depth 0 and 1)
@@ -458,9 +473,9 @@ def test_tampered_ledger_diverges_from_live():
     for entry in ("step", "step_donated"):
         rows = decl["TRANSFER_LEDGER"][entry]["up"]
         decl["TRANSFER_LEDGER"][entry]["up"] = tuple(
-            r for r in rows if r.get("tag") != "input_up")
+            r for r in rows if r.get("tag") != "round_up")
     fs = transfer.live_transfer_check(REPO, decl=decl, use_cache=False)
-    assert any(f.rule == "TB006" and "'input_up'" in f.message
+    assert any(f.rule == "TB006" and "'round_up'" in f.message
                for f in fs)
 
 
@@ -492,23 +507,24 @@ def test_emit_ledger_artifact(tmp_path):
 
 # PR 17 seeded the mesh per-step budget when the serving entry still
 # downloaded a device->host pending scalar every step; round 17 derives
-# drain-pending from the output flags the host already fetches
-_PR17_MESH_DOWN_BYTES = 8196
+# drain-pending from the activity flags the host already fetches, and
+# PR 26 packed each direction of a round into one array
 _PR17_MESH_DOWN_CROSSINGS = 2
 
 
-def test_mesh_budget_strictly_shrank(tmp_path):
-    """Round 17's device-resident fabric DELETED host crossings from the
-    mesh serving step — the reseeded budget must be strictly below the
-    PR 17 values, and must never regrow past them."""
+def test_per_step_crossings_stay_at_one(tmp_path):
+    """A round makes ONE upload and ONE download on both backends: the
+    reseeded budget must say so (the bytes grew by design — the packed
+    download carries every StepOutput field; a crossing costs its
+    millisecond whatever it carries), and the mesh download crossings
+    must stay below PR 17's."""
     spec = transfer.reseed(REPO, budget_path=str(tmp_path / "b.json"))
-    mesh = spec["budget"]["mesh"]
-    assert mesh["down_bytes_per_step"] < _PR17_MESH_DOWN_BYTES, (
-        "mesh per-step download budget did not shrink vs PR 17 — a "
-        "per-step device->host crossing crept back into the serving "
-        "entry's ledger")
-    assert mesh["down_crossings_per_step"] < _PR17_MESH_DOWN_CROSSINGS, (
-        "mesh per-step download crossings did not shrink vs PR 17")
+    for profile in ("serial", "mesh"):
+        got = spec["budget"][profile]
+        assert got["up_crossings_per_step"] == 1, profile
+        assert got["down_crossings_per_step"] == 1, profile
+    assert (spec["budget"]["mesh"]["down_crossings_per_step"]
+            < _PR17_MESH_DOWN_CROSSINGS)
 
 
 def test_reseed_roundtrip(tmp_path):
