@@ -16,6 +16,7 @@ import functools
 import math
 from typing import NamedTuple
 
+import jax
 import jax.numpy as jnp
 import numpy as np
 
@@ -518,6 +519,80 @@ def init_state(
         quiesced=jnp.asarray(zb()),
         quiesce_epoch=jnp.asarray(z()),
     )
+
+
+def inject_rows(state: ShardState, lanes, rows: dict) -> ShardState:
+    """``state`` with the rows ``lanes`` set to a batch of admissions:
+    ``rows`` holds what differs from lane to lane, the rest is a fresh
+    follower's (``KernelEngine._flush_injections`` builds the batch)."""
+    s = state
+
+    def put(arr, vals):
+        # route sub-32-bit scatters through int32: non-uniform-index
+        # scatters on bool operands silently drop writes on TPU past ~3k
+        # rows (the _set1 miscompile, core/kernel.py) — an admission
+        # batch is exactly that shape
+        if arr.dtype == jnp.bool_:
+            vals_i = (vals.astype(jnp.int32) if hasattr(vals, "astype")
+                      else vals)     # a Python constant sets as it is
+            return (arr.astype(jnp.int32).at[lanes].set(vals_i)
+                    .astype(bool))
+        return arr.at[lanes].set(vals)
+
+    last = rows["last"]
+    return s._replace(
+        replica_id=put(s.replica_id, rows["replica_id"]),
+        seed=put(s.seed, rows["seed"]),
+        rand_timeout=put(s.rand_timeout, rows["rand_timeout"]),
+        rand_counter=put(s.rand_counter, 0),
+        e_timeout=put(s.e_timeout, rows["e_timeout"]),
+        h_timeout=put(s.h_timeout, rows["h_timeout"]),
+        check_quorum=put(s.check_quorum, rows["check_quorum"]),
+        pre_vote=put(s.pre_vote, rows["pre_vote"]),
+        role=put(s.role, rows["role"]),
+        term=put(s.term, rows["term"]),
+        vote=put(s.vote, rows["vote"]),
+        leader=put(s.leader, 0),
+        applied=put(s.applied, rows["applied"]),
+        e_tick=put(s.e_tick, 0),
+        h_tick=put(s.h_tick, 0),
+        pending_cc=put(s.pending_cc, False),
+        ltt=put(s.ltt, 0),
+        is_ltt=put(s.is_ltt, False),
+        pid=put(s.pid, rows["pid"]),
+        kind=put(s.kind, rows["kind"]),
+        match=put(s.match, 0),
+        next=put(s.next, (last + 1)[:, None]),
+        pstate=put(s.pstate, P.R_RETRY),
+        active=put(s.active, False),
+        psnap=put(s.psnap, 0),
+        vresp=put(s.vresp, False),
+        vgrant=put(s.vgrant, False),
+        lt=put(s.lt, rows["lt"]),
+        lcc=put(s.lcc, rows["lcc"]),
+        snap_index=put(s.snap_index, rows["snap_index"]),
+        snap_term=put(s.snap_term, rows["snap_term"]),
+        last=put(s.last, last),
+        committed=put(s.committed, rows["committed"]),
+        processed=put(s.processed, rows["applied"]),
+        stable=put(s.stable, last),
+        ri_head=put(s.ri_head, 0),
+        ri_count=put(s.ri_count, 0),
+        needs_host=put(s.needs_host, False),
+        quiesce_on=put(s.quiesce_on, rows["quiesce_on"]),
+        idle_tick=put(s.idle_tick, 0),
+        quiesced=put(s.quiesced, False),
+        quiesce_epoch=put(s.quiesce_epoch, 0),
+    )
+
+
+@functools.lru_cache(maxsize=None)
+def inject_program(placement=None):
+    """``inject_rows`` jitted, its result placed as ``placement`` says (a
+    pytree of shardings like the state, or None on a single device).  One
+    program per placement for the whole process: engines of one geometry
+    share its compiles."""
+    return jax.jit(inject_rows, out_shardings=placement)
 
 
 class Inbox(NamedTuple):

@@ -78,6 +78,7 @@ STEP_LOOP_METHODS = (
 DISPATCH_SEAMS = (
     "_make_dispatch",
     "_emit_messages",
+    "_send",
     "_prop_target",
     "_mirror_floor",
     "_is_registered",
@@ -354,6 +355,11 @@ class SerialDispatch:
         """Single device: placement is a no-op."""
         return tree
 
+    def placement(self, tree):
+        """Output shardings for a program that rewrites ``tree``: none to
+        ask for on a single device."""
+        return None
+
     def resident_trees(self) -> tuple:
         return ()
 
@@ -410,10 +416,10 @@ class MeshDispatch:
         ``round_serve_step_donated``); the cached cut mask is never
         donated.  Returns ``(state, down)`` like the serial backend."""
         cl = self.cluster
-        up = staging.to_device(cl.sharding(1))
+        up = staging.to_device(cl.sharding())
         if self._cut_dev is None:
             with _capacity.METER.sanctioned("cut_up"):
-                self._cut_dev = jax.device_put(self.cut, cl.sharding(1))
+                self._cut_dev = jax.device_put(self.cut, cl.sharding())
         entry = self.entries["serve_step_donated" if donate
                              else "serve_step"]
         state, self.box, down = entry(
@@ -442,6 +448,12 @@ class MeshDispatch:
         """Place a [G]-leading pytree onto the mesh (digests and the
         like shard along G exactly like the state they derive from)."""
         return self.cluster.shard(tree)
+
+    def placement(self, tree):
+        """Output shardings for a program that rewrites ``tree``: every
+        [G]-leading leaf stays sharded as the serve entry takes it."""
+        sharding = self.cluster.sharding()
+        return jax.tree.map(lambda _: sharding, tree)
 
     def set_cut(self, lane: int, cut: bool) -> None:
         """Flip one row's WHOLE partition mask (every link of the row)

@@ -76,6 +76,7 @@ from dragonboat_tpu.core.kstate import (
     column_value,
     column_views,
     init_state,
+    inject_program,
     round_columns,
 )
 from dragonboat_tpu.core.round import ring_row
@@ -149,6 +150,7 @@ _READ_STAGE_WAIT_US = telemetry.GLOBAL.histogram(
 ADD_SHARD_LOCK_US = telemetry.GLOBAL.histogram(
     "engine_add_shard_lock_us",
     help="add_shard's wait for the engine lock, per call")
+_INJECT_BATCH = 8       # least rows of one flush's program
 _INJECT_FLUSH_US = telemetry.GLOBAL.histogram(
     "engine_inject_flush_us",
     help="one batch of queued lane injections written into the device "
@@ -438,6 +440,7 @@ class KernelEngine:
         # admissions queued for the next step's batched injection
         # (lane -> (node, init, pids, kinds)); see _flush_injections
         self._pending_inject: dict[int, tuple] = {}
+        self._inject_fn = None      # _inject_rows jitted for this state
         # whole-engine tick rounds queued by the host ticker; each step
         # consumes ONE round as a vectorized [G]-bool broadcast (the
         # per-lane Python tick walk was ~25 s/round at 100k lanes).
@@ -622,9 +625,10 @@ class KernelEngine:
         self.mark_dirty(lane)
 
     def _flush_injections(self) -> None:
-        """One ``.at[lanes].set`` per state field for every admission
-        queued since the last step — O(capacity + n) instead of
-        O(n·capacity)."""
+        """Every admission queued since the last step, written into the
+        state by ONE jitted program (kstate.py ``inject_rows``): O(capacity + n)
+        instead of O(n·capacity), and one dispatch instead of one per
+        state field."""
         if not self._pending_inject:
             return
         t0 = monotonic_us()
@@ -633,21 +637,21 @@ class KernelEngine:
         self._pending_inject = {}
         n = len(items)
         lanes_np = np.array([g for g, _ in items], np.int32)
-        f32 = {k: np.zeros((n,), np.int32) for k in (
+        rows = {k: np.zeros((n,), np.int32) for k in (
             "replica_id", "seed", "rand_timeout", "e_timeout", "h_timeout",
             "role", "term", "vote", "applied", "snap_index", "snap_term",
             "last", "committed")}
-        fb = {k: np.zeros((n,), bool) for k in ("check_quorum", "pre_vote",
-                                                "quiesce_on")}
-        pid_rows = np.zeros((n, kp.num_peers), np.int32)
-        kind_rows = np.zeros((n, kp.num_peers), np.int32)
-        lt_rows = np.zeros((n, kp.log_cap), np.int32)
-        lcc_rows = np.zeros((n, kp.log_cap), bool)
+        rows.update({k: np.zeros((n,), bool) for k in (
+            "check_quorum", "pre_vote", "quiesce_on")})
+        rows["pid"] = np.zeros((n, kp.num_peers), np.int32)
+        rows["kind"] = np.zeros((n, kp.num_peers), np.int32)
+        rows["lt"] = np.zeros((n, kp.log_cap), np.int32)
+        rows["lcc"] = np.zeros((n, kp.log_cap), bool)
         for j, (lane, (node, init, pids, kinds)) in enumerate(items):
-            pid_rows[j], kind_rows[j] = pids, kinds
+            rows["pid"][j], rows["kind"][j] = pids, kinds
             for e in init.entries:
-                lt_rows[j, e.index & (kp.log_cap - 1)] = e.term
-                lcc_rows[j, e.index & (kp.log_cap - 1)] = \
+                rows["lt"][j, e.index & (kp.log_cap - 1)] = e.term
+                rows["lcc"][j, e.index & (kp.log_cap - 1)] = \
                     e.is_config_change()
             last = init.entries[-1].index if init.entries \
                 else init.snap_index
@@ -665,84 +669,46 @@ class KernelEngine:
             seed = int(KP.splitmix32(
                 (node.shard_id * 2654435761 + node.replica_id * 40503)
                 & 0xFFFFFFFF)) & 0x7FFFFFFF
-            f32["replica_id"][j] = node.replica_id
-            f32["seed"][j] = seed
-            f32["rand_timeout"][j] = KP.randomized_timeout(
+            rows["replica_id"][j] = node.replica_id
+            rows["seed"][j] = seed
+            rows["rand_timeout"][j] = KP.randomized_timeout(
                 seed, 0, cfg.election_rtt)
-            f32["e_timeout"][j] = cfg.election_rtt
-            f32["h_timeout"][j] = max(1, cfg.heartbeat_rtt)
-            fb["check_quorum"][j] = cfg.check_quorum
-            fb["pre_vote"][j] = cfg.pre_vote
-            fb["quiesce_on"][j] = cfg.quiesce
-            f32["role"][j] = role
-            f32["term"][j] = init.term
-            f32["vote"][j] = init.vote
-            f32["applied"][j] = init.applied
-            f32["snap_index"][j] = init.snap_index
-            f32["snap_term"][j] = init.snap_term
-            f32["last"][j] = last
-            f32["committed"][j] = init.committed
-        s = self.state
+            rows["e_timeout"][j] = cfg.election_rtt
+            rows["h_timeout"][j] = max(1, cfg.heartbeat_rtt)
+            rows["check_quorum"][j] = cfg.check_quorum
+            rows["pre_vote"][j] = cfg.pre_vote
+            rows["quiesce_on"][j] = cfg.quiesce
+            rows["role"][j] = role
+            rows["term"][j] = init.term
+            rows["vote"][j] = init.vote
+            rows["applied"][j] = init.applied
+            rows["snap_index"][j] = init.snap_index
+            rows["snap_term"][j] = init.snap_term
+            rows["last"][j] = last
+            rows["committed"][j] = init.committed
+        # The batch is padded with copies of its last lane (a copy writes
+        # the same values to the same row) to a power of two and at least
+        # _INJECT_BATCH, so the program compiles once per size class and an
+        # engine that admits a few lanes at a time has one.  (45 eager
+        # scatters took 0.5-1 s a batch, and every admission waited for
+        # one under the engine's lock.)
+        size = max(_INJECT_BATCH, 1 << (n - 1).bit_length())
+        if size > n:
+            lanes_np = np.concatenate(
+                [lanes_np, np.repeat(lanes_np[-1:], size - n)])
+            rows = {k: np.concatenate(
+                        [v, np.repeat(v[-1:], size - n, axis=0)])
+                    for k, v in rows.items()}
+        if self._inject_fn is None:
+            # the rows come back placed as the backend keeps its state (a
+            # mesh engine's stay sharded as its serve entry was compiled)
+            self._inject_fn = _capacity.TRACKER.wrap(
+                "inject_rows", inject_program(
+                    self._dispatch.placement(self.state)))
         with _capacity.METER.sanctioned("inject_up"):
-            lanes = jnp.asarray(lanes_np)
-            A = {k: jnp.asarray(v) for k, v in {**f32, **fb}.items()}
-
-            def put(arr, vals):
-                # route sub-32-bit scatters through int32: non-uniform-
-                # index scatters on bool operands silently drop writes on
-                # TPU past ~3k rows (the _set1 miscompile, core/kernel.py)
-                # — an admission batch is exactly that shape
-                if arr.dtype == jnp.bool_:
-                    vals_i = jnp.asarray(vals).astype(jnp.int32)
-                    return (arr.astype(jnp.int32).at[lanes].set(vals_i)
-                            .astype(bool))
-                return arr.at[lanes].set(vals)
-
-            last_v = A["last"]
-            self.state = s._replace(
-                replica_id=put(s.replica_id, A["replica_id"]),
-                seed=put(s.seed, A["seed"]),
-                rand_timeout=put(s.rand_timeout, A["rand_timeout"]),
-                rand_counter=put(s.rand_counter, 0),
-                e_timeout=put(s.e_timeout, A["e_timeout"]),
-                h_timeout=put(s.h_timeout, A["h_timeout"]),
-                check_quorum=put(s.check_quorum, A["check_quorum"]),
-                pre_vote=put(s.pre_vote, A["pre_vote"]),
-                role=put(s.role, A["role"]),
-                term=put(s.term, A["term"]),
-                vote=put(s.vote, A["vote"]),
-                leader=put(s.leader, 0),
-                applied=put(s.applied, A["applied"]),
-                e_tick=put(s.e_tick, 0),
-                h_tick=put(s.h_tick, 0),
-                pending_cc=put(s.pending_cc, False),
-                ltt=put(s.ltt, 0),
-                is_ltt=put(s.is_ltt, False),
-                pid=put(s.pid, jnp.asarray(pid_rows)),
-                kind=put(s.kind, jnp.asarray(kind_rows)),
-                match=put(s.match, 0),
-                next=put(s.next, (last_v + 1)[:, None]),
-                pstate=put(s.pstate, KP.R_RETRY),
-                active=put(s.active, False),
-                psnap=put(s.psnap, 0),
-                vresp=put(s.vresp, False),
-                vgrant=put(s.vgrant, False),
-                lt=put(s.lt, jnp.asarray(lt_rows)),
-                lcc=put(s.lcc, jnp.asarray(lcc_rows)),
-                snap_index=put(s.snap_index, A["snap_index"]),
-                snap_term=put(s.snap_term, A["snap_term"]),
-                last=put(s.last, last_v),
-                committed=put(s.committed, A["committed"]),
-                processed=put(s.processed, A["applied"]),
-                stable=put(s.stable, last_v),
-                ri_head=put(s.ri_head, 0),
-                ri_count=put(s.ri_count, 0),
-                needs_host=put(s.needs_host, False),
-                quiesce_on=put(s.quiesce_on, A["quiesce_on"]),
-                idle_tick=put(s.idle_tick, 0),
-                quiesced=put(s.quiesced, False),
-                quiesce_epoch=put(s.quiesce_epoch, 0),
-            )
+            self.state = self._inject_fn(
+                self.state, jnp.asarray(lanes_np),
+                {k: jnp.asarray(v) for k, v in rows.items()})
         _INJECT_FLUSH_US.observe(monotonic_us() - t0)
 
     def _clear_lane(self, lane: int) -> None:
@@ -1405,7 +1371,7 @@ class KernelEngine:
                     # forward to the leader host (raft.go ReadIndex
                     # leader forwarding)
                     n._local_ri_pending[ctx.low] = ctx
-                    n.send_message(pb.Message(
+                    self._send(n, pb.Message(
                         type=MT.READ_INDEX, from_=n.replica_id,
                         to=n._leader_cache, shard_id=n.shard_id,
                         hint=ctx.low, hint_high=ctx.high))

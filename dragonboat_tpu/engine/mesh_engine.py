@@ -41,6 +41,7 @@ from jax.sharding import Mesh
 from dragonboat_tpu import capacity as _capacity
 from dragonboat_tpu import fabric as _fabric
 from dragonboat_tpu import raftpb as pb
+from dragonboat_tpu import telemetry
 from dragonboat_tpu.config import MeshSpec
 from dragonboat_tpu.core import params as KP
 from dragonboat_tpu.core.kstate import init_state
@@ -59,6 +60,23 @@ from dragonboat_tpu.tracing import monotonic_us
 _LOG = get_logger("mesh_engine")
 
 MT = pb.MessageType
+
+# what a mesh engine still moves over the host transport (always on; the
+# benchmark's mesh_hub_msgs_per_step reads the family): with every link
+# resident and writes sent to leaders all three stay 0, which is what says
+# that the fabric, and not the hub, did the work
+_HUB_MSGS = telemetry.GLOBAL.counter(
+    "engine_mesh_hub_msgs",
+    help="messages of a mesh engine's replicas that met the host "
+         "transport: sent = kernel-family messages handed to it for cut "
+         "or off-mesh links; read_forward = READ_INDEX / READ_INDEX_RESP "
+         "forwarded host to host; stray_dropped = hub copies turned away "
+         "at the inbound gate because the link is resident",
+    labelnames=("way",))
+_HUB_SENT = _HUB_MSGS.labels("sent")
+_HUB_READ_FORWARD = _HUB_MSGS.labels("read_forward")
+_HUB_STRAY_DROPPED = _HUB_MSGS.labels("stray_dropped")
+_READ_FORWARDS = frozenset({MT.READ_INDEX, MT.READ_INDEX_RESP})
 
 
 class MeshEngine(KernelEngine):
@@ -121,6 +139,12 @@ class MeshEngine(KernelEngine):
         self._mirrors: dict[int, dict[int, pb.Entry]] = {}    # sid -> mirror
         self._free_lanes = list(range(self.cluster.num_groups - 1, -1, -1))
         self._free = []   # base's row free-list is unused (rows are fixed)
+        # a row no replica is placed in is cut from the mesh: route()
+        # addresses rows by position, so an uncut empty row would receive
+        # its group's votes and appends and answer them, a member with no
+        # LogDB behind it (add_shard heals the row, remove_replica cuts it;
+        # tests/test_mesh_cell.py test_empty_mesh_rows_take_no_part)
+        self._dispatch.cut[:] = True
         self._refs = 0    # attached NodeHosts (registry lifecycle)
 
     # -- row addressing ----------------------------------------------------
@@ -174,6 +198,7 @@ class MeshEngine(KernelEngine):
             self.nodes[row] = node
             self.by_shard[(node.shard_id, node.replica_id)] = node
             self._inject(row, node, init)
+            self._dispatch.set_cut(row, False)
             self._note_link_classes(node)
 
     def remove_replica(self, node: KernelNode) -> KernelNode | None:
@@ -191,7 +216,7 @@ class MeshEngine(KernelEngine):
             self.nodes.pop(node.lane, None)
             self._removed_nodes.append(node)
             self._clear_lane(node.lane)
-            self._dispatch.set_cut(node.lane, False)
+            self._dispatch.set_cut(node.lane, True)     # empty rows are cut
             if not members:
                 lane = self._lane_of.pop(node.shard_id, None)
                 self._members.pop(node.shard_id, None)
@@ -284,7 +309,10 @@ class MeshEngine(KernelEngine):
         the like) always lands."""
         if m.type not in _KERNEL_MTYPES:
             return True
-        return self.link_hub_served(node, int(m.from_))
+        if self.link_hub_served(node, int(m.from_)):
+            return True
+        _HUB_STRAY_DROPPED.inc()
+        return False
 
     def link_hub_served(self, node: KernelNode, from_rid: int) -> bool:
         """True when the hub must deliver ``from_rid`` -> ``node``: the
@@ -339,6 +367,13 @@ class MeshEngine(KernelEngine):
                 to = item[1].to
                 if 1 <= to <= R and cut[to - 1]:
                     dst.append(item)
+
+    def _send(self, n: KernelNode, m: pb.Message) -> None:
+        # everything a mesh engine hands to the host transport passes here:
+        # the hub fallback of cut links and the reads a follower's host
+        # forwards (and their answers)
+        (_HUB_READ_FORWARD if m.type in _READ_FORWARDS else _HUB_SENT).inc()
+        super()._send(n, m)
 
     def _prop_target(self, n: KernelNode):
         """Forward proposals to the group's leader row (any NodeHost is a

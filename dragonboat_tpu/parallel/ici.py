@@ -71,14 +71,23 @@ class IciCluster:
     def total_rows(self) -> int:
         return self.g_size * self.replicas * self.n_local
 
-    def sharding(self, extra_dims: int = 0) -> NamedSharding:
-        return NamedSharding(self.mesh, PS(("g", "r"), *([None] * extra_dims)))
+    def sharding(self) -> NamedSharding:
+        """Rows over the mesh, spelled the way a jitted entry spells the
+        arrays it returns: no mesh axis of size one, no trailing ``None``.
+        Shardings that differ only in spelling compare unequal, so state
+        placed as ``P(('g', 'r'), None)`` made the serve entry compile
+        once for the placed arrays and again for its own outputs
+        (tests/test_mesh_cell.py holds state and inbox to this spelling)."""
+        axes = tuple(a for a in ("g", "r") if self.mesh.shape[a] > 1)
+        if not axes:
+            return NamedSharding(self.mesh, PS())
+        return NamedSharding(
+            self.mesh, PS(axes[0] if len(axes) == 1 else axes))
 
     def shard(self, tree):
         """Place a [G]-leading pytree onto the mesh."""
-        return jax.tree.map(
-            lambda x: jax.device_put(x, self.sharding(x.ndim - 1)), tree
-        )
+        sharding = self.sharding()
+        return jax.tree.map(lambda x: jax.device_put(x, sharding), tree)
 
 
 def make_ici_cluster(

@@ -14,6 +14,8 @@ worker that is handed this file is the one that describes the topology
 (inside the fixture — never at import, never in a child process).
 """
 
+import math
+import re
 from types import SimpleNamespace
 
 import jax
@@ -143,11 +145,11 @@ def test_mesh_round_compiles_for_v5e(topo, device_kp, entry):
     cl = ici.IciCluster(kp=kp, mesh=mesh, replicas=3, n_local=48,
                         num_groups=48)
     state, box, inp = _shapes(_step_args(kp, cl.total_rows),
-                              lambda x: cl.sharding(x.ndim - 1))
+                              lambda x: cl.sharding())
     cut = jax.ShapeDtypeStruct((cl.total_rows, kp.num_peers), bool,
-                               sharding=cl.sharding(1))
+                               sharding=cl.sharding())
     up = jax.ShapeDtypeStruct((cl.total_rows, round_columns(kp).up_width),
-                              jnp.int32, sharding=cl.sharding(1))
+                              jnp.int32, sharding=cl.sharding())
     hlo = getattr(pround, entry).lower(
         kp, cl, state, box, up, cut).compile().as_text()
     plain = ici.jit_serve_step.lower(
@@ -157,6 +159,44 @@ def test_mesh_round_compiles_for_v5e(topo, device_kp, entry):
         assert hlo.count(f" {collective}(") == plain.count(
             f" {collective}("), collective
     assert "all-gather" in hlo
+
+
+def _collective_result_bytes(hlo: str) -> int:
+    """Bytes of every all-gather's and all-reduce's result in the text."""
+    size = {"pred": 1, "s8": 1, "u8": 1, "s32": 4, "u32": 4, "f32": 4}
+    total = 0
+    for line in hlo.splitlines():
+        m = re.search(r" = (.*?) (?:all-gather|all-reduce)\(", line)
+        if m:
+            for dtype, dims in re.findall(r"(\w+)\[([\d,]*)\]", m.group(1)):
+                total += size[dtype] * math.prod(
+                    int(d) for d in dims.split(",") if d)
+    return total
+
+
+def test_collective_bytes_cover_what_the_compiler_moves(topo, device_kp):
+    """``benchmark/collective_bytes.py`` counts the out-lanes ``route``
+    reads; the compiler moves no more than that for the served mesh round
+    (an all-gather brings a chip two thirds of its result; the one
+    all-reduce is a gather of [G] lanes written as update-slice and sum),
+    and not much less: a field ``route`` starts or stops reading shows."""
+    from benchmark import collective_bytes
+
+    kp = device_kp(min_inbox=10)
+    mesh = Mesh(np.array(topo.devices[:3]).reshape(1, 3), ("g", "r"))
+    cl = ici.IciCluster(kp=kp, mesh=mesh, replicas=3, n_local=48,
+                        num_groups=48)
+    state, box, _inp = _shapes(_step_args(kp, cl.total_rows),
+                               lambda x: cl.sharding())
+    cut = jax.ShapeDtypeStruct((cl.total_rows, kp.num_peers), bool,
+                               sharding=cl.sharding())
+    up = jax.ShapeDtypeStruct((cl.total_rows, round_columns(kp).up_width),
+                              jnp.int32, sharding=cl.sharding())
+    hlo = pround.jit_serve_step.lower(
+        kp, cl, state, box, up, cut).compile().as_text()
+    moved = _collective_result_bytes(hlo) * 2 // 3
+    counted = 2 * collective_bytes.exchange_bytes_per_chip(kp, 48)
+    assert 0 < moved <= counted <= moved * 4 // 3, (moved, counted)
 
 
 @pytest.mark.parametrize("entry", ["jit_serve_step",
@@ -170,9 +210,9 @@ def test_mesh_serve_step_compiles_for_v5e(topo, device_kp, entry):
     cl = ici.IciCluster(kp=kp, mesh=mesh, replicas=3, n_local=48,
                         num_groups=48)
     state, box, inp = _shapes(_step_args(kp, cl.total_rows),
-                              lambda x: cl.sharding(x.ndim - 1))
+                              lambda x: cl.sharding())
     cut = jax.ShapeDtypeStruct((cl.total_rows, kp.num_peers), bool,
-                               sharding=cl.sharding(1))
+                               sharding=cl.sharding())
     hlo = getattr(ici, entry).lower(
         kp, cl, state, box, inp, cut).compile().as_text()
     assert "all-gather" in hlo

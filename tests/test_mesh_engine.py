@@ -117,16 +117,9 @@ def test_mesh_read_from_follower_host(cluster):
     lid = wait_leader(hosts, timeout=60)
     propose_retry(hosts[lid], hosts[lid].get_noop_session(1), b"fr=ok")
     frid = next(r for r in hosts if r != lid)
-    deadline = time.time() + 15
-    val = None
-    while time.time() < deadline:
-        try:
-            val = hosts[frid].sync_read(1, "fr", timeout_s=3)
-            if val == "ok":
-                break
-        except Exception:
-            time.sleep(0.1)
-    assert val == "ok"
+    # one read under one limit: a forwarded read that is lost shows as a
+    # timeout, not as a second try that happened to land
+    assert hosts[frid].sync_read(1, "fr", timeout_s=10) == "ok"
 
 
 def test_mesh_leader_transfer(cluster):

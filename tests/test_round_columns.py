@@ -268,13 +268,13 @@ def test_packed_mesh_step_equals_serve_step():
             kp, cluster, state, cluster.shard(merged),
             cluster.shard(inp), cut)
         up = jax.device_put(np.asarray(_pack_up(kp, staged, inp)),
-                            cluster.sharding(1))
+                            cluster.sharding())
         got_state, got_box, down = pround.jit_serve_step(
             kp, cluster, state, box, up, cut)
         _assert_trees_equal(f"step {i} state", got_state, want_state)
         _assert_trees_equal(f"step {i} box", got_box, want_box)
         saved += _check_download(f"step {i}", kp, down, got_state, want_out)
-        assert down.sharding.is_equivalent_to(cluster.sharding(1), 2)
+        assert down.sharding.is_equivalent_to(cluster.sharding(), 2)
         state, box = want_state, want_box
         committed = int(np.asarray(state.committed).max())
     assert committed > 0 and saved > STEPS // 2, "the steps were not busy"

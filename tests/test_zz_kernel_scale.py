@@ -54,7 +54,6 @@ def test_kernel_multi_replica_shards_at_scale():
     from dragonboat_tpu.request import RequestDroppedError, \
         RequestTimeoutError
 
-    from test_kernel_engine import propose_retry
     from test_nodehost import wait_leader
 
     n_shards = 128
@@ -84,24 +83,36 @@ def test_kernel_multi_replica_shards_at_scale():
         assert elected == n_shards, f"only {elected}/{n_shards} elected"
         # a write on each host's leader for a sample of shards, then a
         # LINEARIZABLE read from a different host (READ_INDEX forwarded
-        # cross-host to the kernel leader lane)
+        # cross-host to the kernel leader lane).  One limit a shard, and
+        # the leader is asked for again on every try: beside five busy
+        # workers the engine that leads every shard (the host opened
+        # first) can stall for longer than an election timeout of the
+        # other two, whose lighter rounds tick faster, and all 128
+        # leaders then move at once (seen 1 run in 6; PERF.md, PR 27).
+        # Proposing to the host that led before the move is DROPPED for
+        # as long as one keeps at it, which is what failed here
         for sid, read_from in ((1, 2), (64, 3), (128, 1)):
-            lid = wait_leader(hosts, shard_id=sid)
-            nh = hosts[lid]
-            sess = nh.get_noop_session(sid)
-            propose_retry(nh, sess, f"mr{sid}=ok".encode(),
-                          timeout_s=10, deadline_s=30)
-            other = read_from if read_from != lid else (read_from % 3) + 1
-            end = time.time() + 30
+            end = time.time() + 60
+            wrote = False
             while True:
                 try:
-                    assert hosts[other].sync_read(
-                        sid, f"mr{sid}", timeout_s=10) == "ok"
+                    lid = wait_leader(hosts, shard_id=sid, timeout=5)
+                    nh = hosts[lid]
+                    if not wrote:
+                        nh.sync_propose(nh.get_noop_session(sid),
+                                        f"mr{sid}=ok".encode(), timeout_s=10)
+                        wrote = True
+                    other = (read_from if read_from != lid
+                             else (read_from % 3) + 1)
+                    got = hosts[other].sync_read(sid, f"mr{sid}",
+                                                 timeout_s=10)
                     break
-                except (RequestDroppedError, RequestTimeoutError):
+                except (RequestDroppedError, RequestTimeoutError,
+                        AssertionError):     # AssertionError: no leader yet
                     if time.time() > end:
                         raise
                     time.sleep(0.2)
+            assert got == "ok"
         # all three kernels still own their lanes (no mass evictions)
         for rid, nh in hosts.items():
             resident = sum(1 for sid in shards
