@@ -50,9 +50,9 @@ from dragonboat_tpu import capacity, fabric, hostenv, lifecycle, native
 from dragonboat_tpu.config import (
     Config, ExpertConfig, MeshSpec, NodeHostConfig,
 )
-from dragonboat_tpu.core.kstate import empty_input
+from dragonboat_tpu.core.kstate import round_columns
 from dragonboat_tpu.nodehost import NodeHost
-from dragonboat_tpu.parallel import ici
+from dragonboat_tpu.parallel import round as mesh_round
 from dragonboat_tpu.request import RequestDroppedError
 from dragonboat_tpu.statemachine import IStateMachine, Result
 
@@ -189,19 +189,23 @@ def compile_report() -> dict:
 
 def mesh_step_collectives(eng) -> dict:
     """Compile the mesh serve entry the engine dispatches through, for the
-    shapes and shardings it holds, and count the collectives in the
+    shapes and shardings it holds (the resident state, the carried inbox,
+    a round's upload, the cut mask), and count the collectives in the
     optimized HLO (shapes only: the engine thread owns the arrays)."""
     cl, disp = eng.cluster, eng._dispatch
+    sharding = cl.sharding()
+
+    def shape(x, dims=None):
+        return jax.ShapeDtypeStruct(dims or x.shape, x.dtype,
+                                    sharding=sharding)
+
     with eng.mu:
-        state, box = jax.tree.map(
-            lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype,
-                                           sharding=x.sharding),
-            (eng.state, disp.box))
-    entry = (ici.jit_serve_step_donated if eng.pipeline_depth > 0
-             else ici.jit_serve_step)
+        state, box = jax.tree.map(shape, (eng._resident, disp._box))
+    up = shape(box, (cl.total_rows, round_columns(cl.kp).up_width))
+    entry = (mesh_round.jit_serve_step_donated if eng.pipeline_depth > 0
+             else mesh_round.jit_serve_step)
     hlo = entry.lower(
-        cl.kp, cl, state, box, cl.shard(empty_input(cl.kp, cl.total_rows)),
-        cl.shard(np.zeros_like(disp.cut))).compile().as_text()
+        cl.kp, cl, state, box, up, shape(disp.cut)).compile().as_text()
     return {c: hlo.count(f" {c}(") + hlo.count(f" {c}-start(")
             for c in MESH_COLLECTIVES}
 

@@ -149,7 +149,8 @@ HOT_PATH_FUNCS = frozenset({
     "_stage_props", "_prop_target", "dispatch",
 })
 #: self.<attr> values that live on device in both engines
-_DEVICE_SELF_ATTRS = frozenset({"state", "box", "_pending_dev", "_cut_dev"})
+_DEVICE_SELF_ATTRS = frozenset({
+    "state", "_resident", "box", "_box", "_pending_dev", "_cut_dev"})
 #: calls whose results are device values
 _DEVICE_PRODUCERS = frozenset({
     "kernel_step", "kernel_step_donated", "step", "step_donated",

@@ -1014,6 +1014,7 @@ def _live_impl(root: str, decl: dict) -> list[Finding]:
 
     from dragonboat_tpu import capacity as _capacity
     from dragonboat_tpu.bench_loop import bench_params, make_cluster
+    from dragonboat_tpu.core.kstate import pack_program
     from dragonboat_tpu.engine import kernel_engine as _ke
     from dragonboat_tpu.engine.dispatch import MeshDispatch, SerialDispatch
 
@@ -1028,9 +1029,11 @@ def _live_impl(root: str, decl: dict) -> list[Finding]:
             np.asarray(down)
 
     # --- serial, depth 0 (non-donated oracle entry) --------------------
+    # the seams take the state in its resident form (kstate.py)
     kp = bench_params(3, platform="cpu")
     state = make_cluster(kp, 2, 3)
     G = int(state.term.shape[0])
+    state = pack_program(kp)(state)
     disp = SerialDispatch(kp)
     staging = _ke._RoundStaging(kp, G)
     state, down = disp.dispatch(state, staging, donate=False)  # warm
@@ -1044,7 +1047,7 @@ def _live_impl(root: str, decl: dict) -> list[Finding]:
                  meter.counts(), N)
 
     # --- serial, depth 1 (donated entry, retire-before-dispatch) -------
-    state = make_cluster(kp, 2, 3)
+    state = pack_program(kp)(make_cluster(kp, 2, 3))
     state, down = disp.dispatch(state, staging, donate=True)  # warm
     meter.reset()
     with meter.guard():
@@ -1067,6 +1070,7 @@ def _live_impl(root: str, decl: dict) -> list[Finding]:
                       msg_entries=2, proposal_cap=2, readindex_cap=4)
     mesh = Mesh(np.asarray(jax.devices()[:2]).reshape(1, 2), ("g", "r"))
     cluster, mstate, _box = ici.make_ici_cluster(mkp, mesh, num_groups=2)
+    mstate = pack_program(mkp, cluster.sharding())(mstate)
     mdisp = MeshDispatch(cluster)
     mstaging = _ke._RoundStaging(mkp, cluster.total_rows,
                                  mesh_replicas=cluster.replicas)

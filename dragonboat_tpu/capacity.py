@@ -69,7 +69,7 @@ DTYPE_BYTES = {"i32": 4, "u32": 4, "f32": 4, "bool": 1}
 #: symbolic contract axis -> the KernelParams field holding its extent
 #: (G is the free variable the model is *per*); kstate.py's table, which
 #: also lays out the round's packed crossings
-from dragonboat_tpu.core.kstate import AXIS_PARAMS  # noqa: E402
+from dragonboat_tpu.core.kstate import AXIS_PARAMS, RING_FIELDS  # noqa: E402
 
 #: contract classes with a leading-G per-group footprint.  HealthReport /
 #: ShardRow are replicated O(K)/O(1) aggregates — not per-group cost
@@ -80,6 +80,11 @@ MODEL_CLASSES = ("ShardState", "Inbox", "StepInput", "StepOutput",
 #: StepOutput are per-step transients) — the default for budget math
 RESIDENT_CLASSES = ("ShardState", "Inbox", "HealthDigest",
                     "InvariantDigest")
+
+#: the classes an engine keeps in their packed resident form (kstate.py
+#: ResidentState, the mesh backend's carried inbox): every field but the
+#: rings rides an int32 column, so a bool there costs 4 bytes, not 1
+PACKED_RESIDENT = ("ShardState", "Inbox")
 
 
 def _optional_materialized(cls: str, fld: str, kp) -> bool:
@@ -104,10 +109,12 @@ def _contract_table():
     return parse_contracts(table, "capacity")
 
 
-def model_bytes_per_group(kp, classes=MODEL_CLASSES) -> dict:
+def model_bytes_per_group(kp, classes=MODEL_CLASSES, packed=()) -> dict:
     """Analytic bytes-per-group for each contract class at geometry
-    ``kp``, plus ``"total"``.  Raises ValueError on a contract axis the
-    model cannot size (a new axis must be added to AXIS_PARAMS)."""
+    ``kp``, plus ``"total"``; the classes named in ``packed`` are sized in
+    their packed resident form (PACKED_RESIDENT).  Raises ValueError on a
+    contract axis the model cannot size (a new axis must be added to
+    AXIS_PARAMS)."""
     table = _contract_table()
     per: dict = {}
     for cls in classes:
@@ -119,7 +126,8 @@ def model_bytes_per_group(kp, classes=MODEL_CLASSES) -> dict:
                     f"({fc.axes}) — not a per-group field")
             if fc.optional and not _optional_materialized(cls, fld, kp):
                 continue
-            n = DTYPE_BYTES[fc.dtype]
+            n = (4 if cls in packed and fld not in RING_FIELDS
+                 else DTYPE_BYTES[fc.dtype])
             for ax in fc.axes[1:]:
                 if ax not in AXIS_PARAMS:
                     raise ValueError(
@@ -132,15 +140,18 @@ def model_bytes_per_group(kp, classes=MODEL_CLASSES) -> dict:
     return per
 
 
-def predict_bytes(kp, num_groups: int, classes=MODEL_CLASSES) -> int:
+def predict_bytes(kp, num_groups: int, classes=MODEL_CLASSES,
+                  packed=()) -> int:
     """Analytic device bytes for ``num_groups`` groups of ``classes``."""
-    return model_bytes_per_group(kp, classes)["total"] * int(num_groups)
+    return (model_bytes_per_group(kp, classes, packed)["total"]
+            * int(num_groups))
 
 
 def max_g_for_budget(kp, budget_bytes: int,
                      classes=RESIDENT_CLASSES) -> int:
-    """Largest G whose resident footprint fits ``budget_bytes``."""
-    per_group = model_bytes_per_group(kp, classes)["total"]
+    """Largest G whose resident footprint, as an engine keeps it, fits
+    ``budget_bytes``."""
+    per_group = model_bytes_per_group(kp, classes, PACKED_RESIDENT)["total"]
     if budget_bytes <= 0 or per_group <= 0:
         return 0
     return int(budget_bytes) // per_group
@@ -611,7 +622,7 @@ def engine_snapshot(kp, num_groups: int, live_bytes: int, peak_bytes: int,
         pressure = headroom < float(watermark_pct)
     else:
         headroom, pressure = 100.0, False
-    per_group = model_bytes_per_group(kp, classes)["total"]
+    per_group = model_bytes_per_group(kp, classes, PACKED_RESIDENT)["total"]
     return {
         "ticks": int(ticks),
         "capacity": int(num_groups),

@@ -307,9 +307,11 @@ DONATION = {
         "result_classes": ("ShardState", "Inbox", "StepOutput"),
     },
     "round_step_donated": {
-        # the serial engine round (packed upload in, packed download
-        # out): only the state is donated — the upload matches no
-        # output's shape, the download is an output
+        # the serial engine round (resident state and packed upload in,
+        # resident state and packed download out): only the state is
+        # donated, in its resident form (ResidentState below: the packed
+        # columns and the rings, each the shape of its own result) — the
+        # upload matches no output's shape, the download is an output
         "module": "dragonboat_tpu/core/round.py",
         "function": "step_donated",
         "argnums": (2,),
@@ -318,8 +320,9 @@ DONATION = {
         "result_classes": ("ShardState",),
     },
     "round_serve_step_donated": {
-        # the mesh engine round: state and the carried device inbox are
-        # donated; the packed upload and the cached partition mask are not
+        # the mesh engine round: the resident state and the carried
+        # device inbox (one [G, Wi] array) are donated; the packed upload
+        # and the cached partition mask are not
         "module": "dragonboat_tpu/parallel/round.py",
         "function": "jit_serve_step_donated",
         "argnums": (2, 3),
@@ -586,15 +589,6 @@ def inject_rows(state: ShardState, lanes, rows: dict) -> ShardState:
     )
 
 
-@functools.lru_cache(maxsize=None)
-def inject_program(placement=None):
-    """``inject_rows`` jitted, its result placed as ``placement`` says (a
-    pytree of shardings like the state, or None on a single device).  One
-    program per placement for the whole process: engines of one geometry
-    share its compiles."""
-    return jax.jit(inject_rows, out_shardings=placement)
-
-
 class Inbox(NamedTuple):
     """Fixed-width inbound message block, [G, K] lanes (+ [G, K, E] entries).
 
@@ -787,9 +781,11 @@ def save_window(kp) -> int:
     return min(s, kp.log_cap)
 
 
-def _class_columns(cls, kp, start: int) -> tuple[list, int]:
+def _class_columns(cls, kp, start: int, without=()) -> tuple[list, int]:
     cols = []
     for f in cls._fields:
+        if f in without:
+            continue
         axes, rest = CONTRACTS[cls.__name__][f][1:].split("]", 1)
         tags = rest.split()
         if "optional" in tags and not kp.inline_payloads:
@@ -859,3 +855,117 @@ def pack_download(kp, flags, out: StepOutput, save_terms):
     return pack_columns(
         round_columns(kp).down,
         {**out._asdict(), "flags": flags, "save_terms": save_terms})
+
+
+# ---------------------------------------------------------------------------
+# The resident form: what an engine keeps on the device between rounds.
+#
+# A ShardState is 45 device arrays, and every round let 45 go: jaxlib's
+# array destructor gives the interpreter lock up to free each buffer and
+# has to take it back, one wait per array for whichever thread holds it
+# (PERF.md section 6, PR 28: 528 ms for one state's arrays beside three
+# busy threads, on the chip).  So between rounds the state is THREE arrays:
+# every field but the rings as columns of one [G, Ws] int32 array, laid
+# out by the same table as the crossings, and the rings as they are (a
+# concatenate would copy their megabytes every round).  ``pack_state`` /
+# ``unpack_state`` are the only two functions that convert, and both are
+# traced inside programs, never eager: the round's entry (core/round.py,
+# parallel/round.py) and the small programs below.
+# ---------------------------------------------------------------------------
+
+#: the ShardState fields that stay arrays of their own in the resident form
+RING_FIELDS = ("lt", "lcc", "lv")
+
+
+class ResidentState(NamedTuple):
+    cols: jnp.ndarray           # [G, Ws] i32: every other field, a bool 0/1
+    lt: jnp.ndarray             # [G, CAP] i32
+    lcc: jnp.ndarray            # [G, CAP] bool
+    lv: jnp.ndarray | None = None   # [G, CAP] i32 where kp.inline_payloads
+
+
+@functools.lru_cache(maxsize=None)
+def state_columns(kp) -> tuple[tuple, int]:
+    """The columns of ``ResidentState.cols`` at ``kp``'s geometry, and
+    their width ``Ws``."""
+    cols, w = _class_columns(ShardState, kp, 0, without=RING_FIELDS)
+    return tuple(cols), w
+
+
+def pack_state(kp, s: ShardState) -> ResidentState:
+    return ResidentState(
+        cols=pack_columns(state_columns(kp)[0], s._asdict()),
+        lt=s.lt, lcc=s.lcc, lv=s.lv)
+
+
+def unpack_state(kp, r: ResidentState) -> ShardState:
+    s = unpack_columns(ShardState, state_columns(kp)[0], r.cols)
+    return s._replace(lt=r.lt, lcc=r.lcc, lv=r.lv)
+
+
+@functools.lru_cache(maxsize=None)
+def inbox_columns(kp) -> tuple[tuple, int]:
+    """The Inbox columns of the upload (they lead it) and their width
+    ``Wi``: the layout of the mesh backend's carried inbox, ONE [G, Wi]
+    int32 array between rounds."""
+    cols = tuple(c for c in round_columns(kp).up if c.field in Inbox._fields)
+    return cols, cols[-1].start + cols[-1].width
+
+
+@functools.lru_cache(maxsize=None)
+def resident_program(kp, fn, static_argnames=()):
+    """``fn(state, ...)`` as a jitted program that takes the resident form
+    in the state's place (the fleet, health and invariant reductions, a
+    lane's health row).  One per ``(kp, fn)`` for the process."""
+    return jax.jit(
+        lambda resident, *a, **kw: fn(unpack_state(kp, resident), *a, **kw),
+        static_argnames=static_argnames)
+
+
+@functools.lru_cache(maxsize=None)
+def pack_program(kp, placement=None):
+    """``pack_state`` jitted, its result placed as ``placement`` says (the
+    backend's sharding along G, or None on a single device): what the
+    ``engine.state`` setter runs."""
+    return jax.jit(lambda s: pack_state(kp, s), out_shardings=placement)
+
+
+@functools.lru_cache(maxsize=None)
+def view_program(kp, placement=None):
+    """The packed columns as the ShardState fields they hold (the rings
+    None: the caller hands the resident ones over as they are): what the
+    ``engine.state`` getter runs."""
+    return jax.jit(
+        lambda cols: unpack_state(kp, ResidentState(cols, None, None)),
+        out_shardings=placement)
+
+
+@functools.lru_cache(maxsize=None)
+def box_view_program(kp, placement=None):
+    """The mesh backend's carried [G, Wi] inbox as the Inbox it holds
+    (``MeshDispatch.box``, for callers outside a round)."""
+    return jax.jit(
+        lambda box: unpack_columns(Inbox, inbox_columns(kp)[0], box),
+        out_shardings=placement)
+
+
+@functools.lru_cache(maxsize=None)
+def inject_program(kp, placement=None):
+    """``inject_rows`` on the resident form, jitted, its result placed as
+    ``placement`` says.  One program per geometry and placement for the
+    whole process: engines of one geometry share its compiles."""
+    return jax.jit(
+        lambda resident, lanes, rows: pack_state(
+            kp, inject_rows(unpack_state(kp, resident), lanes, rows)),
+        out_shardings=placement)
+
+
+@functools.lru_cache(maxsize=None)
+def write_cells_program(placement=None):
+    """``cols`` with ``cols[cells[0, i], cells[1, i]] = cells[2, i]`` for
+    every ``i``: the one program behind a lane's clearing and a
+    membership's peer-book write (``cells`` is one [3, N] int32 upload; a
+    cell written twice must carry the same value both times)."""
+    return jax.jit(
+        lambda cols, cells: cols.at[cells[0], cells[1]].set(cells[2]),
+        out_shardings=placement)

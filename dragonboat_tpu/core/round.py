@@ -1,5 +1,5 @@
-"""The serial engine round as ONE jitted program: packed upload in, new
-state and packed download out.
+"""The serial engine round as ONE jitted program: the resident state and
+the packed upload in, the new resident state and the packed download out.
 
 ``step`` / ``step_donated`` here are what ``SerialDispatch`` serves (their
 names are the program names a device capture shows, ``jit_step`` /
@@ -7,8 +7,11 @@ names are the program names a device capture shows, ``jit_step`` /
 the plain ``(state, Inbox, StepInput) -> (state, StepOutput)`` step the
 differentials and the benchmark's shape accounting call, and the
 ``step_fn`` wrapped here (a static argument: the engine's kernel step, or
-a chaos test's mutated one).  The layouts of both packed arrays are
-kstate.py's column table.
+a chaos test's mutated one).  ``state`` here is the resident form
+(kstate.py ``ResidentState``: three arrays, not a ShardState's 45), so
+the entry takes 4 device arrays and returns 4: what a round lets go of
+is what its thread waits for the interpreter over, one array at a time.
+The layouts of all packed arrays are kstate.py's column table.
 """
 
 from __future__ import annotations
@@ -21,7 +24,9 @@ import jax.numpy as jnp
 from dragonboat_tpu.core.kernel import output_row_flags
 from dragonboat_tpu.core.kstate import (
     pack_download,
+    pack_state,
     round_columns,
+    unpack_state,
     unpack_upload,
 )
 
@@ -64,14 +69,15 @@ def pack_round(kp, state, out):
 
 def _round(kp, step_fn, state, up):
     inbox, inp = unpack_upload(kp, up)
-    state, out = step_fn(kp, state, inbox, inp)
-    return state, pack_round(kp, state, out)
+    s, out = step_fn(kp, unpack_state(kp, state), inbox, inp)
+    return pack_state(kp, s), pack_round(kp, s, out)
 
 
 @functools.partial(jax.jit, static_argnums=(0, 1))
 def step(kp, step_fn, state, up):
-    """One round, non-donating (depth 0): ``up`` is the staged [G, Wu]
-    upload; returns ``(state, down)`` with ``down`` the [G, Wd] download."""
+    """One round, non-donating (depth 0): ``state`` is the resident form,
+    ``up`` the staged [G, Wu] upload; returns ``(state, down)`` with
+    ``down`` the [G, Wd] download."""
     return _round(kp, step_fn, state, up)
 
 
@@ -89,3 +95,10 @@ def ring_row(ring, g):
     """One lane's whole [CAP] ring row, ``g`` traced: the fixed-shape
     fallback for a save window wider than ``S``."""
     return ring[g]
+
+
+@jax.jit
+def state_cell(cols, g, c):
+    """One cell of the resident columns, ``g`` and ``c`` traced: a lane's
+    scalar field (kstate.py ``state_columns`` names the column)."""
+    return cols[g, c]

@@ -13,8 +13,13 @@ upload (the staged Inbox and StepInput, packed into one [G, Wu] int32
 array by ``_RoundStaging``), ONE call of the backend's jitted entry, and
 ONE download (the [G, Wd] int32 array that entry ends by writing: the
 activity flags, every StepOutput field and the save window's terms).
-Both layouts are kstate.py's column table; pack and unpack are generic
-over the leading [G] axis, so the backends differ only in ``dispatch()``.
+Between rounds the state stays on the device in its resident form
+(kstate.py ``ResidentState``: three arrays; the mesh backend's carried
+inbox one more), which the entry takes and returns: a round lets go of
+4-6 device arrays, not 46-60, and each one let go is a wait for the
+interpreter (the gauge ``engine_entry_arrays`` counts them).  All layouts
+are kstate.py's column table; pack and unpack are generic over the
+leading [G] axis, so the backends differ only in ``dispatch()``.
 
 The module-level tuples/dicts below are the MACHINE-READ contract the
 engine-unity pass enforces (pure literals, parsed with
@@ -38,15 +43,21 @@ import jax
 import numpy as np
 
 from dragonboat_tpu import capacity as _capacity
+from dragonboat_tpu import telemetry
 from dragonboat_tpu.core import params as KP
 from dragonboat_tpu.core.kernel import step as kernel_step
-from dragonboat_tpu.core.kstate import FLAG_CLASSES, empty_inbox
+from dragonboat_tpu.core.kstate import (
+    FLAG_CLASSES,
+    box_view_program,
+    inbox_columns,
+)
 from dragonboat_tpu.core.round import (
     step as round_step,
     step_donated as round_step_donated,
 )
 from dragonboat_tpu.parallel.ici import IciCluster
 from dragonboat_tpu.parallel.round import (
+    box_from,
     jit_serve_step,
     jit_serve_step_donated,
 )
@@ -177,8 +188,15 @@ SYNC_POINTS = {
 #:   per_step crossing happens on EVERY step of this entry's profile
 #:   masked   download is lane-masked (only the lanes that need it)
 #:   cached   upload is memoized until invalidated (not per-step)
+#: ``resident`` names the contract classes an entry takes from the device
+#: and never from the host: the engine keeps them between rounds in
+#: their resident form (kstate.py ``ResidentState``; the mesh backend's
+#: carried inbox one [G, Wi] array) and every program that reads or
+#: writes them converts inside itself.
 #: ``_control`` rows are step-loop control-plane crossings (admissions,
-#: membership, telemetry) that belong to no single entry.
+#: membership, lane clearing, telemetry) that belong to no single entry;
+#: a ``[3, N] i32`` value is ``_write_cells``' one upload (row, column,
+#: value of N cells of the packed columns, at its least size class).
 TRANSFER_LEDGER = {
     "step": {
         "resident": ("ShardState",),
@@ -293,12 +311,12 @@ TRANSFER_LEDGER = {
         {"value": "ShardState", "dir": "up",
          "site": "KernelEngine._flush_injections", "tag": "inject_up",
          "per_step": False},
-        {"value": "[G, P] i32", "dir": "up",
-         "site": "KernelEngine.update_lane_membership",
+        {"value": "[3, 32] i32", "dir": "up",
+         "site": "KernelEngine._write_cells",
          "tag": "membership_up", "per_step": False},
-        {"value": "[G, P] i32", "dir": "up",
-         "site": "MeshEngine.update_lane_membership",
-         "tag": "membership_up", "per_step": False},
+        {"value": "[3, 16] i32", "dir": "up",
+         "site": "KernelEngine._write_cells",
+         "tag": "lane_clear_up", "per_step": False},
         {"value": "ShardRow", "dir": "down",
          "site": "KernelEngine.health_row", "tag": "health_row",
          "per_step": False},
@@ -307,6 +325,22 @@ TRANSFER_LEDGER = {
          "per_step": False},
     ),
 }
+
+
+_ENTRY_ARRAYS = telemetry.GLOBAL.gauge(
+    "engine_entry_arrays",
+    help="device arrays the round's jitted entry takes (in) and returns "
+         "(out), flattened: set by each dispatch backend at its first "
+         "call.  What a round lets go of costs its thread one wait for "
+         "the interpreter per array",
+    labelnames=("dir",))
+
+
+def _note_entry_arrays(args, results) -> tuple[int, int]:
+    n = (len(jax.tree.leaves(args)), len(jax.tree.leaves(results)))
+    _ENTRY_ARRAYS.labels("in").set(n[0])
+    _ENTRY_ARRAYS.labels("out").set(n[1])
+    return n
 
 
 class SerialDispatch:
@@ -326,17 +360,23 @@ class SerialDispatch:
             "step_donated": _capacity.TRACKER.wrap(
                 "step_donated", round_step_donated),
         }
+        #: (in, out) device arrays of the entry, from its first call
+        self.entry_arrays: tuple[int, int] | None = None
 
     def dispatch(self, state, staging, donate: bool):
         """One round's device work: upload ``staging``, run the jitted
-        entry; returns ``(state, down)`` with ``down`` the packed download,
-        still on the device.  ``donate=True`` routes through the donating
-        entry (core/round.py ``step_donated``): XLA reuses the state's
-        buffers, so after this call the host must not read the passed-in
-        state again — step_all's retire-before-dispatch order upholds
-        that."""
+        entry on the resident ``state``; returns ``(state, down)`` with
+        ``down`` the packed download, still on the device.
+        ``donate=True`` routes through the donating entry (core/round.py
+        ``step_donated``): XLA reuses the state's buffers, so after this
+        call the host must not read the passed-in state again —
+        step_all's retire-before-dispatch order upholds that."""
         entry = self.entries["step_donated" if donate else "step"]
-        return entry(self.kp, self._step_fn, state, staging.to_device())
+        args = (state, staging.to_device())
+        res = entry(self.kp, self._step_fn, *args)
+        if self.entry_arrays is None:
+            self.entry_arrays = _note_entry_arrays(args, res)
+        return res
 
     def pending(self) -> bool:
         """No device-resident inbox: nothing carries between steps."""
@@ -355,9 +395,9 @@ class SerialDispatch:
         """Single device: placement is a no-op."""
         return tree
 
-    def placement(self, tree):
-        """Output shardings for a program that rewrites ``tree``: none to
-        ask for on a single device."""
+    def placement(self):
+        """The sharding a program that rewrites resident arrays asks for
+        its results: none on a single device."""
         return None
 
     def resident_trees(self) -> tuple:
@@ -386,8 +426,10 @@ class MeshDispatch:
         self.cluster = cluster
         total = cluster.total_rows
         # device-resident inbox carried between steps (messages ride
-        # the mesh, not the host queues)
-        self.box = cluster.shard(empty_inbox(cluster.kp, total))
+        # the mesh, not the host queues): ONE [G, Wi] int32 array in the
+        # upload's own inbox-column layout
+        self._box = cluster.shard(
+            np.zeros((total, inbox_columns(cluster.kp)[1]), np.int32))
         # drain-pending, derived host-side from the [G, C] activity
         # flags the step loop already fetches every step — the round-16
         # per-step pending-scalar download is gone
@@ -404,6 +446,7 @@ class MeshDispatch:
             "serve_step_donated": _capacity.TRACKER.wrap(
                 "serve_step_donated", jit_serve_step_donated),
         }
+        self.entry_arrays: tuple[int, int] | None = None
 
     def dispatch(self, state, staging, donate: bool):
         """Advance the mesh: host-staged inputs, device-routed messages.
@@ -422,9 +465,20 @@ class MeshDispatch:
                 self._cut_dev = jax.device_put(self.cut, cl.sharding())
         entry = self.entries["serve_step_donated" if donate
                              else "serve_step"]
-        state, self.box, down = entry(
-            cl.kp, cl, state, self.box, up, self._cut_dev)
+        args = (state, self._box, up, self._cut_dev)
+        res = entry(cl.kp, cl, *args)
+        if self.entry_arrays is None:
+            self.entry_arrays = _note_entry_arrays(args, res)
+        state, self._box, down = res
         return state, down
+
+    @property
+    def box(self):
+        """The carried inbox as an Inbox of device arrays, unpacked on
+        demand and placed like the carried array (for callers outside a
+        round, like ``engine.state``)."""
+        return box_view_program(
+            self.cluster.kp, self.cluster.sharding())(self._box)
 
     def pending(self) -> bool:
         return self._pending_msgs
@@ -442,18 +496,19 @@ class MeshDispatch:
 
     def inbox_from(self, inbox_buf):
         # the mesh inbox is device-resident between steps; no host copy
-        return self.box.from_
+        # (one slice of the carried array, every tenth round)
+        return box_from(self.cluster.kp, self._box)
 
     def shard(self, tree):
         """Place a [G]-leading pytree onto the mesh (digests and the
         like shard along G exactly like the state they derive from)."""
         return self.cluster.shard(tree)
 
-    def placement(self, tree):
-        """Output shardings for a program that rewrites ``tree``: every
-        [G]-leading leaf stays sharded as the serve entry takes it."""
-        sharding = self.cluster.sharding()
-        return jax.tree.map(lambda _: sharding, tree)
+    def placement(self):
+        """The sharding a program that rewrites resident arrays asks for
+        its results: every [G]-leading array stays sharded as the serve
+        entry takes and returns it."""
+        return self.cluster.sharding()
 
     def set_cut(self, lane: int, cut: bool) -> None:
         """Flip one row's WHOLE partition mask (every link of the row)
@@ -473,7 +528,7 @@ class MeshDispatch:
 
     def resident_trees(self) -> tuple:
         # the carried inbox is device-resident between steps here
-        return (self.box,)
+        return (self._box,)
 
     def resident_classes(self) -> tuple:
         return ("Inbox",)

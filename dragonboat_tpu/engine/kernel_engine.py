@@ -77,9 +77,14 @@ from dragonboat_tpu.core.kstate import (
     column_views,
     init_state,
     inject_program,
+    pack_program,
+    resident_program,
     round_columns,
+    state_columns,
+    view_program,
+    write_cells_program,
 )
-from dragonboat_tpu.core.round import ring_row
+from dragonboat_tpu.core.round import ring_row, state_cell
 from dragonboat_tpu.events import EventHub
 from dragonboat_tpu.logger import get_logger
 from dragonboat_tpu.node import Node, _SnapshotRequest
@@ -151,6 +156,7 @@ ADD_SHARD_LOCK_US = telemetry.GLOBAL.histogram(
     "engine_add_shard_lock_us",
     help="add_shard's wait for the engine lock, per call")
 _INJECT_BATCH = 8       # least rows of one flush's program
+_CELL_BATCH = 16        # least cells of one _write_cells program
 _INJECT_FLUSH_US = telemetry.GLOBAL.histogram(
     "engine_inject_flush_us",
     help="one batch of queued lane injections written into the device "
@@ -393,15 +399,6 @@ class KernelEngine:
         self.nodes: dict[int, KernelNode] = {}     # lane -> node
         self.by_shard: dict[int, KernelNode] = {}
         self._free = list(range(capacity - 1, -1, -1))
-        self.state: ShardState = init_state(
-            kp, capacity,
-            replica_id=np.ones((capacity,), np.int32),
-            peer_ids=np.zeros((capacity, kp.num_peers), np.int32),
-            election_timeout=election_rtt,
-            heartbeat_timeout=heartbeat_rtt,
-        )
-        # all lanes start ABSENT: no peers -> non-single, no campaigns
-        # (mask: a lane with kind all K_ABSENT and tick never set is inert)
         # per-lane (term, vote, commit) as persisted — an np array so the
         # outputs pass can find changed lanes with one vectorized compare
         # (-1 rows = absent lane: the first real triple always differs)
@@ -440,7 +437,7 @@ class KernelEngine:
         # admissions queued for the next step's batched injection
         # (lane -> (node, init, pids, kinds)); see _flush_injections
         self._pending_inject: dict[int, tuple] = {}
-        self._inject_fn = None      # _inject_rows jitted for this state
+        self._inject_fn = None      # inject_rows jitted for this state
         # whole-engine tick rounds queued by the host ticker; each step
         # consumes ONE round as a vectorized [G]-bool broadcast (the
         # per-lane Python tick walk was ~25 s/round at 100k lanes).
@@ -554,6 +551,19 @@ class KernelEngine:
         # a backend through the _make_dispatch seam instead of overriding
         # step-loop internals — the engine-unity lint pass enforces it
         self._dispatch = self._make_dispatch()
+        # the device state between rounds, in its resident form (kstate.py
+        # ResidentState: three arrays, placed by the backend); ``state``
+        # below is the ShardState view of it for callers outside a round.
+        # All lanes start ABSENT: no peers -> non-single, no campaigns
+        # (a lane with kind all K_ABSENT and tick never set is inert)
+        self._state_cols = {c.field: c for c in state_columns(kp)[0]}
+        self.state = init_state(
+            kp, capacity,
+            replica_id=np.ones((capacity,), np.int32),
+            peer_ids=np.zeros((capacity, kp.num_peers), np.int32),
+            election_timeout=election_rtt,
+            heartbeat_timeout=heartbeat_rtt,
+        )
         self._cap_entries = self._capacity_entries()
         self.last_capacity: dict | None = None
         self._capacity_seq = 0          # capacity ticks (flight stamp)
@@ -598,6 +608,47 @@ class KernelEngine:
         can leave the trace dir empty (a user-started ``start_trace``
         capture is deliberately left to its owner)."""
         stop_env_trace()
+
+    @property
+    def state(self) -> ShardState:
+        """The device state as a ShardState of device arrays, unpacked on
+        demand by one jitted program and placed like the resident arrays
+        (``state.lt`` / ``.lcc`` / ``.lv`` ARE the resident rings).  For
+        callers outside a round: device checks, differentials, tests.
+        Nothing inside ``step_all`` reads it (a ShardState is 45 arrays to
+        let go of; tests/test_round_budget.py holds that)."""
+        r = self._resident
+        view = view_program(self.kp, self._dispatch.placement())(r.cols)
+        return view._replace(lt=r.lt, lcc=r.lcc, lv=r.lv)
+
+    @state.setter
+    def state(self, s: ShardState) -> None:
+        self._resident = pack_program(
+            self.kp, self._dispatch.placement())(s)
+
+    def _write_cells(self, writes, tag: str) -> None:
+        """Set ``field[lane] = value`` on the device for every ``(lane,
+        field, value)`` of ``writes`` (a [G] or [G, P] field of the
+        packed columns), by ONE small jitted program and one upload.  The
+        batch is padded with copies of its last cell to a power of two, so
+        the program compiles once per size class."""
+        r, c, v = [], [], []
+        for lane, field, value in writes:
+            col = self._state_cols[field]
+            vals = np.broadcast_to(np.asarray(value, np.int32), col.shape)
+            r += [lane] * col.width
+            c += range(col.start, col.start + col.width)
+            v += vals.ravel().tolist()
+        n = len(r)
+        size = max(_CELL_BATCH, 1 << (n - 1).bit_length())
+        cells = np.empty((3, size), np.int32)
+        cells[:, :n] = (r, c, v)
+        cells[:, n:] = cells[:, n - 1:n]
+        with _capacity.METER.sanctioned(tag):
+            up = jnp.asarray(cells)
+        res = self._resident
+        self._resident = res._replace(cols=write_cells_program(
+            self._dispatch.placement())(res.cols, up))
 
     def _inject(self, lane: int, node: KernelNode, init: _LaneInit) -> None:
         """Queue one lane injection; the next ``step_all`` flushes every
@@ -704,10 +755,10 @@ class KernelEngine:
             # mesh engine's stay sharded as its serve entry was compiled)
             self._inject_fn = _capacity.TRACKER.wrap(
                 "inject_rows", inject_program(
-                    self._dispatch.placement(self.state)))
+                    self.kp, self._dispatch.placement()))
         with _capacity.METER.sanctioned("inject_up"):
-            self.state = self._inject_fn(
-                self.state, jnp.asarray(lanes_np),
+            self._resident = self._inject_fn(
+                self._resident, jnp.asarray(lanes_np),
                 {k: jnp.asarray(v) for k, v in rows.items()})
         _INJECT_FLUSH_US.observe(monotonic_us() - t0)
 
@@ -721,15 +772,12 @@ class KernelEngine:
             self._triple_np[lane] = -1
             self._occ_np[lane] = False
             return
-        s = self.state
-        self.state = s._replace(
-            kind=s.kind.at[lane].set(KP.K_ABSENT),
-            pid=s.pid.at[lane].set(0),
-            needs_host=s.needs_host.at[lane].set(False),
+        self._write_cells((
+            (lane, "kind", KP.K_ABSENT), (lane, "pid", 0),
+            (lane, "needs_host", False),
             # a vacated lane must not linger in the fleet quiesced count
-            quiesce_on=s.quiesce_on.at[lane].set(False),
-            quiesced=s.quiesced.at[lane].set(False),
-        )
+            (lane, "quiesce_on", False), (lane, "quiesced", False),
+        ), "lane_clear_up")
         self._kind_np[lane] = KP.K_ABSENT
         self._pid_np[lane] = 0
         self._triple_np[lane] = -1
@@ -764,18 +812,14 @@ class KernelEngine:
                 pids[i], kinds[i] = rid, KP.K_WITNESS
                 i += 1
         g = node.lane
-        s = self.state
-        with _capacity.METER.sanctioned("membership_up"):
-            jp, jk = jnp.asarray(pids), jnp.asarray(kinds)
-        self.state = s._replace(
-            pid=s.pid.at[g].set(jp),
-            kind=s.kind.at[g].set(jk),
+        self._write_cells((
+            (g, "pid", pids), (g, "kind", kinds),
             # the applied CC releases the one-in-flight gate (pycore
             # add_node/add_non_voting/... clear pending_config_change on
             # apply; without this a lane accepts exactly ONE config
             # change in its lifetime and drops every later one)
-            pending_cc=s.pending_cc.at[g].set(False),
-        )
+            (g, "pending_cc", False),
+        ), "membership_up")
         self._kind_np[g] = kinds
         self._pid_np[g] = pids
 
@@ -947,11 +991,13 @@ class KernelEngine:
                     # (2026-07-31); once the executable is cached the
                     # lock is never touched again
                     with KernelEngine._first_compile_mu:
-                        state, out = self._kernel_call(staging)
+                        resident, out = self._kernel_call(staging)
                     self._compiled_once = True
                 else:
-                    state, out = self._kernel_call(staging)
-            self.state = state
+                    resident, out = self._kernel_call(staging)
+            # the previous resident arrays die here, three of them: each
+            # one let go is a wait for the interpreter (kstate.py)
+            self._resident = resident
             ctx.out = out
             np.maximum(self._applied_sent_np, inp._applied,
                        out=self._applied_sent_np)
@@ -1075,7 +1121,7 @@ class KernelEngine:
 
         with _capacity.METER.sanctioned("fleet_down"):
             stats = self._cap_entries["fleet_stats"](
-                self.state, self._fleet_inbox_from())
+                self._resident, self._fleet_inbox_from())
             self.last_fleet = _fleet.stats_to_dict(stats)
 
     def _make_health_digest(self):
@@ -1101,7 +1147,7 @@ class KernelEngine:
             self._health_digest = self._make_health_digest()
         with _capacity.METER.sanctioned("health_down"):
             report, self._health_digest = self._cap_entries["fleet_health"](
-                self.state, self._fleet_inbox_from(), self._health_digest,
+                self._resident, self._fleet_inbox_from(), self._health_digest,
                 thresholds=self.health_thresholds, k=self.health_top_k)
             cur = _health.report_to_dict(report)
         prev = self.last_health
@@ -1149,7 +1195,7 @@ class KernelEngine:
                 self._inv_digest = d._replace(
                     ticks=d.ticks.at[lanes].set(0))
             report, self._inv_digest = self._cap_entries[
-                "check_invariants"](self.state, self._inv_digest)
+                "check_invariants"](self._resident, self._inv_digest)
             cur = _invariants.report_to_dict(report)
         prev = self.last_invariants
         self._inv_seq += 1
@@ -1177,21 +1223,26 @@ class KernelEngine:
         from dragonboat_tpu.core import health as _health
         from dragonboat_tpu.core import invariants as _invariants
 
+        # the reductions take the resident form and unpack it inside
+        # their own programs (kstate.py resident_program)
+        kp = self.kp
         entries = dict(self._dispatch.entries)
         entries.update({
             "fleet_stats": _capacity.TRACKER.wrap(
-                "fleet_stats", _fleet.fleet_stats),
+                "fleet_stats", resident_program(kp, _fleet.fleet_stats)),
             "fleet_health": _capacity.TRACKER.wrap(
-                "fleet_health", _health.fleet_health),
+                "fleet_health", resident_program(
+                    kp, _health.fleet_health, ("thresholds", "k"))),
             "check_invariants": _capacity.TRACKER.wrap(
-                "check_invariants", _invariants.check_invariants),
+                "check_invariants",
+                resident_program(kp, _invariants.check_invariants)),
         })
         return entries
 
     def _capacity_trees(self) -> tuple:
         """Device-resident trees this engine keeps alive between steps
         (the mesh backend adds its carried inbox)."""
-        return (self.state, self._health_digest, self._inv_digest) \
+        return (self._resident, self._health_digest, self._inv_digest) \
             + self._dispatch.resident_trees()
 
     def _capacity_model_classes(self) -> tuple:
@@ -1243,8 +1294,9 @@ class KernelEngine:
             if self._health_digest is None:
                 self._health_digest = self._make_health_digest()
             with _capacity.METER.sanctioned("health_row"):
-                row = _health.shard_row(
-                    self.state, self._fleet_inbox_from(),
+                row = resident_program(
+                    self.kp, _health.shard_row, ("thresholds",))(
+                    self._resident, self._fleet_inbox_from(),
                     self._health_digest, np.int32(lane),
                     thresholds=self.health_thresholds)
                 return _health.row_to_dict(row)
@@ -1258,7 +1310,7 @@ class KernelEngine:
         # the passed-in state again — step_all's retire-before-dispatch
         # order upholds that on BOTH backends
         return self._dispatch.dispatch(
-            self.state, staging, donate=self.pipeline_depth > 0)
+            self._resident, staging, donate=self.pipeline_depth > 0)
 
     # -- staging ----------------------------------------------------------
 
@@ -1679,7 +1731,9 @@ class KernelEngine:
                 # never bridge (re-sent forever) — evict instead.
                 ss = n.logdb.get_snapshot(n.shard_id, n.replica_id)
                 with _capacity.METER.sanctioned("wit_snap_floor"):
-                    floor = int(self.state.snap_index[g])  # wit_snap only
+                    floor = int(state_cell(           # wit_snap only
+                        self._resident.cols, np.int32(g), np.int32(
+                            self._state_cols["snap_index"].start)))
                 if ss is not None and not ss.is_empty() \
                         and ss.index >= floor:
                     others.append((n, pb.Message(
@@ -1723,13 +1777,13 @@ class KernelEngine:
         from ``first``: the download's window, or for a lane whose window
         is wider than its ``S`` entries (none expected; counted) one
         fixed-shape fetch of the lane's whole ring row from the state
-        that step returned (still ``self.state``: a retire runs before
+        that step returned (still the resident one: a retire runs before
         the next dispatch)."""
         if last - first < self._cols.save_window:
             return o["save_terms"][g]
         _SAVE_WINDOW_OVERFLOW.inc()
         with _capacity.METER.sanctioned("save_window_row"):
-            row = np.asarray(ring_row(self.state.lt, np.int32(g)))
+            row = np.asarray(ring_row(self._resident.lt, np.int32(g)))
         return row[(first + np.arange(last - first + 1))
                    & (self.kp.log_cap - 1)]
 

@@ -38,7 +38,6 @@ import jax
 import numpy as np
 from jax.sharding import Mesh
 
-from dragonboat_tpu import capacity as _capacity
 from dragonboat_tpu import fabric as _fabric
 from dragonboat_tpu import raftpb as pb
 from dragonboat_tpu import telemetry
@@ -127,9 +126,10 @@ class MeshEngine(KernelEngine):
             for ir in range(spec.replicas):
                 lo = (ig * spec.replicas + ir) * spec.n_local
                 rids[lo:lo + spec.n_local] = ir + 1
-        self.state = self.cluster.shard(init_state(
+        # (the setter packs it and places it along G)
+        self.state = init_state(
             kp, total, replica_id=rids,
-            peer_ids=np.zeros((total, kp.num_peers), np.int32)))
+            peer_ids=np.zeros((total, kp.num_peers), np.int32))
         # group-lane bookkeeping
         self._lane_of: dict[int, int] = {}            # shard_id -> lane
         # newest membership ccid written to each group's shared peer
@@ -421,13 +421,11 @@ class MeshEngine(KernelEngine):
             # group serves witnesses from the host engines instead
             self._evict(node, reason="witness member on a mesh group")
             return
-        s = self.state
         # the applied CC releases THIS replica's one-in-flight gate only
         # (pycore clears pending_config_change per replica at apply) — a
         # lagging follower's apply must not release the leader row's
         # gate while a newer CC is still uncommitted there
-        s = s._replace(
-            pending_cc=s.pending_cc.at[node.lane].set(False))
+        writes = [(node.lane, "pending_cc", False)]
         # shared peer books: members apply the same CCs at different
         # steps, so only the NEWEST applied membership may write them —
         # a lagging member's view would roll the group's books back
@@ -444,16 +442,12 @@ class MeshEngine(KernelEngine):
             for rid in sorted(m.non_votings):
                 pids[i], kinds[i] = rid, KP.K_NON_VOTING
                 i += 1
-            with _capacity.METER.sanctioned("membership_up"):
-                jp, jk = jax.numpy.asarray(pids), jax.numpy.asarray(kinds)
             for member in list(self._members.get(node.shard_id, {}).values()):
-                s = s._replace(
-                    pid=s.pid.at[member.lane].set(jp),
-                    kind=s.kind.at[member.lane].set(jk),
-                )
+                writes += [(member.lane, "pid", pids),
+                           (member.lane, "kind", kinds)]
                 self._kind_np[member.lane] = kinds
                 self._pid_np[member.lane] = pids
-        self.state = s
+        self._write_cells(writes, "membership_up")
 
     def _evict(self, n: KernelNode, reason: str, carry=None) -> None:
         """Whole-group escalation: every member leaves the mesh and is

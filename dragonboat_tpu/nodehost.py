@@ -1307,8 +1307,10 @@ class NodeHost:
             self.config.raft_address, batch,
             nbytes=sum(_msg_size(m) for m in batch.requests))
         for m in batch.requests:
-            with self.mu:
-                node = self.nodes.get(m.shard_id)
+            # no host lock for one dict read (as _node): on the loopback
+            # transport this runs on the SENDING engine's thread, inside its
+            # round's resolve phase, once a message
+            node = self.nodes.get(m.shard_id)
             if node is not None:
                 # hub delivery skips links the mesh serves: a resident
                 # link's copy is a stray (the exchange already carried
@@ -1336,8 +1338,7 @@ class NodeHost:
             source_address=source_address))
 
     def _on_unreachable(self, m: pb.Message) -> None:
-        with self.mu:
-            node = self.nodes.get(m.shard_id)
+        node = self.nodes.get(m.shard_id)
         if node is not None:
             node.handle_message(m)
 
@@ -1365,8 +1366,12 @@ class NodeHost:
         if self.fatal_error is not None:
             raise RequestError(
                 f"node host halted by storage failure: {self.fatal_error}")
-        with self.mu:
-            node = self.nodes.get(shard_id)
+        # one dict read, atomic as it is: no host lock.  Every client call
+        # comes through here, and a thread that finds the host's lock held
+        # gives the interpreter up and then waits a switch interval or more
+        # to get it back from an engine thread in the middle of a round
+        # (PERF.md section 6, PR 28)
+        node = self.nodes.get(shard_id)
         if node is None:
             raise ShardNotFoundError(f"shard {shard_id} not found")
         return node

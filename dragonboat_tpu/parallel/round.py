@@ -1,6 +1,9 @@
 """The mesh engine round as ONE jitted program (the shard_map twin of
-core/round.py): packed upload in; new state, the carried inbox and the
-packed download out.
+core/round.py): the resident state, the carried inbox and the packed
+upload in; the new resident state, the carried inbox and the packed
+download out.  The carried inbox is ONE [G, Wi] int32 array in the
+upload's own inbox-column layout (kstate.py ``inbox_columns``), so the
+entry takes 6 device arrays and returns 5.
 
 ``jit_serve_step`` / ``jit_serve_step_donated`` here are what
 ``MeshDispatch`` serves (a device capture shows them under the same
@@ -19,7 +22,16 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as PS
 
-from dragonboat_tpu.core.kstate import unpack_upload
+from dragonboat_tpu.core.kstate import (
+    Inbox,
+    column_value,
+    inbox_columns,
+    pack_columns,
+    pack_state,
+    unpack_columns,
+    unpack_state,
+    unpack_upload,
+)
 from dragonboat_tpu.core.round import pack_round
 from dragonboat_tpu.parallel.ici import IciCluster, serve_body, shard_map
 
@@ -28,13 +40,16 @@ def _round_body(kp, replicas, state, box, up, cut):
     # hub-fallback deliveries (cut links, off-mesh senders) were staged
     # slot-exact by the host; a staged slot replaces the carried one
     staged, inp = unpack_upload(kp, up)
+    box_cols, _ = inbox_columns(kp)
     live = staged.mtype != 0
     box = jax.tree.map(
         lambda s, b: jnp.where(
             live.reshape(live.shape + (1,) * (s.ndim - 2)), s, b),
-        staged, box)
-    state, box, out = serve_body(kp, replicas, state, box, inp, cut)
-    return state, box, pack_round(kp, state, out)
+        staged, unpack_columns(Inbox, box_cols, box))
+    s, box, out = serve_body(kp, replicas, unpack_state(kp, state), box,
+                             inp, cut)
+    return (pack_state(kp, s), pack_columns(box_cols, box._asdict()),
+            pack_round(kp, s, out))
 
 
 def _round(kp, cluster: IciCluster, state, box, up, cut):
@@ -50,9 +65,10 @@ def _round(kp, cluster: IciCluster, state, box, up, cut):
 
 @functools.partial(jax.jit, static_argnums=(0, 1))
 def jit_serve_step(kp, cluster: IciCluster, state, box, up, cut):
-    """One mesh round, non-donating (depth 0): ``up`` is the staged
-    [G, Wu] upload sharded along G, ``cut`` the per-link mask; returns
-    ``(state, box, down)``."""
+    """One mesh round, non-donating (depth 0): ``state`` is the resident
+    form and ``box`` the carried [G, Wi] inbox, both sharded along G like
+    the staged [G, Wu] upload ``up``; ``cut`` is the per-link mask;
+    returns ``(state, box, down)``."""
     return _round(kp, cluster, state, box, up, cut)
 
 
@@ -62,3 +78,11 @@ def jit_serve_step(kp, cluster: IciCluster, state, box, up, cut):
 @functools.partial(jax.jit, static_argnums=(0, 1), donate_argnums=(2, 3))
 def jit_serve_step_donated(kp, cluster: IciCluster, state, box, up, cut):
     return _round(kp, cluster, state, box, up, cut)
+
+
+@functools.partial(jax.jit, static_argnums=(0,))
+def box_from(kp, box):
+    """[G, K] sender ids of the carried inbox (the fleet collections'
+    inbox-occupancy input, every tenth round)."""
+    cols, _ = inbox_columns(kp)
+    return column_value(next(c for c in cols if c.field == "from_"), box)

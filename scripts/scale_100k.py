@@ -254,7 +254,7 @@ def phase_b() -> None:
 
     classes = eng._capacity_model_classes()
     predicted = capacity.predict_bytes(
-        eng.kp, int(eng.state.term.shape[0]), classes)
+        eng.kp, eng.capacity, classes, capacity.PACKED_RESIDENT)
     measured = capacity.measure_tree_bytes(*eng._capacity_trees())
     max_g = capacity.max_g_for_budget(
         eng.kp, _budget_bytes(capacity), classes)

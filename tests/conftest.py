@@ -5,6 +5,7 @@ host-platform device mesh (the driver separately dry-runs multichip via
 ``__graft_entry__.dryrun_multichip``).
 """
 
+import collections
 import os
 import sys
 
@@ -179,6 +180,59 @@ def pytest_terminal_summary(terminalreporter, exitstatus, config):
             "test; the silent rerun is a crutch, not a policy.")
 
 
+# -- a failed test's hosts do not outlive it ----------------------------------
+# An end-to-end test that fails between building its cluster and its own
+# ``finally`` leaves the hosts running: a few dozen engine, tick and apply
+# threads each, for the rest of the worker's life.  Every later test of that
+# worker then shares the interpreter with them (test_model_check's fast
+# scope: 10 s alone, 261 s beside six hosts left open), the retry above runs
+# beside the first attempt's hosts under the same addresses, and under
+# ``--dist loadfile`` the files queued behind crawl until the run's time
+# limit cuts them (CHANGES.md, PR 28).  After a failed test's own fixtures
+# have finished, whatever NodeHost is still open in the process is closed
+# here (no fixture of a wider scope than one test keeps a host; one that
+# comes to would have to be spared).
+_FAILED = pytest.StashKey[bool]()
+
+
+def _close_hosts_left_open() -> int:
+    import gc
+
+    from dragonboat_tpu.nodehost import NodeHost
+
+    closed = 0
+    for obj in gc.get_objects():
+        try:
+            if not isinstance(obj, NodeHost) or getattr(obj, "_stopped", True):
+                continue
+        except Exception:       # noqa: BLE001 — a proxy that resists inspection
+            continue
+        closed += 1
+        try:
+            obj.close()
+        except Exception as e:  # noqa: BLE001 — best effort, say so
+            sys.stderr.write(f"\n[conftest] closing a left-open host: {e!r}\n")
+    return closed
+
+
+@pytest.hookimpl(hookwrapper=True)
+def pytest_runtest_makereport(item, call):
+    outcome = yield
+    if outcome.get_result().failed:
+        item.stash[_FAILED] = True
+
+
+@pytest.hookimpl(hookwrapper=True)
+def pytest_runtest_teardown(item, nextitem):
+    yield
+    if item.stash.get(_FAILED, False):
+        item.stash[_FAILED] = False     # a retry answers for itself
+        n = _close_hosts_left_open()
+        if n:
+            sys.stderr.write(f"\n[conftest] {item.nodeid} failed and left {n} "
+                             f"NodeHost(s) open: closed\n")
+
+
 _age_counter = {"n": 0, "cleared": 0}
 
 # The "late-process XLA abort" (run_tests.sh header) ROOT CAUSE,
@@ -237,6 +291,20 @@ def pytest_runtest_setup(item):
                 f" fds={fds} test={item.nodeid}\n")
 
 
+def pytest_configure(config):
+    """Under ``--dist loadfile`` hand the files out in collection order,
+    which ``pytest_collection_modifyitems`` below decides.  xdist 3.8
+    otherwise sorts them by their NUMBER of tests, most first, on its own:
+    that undid the order below without a word, and sent the one-test
+    ``test_zz_mesh_scale`` (190-300 s pinned to one core) out last of all.
+    It started when 99% of the tests were done and ran on alone beside
+    five idle workers for a third of the whole run's time; beside other
+    load the run was cut at its time limit there (CHANGES.md, PR 28)."""
+    if hasattr(config.option, "loadscopereorder"):
+        config.option.loadscopereorder = False
+
+
+@pytest.hookimpl(trylast=True)      # after ``-m`` has deselected
 def pytest_collection_modifyitems(session, config, items):
     """Big-shape jit tests run FIRST.
 
@@ -246,12 +314,33 @@ def pytest_collection_modifyitems(session, config, items):
     2026-07-31: deterministic SIGABRT/SIGSEGV at the same collection
     position across four full-suite runs, while every subset and a
     fresh process pass).  A fresh process handles the big shapes
-    reliably, so they go to the front of the run."""
-    big = [it for it in items if "test_zz_" in it.nodeid]
-    if big:
-        rest = [it for it in items if "test_zz_" not in it.nodeid]
-        items[:] = big + rest
-    # ...and the most load-sensitive file runs LAST: under ``--dist
-    # loadfile`` the last file goes to the first worker that runs dry, when
-    # most of the others are draining or idle, not beside five busy ones
-    items.sort(key=lambda it: "test_chaos_hotspot" in it.nodeid)
+    reliably, so they go to the front of the run.  They are also the
+    longest tests of the suite, each on one worker for minutes: begun
+    first, they end while the other workers still have files to take.
+
+    With them go the two files that rehearse whole benchmark cells inside
+    the worker's process and then read process-wide counters (the compile
+    tracker's rows add up over every engine the process ever had): in a
+    fresh worker nothing an earlier file left can count against them
+    (``test_last_line_shape`` read a retrace 2 runs of 4 that gave it
+    test_round_budget's worker, 0 of 6 otherwise; CHANGES.md, PR 28).
+
+    The other files follow by their number of tests, most first (the
+    order xdist would have given them): the many short tests come before
+    the few-test end-to-end files, so the workers run dry on files of a
+    minute, not of five."""
+    first = ("test_zz_", "test_benchmark_rehearsal", "test_benchmark_mesh_cell")
+    per_file = collections.Counter(it.path for it in items)
+
+    def order(it):
+        if it.path.stem.startswith(first):
+            return (0, 0)
+        # ...and the most load-sensitive file runs LAST: under ``--dist
+        # loadfile`` the last file goes to the first worker that runs dry,
+        # when most of the others are draining or idle, not beside five
+        # busy ones
+        if it.path.stem == "test_chaos_hotspot":
+            return (2, 0)
+        return (1, -per_file[it.path])
+
+    items.sort(key=order)

@@ -72,15 +72,27 @@ def test_model_matches_measured_bytes(name, kp, groups):
         assert delta <= 0.01, (
             f"{name}/{cls}: predicted {predicted} vs measured {measured} "
             f"({delta:.2%} off)")
+    # ... and what an engine keeps between rounds: the state in its
+    # resident form and the mesh backend's carried inbox, every packed
+    # field an int32 column, the rings as they are; exact
+    packed = capacity.model_bytes_per_group(
+        kp, packed=capacity.PACKED_RESIDENT)
+    resident = kstate.pack_program(kp)(state)
+    assert packed["ShardState"] * groups \
+        == capacity.measure_tree_bytes(resident) > per["ShardState"] * groups
+    assert packed["Inbox"] == 4 * kstate.inbox_columns(kp)[1]
+    assert packed["HealthDigest"] == per["HealthDigest"]
 
 
 def test_predict_and_max_g_consistency():
     kp = KernelParams()
-    per = capacity.model_bytes_per_group(kp, capacity.RESIDENT_CLASSES)
+    # the resident set as an engine keeps it (the packed form)
+    per = capacity.model_bytes_per_group(
+        kp, capacity.RESIDENT_CLASSES, capacity.PACKED_RESIDENT)
     total = per["total"]
     assert total == sum(per[c] for c in capacity.RESIDENT_CLASSES)
-    assert capacity.predict_bytes(kp, 7, capacity.RESIDENT_CLASSES) \
-        == 7 * total
+    assert capacity.predict_bytes(kp, 7, capacity.RESIDENT_CLASSES,
+                                  capacity.PACKED_RESIDENT) == 7 * total
     # max_g * per_group fits the budget; one more group does not
     budget = 1000 * total + total // 2
     g = capacity.max_g_for_budget(kp, budget)
@@ -318,13 +330,16 @@ def test_validate_capacity_is_strict():
 
 
 def _clear_jit_caches():
-    from dragonboat_tpu.core import fleet, kernel
+    from dragonboat_tpu.core import fleet, kernel, kstate
 
     for fn in (kernel.step, kernel.step_donated, fleet.fleet_stats,
                health.fleet_health):
         clear = getattr(fn, "_clear_cache", None)
         if clear is not None:
             clear()
+    # an engine's reductions are programs over the resident form, one per
+    # (geometry, reduction) for the process: forget them too
+    kstate.resident_program.cache_clear()
 
 
 def _wait(cond, timeout):

@@ -407,6 +407,8 @@ def test_corrupt_follower_log_requarantines_and_rejoins(tmp_path):
     from dragonboat_tpu.logdb.sharded import ShardedLogDBFactory
     from dragonboat_tpu.nodehost import NodeHost
 
+    from test_nodehost import propose_to_leader
+
     addrs = {i: f"cq-{i}" for i in (1, 2, 3)}
 
     def mk(rid, mode="quarantine"):
@@ -433,10 +435,9 @@ def test_corrupt_follower_log_requarantines_and_rejoins(tmp_path):
 
     hosts = {rid: mk(rid) for rid in addrs}
     try:
-        leader = leader_of(hosts)
-        sess = hosts[leader].get_noop_session(1)
         for i in range(60):
-            hosts[leader].sync_propose(sess, f"k{i}=v{i}".encode())
+            propose_to_leader(hosts, f"k{i}=v{i}".encode())
+        leader = leader_of(hosts)
         victim = next(r for r in (1, 2, 3) if r != leader)
         # wait for the victim to have applied everything, then detach it
         deadline = time.time() + 10
@@ -468,8 +469,7 @@ def test_corrupt_follower_log_requarantines_and_rejoins(tmp_path):
         assert hosts[victim].logdb.quarantined
         # keep the shard moving so compaction passes the lost range
         for i in range(60, 75):
-            h = hosts[leader_of(hosts)]
-            h.sync_propose(h.get_noop_session(1), f"k{i}=v{i}".encode())
+            propose_to_leader(hosts, f"k{i}=v{i}".encode())
         deadline = time.time() + 30
         ok = False
         while time.time() < deadline and not ok:

@@ -22,6 +22,7 @@ Budget: ~22 s per seed; two fixed seeds ride tier-1 as ``chaos_fast``.
 
 import os
 import threading
+import time
 
 import pytest
 
@@ -71,3 +72,58 @@ def test_one_core_pin_is_lifted_from_threads_born_under_it():
     finally:
         stop.set()
         t.join()
+
+
+def test_hosts_a_failed_test_left_open_are_closed(tmp_path):
+    """What conftest does after a FAILED test: every NodeHost still open
+    is closed (a host left running keeps its engine, tick and apply
+    threads in the worker for good, and the tests after it crawl), one
+    already closed is left alone, and a second sweep finds nothing."""
+    import conftest
+
+    from dragonboat_tpu.config import NodeHostConfig
+    from dragonboat_tpu.nodehost import NodeHost
+
+    tag = time.monotonic_ns()
+    left_open = NodeHost(NodeHostConfig(raft_address=f"left-open-{tag}",
+                                        rtt_millisecond=5))
+    closed = NodeHost(NodeHostConfig(raft_address=f"closed-{tag}",
+                                     rtt_millisecond=5))
+    try:
+        closed.close()
+        assert conftest._close_hosts_left_open() == 1
+        assert left_open._stopped
+        assert conftest._close_hosts_left_open() == 0
+    finally:
+        if not left_open._stopped:
+            left_open.close()
+
+
+def test_files_are_handed_out_in_collection_order(request):
+    """Under ``--dist loadfile`` conftest keeps xdist from re-sorting the
+    files by their number of tests, so the order conftest gives the
+    collection (the long big-shape files first, this file last) is the
+    order the workers take them in."""
+    import conftest
+
+    opt = request.config.option
+    if hasattr(opt, "loadscopereorder"):        # xdist is loaded
+        assert opt.loadscopereorder is False
+
+    class Item:
+        def __init__(self, path):
+            import pathlib
+
+            self.path = pathlib.Path(path)
+            self.nodeid = path
+
+    items = [Item(p) for p in (
+        "tests/test_chaos_hotspot.py", "tests/test_few.py",
+        "tests/test_many.py", "tests/test_many.py", "tests/test_many.py",
+        "tests/test_few.py", "tests/benchmark/test_benchmark_rehearsal.py",
+        "tests/test_zz_mesh_scale.py")]
+    conftest.pytest_collection_modifyitems(None, request.config, items)
+    assert [it.path.stem for it in items] == [
+        "test_benchmark_rehearsal", "test_zz_mesh_scale",
+        "test_many", "test_many", "test_many", "test_few", "test_few",
+        "test_chaos_hotspot"]

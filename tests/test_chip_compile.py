@@ -29,7 +29,9 @@ from dragonboat_tpu.core import kernel, round as cround
 from dragonboat_tpu.core.kstate import (
     empty_inbox,
     empty_input,
+    inbox_columns,
     init_state,
+    pack_state,
     round_columns,
 )
 from dragonboat_tpu.nodehost import NodeHost
@@ -102,6 +104,16 @@ def _step_args(kp, rows):
                                    empty_input(kp, rows)))
 
 
+def _round_args(kp, rows, sharding):
+    """What a served round's entry takes: the resident state (three
+    arrays), the mesh's carried [G, Wi] inbox, one [G, Wu] upload."""
+    state, _box, _inp = _step_args(kp, rows)
+    resident = jax.eval_shape(lambda s: pack_state(kp, s), state)
+    mat = lambda w: jax.ShapeDtypeStruct((rows, w), jnp.int32)  # noqa: E731
+    return _shapes((resident, mat(inbox_columns(kp)[1]),
+                    mat(round_columns(kp).up_width)), lambda x: sharding)
+
+
 @pytest.mark.parametrize("entry", ["step", "step_donated"])
 def test_kernel_step_compiles_for_v5e(one_chip, device_kp, entry):
     kp = device_kp()
@@ -113,14 +125,14 @@ def test_kernel_step_compiles_for_v5e(one_chip, device_kp, entry):
 
 @pytest.mark.parametrize("entry", ["step", "step_donated"])
 def test_round_step_compiles_for_v5e(one_chip, device_kp, entry):
-    """The serial round as served: one [G, Wu] upload in, the state and
-    one [G, Wd] download out, and no gather added around the step (the
-    save window is read by block select, core/round.py)."""
+    """The serial round as served: the resident state and one [G, Wu]
+    upload in (4 arrays), the resident state and one [G, Wd] download out
+    (4), and no gather added around the step (the save window is read by
+    block select, core/round.py)."""
     kp = device_kp()
     rc = round_columns(kp)
-    state, _box, _inp = _shapes(_step_args(kp, ROWS), lambda x: one_chip)
-    up = jax.ShapeDtypeStruct((ROWS, rc.up_width), jnp.int32,
-                              sharding=one_chip)
+    state, _box, up = _round_args(kp, ROWS, one_chip)
+    assert len(jax.tree.leaves((state, up))) == 4
     compiled = getattr(cround, entry).lower(
         kp, kernel.step, state, up).compile()
     assert jax.eval_shape(
@@ -148,10 +160,9 @@ def test_mesh_round_compiles_for_v5e(topo, device_kp, entry):
                               lambda x: cl.sharding())
     cut = jax.ShapeDtypeStruct((cl.total_rows, kp.num_peers), bool,
                                sharding=cl.sharding())
-    up = jax.ShapeDtypeStruct((cl.total_rows, round_columns(kp).up_width),
-                              jnp.int32, sharding=cl.sharding())
     hlo = getattr(pround, entry).lower(
-        kp, cl, state, box, up, cut).compile().as_text()
+        kp, cl, *_round_args(kp, cl.total_rows, cl.sharding()), cut,
+    ).compile().as_text()
     plain = ici.jit_serve_step.lower(
         kp, cl, state, box, inp, cut).compile().as_text()
     for collective in ("all-gather", "all-reduce", "all-to-all",
@@ -186,14 +197,11 @@ def test_collective_bytes_cover_what_the_compiler_moves(topo, device_kp):
     mesh = Mesh(np.array(topo.devices[:3]).reshape(1, 3), ("g", "r"))
     cl = ici.IciCluster(kp=kp, mesh=mesh, replicas=3, n_local=48,
                         num_groups=48)
-    state, box, _inp = _shapes(_step_args(kp, cl.total_rows),
-                               lambda x: cl.sharding())
     cut = jax.ShapeDtypeStruct((cl.total_rows, kp.num_peers), bool,
                                sharding=cl.sharding())
-    up = jax.ShapeDtypeStruct((cl.total_rows, round_columns(kp).up_width),
-                              jnp.int32, sharding=cl.sharding())
     hlo = pround.jit_serve_step.lower(
-        kp, cl, state, box, up, cut).compile().as_text()
+        kp, cl, *_round_args(kp, cl.total_rows, cl.sharding()), cut,
+    ).compile().as_text()
     moved = _collective_result_bytes(hlo) * 2 // 3
     counted = 2 * collective_bytes.exchange_bytes_per_chip(kp, 48)
     assert 0 < moved <= counted <= moved * 4 // 3, (moved, counted)

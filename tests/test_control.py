@@ -201,7 +201,8 @@ def host(tmp_path):
     nhc.expert.capacity_watermark_pct = 0.0
     nh = NodeHost(nhc, auto_run=False)
     per = _capacity.model_bytes_per_group(
-        nh._kernel_params(), _capacity.RESIDENT_CLASSES)["total"]
+        nh._kernel_params(), _capacity.RESIDENT_CLASSES,
+        _capacity.PACKED_RESIDENT)["total"]
     nhc.expert.capacity_device_budget_bytes = 2 * per
     yield nh
     nh.close()

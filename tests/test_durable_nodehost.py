@@ -23,7 +23,7 @@ from dragonboat_tpu.server.env import (
 )
 
 from test_kernel_engine import propose_retry
-from test_nodehost import KVStateMachine, wait_leader
+from test_nodehost import KVStateMachine, propose_to_leader, wait_leader
 
 
 def make_hosts(base_dir, n=3, prefix="dur", snapshot_entries=0):
@@ -119,19 +119,19 @@ def test_cluster_restart_from_disk(tmp_path):
     dirs with brand-new NodeHosts (fresh TanLogDB built from the files),
     and verify state + liveness."""
     hosts, addrs = make_hosts(tmp_path, snapshot_entries=10)
-    lead = wait_leader(hosts)
-    nh = hosts[lead]
-    sess = nh.get_noop_session(1)
-    for i in range(25):
-        nh.sync_propose(sess, f"k{i}=v{i}".encode())
-    # let replication reach everyone
-    deadline = time.time() + 5
-    while time.time() < deadline:
-        if all(h.stale_read(1, "k24") == "v24" for h in hosts.values()):
-            break
-        time.sleep(0.05)
-    for h in hosts.values():
-        h.close()
+    try:
+        # ``k=v`` sets: one applied twice changes nothing
+        for i in range(25):
+            propose_to_leader(hosts, f"k{i}=v{i}".encode())
+        # let replication reach everyone
+        deadline = time.time() + 5
+        while time.time() < deadline:
+            if all(h.stale_read(1, "k24") == "v24" for h in hosts.values()):
+                break
+            time.sleep(0.05)
+    finally:
+        for h in hosts.values():
+            h.close()
 
     hosts2 = {}
     for rid, addr in addrs.items():

@@ -230,7 +230,15 @@ def test_mesh_round_phases_crossings_and_counter():
         with eng.mu:
             placed = eng.cluster.sharding()
             assert all(x.sharding == placed for x in jax.tree.leaves(
-                (eng.state, eng._dispatch.box)))
+                (eng._resident, eng._dispatch._box,
+                 eng.state, eng._dispatch.box)))
+            # the serve entry takes the resident state's three arrays, the
+            # carried inbox, the upload and the cut mask, and returns the
+            # state, the inbox and the download: at most 8 each way
+            assert eng._dispatch.entry_arrays == (6, 5)
+            snap = telemetry.GLOBAL.snapshot()
+            assert snap["engine_entry_arrays{dir=in}"] == 6
+            assert snap["engine_entry_arrays{dir=out}"] == 5
         assert eng._cap_entries["serve_step"].stats()["compiles"] <= 1
 
         def snap():

@@ -75,6 +75,25 @@ def wait_leader(hosts, shard_id=1, timeout=10.0):
     raise AssertionError("no leader elected")
 
 
+def propose_to_leader(hosts, cmd, shard_id=1, deadline_s=30.0):
+    """``sync_propose`` on whoever leads when asked, asked again (of the
+    leader of then) after the drops raft returns around an election: with
+    a 50 ms election timeout leadership does move on a loaded box, and the
+    old leader then drops every proposal.  For idempotent commands (a
+    timed-out proposal may still apply)."""
+    from dragonboat_tpu.request import RequestDroppedError, RequestTimeoutError
+
+    end = time.time() + deadline_s
+    while True:
+        nh = hosts[wait_leader(hosts, shard_id=shard_id)]
+        try:
+            return nh.sync_propose(nh.get_noop_session(shard_id), cmd)
+        except (RequestDroppedError, RequestTimeoutError):
+            if time.time() > end:
+                raise
+            time.sleep(0.1)
+
+
 @pytest.fixture
 def cluster():
     hosts, addrs = make_cluster(addr_prefix=f"nhA{time.monotonic_ns()}")

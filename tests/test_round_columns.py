@@ -1,8 +1,10 @@
-"""The round's two packed crossings (kstate.py's column table, the jitted
-round entries of core/round.py and parallel/round.py): pack then unpack is
-the identity on every field, the host builders write the same columns the
-device unpacks, and the packed step is ``core.kernel.step`` (the mesh one
-``ici.jit_serve_step``) with nothing added or lost, field for field."""
+"""The round's two packed crossings and the resident form of the state
+between rounds (kstate.py's column table, the jitted round entries of
+core/round.py and parallel/round.py): pack then unpack is the identity on
+every field, the host builders write the same columns the device unpacks,
+and the packed step on the resident state is ``core.kernel.step`` on the
+ShardState (the mesh one ``ici.jit_serve_step``) with nothing added or
+lost, field for field, after every round."""
 
 from __future__ import annotations
 
@@ -13,7 +15,12 @@ import pytest
 from jax.sharding import Mesh
 
 from dragonboat_tpu.core import kernel, kstate, params as KP, round as cround
-from dragonboat_tpu.core.kstate import Inbox, StepInput, StepOutput
+from dragonboat_tpu.core.kstate import (
+    Inbox,
+    ShardState,
+    StepInput,
+    StepOutput,
+)
 from dragonboat_tpu.core.router import route
 from dragonboat_tpu.engine import kernel_engine as ke
 from dragonboat_tpu.parallel import ici, round as pround
@@ -120,10 +127,51 @@ def test_pack_then_unpack_returns_every_field(geometry, inline):
     assert np.array_equal(o["save_terms"], terms)
 
 
+@pytest.mark.parametrize("inline", [False, True], ids=["plain", "inline"])
+@pytest.mark.parametrize("geometry", sorted(GEOMETRIES))
+def test_pack_state_then_unpack_returns_every_field(geometry, inline):
+    """``unpack_state(pack_state(s)) == s`` field for field on a random
+    state (bools, the [G, RI, P] ``ri_acks``, ``lv`` present and absent):
+    the resident form is three arrays (four with ``lv``), the rings handed
+    over as they are."""
+    kp = KP.KernelParams(inline_payloads=inline, **GEOMETRIES[geometry])
+    state = _random_tree(ShardState, kp, np.random.default_rng(11))
+    assert (state.lv is not None) == inline
+    assert state.ri_acks.shape == (G, kp.readindex_cap, kp.num_peers)
+    cols, width = kstate.state_columns(kp)
+    assert width == sum(c.width for c in cols)
+    assert not {c.field for c in cols} & set(kstate.RING_FIELDS)
+    assert {c.field for c in cols} | set(kstate.RING_FIELDS) \
+        == set(ShardState._fields)
+    resident = kstate.pack_program(kp)(state)
+    assert len(jax.tree.leaves(resident)) == (4 if inline else 3)
+    assert resident.cols.shape == (G, width)
+    assert resident.cols.dtype == jnp.int32
+    for ring in kstate.RING_FIELDS:
+        if getattr(state, ring) is not None:
+            assert np.array_equal(getattr(resident, ring),
+                                  getattr(state, ring)), ring
+    # the view the engine's ``state`` property runs leaves the rings out
+    view = kstate.view_program(kp)(resident.cols)
+    assert view.lt is None and view.lcc is None and view.lv is None
+    _assert_same("ShardState", _unpack(kp, resident), state)
+
+
+def _unpack(kp, resident, placement=None) -> ShardState:
+    """As the engine's ``state`` property: the columns unpacked by one
+    program, the resident rings handed over."""
+    return kstate.view_program(kp, placement)(resident.cols)._replace(
+        lt=resident.lt, lcc=resident.lcc, lv=resident.lv)
+
+
 def test_served_geometry_widths():
-    """The sizes PERF.md quotes: 231 columns up, 344 down, S 64."""
-    rc = kstate.round_columns(KP.KernelParams(**GEOMETRIES["served"]))
+    """The sizes PERF.md quotes: 231 columns up, 344 down, S 64; 108
+    columns of resident state, 208 of carried mesh inbox."""
+    served = KP.KernelParams(**GEOMETRIES["served"])
+    rc = kstate.round_columns(served)
     assert (rc.up_width, rc.down_width, rc.save_window) == (231, 344, 64)
+    assert kstate.state_columns(served)[1] == 108
+    assert kstate.inbox_columns(served)[1] == 208
     assert kstate.save_window(KP.KernelParams(
         log_cap=16, **{k: v for k, v in GEOMETRIES["served"].items()
                        if k != "log_cap"})) == 16, "never past the ring"
@@ -205,15 +253,19 @@ def _check_download(tag, kp, down, state, out):
     return int((np.asarray(out.save_last) >= first).sum())
 
 
-def test_packed_serial_step_equals_kernel_step():
-    """50 random busy steps of 4 routed groups: ``core.round.step`` on the
-    packed upload returns the state and (unpacked) outputs of
-    ``core.kernel.step`` on the same inputs, bit for bit; the donating
-    twin agrees too."""
+@pytest.mark.parametrize("depth", [0, 1])
+def test_packed_serial_step_equals_kernel_step(depth):
+    """50 random busy steps of 4 routed groups: ``core.round.step`` (depth
+    1: the donating ``step_donated``) on the resident state and the packed
+    upload, its own result carried from round to round as an engine carries
+    it, returns the state and (unpacked) outputs of ``core.kernel.step`` on
+    the ShardState and the same inputs, bit for bit after every round."""
     from dragonboat_tpu.bench_loop import make_cluster
 
     kp = _step_kp(REPLICAS)
+    entry = cround.step_donated if depth else cround.step
     state = make_cluster(kp, 4, REPLICAS)
+    resident = kstate.pack_program(kp)(state)
     box = kstate.empty_inbox(kp, state.term.shape[0])
     rng = np.random.default_rng(5)
     route_jit = jax.jit(route, static_argnums=(0, 1))
@@ -221,15 +273,14 @@ def test_packed_serial_step_equals_kernel_step():
     for i in range(STEPS):
         inp = _busy_input(kp, rng, state)
         want_state, want_out = kernel.step(kp, state, box, inp)
-        up = _pack_up(kp, box, inp)
-        got_state, down = cround.step(kp, kernel.step, state, up)
+        args = (resident, _pack_up(kp, box, inp))
+        resident, down = entry(kp, kernel.step, *args)
+        # the entry takes and returns 4 device arrays, not 46
+        assert len(jax.tree.leaves(args)) == 4
+        assert len(jax.tree.leaves((resident, down))) == 4
+        got_state = _unpack(kp, resident)
         _assert_trees_equal(f"step {i} state", got_state, want_state)
         saved += _check_download(f"step {i}", kp, down, got_state, want_out)
-        twin_state, twin_down = cround.step_donated(
-            kp, kernel.step, jax.tree.map(jnp.array, state), up)
-        _assert_trees_equal(f"step {i} donated state", twin_state,
-                            want_state)
-        assert np.array_equal(np.asarray(twin_down), np.asarray(down))
         state, box = want_state, route_jit(kp, REPLICAS, want_out)
         committed = int(np.asarray(state.committed).max())
     assert committed > 0 and saved > STEPS, "the steps were not busy"
@@ -239,7 +290,9 @@ def test_packed_mesh_step_equals_serve_step():
     """The same over a 1x2 device mesh (forced host devices):
     ``parallel.round.jit_serve_step`` against ``ici.jit_serve_step``, with
     hub-fallback rows staged in the upload's inbox columns on some steps
-    (merged into the carried inbox as MeshDispatch used to, eagerly)."""
+    (merged into the carried inbox as MeshDispatch used to, eagerly); the
+    resident state and the carried [G, Wi] inbox are the entry's own
+    results, carried from round to round."""
     if len(jax.devices()) < 2:
         pytest.skip("needs 2 devices")
     kp = _step_kp(2)
@@ -247,6 +300,12 @@ def test_packed_mesh_step_equals_serve_step():
     cluster, state, box = ici.make_ici_cluster(kp, mesh, num_groups=4)
     Gn = cluster.total_rows
     cut = cluster.shard(np.zeros((Gn, kp.num_peers), bool))
+    placed = cluster.sharding()
+    box_cols, wi = kstate.inbox_columns(kp)
+    resident = kstate.pack_program(kp, placed)(state)
+    carried = jax.device_put(
+        np.asarray(kstate.pack_columns(box_cols, box._asdict())), placed)
+    assert carried.shape == (Gn, wi)
     rng = np.random.default_rng(9)
     empty = jax.tree.map(np.asarray, kstate.empty_inbox(kp, Gn))
     saved = committed = 0
@@ -269,8 +328,14 @@ def test_packed_mesh_step_equals_serve_step():
             cluster.shard(inp), cut)
         up = jax.device_put(np.asarray(_pack_up(kp, staged, inp)),
                             cluster.sharding())
-        got_state, got_box, down = pround.jit_serve_step(
-            kp, cluster, state, box, up, cut)
+        args = (resident, carried, up, cut)
+        resident, carried, down = pround.jit_serve_step(kp, cluster, *args)
+        assert len(jax.tree.leaves(args)) == 6
+        assert len(jax.tree.leaves((resident, carried, down))) == 5
+        for x in jax.tree.leaves((resident, carried)):
+            assert x.sharding == placed, "spelled as it was placed"
+        got_state = _unpack(kp, resident, placed)
+        got_box = kstate.box_view_program(kp, placed)(carried)
         _assert_trees_equal(f"step {i} state", got_state, want_state)
         _assert_trees_equal(f"step {i} box", got_box, want_box)
         saved += _check_download(f"step {i}", kp, down, got_state, want_out)

@@ -436,12 +436,14 @@ def test_runtime_guard_catches_host_round_trip():
 
     from dragonboat_tpu import capacity
     from dragonboat_tpu.bench_loop import bench_params, make_cluster
+    from dragonboat_tpu.core.kstate import pack_program
     from dragonboat_tpu.engine import kernel_engine as _ke
     from dragonboat_tpu.engine.dispatch import SerialDispatch
 
     kp = bench_params(3, platform="cpu")
     state = make_cluster(kp, 1, 3)
     G = int(state.term.shape[0])
+    state = pack_program(kp)(state)      # the seam takes the resident form
     disp = SerialDispatch(kp)
     staging = _ke._RoundStaging(kp, G)
     state, _down = disp.dispatch(state, staging, donate=False)  # warm
