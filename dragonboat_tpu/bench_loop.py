@@ -126,23 +126,6 @@ def full_step(kp: KP.KernelParams, replicas: int, state: ShardState,
     return state, nxt, out
 
 
-@functools.partial(jax.jit, static_argnums=(0, 1))
-def cc_step(kp: KP.KernelParams, replicas: int, state: ShardState,
-            box: Inbox):
-    """One step of the membership-change wave (BASELINE config #5): every
-    leader proposes a config-change entry in lane 0 alongside its normal
-    write batch.  The CC rides the ordinary append→replicate→commit
-    pipeline (one-at-a-time gate enforced by the kernel); the bench's
-    host loop plays the engine's role of releasing the gate after the
-    apply (engine update_lane_membership clears pending_cc).  Returns
-    (state, next_box, accepted_cc_mask, cc_index)."""
-    inp = _self_input(kp, state, True, True, None, False, 0)
-    inp = inp._replace(prop_cc=inp.prop_cc.at[:, 0].set(True))
-    state, out = step(kp, state, box, inp)
-    return (state, route(kp, replicas, out),
-            out.prop_accepted[:, 0], out.prop_index[:, 0])
-
-
 @functools.partial(jax.jit, static_argnums=(0, 1, 2))
 def run_steps(kp: KP.KernelParams, replicas: int, iters: int,
               tick, propose, state: ShardState, box: Inbox):
@@ -497,32 +480,6 @@ def run_steps_lat(kp: KP.KernelParams, replicas: int, iters: int,
         st, bx, sp, hi, rd = full_step_lat(
             kp, replicas, write_width, do_reads, st, bx,
             tick, propose, now0 + i, sp, hi, rd)
-        return st, bx, sp, hi, rd
-
-    return jax.lax.fori_loop(0, iters, body,
-                             (state, box, stamp, hist, reads))
-
-
-@functools.partial(jax.jit, static_argnums=(0, 1, 2, 3, 4))
-def run_steps_lat_pipelined(kp: KP.KernelParams, replicas: int, iters: int,
-                            write_width: int, do_reads: bool, tick, propose,
-                            now0, state, box, stamp, hist, reads):
-    """Instrumented pipelined loop: both fused micro-steps stamp and
-    bucket against the SAME pipeline-step clock ``now0 + i`` — the
-    histogram therefore measures commit latency in PIPELINE steps, the
-    unit a client of the overlapped loop actually waits in.  (Deliberately
-    NOT bitwise-comparable to ``run_steps_lat``: the stamp ring differs by
-    construction.  The uninstrumented pipelined loops are the bitwise
-    oracles.)"""
-    tick = jnp.asarray(tick, bool)
-    propose = jnp.asarray(propose, bool)
-
-    def body(i, carry):
-        st, bx, sp, hi, rd = carry
-        for _ in (0, 1):
-            st, bx, sp, hi, rd = full_step_lat(
-                kp, replicas, write_width, do_reads, st, bx,
-                tick, propose, now0 + i, sp, hi, rd)
         return st, bx, sp, hi, rd
 
     return jax.lax.fori_loop(0, iters, body,

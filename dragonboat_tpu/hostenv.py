@@ -1,7 +1,7 @@
 """The process's one persistent-compilation-cache setting.
 
 Every entry point that wants compiled executables to survive the process
-(tests, chip_smoke.py, bench.py, the scripts, ``ExpertConfig.compile_cache``)
+(tests, the benchmark, the scripts, ``ExpertConfig.compile_cache``)
 calls ``enable_compile_cache`` — nothing else in the tree names a cache
 directory.  The directory is placeable from outside: where
 ``JAX_COMPILATION_CACHE_DIR`` is set jax reads it itself and this module

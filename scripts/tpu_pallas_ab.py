@@ -18,8 +18,8 @@ the two hot gather shapes on that path — inbox lane staging and the
 quorum match select — as pallas VMEM block kernels vs their XLA
 lowerings (parallel/fabric_pallas.py).
 
-Appends JSON lines (kind=pallas_ab / pipeline_ab / fabric_ab) to
-PERF_TPU.jsonl.  On the CPU backend (PALLAS_AB_FORCE_CPU=1 also asks
+Prints one JSON line per rung family (kind=pallas_ab / pipeline_ab /
+fabric_ab) to stdout.  On the CPU backend (PALLAS_AB_FORCE_CPU=1 also asks
 for 8 virtual devices) pallas runs in interpret mode — the relative
 number means nothing there, the plumbing check does.
 
@@ -56,8 +56,6 @@ print("PALLAS_AB compile_cache: "
          else f"{'warm' if _CACHE_ARTIFACTS else 'cold'} "
               f"({_CACHE_ARTIFACTS} artifact(s)) dir={_CACHE_DIR}"),
       flush=True)
-
-OUT = os.path.join(REPO, "PERF_TPU.jsonl")
 
 
 def bare_apply_ab(G: int, AB: int, iters: int = 50) -> dict:
@@ -358,10 +356,6 @@ def main() -> None:
     fab.update(fabric_serve_ab(min(g, 1024),
                                micro=max(5, min(40, 20_000 // g))))
     fab.update(fabric_gather_ab(g))
-    with open(OUT, "a") as f:
-        f.write(json.dumps(rec) + "\n")
-        f.write(json.dumps(pipe) + "\n")
-        f.write(json.dumps(fab) + "\n")
     print(json.dumps(rec), flush=True)
     print(json.dumps(pipe), flush=True)
     print(json.dumps(fab), flush=True)

@@ -436,9 +436,9 @@ def test_steady_state_compiles_each_entry_once_per_geometry(depth):
 
 @pytest.mark.parametrize("case", ["env_set", "env_unset", "veto"])
 def test_compile_cache_placement(monkeypatch, tmp_path, case):
-    """The one compile-cache helper (hostenv; conftest, bench, the
-    scripts, chip_smoke and ExpertConfig.compile_cache all route through
-    it): JAX_COMPILATION_CACHE_DIR set -> that directory, and code sets
+    """The one compile-cache helper (hostenv; conftest, the benchmark,
+    the scripts and ExpertConfig.compile_cache all route through it):
+    JAX_COMPILATION_CACHE_DIR set -> that directory, and code sets
     none; unset -> the fixed <checkout>/.jax_cache;
     DRAGONBOAT_TPU_COMPILE_CACHE=0 -> None and nothing is set."""
     import jax

@@ -5,7 +5,7 @@ Covers the entries an engine serves through (the packed rounds of
 ``core/round.py`` and ``parallel/round.py`` with the KernelParams a NodeHost
 picks on a TPU, serial and on a 1x3 mesh), the unpacked steps they wrap
 (``kernel.step`` / ``step_donated``, ``ici.jit_serve_step``: the
-differentials' and ``chip_smoke.py``'s entries) and the three Pallas kernels
+differentials' entries) and the three Pallas kernels
 at their bench shapes.  Nothing runs — these say nothing about results or
 times.
 
@@ -211,8 +211,8 @@ def test_collective_bytes_cover_what_the_compiler_moves(topo, device_kp):
                                    "jit_serve_step_donated"])
 def test_mesh_serve_step_compiles_for_v5e(topo, device_kp, entry):
     """1x3 mesh of described chips, 48 group lanes per replica slot — the
-    geometry ``chip_smoke.py --chips 4`` serves — and the collectives are
-    in the program."""
+    geometry the cell ``upstream-48-mesh4.write16`` serves — and the
+    collectives are in the program."""
     kp = device_kp(min_inbox=10)    # as NodeHost._inject_mesh_shard asks
     mesh = Mesh(np.array(topo.devices[:3]).reshape(1, 3), ("g", "r"))
     cl = ici.IciCluster(kp=kp, mesh=mesh, replicas=3, n_local=48,
