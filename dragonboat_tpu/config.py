@@ -89,9 +89,15 @@ class EngineConfig:
 
 @dataclass
 class LogDBConfig:
-    """Expert log-engine geometry (config/config.go:780,845): the durable
-    log is split into ``shards`` single-writer partitions so concurrent
-    step workers flush different files (internal/logdb/sharded.go:34).
+    """Expert log-engine geometry (config/config.go:780,845).  ``shards``
+    is the number of single-writer partitions the durable log is split
+    into (internal/logdb/sharded.go:34).  Left at 0 (unset), an existing
+    directory keeps the count it was created with and a new one is ONE
+    log: an engine saves a whole round in one write and one fsync on its
+    own thread, and host step workers share that log's fsync (group
+    commit), where 16 partitions cost a round 16 flushes through a thread
+    pool.  An explicit count is honoured; one that disagrees with an
+    existing directory is refused (``ShardGeometryError``).
 
     ``engine`` picks the per-partition storage engine — ``"tan"`` (the
     purpose-built log-file engine, the default) or ``"kv"`` (the
@@ -105,7 +111,7 @@ class LogDBConfig:
     contiguously present, and lets raft re-replicate the rest from the
     quorum (snapshot fallback when the entries were compacted away)."""
 
-    shards: int = 16
+    shards: int = 0
     engine: str = "tan"
     recovery_mode: str = "strict"
 

@@ -1,4 +1,17 @@
-"""Sharded LogDB — N single-writer tan partitions whose fsyncs overlap.
+"""Sharded LogDB — one log by default, or N single-writer tan partitions
+whose fsyncs overlap.
+
+**The default geometry is one partition** (``num_shards`` 0, "unset": a new
+directory gets 1, an existing one keeps the count its ``TANSHARDS`` marker
+pins; an explicit count is honoured and an explicit mismatch refused).  A
+kernel or mesh engine is ONE writer per LogDB that saves its whole ``[G]``
+batch in one call, and what that wants is one write and one fsync of one
+file on the calling thread: with 16 partitions the same save was 16 pool
+tasks, 16 writes and 16 fsyncs, ~160 points a round where a thread gave the
+interpreter up and waited to get it back (PERF.md section 6, PR 30).  Host
+step workers that meet at the one log share its fsync (tan's group commit)
+instead of overlapping 16 of them.  Partitions remain for directories that
+have them and for operators who ask: what follows describes those.
 
 Parity with the reference's ``internal/logdb/sharded.go:34-80`` ShardedDB:
 the log engine is split into ``num_shards`` independent single-writer
@@ -41,10 +54,16 @@ from typing import Sequence
 
 from dragonboat_tpu import lifecycle
 from dragonboat_tpu import raftpb as pb
+from dragonboat_tpu import telemetry
 from dragonboat_tpu.logdb.tan import TanLogDB
 from dragonboat_tpu.raftio import ILogDB, NodeInfo, RaftState
 
 _MARKER = "TANSHARDS"
+
+_SAVE_PARTS = telemetry.GLOBAL.histogram(
+    "logdb.save_parts",
+    help="partitions one save_raft_state call touched",
+    buckets=(1, 2, 4, 8, 16, 32, 64))
 
 
 class ShardGeometryError(Exception):
@@ -54,19 +73,23 @@ class ShardGeometryError(Exception):
 class ShardedLogDB(ILogDB):
     """``num_shards`` TanLogDB partitions under one root directory."""
 
-    def __init__(self, root_dir: str, num_shards: int = 16,
+    def __init__(self, root_dir: str, num_shards: int = 0,
                  max_file_size: int = 64 << 20, fs=None,
                  engine: str = "tan",
                  recovery_mode: str = "strict") -> None:
         from dragonboat_tpu.vfs import default_fs
 
-        if num_shards < 1:
-            raise ValueError("num_shards must be >= 1")
+        if num_shards < 0:
+            raise ValueError("num_shards must be >= 1, or 0 for the "
+                             "directory's own count")
         if engine not in ("tan", "kv"):
             raise ValueError(f"unknown logdb engine {engine!r}")
         self.fs = fs if fs is not None else default_fs()
         self.root = root_dir
-        self.num_shards = num_shards
+        # THE place a directory's partition count is decided: unset means
+        # what the marker pins, and one log for a new directory
+        self.num_shards = num_shards or self.stored_shard_count(
+            root_dir, self.fs) or 1
         self.engine = engine
         self.recovery_mode = recovery_mode
         self.fs.makedirs(self.root)
@@ -90,7 +113,7 @@ class ShardedLogDB(ILogDB):
 
         self._parts = [
             make_part(os.path.join(self.root, f"part-{i:02d}"))
-            for i in range(num_shards)
+            for i in range(self.num_shards)
         ]
         # corruption sites quarantined by the tan partitions on open
         # (always empty under engine="kv" or recovery_mode="strict")
@@ -101,7 +124,7 @@ class ShardedLogDB(ILogDB):
         # sized to the partition count, NOT cpu_count — these tasks block
         # in fsync, they do not compute
         self._pool = ThreadPoolExecutor(
-            max_workers=min(num_shards, 16),
+            max_workers=min(self.num_shards, 16),
             thread_name_prefix="tanshard-flush")
         self._closed = False
         self._close_mu = threading.Lock()
@@ -145,8 +168,7 @@ class ShardedLogDB(ILogDB):
     @staticmethod
     def stored_shard_count(root_dir: str, fs) -> int | None:
         """The shard count pinned in ``root_dir``, or None if the dir was
-        never opened by a ShardedLogDB (tools open existing dirs with
-        whatever geometry the owning NodeHost pinned)."""
+        never opened by a ShardedLogDB."""
         mp = os.path.join(root_dir, _MARKER)
         if not fs.exists(mp):
             return None
@@ -239,6 +261,7 @@ class ShardedLogDB(ILogDB):
             groups.setdefault(self._pid(ud.shard_id), []).append(ud)
         if not groups:
             return
+        _SAVE_PARTS.observe(len(groups))
         if len(groups) == 1:
             pid, uds = next(iter(groups.items()))
             self._parts[pid].save_raft_state(uds, worker_id)
@@ -291,7 +314,7 @@ class ShardedLogDB(ILogDB):
 class ShardedLogDBFactory:
     """config.LogDBFactory equivalent producing the sharded engine."""
 
-    def __init__(self, root_dir: str, num_shards: int = 16,
+    def __init__(self, root_dir: str, num_shards: int = 0,
                  max_file_size: int = 64 << 20, fs=None,
                  engine: str = "tan",
                  recovery_mode: str = "strict") -> None:

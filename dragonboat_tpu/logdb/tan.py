@@ -11,8 +11,19 @@ Differences from the reference, deliberate:
 
 - one record = one ``pb.Update`` batch (state + entries + optional snapshot
   metadata), matching the engine's batched ``save_raft_state`` shape — the
-  ``[G]``-batch from the device kernel lands as a run of records followed by
-  ONE fsync (raftio/logdb.go:78-83 single-writer contract);
+  ``[G]``-batch from the device kernel lands as a run of records handed to
+  the file in ONE write, followed by ONE fsync (raftio/logdb.go:78-83
+  single-writer contract).  Each record keeps its own header and CRC, so a
+  crash inside the write leaves a valid prefix, none of it acknowledged;
+- the writer keeps the active file's offset itself and never asks the file
+  (``tell`` is an ``lseek`` that gives the interpreter up, twice a record);
+- the fsync is shared (group commit): a writer appends under ``_mu``, lets
+  it go, and under ``_sync_mu`` returns at once if a concurrent fsync
+  already covered its append, else fsyncs for everything appended so far.
+  One writer pays one uncontended lock; several writers on one log append
+  while one of them is in ``fsync`` and the next fsync covers them all.
+  Lock order is always ``_sync_mu`` then ``_mu``; what moves the active
+  file (rotation, close) holds both;
 - node metadata (latest state / snapshot / bootstrap) is re-appended to the
   active file before an old file is deleted, replacing tan's
   versionSet/manifest machinery with a self-describing log;
@@ -42,6 +53,9 @@ _SAVE_US = telemetry.GLOBAL.histogram(
     "logdb.save_us", help="save_raft_state batch latency (append+fsync), us")
 _FSYNC_US = telemetry.GLOBAL.histogram(
     "logdb.fsync_us", help="fsync latency at the durability point, us")
+_SYNC_SHARED = telemetry.GLOBAL.counter(
+    "logdb.sync_shared",
+    help="saves made durable by another writer's fsync (group commit)")
 
 MAGIC = 0x7A4E0002
 _HDR = struct.Struct("<III")          # magic, payload length, crc32
@@ -150,6 +164,12 @@ class _Node:
     removed: bool = False
 
 
+def _frame(rectype: int, shard_id: int, replica_id: int,
+           body: bytes) -> bytes:
+    payload = _KEY.pack(rectype, shard_id, replica_id) + body
+    return _HDR.pack(MAGIC, len(payload), zlib.crc32(payload)) + payload
+
+
 def _enc_update(ud: pb.Update) -> bytes:
     buf = bytearray()
     st = pb.encode_state(ud.state)
@@ -206,6 +226,10 @@ class TanLogDB(ILogDB):
         self.recovery_mode = recovery_mode
         self.quarantined: list[str] = []
         self.fs.makedirs(self.root)
+        # lock order: _sync_mu, then _mu.  _mu guards the index and the
+        # append position; _sync_mu serialises fsyncs and whatever moves
+        # the active file under them (rotation, close)
+        self._sync_mu = threading.RLock()
         self._mu = threading.RLock()
         self._nodes: dict[tuple[int, int], _Node] = {}
         # fileno -> set of node keys whose latest metadata lives there
@@ -215,6 +239,13 @@ class TanLogDB(ILogDB):
         self._readers: dict[int, object] = {}
         self._active_fileno = 0
         self._active = None
+        # the active file's append offset, kept here; None after an
+        # OSError from a write or fsync: the next append asks the file
+        self._off: int | None = 0                         # guarded-by: _mu
+        # append sequence, and the highest one an fsync has covered
+        self._appended = 0                                # guarded-by: _mu
+        # written under _sync_mu; _sync also reads it before queueing there
+        self._synced = 0
         self._closed = False
         self._recover()
         if self._active is None:
@@ -239,6 +270,7 @@ class TanLogDB(ILogDB):
     def _open_active(self, fileno: int) -> None:
         self._active_fileno = fileno
         self._active = self.fs.open(self._path(fileno), "ab")
+        self._off = self.fs.getsize(self._path(fileno))
 
     def _reader(self, fileno: int):
         f = self._readers.get(fileno)
@@ -246,28 +278,79 @@ class TanLogDB(ILogDB):
             f = self._readers[fileno] = self.fs.open(self._path(fileno), "rb")
         return f
 
-    def _append(self, rectype: int, shard_id: int, replica_id: int,
-                body: bytes) -> tuple[int, int]:
-        """Append one framed record; returns (fileno, offset)."""
-        payload = _KEY.pack(rectype, shard_id, replica_id) + body
-        frame = _HDR.pack(MAGIC, len(payload), zlib.crc32(payload)) + payload
-        if self._active.tell() + len(frame) > self.max_file_size \
-                and self._active.tell() > 0:
+    def _offset(self) -> int:
+        if self._off is None:
+            self._off = self._active.tell()
+        return self._off
+
+    def _fits(self, nbytes: int) -> bool:
+        """Room for ``nbytes`` more in the active file?  An empty file
+        takes any batch whole."""
+        off = self._offset()
+        return off == 0 or off + nbytes <= self.max_file_size
+
+    def _write(self, blob: bytes) -> tuple[int, int]:
+        """Hand whole frames to the active file in one write; returns
+        (fileno, offset of the first).  Caller holds ``_mu``, and
+        ``_sync_mu`` too unless it saw ``_fits(len(blob))`` under this
+        hold of ``_mu`` (rotation moves the file an fsync may be on)."""
+        if not self._fits(len(blob)):
             self._rotate()
-        off = self._active.tell()
-        self._active.write(frame)
+        off = self._offset()
+        try:
+            self._active.write(blob)
+            # into the OS before _mu is let go: a reader of this record
+            # opens the file by name and must not find it in our buffer
+            self._active.flush()
+        except OSError:
+            self._off = None
+            raise
+        self._off = off + len(blob)
+        self._appended += 1
         return self._active_fileno, off
 
+    def _append(self, rectype: int, shard_id: int, replica_id: int,
+                body: bytes) -> tuple[int, int]:
+        """Append one framed record; returns (fileno, offset).  Caller
+        holds ``_sync_mu`` and ``_mu``."""
+        return self._write(_frame(rectype, shard_id, replica_id, body))
+
     def _rotate(self) -> None:
-        self.fs.fsync(self._active)
+        self._fsync(self._active)
         self._active.close()
+        self._synced = self._appended
         self._open_active(self._active_fileno + 1)
 
-    def _sync(self) -> None:
-        """THE fsync (engine.go:1343 SaveRaftState durability point)."""
-        t0 = time.perf_counter()
-        self.fs.fsync(self._active)
-        _FSYNC_US.observe((time.perf_counter() - t0) * 1e6)
+    def _fsync(self, f) -> None:
+        try:
+            self.fs.fsync(f)
+        except OSError:
+            # the flush inside may have moved part of the buffer
+            with self._mu:
+                self._off = None
+            raise
+
+    def _sync(self, upto: int | None = None) -> None:
+        """THE fsync (engine.go:1343 SaveRaftState durability point),
+        shared: returns once an fsync has covered append ``upto``
+        (default: everything appended so far).  The caller holds
+        ``_sync_mu`` and ``_mu`` (tan's own records) or neither (a save:
+        other writers append to the file while this one is in fsync)."""
+        # _synced only grows: a covered writer need not queue for the lock
+        if upto is not None and self._synced >= upto:
+            _SYNC_SHARED.inc()
+            return
+        with self._sync_mu:
+            if upto is not None and self._synced >= upto:
+                _SYNC_SHARED.inc()
+                return
+            with self._mu:
+                covered = self._appended
+                f = self._active
+            t0 = time.perf_counter()
+            self._fsync(f)
+            _FSYNC_US.observe((time.perf_counter() - t0) * 1e6)
+            self._synced = covered
 
     # -- recovery --------------------------------------------------------
 
@@ -378,7 +461,7 @@ class TanLogDB(ILogDB):
         return "tan"
 
     def close(self) -> None:
-        with self._mu:
+        with self._sync_mu, self._mu:
             if self._closed:
                 return
             self._closed = True
@@ -397,7 +480,7 @@ class TanLogDB(ILogDB):
                     if not n.removed]
 
     def save_bootstrap_info(self, shard_id, replica_id, bootstrap) -> None:
-        with self._mu:
+        with self._sync_mu, self._mu:
             fileno, _ = self._append(R_BOOTSTRAP, shard_id, replica_id,
                                      pb.encode_bootstrap(bootstrap))
             self._sync()
@@ -412,22 +495,35 @@ class TanLogDB(ILogDB):
 
     def save_raft_state(self, updates: Sequence[pb.Update],
                         worker_id: int) -> None:
-        """Batch append + ONE fsync (raftio/logdb.go:78-83)."""
+        """Batch append in ONE write + ONE fsync, shared with whoever
+        else is at the log (raftio/logdb.go:78-83)."""
         t0 = time.perf_counter()
+        kept = [ud for ud in updates
+                if not (ud.state.is_empty() and not ud.entries_to_save
+                        and ud.snapshot.is_empty())]
+        if not kept:
+            return
+        frames = [_frame(R_UPDATE, ud.shard_id, ud.replica_id,
+                         _enc_update(ud)) for ud in kept]
+        blob = b"".join(frames)
         with self._mu:
-            wrote = False
-            for ud in updates:
-                if ud.state.is_empty() and not ud.entries_to_save \
-                        and ud.snapshot.is_empty():
-                    continue
-                fileno, off = self._append(
-                    R_UPDATE, ud.shard_id, ud.replica_id, _enc_update(ud))
-                self._apply_record_index(fileno, off, ud)
-                wrote = True
-            if wrote:
-                self._sync()
-        if wrote:
-            _SAVE_US.observe((time.perf_counter() - t0) * 1e6)
+            seq = (self._write_indexed(blob, frames, kept)
+                   if self._fits(len(blob)) else None)
+        if seq is None:             # rotation: it takes the sync lock first
+            with self._sync_mu, self._mu:
+                seq = self._write_indexed(blob, frames, kept)
+        self._sync(seq)
+        _SAVE_US.observe((time.perf_counter() - t0) * 1e6)
+
+    def _write_indexed(self, blob: bytes, frames: list[bytes],
+                       kept: list[pb.Update]) -> int:
+        """Write a batch's frames and index each record at its offset in
+        the batch; returns the append sequence an fsync must cover."""
+        fileno, off = self._write(blob)
+        for fr, ud in zip(frames, kept):
+            self._apply_record_index(fileno, off, ud)
+            off += len(fr)
+        return self._appended
 
     def _apply_record_index(self, fileno: int, off: int,
                             ud: pb.Update) -> None:
@@ -482,7 +578,7 @@ class TanLogDB(ILogDB):
                              entry_count=count)
 
     def remove_entries_to(self, shard_id, replica_id, index):
-        with self._mu:
+        with self._sync_mu, self._mu:
             key = (shard_id, replica_id)
             n = self._nodes.get(key)
             if n is None:
@@ -533,7 +629,7 @@ class TanLogDB(ILogDB):
             self._file_entries.pop(fileno, None)
 
     def save_snapshots(self, updates):
-        with self._mu:
+        with self._sync_mu, self._mu:
             wrote = False
             for ud in updates:
                 if ud.snapshot.is_empty():
@@ -559,7 +655,7 @@ class TanLogDB(ILogDB):
             return n.snapshot
 
     def remove_node_data(self, shard_id, replica_id):
-        with self._mu:
+        with self._sync_mu, self._mu:
             self._append(R_REMOVE, shard_id, replica_id, b"")
             self._sync()
             self._nodes[(shard_id, replica_id)] = _Node(removed=True)
@@ -567,7 +663,7 @@ class TanLogDB(ILogDB):
 
     def import_snapshot(self, snapshot: pb.Snapshot, replica_id: int) -> None:
         """Rebuild a node from an exported snapshot (tools/import.go:134)."""
-        with self._mu:
+        with self._sync_mu, self._mu:
             key = (snapshot.shard_id, replica_id)
             self._append(R_REMOVE, snapshot.shard_id, replica_id, b"")
             n = _Node()

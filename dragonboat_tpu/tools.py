@@ -136,16 +136,15 @@ def import_snapshot(nhconfig: NodeHostConfig, src_path: str,
         # drop old state, stamp the snapshot + bootstrap (import.go main
         # flow: ssEnv.FinalizeSnapshot + logdb writes)
         # open the dir's own engine: the geometry the owning NodeHost
-        # pinned (TANSHARDS marker), or the default sharded layout for a
-        # fresh/legacy dir — a flat TanLogDB here would strand the
-        # R_REMOVE + import records outside the partitions
+        # pinned (TANSHARDS marker; num_shards 0 reads it), or the
+        # configured layout for a fresh/legacy dir — a flat TanLogDB here
+        # would strand the R_REMOVE + import records outside the partitions
         from dragonboat_tpu.logdb.sharded import ShardedLogDB
 
-        stored = ShardedLogDB.stored_shard_count(env.logdb_dir, fs)
         db = ShardedLogDB(
             env.logdb_dir,
-            num_shards=(stored if stored is not None
-                        else nhconfig.expert.logdb.shards),
+            num_shards=(0 if ShardedLogDB.stored_shard_count(
+                env.logdb_dir, fs) else nhconfig.expert.logdb.shards),
             fs=fs)
         try:
             db.import_snapshot(ss, replica_id)

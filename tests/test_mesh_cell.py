@@ -170,11 +170,27 @@ def test_mesh_cell_reads_back_after_leader_placement(cell):
     traffic = deployment.load_json("traffic", "write16")
     gen.validate(traffic)
     hub0 = hub_msgs()
+
+    def durability():
+        snap = telemetry.GLOBAL.snapshot()
+        return [snap.get(k, 0) for k in (
+            "engine_round_us.count{phase=total}", "logdb.fsync_us.count",
+            "logdb.save_parts.count", "logdb.save_parts.sum",
+            "logdb.sync_shared")]
+
+    assert {h.logdb.name() for h in dep.hosts.values()} == {"sharded-tan-1"}
+    d0 = durability()
     load = gen.Load(dep, traffic, gen.client_streams(
         traffic, 2**31 + 27, dep.shards, 0))
     load.start()
     time.sleep(WRITE_S)
     records = load.join(30.0)
+    # the one engine saves its three hosts' logs in turn: a round is at
+    # most one fsync a LogDB, each save one partition, none shared
+    rounds, fsyncs, saves, parts, shared = (
+        b - a for a, b in zip(d0, durability()))
+    assert 0 < fsyncs <= 3 * rounds, (fsyncs, rounds)
+    assert saves == parts == fsyncs and shared == 0
     assert records and all(r.status == gen.OK for r in records)
     acked = {(r.shard, r.key): r.value for r in records}
     assert len(acked) == len(records)           # every write a new key

@@ -40,11 +40,13 @@ def _wait(cond, timeout):
     return cond()
 
 
-def _host(prefix, shards, depth=0, device=True):
+def _host(prefix, shards, depth=0, device=True, root=None):
     """One NodeHost, ``shards`` single-replica shards: a proposal is
-    appended, committed, saved and applied in the round that stages it."""
+    appended, committed, saved and applied in the round that stages it.
+    With ``root`` the LogDB is the on-disk default under it."""
     nh = NodeHost(NodeHostConfig(
         raft_address=f"{prefix}-1", rtt_millisecond=5,
+        node_host_dir=root or "",
         expert=ExpertConfig(kernel_log_cap=64, kernel_capacity=64,
                             fleet_stats_every=EVERY,
                             kernel_pipeline_depth=depth)))
@@ -113,6 +115,54 @@ def test_round_crosses_the_boundary_once_each_way(depth):
             _settle(eng)                    # depth 1: retire the last step
         for rs in states:
             assert rs.get(30) is not None
+    finally:
+        nh.close()
+
+
+@pytest.mark.parametrize("depth", [0, 1])
+def test_steady_round_is_one_write_and_one_fsync_of_one_log(tmp_path, depth):
+    """On a new default directory a round that saves all 48 lanes' entries
+    hands its LogDB ONE save that touches one partition and costs one
+    fsync on the engine thread: no flush pool, no shared fsync to wait
+    for (16 partition flushes through a pool before PR 30)."""
+    nh = _host(f"rb-fsync{depth}", SHARDS, depth=depth,
+               root=str(tmp_path / "nh"))
+    try:
+        assert nh.logdb.name() == "sharded-tan-1"
+        eng = nh.kernel_engine
+        sessions = {sid: nh.get_noop_session(sid)
+                    for sid in range(1, SHARDS + 1)}
+
+        def durability():
+            snap = telemetry.GLOBAL.snapshot()
+            return {k: snap.get(k, 0) for k in (
+                "logdb.fsync_us.count", "logdb.save_parts.count",
+                "logdb.save_parts.sum", "logdb.sync_shared")}
+
+        with eng.mu:
+            _settle(eng)
+            states, saves = [], 0
+            for i in range(12):
+                for sid, s in sessions.items():
+                    states.append(nh.propose(s, f"k{i}={sid}".encode(), 30))
+                d0 = durability()
+                assert eng.step_all(), f"round {i} found nothing to do"
+                d1 = durability()
+                grew = {k: d1[k] - d0[k] for k in d0}
+                assert grew["logdb.fsync_us.count"] <= 1, (i, grew)
+                assert grew["logdb.save_parts.count"] == \
+                    grew["logdb.save_parts.sum"] == \
+                    grew["logdb.fsync_us.count"], (i, grew)
+                assert grew["logdb.sync_shared"] == 0, (i, grew)
+                saves += grew["logdb.fsync_us.count"]
+            _settle(eng)
+            assert saves >= 11          # depth 1 saves a round late
+        for rs in states:
+            assert rs.get(30) is not None
+        assert not any(t.name.startswith("tanshard-flush")
+                       for t in threading.enumerate())
+        assert [c for _i, _t, c in _persisted(nh, SHARDS) if c] == \
+            [f"k{i}={SHARDS}".encode() for i in range(12)]
     finally:
         nh.close()
 
