@@ -69,6 +69,7 @@ STEP_LOOP_OWNER = "KernelEngine"
 #: is a second step-loop implementation (EU001)
 STEP_LOOP_METHODS = (
     "step_all",
+    "_take_admissions",
     "_flush_injections",
     "_stage_lane",
     "_stage_props",
@@ -90,11 +91,13 @@ DISPATCH_SEAMS = (
     "_make_dispatch",
     "_emit_messages",
     "_send",
+    "_send_all",
     "_prop_target",
     "_mirror_floor",
     "_is_registered",
     "_evict",
     "add_shard",
+    "_register",
     "remove_shard",
     "update_lane_membership",
 )

@@ -149,18 +149,42 @@ _READ_STAGE_WAIT_US = telemetry.GLOBAL.histogram(
          "the staging, from the enqueue of its batch's first read (a "
          "forwarded one: from its arrival at this host)")
 # lane admission, beside nodehost_start_replica_us: the caller's wait for
-# the engine lock in add_shard (a round holds it, flushes of earlier
-# lanes included; part of start_replica's ``stage``), and one
-# _flush_injections batch on the engine thread (inside a round's ``stage``)
+# the admission lock in add_shard (no round holds it; part of
+# start_replica's ``stage``), one _flush_injections batch on the engine
+# thread (inside a round's ``stage``), the replicas it wrote, and an
+# admission's time from add_shard's call to the end of that flush
 ADD_SHARD_LOCK_US = telemetry.GLOBAL.histogram(
     "engine_add_shard_lock_us",
-    help="add_shard's wait for the engine lock, per call")
+    help="add_shard's wait for the admission lock, per call")
 _INJECT_BATCH = 8       # least rows of one flush's program
 _CELL_BATCH = 16        # least cells of one _write_cells program
 _INJECT_FLUSH_US = telemetry.GLOBAL.histogram(
     "engine_inject_flush_us",
     help="one batch of queued lane injections written into the device "
          "state, on the engine thread")
+_INJECT_ROWS = telemetry.GLOBAL.counter(
+    "engine_inject_rows",
+    help="replicas written into the device state by _flush_injections "
+         "(engine_inject_flush_us counts the batches)")
+_ADMIT_WAIT_US = telemetry.GLOBAL.histogram(
+    "engine_admit_wait_us",
+    help="one admission, from add_shard's call to the end of the flush "
+         "that injected its lane")
+# the width of a round, beside the round timer's histograms: per
+# committed round the lanes step_all staged and the lanes
+# _process_outputs took into its two per-lane loops; and the lanes that
+# hold a replica, per engine
+_ROUND_LANES = telemetry.GLOBAL.counter(
+    "engine_round_lanes",
+    help="lanes of committed rounds: staged by step_all, processed by "
+         "the output pass's per-lane loops",
+    labelnames=("what",))
+_LANES_STAGED = _ROUND_LANES.labels("staged")
+_LANES_PROCESSED = _ROUND_LANES.labels("processed")
+_LANES_LIVE = telemetry.GLOBAL.gauge(
+    "engine_lanes_live",
+    help="lanes holding a replica a round can see, per engine",
+    labelnames=("engine",))
 
 
 class _RoundDown:
@@ -210,6 +234,8 @@ class KernelNode(Node):
     """A device-resident shard: client surface + books + RSM live on the
     host exactly like ``Node``; the raft state machine lives in a kernel
     lane and is advanced by the owning ``KernelEngine``."""
+
+    engine_driven = True
 
     def __init__(self, *args, **kw) -> None:
         super().__init__(*args, **kw)
@@ -434,8 +460,17 @@ class KernelEngine:
         # pay a device->host transfer for them every step
         self._kind_np = np.zeros((capacity, kp.num_peers), np.int32)
         self._pid_np = np.zeros((capacity, kp.num_peers), np.int32)
-        # admissions queued for the next step's batched injection
-        # (lane -> (node, init, pids, kinds)); see _flush_injections
+        # admission, in two steps.  ``add_shard`` reserves a lane and
+        # queues the replica under ``_admit_mu``, a lock NO round holds
+        # (lane -> (node, init, the call's start)); it guards the free
+        # list, ``by_shard`` and this queue, and is taken after ``mu``
+        # where both are held.  ``step_all`` takes the queue as it begins:
+        # only then does a round see the node (``self.nodes``), and that
+        # same round injects it
+        self._admit_mu = threading.Lock()
+        self._admitting: dict[int, tuple] = {}
+        # taken admissions awaiting this step's batched injection
+        # (lane -> (node, init, pids, kinds, start)); see _flush_injections
         self._pending_inject: dict[int, tuple] = {}
         self._inject_fn = None      # inject_rows jitted for this state
         # whole-engine tick rounds queued by the host ticker; each step
@@ -484,10 +519,12 @@ class KernelEngine:
         # opt-in jax.profiler capture
         self._round = RoundTimer(self.events.metrics, "engine.kernel_step",
                                  engine=label or f"engine-{id(self):x}")
-        # staging counts of the round being staged (the round's record)
+        # staging counts of the round being staged (the round's record),
+        # and the lanes its output pass took into the per-lane loops
         self._props_staged = 0
         self._props_deferred = 0
         self._reads_staged = 0
+        self._lanes_processed = 0
         maybe_start_from_env()
         self.events.metrics.set("engine.pipeline.depth", self.pipeline_depth)
         # decimated device-side fleet telemetry (core/fleet.py): every N
@@ -574,30 +611,61 @@ class KernelEngine:
     # -- lane lifecycle ---------------------------------------------------
 
     def add_shard(self, node: KernelNode, init: _LaneInit) -> None:
-        """Inject a bootstrapped shard into a free lane.  The lane write
-        happens under the engine lock: a concurrent step must never run
-        between registration and injection (it would write back a stepped
-        pre-injection state, clobbering the lane)."""
+        """Admit a bootstrapped shard: reserve a free lane and queue the
+        replica for the next round, without waiting for the round that
+        may be running (a round holds ``mu`` from end to end; 768
+        admissions each waited one out).  A concurrent step must never
+        run between a node becoming visible to a round and its injection
+        (it would write back a stepped pre-injection state, clobbering
+        the lane): ``step_all`` makes the node visible itself
+        (``_take_admissions``) and injects it before it stages anything.
+        What arrives for the node meanwhile waits in its own queues; the
+        take dirties the lane, so the injecting round stages it."""
         t0 = monotonic_us()
-        with self.mu:
+        with self._admit_mu:
             ADD_SHARD_LOCK_US.observe(monotonic_us() - t0)
             if not self._free:
                 raise RuntimeError("kernel engine is at capacity")
             lane = self._free.pop()
             node.lane = lane
             node.engine = self
-            self.nodes[lane] = node
             self.by_shard[node.shard_id] = node
-            self._inject(lane, node, init)
+            self._admitting[lane] = (node, init, t0)
+
+    def _take_admissions(self) -> None:
+        """On the engine thread, holding ``mu``, as a round begins: every
+        admission queued since the last one becomes visible to this round
+        and is queued for its one ``inject_rows`` program."""
+        if not self._admitting:
+            return
+        with self._admit_mu:
+            taken, self._admitting = self._admitting, {}
+        for lane, (node, init, t0) in taken.items():
+            self._register(lane, node)
+            self._inject(lane, node, init, t0)
+        self._note_lanes_live()
+
+    def _note_lanes_live(self) -> None:
+        _LANES_LIVE.labels(self._round.engine).set(len(self.nodes))
+
+    def _register(self, lane: int, node: KernelNode) -> None:
+        """Make an admitted node visible to rounds (a seam: the mesh
+        engine also joins it to its group and heals its row)."""
+        self.nodes[lane] = node
 
     def remove_shard(self, shard_id: int) -> KernelNode | None:
         with self.mu:
-            node = self.by_shard.pop(shard_id, None)
-            if node is None:
-                return None
-            self.nodes.pop(node.lane, None)
-            self._free.append(node.lane)
-            self._clear_lane(node.lane)
+            with self._admit_mu:
+                node = self.by_shard.pop(shard_id, None)
+                if node is None:
+                    return None
+                queued = self._admitting.pop(node.lane, None) is not None
+                self._free.append(node.lane)
+            if not queued:
+                # (an admission no round took was never written anywhere)
+                self.nodes.pop(node.lane, None)
+                self._clear_lane(node.lane)
+                self._note_lanes_live()
             self._removed_nodes.append(node)
         return node
 
@@ -650,8 +718,9 @@ class KernelEngine:
         self._resident = res._replace(cols=write_cells_program(
             self._dispatch.placement())(res.cols, up))
 
-    def _inject(self, lane: int, node: KernelNode, init: _LaneInit) -> None:
-        """Queue one lane injection; the next ``step_all`` flushes every
+    def _inject(self, lane: int, node: KernelNode, init: _LaneInit,
+                t0: int) -> None:
+        """Queue one lane injection; this ``step_all`` flushes every
         queued lane in ONE vectorized state update.  The eager form was
         ~30 full-[capacity] array copies PER admission — O(n·capacity)
         total, the first structure to fall over at 100k groups.  Host
@@ -671,7 +740,7 @@ class KernelEngine:
         self._lead_term_np[lane] = 0
         self._occ_np[lane] = True
         self._applied_sent_np[lane] = init.applied
-        self._pending_inject[lane] = (node, init, pids, kinds)
+        self._pending_inject[lane] = (node, init, pids, kinds, t0)
         self._inv_dirty.add(lane)
         self.mark_dirty(lane)
 
@@ -698,7 +767,7 @@ class KernelEngine:
         rows["kind"] = np.zeros((n, kp.num_peers), np.int32)
         rows["lt"] = np.zeros((n, kp.log_cap), np.int32)
         rows["lcc"] = np.zeros((n, kp.log_cap), bool)
-        for j, (lane, (node, init, pids, kinds)) in enumerate(items):
+        for j, (lane, (node, init, pids, kinds, _t0)) in enumerate(items):
             rows["pid"][j], rows["kind"][j] = pids, kinds
             for e in init.entries:
                 rows["lt"][j, e.index & (kp.log_cap - 1)] = e.term
@@ -760,7 +829,11 @@ class KernelEngine:
             self._resident = self._inject_fn(
                 self._resident, jnp.asarray(lanes_np),
                 {k: jnp.asarray(v) for k, v in rows.items()})
-        _INJECT_FLUSH_US.observe(monotonic_us() - t0)
+        now = monotonic_us()
+        _INJECT_FLUSH_US.observe(now - t0)
+        _INJECT_ROWS.inc(n)
+        for _lane, item in items:
+            _ADMIT_WAIT_US.observe(now - item[4])
 
     def _clear_lane(self, lane: int) -> None:
         self._inv_dirty.add(lane)
@@ -864,7 +937,8 @@ class KernelEngine:
         with self.mu, self._round as rt:
             self._round_t0_us = monotonic_us()
             self._props_staged = self._props_deferred = 0
-            self._reads_staged = 0
+            self._reads_staged = self._lanes_processed = 0
+            self._take_admissions()
             nodes = dict(self.nodes)
             if not nodes:
                 if self._pending_ctx is not None:
@@ -1048,11 +1122,14 @@ class KernelEngine:
             _MAX_TICK_FLOOR_US,
             max(min(monotonic_us() - self._round_t0_us, 2 * floor + 5_000),
                 floor * 15 // 16))
+        _LANES_STAGED.inc(lanes_staged)
+        _LANES_PROCESSED.inc(self._lanes_processed)
         self._round.commit(
             props_staged=self._props_staged,
             props_deferred=self._props_deferred,
             reads_staged=self._reads_staged,
-            lanes_staged=lanes_staged, keys=list(keys))
+            lanes_staged=lanes_staged,
+            lanes_processed=self._lanes_processed, keys=list(keys))
 
     def _is_registered(self, n: KernelNode) -> bool:
         # identity, not membership: with a deferred (pipelined) output
@@ -1585,6 +1662,7 @@ class KernelEngine:
         # by re-examination, exactly as the full scan did
         for g, _n in cand:
             self._dirty.add(g)
+        self._lanes_processed += len(cand)
 
         rt.enter("resolve")
         for g, n in cand:
@@ -1620,8 +1698,7 @@ class KernelEngine:
                 updates.append((n, ud))
 
         # replicate-before-fsync (engine.go:1332-1343)
-        for sender, m in replicates:
-            self._send(sender, m)
+        self._send_all(replicates)
         if updates:
             rt.enter("save")
             # one batched fsync per LogDB (nodes of a shared mesh engine
@@ -1637,8 +1714,7 @@ class KernelEngine:
             for db, uds in by_db.values():
                 db.save_raft_state(uds, worker_id=0)
             rt.enter("resolve")
-        for sender, m in others:
-            self._send(sender, m)
+        self._send_all(others)
 
         rt.enter("finish")
         for g, n in cand:
@@ -1967,6 +2043,21 @@ class KernelEngine:
         # sending node's NodeHost dispatch (same path as remote; on a
         # shared mesh engine each node routes via its own host)
         n.send_message(m)
+
+    def _send_all(self, pairs: list) -> None:
+        """What a round sends ([(node, message)]), as one call a sending
+        host: its transport makes ONE batch a target of them.  Sent one
+        by one, 256 lanes' ~430 messages a round took the process-wide
+        locks of the send path (the fabric meter's, the receiving
+        registry's and engine's) ~10 times each, and three engines whose
+        rounds resolved at the same time queued on them behind the
+        interpreter's switch interval: rounds of 150-250 ms became
+        600-900 ms for seconds on end (PERF.md, PR 31)."""
+        by_host: dict = {}
+        for n, m in pairs:
+            by_host.setdefault(n.send_messages, []).append(m)
+        for send, msgs in by_host.items():
+            send(msgs)
 
 
 # ---------------------------------------------------------------------------

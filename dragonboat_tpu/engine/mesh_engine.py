@@ -174,56 +174,70 @@ class MeshEngine(KernelEngine):
             raise ValueError(
                 f"mesh-resident shard {node.shard_id}: witness members "
                 f"are host-engine only")
+        # as the base engine's: reserve the row and queue the replica
+        # under the admission lock, which no round holds.  A group lane is
+        # held while any replica of the shard is in ``by_shard``; the
+        # group's books (``_members``, ``_mirrors``) and the row's place in
+        # the mesh are the engine thread's, written when a round takes the
+        # admission (``_register``): the row stays cut until the round
+        # that injects it
+        key = (node.shard_id, node.replica_id)
         t0 = monotonic_us()
-        with self.mu:
+        with self._admit_mu:
             ADD_SHARD_LOCK_US.observe(monotonic_us() - t0)
+            if key in self.by_shard:
+                raise RuntimeError(
+                    f"replica {node.replica_id} of shard {node.shard_id} "
+                    f"already mesh-resident")
             lane = self._lane_of.get(node.shard_id)
             if lane is None:
                 if not self._free_lanes:
                     raise RuntimeError("mesh engine is at capacity")
                 lane = self._free_lanes.pop()
                 self._lane_of[node.shard_id] = lane
-                self._members[node.shard_id] = {}
-                self._mirrors[node.shard_id] = {}
-            members = self._members[node.shard_id]
-            if node.replica_id in members:
-                raise RuntimeError(
-                    f"replica {node.replica_id} of shard {node.shard_id} "
-                    f"already mesh-resident")
             row = self._row(lane, node.replica_id)
             node.lane = row
             node.engine = self
-            node.mirror = self._mirrors[node.shard_id]   # shared payloads
-            members[node.replica_id] = node
-            self.nodes[row] = node
-            self.by_shard[(node.shard_id, node.replica_id)] = node
-            self._inject(row, node, init)
-            self._dispatch.set_cut(row, False)
-            self._note_link_classes(node)
+            self.by_shard[key] = node
+            self._admitting[row] = (node, init, t0)
+
+    def _register(self, row: int, node: KernelNode) -> None:
+        sid = node.shard_id
+        node.mirror = self._mirrors.setdefault(sid, {})   # shared payloads
+        self._members.setdefault(sid, {})[node.replica_id] = node
+        self.nodes[row] = node
+        self._dispatch.set_cut(row, False)
+        self._note_link_classes(node)
 
     def remove_replica(self, node: KernelNode) -> KernelNode | None:
         """Detach one replica (stop_replica / NodeHost.close); the group
-        lane lives on for the remaining members."""
+        lane lives on for the remaining members, queued ones included."""
+        sid = node.shard_id
         with self.mu:
-            if self.by_shard.pop((node.shard_id, node.replica_id),
-                                 None) is None:
-                return None
-            addr = self._link_class_book(node).get(node.replica_id)
-            if addr:
-                _fabric.METER.drop_link_classes(addr)
-            members = self._members.get(node.shard_id, {})
-            members.pop(node.replica_id, None)
-            self.nodes.pop(node.lane, None)
+            with self._admit_mu:
+                if self.by_shard.pop((sid, node.replica_id), None) is None:
+                    return None
+                queued = self._admitting.pop(node.lane, None) is not None
+                last = not any((sid, r) in self.by_shard
+                               for r in range(1, self.spec.replicas + 1))
+                if last:
+                    lane = self._lane_of.pop(sid, None)
+                    if lane is not None:
+                        self._free_lanes.append(lane)
             self._removed_nodes.append(node)
-            self._clear_lane(node.lane)
-            self._dispatch.set_cut(node.lane, True)     # empty rows are cut
-            if not members:
-                lane = self._lane_of.pop(node.shard_id, None)
-                self._members.pop(node.shard_id, None)
-                self._mirrors.pop(node.shard_id, None)
-                self._books_ccid.pop(node.shard_id, None)
-                if lane is not None:
-                    self._free_lanes.append(lane)
+            if not queued:
+                addr = self._link_class_book(node).get(node.replica_id)
+                if addr:
+                    _fabric.METER.drop_link_classes(addr)
+                self._members.get(sid, {}).pop(node.replica_id, None)
+                self.nodes.pop(node.lane, None)
+                self._clear_lane(node.lane)
+                self._dispatch.set_cut(node.lane, True)  # empty rows are cut
+                self._note_lanes_live()
+            if last:
+                self._members.pop(sid, None)
+                self._mirrors.pop(sid, None)
+                self._books_ccid.pop(sid, None)
         return node
 
     def remove_shard(self, shard_id: int) -> KernelNode | None:
@@ -375,6 +389,10 @@ class MeshEngine(KernelEngine):
         (_HUB_READ_FORWARD if m.type in _READ_FORWARDS else _HUB_SENT).inc()
         super()._send(n, m)
 
+    def _send_all(self, pairs: list) -> None:
+        for n, m in pairs:      # (cut links only: few, and counted above)
+            self._send(n, m)
+
     def _prop_target(self, n: KernelNode):
         """Forward proposals to the group's leader row (any NodeHost is a
         valid entry point, like the reference's MsgProp forwarding). Falls
@@ -454,6 +472,9 @@ class MeshEngine(KernelEngine):
         rebuilt host-side by ITS OWN NodeHost; the group continues over
         the regular transport (all state is already durable)."""
         members = list(self._members.get(n.shard_id, {}).values())
+        with self._admit_mu:    # and those no round has taken yet
+            members += [q[0] for q in self._admitting.values()
+                        if q[0].shard_id == n.shard_id]
         if not members:
             return
         _LOG.info("shard %d: leaving the mesh (%s)", n.shard_id, reason)

@@ -53,6 +53,10 @@ class _SnapshotRequest:
 
 
 class Node:
+    #: a NodeHost step worker calls ``step`` on this node (an
+    #: engine-driven subclass is advanced by its engine's rounds instead)
+    engine_driven = False
+
     def __init__(
         self,
         cfg: Config,
@@ -575,6 +579,13 @@ class Node:
         if (self.apply_pool is None and self.cfg.snapshot_entries > 0
                 and self.applied_since_snapshot >= self.cfg.snapshot_entries):
             self._take_snapshot(_SnapshotRequest())
+
+    def send_messages(self, msgs) -> None:
+        """What an engine's round sends for this node's host, in one call
+        (a NodeHost puts its own in its place: one transport batch a
+        target)."""
+        for m in msgs:
+            self.send_message(m)
 
     def _send(self, m: pb.Message) -> None:
         if m.to == self.replica_id:

@@ -289,6 +289,19 @@ class NodeHost:
         self._worker_events = [threading.Event()
                                for _ in range(self._num_workers)]
         self._workers: list[threading.Thread] = []
+        # the host's nodes as its threads walk them (``_node_views``): a
+        # step worker's share of the HOST-resident nodes (an engine-driven
+        # node's ``step`` is a no-op; the engines ride worker 0), and the
+        # engine-driven ones for the ticker's sweep.  ``_nodes_version``
+        # counts the changes of ``self.nodes`` (bumped under ``self.mu``)
+        # and the views are rebuilt only when it moved.  Every worker
+        # listing and calling every node on every wake-up, and every
+        # wake-up waking every worker, was work in the square of a host's
+        # replicas: 256 engine-driven shards a host stretched its engine's
+        # rounds to 1.5 s (PERF.md, PR 31)
+        self._nodes_version = 0
+        self._views: tuple[int, list[list], list] = (
+            0, [[] for _ in range(self._num_workers)], [])
         # dedicated RSM-apply workers (engine.go:1153 applyWorkerMain): a
         # slow user SM occupies one of these, never a step worker
         from dragonboat_tpu.engine.apply_pool import ApplyPool
@@ -297,7 +310,7 @@ class NodeHost:
         # user SMs (the reference runs a fixed 16 regardless of cores)
         self._apply_pool = ApplyPool(
             num_workers=max(1, min(nhconfig.expert.engine.apply_shards, 16)),
-            on_work_done=self._work.set, name=f"apply-{self.id[:8]}")
+            on_work_done=self._kick, name=f"apply-{self.id[:8]}")
         # proposal-lifecycle tracing (lifecycle.py): re-point the
         # process-global tracer at this host's expert knobs — the tracer
         # is process-wide (like flight.RECORDER) so spans stay whole
@@ -483,6 +496,7 @@ class NodeHost:
             self._stopped = True
             nodes = list(self.nodes.values())
             self.nodes.clear()
+            self._nodes_version += 1
         if self.mesh_engine is not None:
             from dragonboat_tpu.engine.mesh_engine import detach_mesh_engine
 
@@ -491,7 +505,7 @@ class NodeHost:
                     self.mesh_engine.remove_replica(n)
             detach_mesh_engine(self.mesh_engine)
             self.mesh_engine = None
-        self._work.set()
+        self._kick()
         for ev in self._worker_events:
             ev.set()
         if self._engine_thread is not None:
@@ -554,6 +568,7 @@ class NodeHost:
                     "cannot restart: no durable data dir to recover from")
             nodes = list(self.nodes.values())
             self.nodes.clear()
+            self._nodes_version += 1
             specs = sorted(self._replica_specs.items())
             self._replica_specs.clear()
         if self.mesh_engine is not None:
@@ -565,7 +580,7 @@ class NodeHost:
             detach_mesh_engine(self.mesh_engine)
             self.mesh_engine = None
         self.kernel_engine = None
-        self._work.set()
+        self._kick()
         for ev in self._worker_events:
             ev.set()
         if self._engine_thread is not None:
@@ -615,6 +630,7 @@ class NodeHost:
                 self.fatal_error = RequestError("simulated process kill")
             nodes = list(self.nodes.values())
             self.nodes.clear()
+            self._nodes_version += 1
             self._replica_specs.clear()
         if self.mesh_engine is not None:
             from dragonboat_tpu.engine.mesh_engine import detach_mesh_engine
@@ -624,7 +640,7 @@ class NodeHost:
                     self.mesh_engine.remove_replica(n)
             detach_mesh_engine(self.mesh_engine)
             self.mesh_engine = None
-        self._work.set()
+        self._kick()
         for ev in self._worker_events:
             ev.set()
         if self._engine_thread is not None:
@@ -698,6 +714,7 @@ class NodeHost:
                 lambda cc, sid=cfg.shard_id: self._on_membership_change(sid, cc)
             )
             node.stream_snapshot_cb = self._stream_snapshot
+            node.send_messages = self._send_messages
             node.notify_commit = self.config.notify_commit
             node.apply_pool = self._apply_pool
             members = initial_members if not join else {}
@@ -709,6 +726,7 @@ class NodeHost:
             for rid, addr in {**m.addresses, **m.non_votings, **m.witnesses}.items():
                 self.registry.add(cfg.shard_id, rid, addr)
             self.nodes[cfg.shard_id] = node
+            self._nodes_version += 1
             self._replica_specs[cfg.shard_id] = (
                 dict(initial_members), join, create_sm, cfg)
         t_build = monotonic_us()
@@ -719,7 +737,7 @@ class NodeHost:
             # on the eviction path, so injection must not hold host.mu
             self._inject_kernel_shard(node, members)
         self.events.node_ready(NodeInfo(cfg.shard_id, cfg.replica_id))
-        self._work.set()
+        self._kick()
         t_end = monotonic_us()
         for phase, us in (("open", t_open - t0), ("build", t_build - t_open),
                           ("stage", t_end - t_build), ("total", t_end - t0)):
@@ -728,6 +746,7 @@ class NodeHost:
     def stop_replica(self, shard_id: int) -> None:
         with self.mu:
             node = self.nodes.pop(shard_id, None)
+            self._nodes_version += 1
             self._replica_specs.pop(shard_id, None)
         if node is None:
             raise ShardNotFoundError(f"shard {shard_id} not found")
@@ -987,8 +1006,9 @@ class NodeHost:
         with self.mu:
             if self.nodes.get(cfg.shard_id) is knode:
                 self.nodes[cfg.shard_id] = node
+                self._nodes_version += 1
             # else: stop_replica raced us and already destroyed the books
-        self._work.set()
+        self._kick()
 
     stop_shard = stop_replica
 
@@ -1006,23 +1026,54 @@ class NodeHost:
                 last_tick = now
                 self._do_tick_round()
                 self.chunk_sink.tick()
-            for ev in self._worker_events:
-                ev.set()
+            # worker 0 drives the engines; another worker has something to
+            # do only while it holds host-resident nodes
+            shares, _ = self._node_views()
+            for w, ev in enumerate(self._worker_events):
+                if w == 0 or shares[w]:
+                    ev.set()
+
+    def _kick(self) -> None:
+        """Wake the ticker, which wakes the workers.  Called once a
+        message, a proposal and an apply batch, from every thread of the
+        host: ``Event.set`` takes the event's lock whether or not the
+        event is set, and callers queued on it one behind the other (the
+        engine thread, sending, most of all).  A set event needs no second
+        set: whatever this caller queued before the test is seen by the
+        workers the ticker wakes after its ``clear``."""
+        if not self._work.is_set():
+            self._work.set()
+
+    def _node_views(self) -> tuple[list[list], list]:
+        """-> (per step worker, the host-resident nodes hashed to it by
+        shard_id % workers; the engine-driven nodes), rebuilt when
+        ``self.nodes`` changed.  Two threads may rebuild at once; both
+        build the same from the same version."""
+        version, shares, driven = self._views
+        if version != self._nodes_version:
+            with self.mu:
+                version, nodes = self._nodes_version, list(self.nodes.items())
+            shares, driven = [[] for _ in range(self._num_workers)], []
+            for sid, n in nodes:
+                (driven if n.engine_driven
+                 else shares[sid % self._num_workers]).append(n)
+            self._views = (version, shares, driven)
+        return shares, driven
 
     def _worker_main(self, w: int) -> None:
-        """One step worker: advances the shards hashed to partition w
-        (shard_id % workers), plus the kernel engine on worker 0."""
+        """One step worker: advances the host-resident shards hashed to
+        partition w, plus the device engines on worker 0."""
         ev = self._worker_events[w]
         while not self._stopped:
-            ev.wait(timeout=self._tick_interval / 2)
+            # a worker with nothing of its own sleeps until the ticker
+            # finds it a share
+            idle = w != 0 and not self._node_views()[0][w]
+            ev.wait(timeout=0.05 if idle else self._tick_interval / 2)
             ev.clear()
             progressed = True
             while progressed and not self._stopped:
                 progressed = False
-                with self.mu:
-                    nodes = [n for sid, n in self.nodes.items()
-                             if sid % self._num_workers == w]
-                for n in nodes:
+                for n in self._node_views()[0][w]:
                     try:
                         if n.step():
                             progressed = True
@@ -1087,7 +1138,7 @@ class NodeHost:
                 self.fatal_error = exc
             self._stopped = True
         _LOG.critical("storage failure, halting NodeHost: %s", exc)
-        self._work.set()
+        self._kick()
         for ev in self._worker_events:
             ev.set()
 
@@ -1104,14 +1155,15 @@ class NodeHost:
         self.logical_clock.advance()
         self._tick_round_no += 1
         sweep = (self._tick_round_no % sweep_every) == 0
-        with self.mu:
-            nodes = list(self.nodes.values())
-        for n in nodes:
-            if getattr(n, "engine", None) is not None and n.lane >= 0:
-                if sweep:
-                    n.gc_books()
-                continue
-            n.tick()
+        # (a pass over every node every tick was a tenth of a 256-shard
+        # host's interpreter time)
+        shares, engine_driven = self._node_views()
+        for share in shares:
+            for n in share:
+                n.tick()
+        if sweep:
+            for n in engine_driven:
+                n.gc_books()
         for eng in (self.kernel_engine, self.mesh_engine):
             if eng is not None:
                 eng.tick_round()
@@ -1279,7 +1331,13 @@ class NodeHost:
         if self._partitioned:
             return  # monkey partition: silence sends (nodehost.go:1877)
         self.hub.send(m)
-        self._work.set()
+        self._kick()
+
+    def _send_messages(self, msgs: list) -> None:
+        if self._partitioned:
+            return
+        self.hub.send_all(msgs)
+        self._kick()
 
     def _handle_message_batch(self, batch: pb.MessageBatch) -> None:
         """Inbound dispatch (messageHandler.HandleMessageBatch,
@@ -1322,7 +1380,7 @@ class NodeHost:
                         and not eng.hub_accepts(node, m)):
                     continue
                 node.handle_message(m)
-        self._work.set()
+        self._kick()
 
     def _on_snapshot_reassembled(self, m: pb.Message,
                                  source_address: str) -> None:
@@ -1385,7 +1443,7 @@ class NodeHost:
                 timeout_s: float = DEFAULT_TIMEOUT_S) -> RequestState:
         node = self._node(session.shard_id)
         rs = node.propose(session, cmd, self._ticks(timeout_s))
-        self._work.set()
+        self._kick()
         return rs
 
     def sync_propose(self, session: Session, cmd: bytes,
@@ -1409,7 +1467,7 @@ class NodeHost:
         s.prepare_for_register()
         node = self._node(shard_id)
         rs = node.propose_session_op(s, self._ticks(timeout_s))
-        self._work.set()
+        self._kick()
         rs.get(timeout_s)
         s.prepare_for_propose()
         return s
@@ -1419,7 +1477,7 @@ class NodeHost:
         session.prepare_for_unregister()
         node = self._node(session.shard_id)
         rs = node.propose_session_op(session, self._ticks(timeout_s))
-        self._work.set()
+        self._kick()
         rs.get(timeout_s)
 
     def get_noop_session(self, shard_id: int) -> Session:
@@ -1431,7 +1489,7 @@ class NodeHost:
                    timeout_s: float = DEFAULT_TIMEOUT_S) -> RequestState:
         node = self._node(shard_id)
         rs = node.read(self._ticks(timeout_s))
-        self._work.set()
+        self._kick()
         return rs
 
     def read_local_node(self, shard_id: int, query: object) -> object:
@@ -1544,7 +1602,7 @@ class NodeHost:
             compaction_overhead=compaction_overhead or 0,
         )
         rs = node.request_snapshot(req, self._ticks(timeout_s))
-        self._work.set()
+        self._kick()
         return rs
 
     def request_compaction(self, shard_id: int,
@@ -1552,7 +1610,7 @@ class NodeHost:
                            ) -> RequestState:
         """RequestCompaction (nodehost.go:993) — the async variant."""
         rs = self._node(shard_id).request_compaction(self._ticks(timeout_s))
-        self._work.set()
+        self._kick()
         return rs
 
     def _request_config_change(
@@ -1565,7 +1623,7 @@ class NodeHost:
             type=cc_type, replica_id=replica_id, address=target,
         )
         rs = node.request_config_change(cc, self._ticks(timeout_s))
-        self._work.set()
+        self._kick()
         return rs
 
     def request_add_replica(self, shard_id: int, replica_id: int,
@@ -1608,7 +1666,7 @@ class NodeHost:
         unregister) and return the future."""
         node = self._node(session.shard_id)
         rs = node.propose_session_op(session, self._ticks(timeout_s))
-        self._work.set()
+        self._kick()
         return rs
 
     # -- leadership ------------------------------------------------------
@@ -1616,7 +1674,7 @@ class NodeHost:
     def request_leader_transfer(self, shard_id: int, target: int) -> None:
         node = self._node(shard_id)
         node.request_leader_transfer(target, self._ticks(DEFAULT_TIMEOUT_S))
-        self._work.set()
+        self._kick()
 
     def get_leader_id(self, shard_id: int) -> tuple[int, bool]:
         node = self._node(shard_id)
@@ -1674,7 +1732,7 @@ class NodeHost:
         node = self._node(shard_id)
         rs = node.query_raft_log(first, last, max_size,
                                  self._ticks(timeout_s))
-        self._work.set()
+        self._kick()
         r = rs.wait(timeout_s)
         if r.code == RequestResultCode.COMPLETED:
             return rs.log_query_result
@@ -1862,7 +1920,7 @@ class NodeHost:
         if hasattr(t, "partitioned"):
             t.partitioned = False
         self._set_mesh_partitioned(False)
-        self._work.set()
+        self._kick()
 
     def _set_mesh_partitioned(self, cut: bool) -> None:
         """Mesh traffic never crosses the host transport, so a monkey

@@ -19,6 +19,11 @@ class Registry(INodeRegistry):
         self.stream_connections = stream_connections
 
     def add(self, shard_id: int, replica_id: int, url: str) -> None:
+        # every inbound message re-learns its sender's address, on the
+        # SENDING engine's thread with the loopback transport: one dict
+        # read (atomic) where nothing changes, the lock only to write
+        if self.addr.get((shard_id, replica_id)) == url:
+            return
         with self.mu:
             self.addr[(shard_id, replica_id)] = url
 
@@ -32,8 +37,7 @@ class Registry(INodeRegistry):
                 del self.addr[k]
 
     def resolve(self, shard_id: int, replica_id: int) -> tuple[str, str]:
-        with self.mu:
-            addr = self.addr.get((shard_id, replica_id))
+        addr = self.addr.get((shard_id, replica_id))    # (atomic: no lock)
         if addr is None:
             raise KeyError(f"no address for shard {shard_id} replica {replica_id}")
         # connection key spreads (shard, replica) pairs over StreamConnections

@@ -207,20 +207,39 @@ class TransportHub:
     def send(self, m: pb.Message) -> bool:
         """Enqueue and (synchronously, in the loopback runtime) flush one
         message — Send (transport.go:115-136)."""
+        addr = self._enqueue(m)
+        if addr and self.sync:
+            self.flush(addr)
+        return addr is not None
+
+    def send_all(self, msgs) -> None:
+        """Enqueue every message as ``send`` does, then flush each target
+        once: what a step's round sends to a host leaves as ONE batch
+        (the reference's send queues coalesce the same way,
+        transport.go:425-470)."""
+        addrs = dict.fromkeys(self._enqueue(m) for m in msgs)
+        if self.sync:
+            for addr in addrs:
+                if addr:
+                    self.flush(addr)
+
+    def _enqueue(self, m: pb.Message) -> str | None:
+        """-> the target address whose queue took ``m``; "" for a snapshot
+        handed to its stream job; None where it was dropped."""
         if m.is_local():
             raise AssertionError("local message sent to transport")
         if m.type == pb.MessageType.INSTALL_SNAPSHOT:
-            return self.send_snapshot(m)
+            return "" if self.send_snapshot(m) else None
         try:
             addr, _key = self.resolver.resolve(m.shard_id, m.to)
         except KeyError:
             self.metrics.inc("transport.dropped")
-            return False
+            return None
         b = self.breaker(addr)
         if not b.ready():
             self.metrics.inc("transport.dropped")
             self._notify_unreachable(m)
-            return False
+            return None
         sz = _msg_size(m)
         with self.mu:
             q = self.queues.setdefault(addr, deque())
@@ -229,12 +248,10 @@ class TransportHub:
                     and used + sz > self.max_send_queue_bytes) \
                     or len(q) >= SEND_QUEUE_LEN:
                 self.metrics.inc("transport.dropped")
-                return False
+                return None
             q.append((m, sz))
             self.queue_bytes[addr] = used + sz
-        if self.sync:
-            self.flush(addr)
-        return True
+        return addr
 
     def flush(self, addr: str | None = None) -> None:
         addrs = [addr] if addr else list(self.queues)
