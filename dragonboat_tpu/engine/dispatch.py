@@ -28,7 +28,7 @@ engine-unity pass enforces (pure literals, parsed with
 - ``STEP_LOOP_METHODS``: step-loop internals only ``STEP_LOOP_OWNER``
   may define — a subclass override is a second step loop (EU001);
 - ``DISPATCH_SEAMS``: the sanctioned subclass seams (addressing,
-  membership, escalation, message emission, and ``_make_dispatch``);
+  membership, escalation, the link mask, and ``_make_dispatch``);
 - ``ENGINE_FEATURE_KNOBS`` / ``ENGINE_FEATURE_CALLS``: dispatch
   features that must be reachable from ``step_all`` on every engine
   path (EU002/EU004);
@@ -74,7 +74,11 @@ STEP_LOOP_METHODS = (
     "_stage_lane",
     "_stage_props",
     "_process_outputs",
+    "_resolve_fates",
+    "_emit_messages",
+    "_build_updates",
     "_save_terms",
+    "_finish",
     "_kernel_call",
     "_capacity_entries",
     "_device_pending",
@@ -86,10 +90,11 @@ STEP_LOOP_METHODS = (
 )
 
 #: sanctioned subclass seams: addressing, membership, escalation,
-#: host-side message emission, and the dispatch-backend factory
+#: which links the host carries, and the dispatch-backend factory
 DISPATCH_SEAMS = (
     "_make_dispatch",
-    "_emit_messages",
+    "_link_mask",
+    "_witness_snapshot",
     "_send",
     "_send_all",
     "_prop_target",
@@ -169,7 +174,7 @@ SYNC_POINTS = {
                "wider than the download's S entries (none expected; "
                "counted in engine_save_window_overflow)",
     },
-    "KernelEngine._emit_messages": {
+    "KernelEngine._witness_snapshot": {
         "tag": "wit_snap_floor",
         "why": "witness-snapshot floor probe (snap_index scalar) on the "
                "rare wit_snap retire path only",
@@ -324,7 +329,7 @@ TRANSFER_LEDGER = {
          "site": "KernelEngine.health_row", "tag": "health_row",
          "per_step": False},
         {"value": "[G] i32", "dir": "down",
-         "site": "KernelEngine._emit_messages", "tag": "wit_snap_floor",
+         "site": "KernelEngine._witness_snapshot", "tag": "wit_snap_floor",
          "per_step": False},
     ),
 }

@@ -110,6 +110,15 @@ _KERNEL_MTYPES = frozenset({
 _F = {c: i for i, c in enumerate(FLAG_CLASSES)}
 _F_RESP, _F_REP, _F_HB, _F_VOTE = _F["resp"], _F["rep"], _F["hb"], _F["vote"]
 _F_TIMEOUT, _F_WITSNAP, _F_RTR = _F["timeout_now"], _F["wit_snap"], _F["rtr"]
+# the activity mask's columns (``KernelEngine._mask_cols``): the flags and
+# the two escalation bits (any of them set), the two windows' bounds, and
+# the five cells compared with what the host last saw (``_seen_np``)
+_MASK_FIELDS = ("ri_dropped", "needs_host", "save_first", "save_last",
+                "apply_first", "apply_last", "term", "vote", "commit",
+                "leader", "leader_term")
+_M_BITS = len(FLAG_CLASSES) + 2
+_M_SAVE_FIRST, _M_SAVE_LAST, _M_APPLY_FIRST, _M_APPLY_LAST, _M_SEEN = range(
+    _M_BITS, _M_BITS + 5)
 
 # admission at the staging boundary, all engines of the process
 # (telemetry.GLOBAL, where the round timer's histograms live)
@@ -172,15 +181,26 @@ _ADMIT_WAIT_US = telemetry.GLOBAL.histogram(
          "that injected its lane")
 # the width of a round, beside the round timer's histograms: per
 # committed round the lanes step_all staged and the lanes
-# _process_outputs took into its two per-lane loops; and the lanes that
-# hold a replica, per engine
+# _process_outputs retired (its candidate rows); and the lanes that hold a
+# replica, per engine
 _ROUND_LANES = telemetry.GLOBAL.counter(
     "engine_round_lanes",
     help="lanes of committed rounds: staged by step_all, processed by "
-         "the output pass's per-lane loops",
+         "the output pass (its candidate rows)",
     labelnames=("what",))
 _LANES_STAGED = _ROUND_LANES.labels("staged")
 _LANES_PROCESSED = _ROUND_LANES.labels("processed")
+# how the output pass retired them: by columns of the download, or through
+# the per-lane handler of a rare class (a witness snapshot, a ReadIndex
+# completion or drop, a config change, an escalation, a save window wider
+# than the download's)
+_RETIRE_LANES = telemetry.GLOBAL.counter(
+    "engine_retire_lanes",
+    help="lanes the output pass processed, by the path a round took "
+         "them: columnar, or per_lane where a rare class's handler ran",
+    labelnames=("path",))
+_RETIRED_COLUMNAR = _RETIRE_LANES.labels("columnar")
+_RETIRED_PER_LANE = _RETIRE_LANES.labels("per_lane")
 _LANES_LIVE = telemetry.GLOBAL.gauge(
     "engine_lanes_live",
     help="lanes holding a replica a round can see, per engine",
@@ -188,13 +208,15 @@ _LANES_LIVE = telemetry.GLOBAL.gauge(
 
 
 class _RoundDown:
-    """Host view of one round's packed download (kstate.py's column
+    """Host view of rows of a round's packed download (kstate.py's column
     table): ``o["term"][g]``, ``o["prop_index"][g, slot]``,
-    ``o["s_ent_term"][g, p, j]`` read the one [G, Wd] int32 host array the
-    round fetched, each field with its StepOutput shape (a bool field is
-    compared ``!= 0`` once); ``o["flags"]`` is the [G, C] activity matrix
-    and ``o["save_terms"][g, j]`` the term of ring entry
-    ``save_first[g] + j``.  Nothing here touches the device."""
+    ``o["s_ent_term"][g, p, j]`` read a [N, Wd] int32 host array (the
+    download, or rows gathered from it), each field with its StepOutput
+    shape (a bool field is compared ``!= 0`` once); ``o["flags"]`` is the
+    [N, C] activity matrix and ``o["save_terms"][g, j]`` the term of ring
+    entry ``save_first[g] + j``.  Nothing here touches the device.  The
+    output pass reads lists (``_Retiring``); this is what the handlers of
+    its rare classes read."""
 
     __slots__ = ("_host", "_cols", "_np")
 
@@ -208,6 +230,95 @@ class _RoundDown:
         if v is None:
             v = self._np[f] = column_value(self._cols[f], self._host)
         return v
+
+
+class _Retiring:
+    """The candidate rows of the round being retired, read ONCE: one gather
+    of those rows from the download and one ``tolist`` make ``cells``,
+    plain lists of Python ints, and everything the pass does to a lane
+    indexes ``cells[i]`` at a column's offset (``KernelEngine._at``): row
+    ``i`` is lane ``lanes[i]``'s and ``nodes[i]``'s.  A numpy call a field
+    or a class costs more than its work: each may let the interpreter go,
+    and beside other runnable threads the engine thread then waits for it
+    back (PERF.md section 6, PR 32).  ``view()`` is the ``_RoundDown`` of
+    the same rows for the rare classes' handlers; ``per_lane`` collects
+    the rows such a handler took, ``fallback`` the nodes whose witness
+    snapshot has to go the eviction way."""
+
+    __slots__ = ("lanes", "nodes", "cells", "per_lane", "fallback",
+                 "_rows", "_cols", "_view")
+
+    def __init__(self, lanes: list, nodes: list, host: np.ndarray,
+                 cols: dict) -> None:
+        self.lanes = lanes
+        self.nodes = nodes
+        self._rows = host[lanes]
+        self._cols = cols
+        self._view: _RoundDown | None = None
+        self.cells: list = self._rows.tolist()
+        self.per_lane: set[int] = set()
+        self.fallback: list = []
+
+    def view(self) -> _RoundDown:
+        if self._view is None:
+            self._view = _RoundDown(self._rows, self._cols)
+        return self._view
+
+
+# what the output pass builds by the hundred a round is built with every
+# field given: a default factory of ``pb.Message`` / ``pb.Update`` makes
+# an empty Snapshot (2.7 us of a message's 4.5), UpdateCommit or
+# LogQueryResult and throws it away.  All three are frozen.
+_NO_SNAPSHOT = pb.Snapshot()
+_NO_COMMIT = pb.UpdateCommit()
+_NO_LOG_QUERY = pb.LogQueryResult()
+_MT_OF = {int(t): t for t in pb.MessageType}
+
+
+def _message(mtype, to: int, n, term: int = 0, log_term: int = 0,
+             log_index: int = 0, commit: int = 0, reject: bool = False,
+             hint: int = 0, hint_high: int = 0, entries: tuple = ()):
+    """A message of node ``n``'s lane (positional: ``pb.Message``'s
+    field order)."""
+    return pb.Message(mtype, to, n.replica_id, n.shard_id, term, log_term,
+                      log_index, commit, reject, hint, hint_high, entries,
+                      _NO_SNAPSHOT)
+
+
+def _update(n, state: pb.State, entries: list):
+    """What node ``n``'s lane persists this round (positional:
+    ``pb.Update``'s field order)."""
+    return pb.Update(n.shard_id, n.replica_id, state, False, tuple(entries),
+                     (), False, _NO_SNAPSHOT, (), (), 0, _NO_COMMIT, (), (),
+                     _NO_LOG_QUERY, None)
+
+
+def _entry_at(e: pb.Entry, index: int, term: int) -> pb.Entry:
+    """``e`` at ``index`` and ``term`` (the constructor:
+    ``dataclasses.replace`` takes half as long again)."""
+    return pb.Entry(term, index, e.type, e.key, e.client_id, e.series_id,
+                    e.responded_to, e.cmd)
+
+
+def _replicate_entries(mirror: dict, prev: int, terms: list,
+                       witness: bool) -> tuple:
+    """The entries after ``prev`` a REPLICATE carries, with the terms the
+    kernel read from its ring: payloads from the mirror, and for a
+    witness peer none (raft.go:770 makeMetadataEntries; config changes
+    ship in full)."""
+    entries = []
+    idx = prev
+    for term in terms:
+        idx += 1
+        e = mirror.get(idx)
+        if e is None:
+            e = pb.Entry(term, idx)
+        elif e.term != term:
+            e = _entry_at(e, e.index, term)
+        if witness and not e.is_config_change():
+            e = pb.Entry(term, idx, pb.EntryType.METADATA)
+        entries.append(e)
+    return tuple(entries)
 
 
 @dataclass
@@ -425,22 +536,26 @@ class KernelEngine:
         self.nodes: dict[int, KernelNode] = {}     # lane -> node
         self.by_shard: dict[int, KernelNode] = {}
         self._free = list(range(capacity - 1, -1, -1))
-        # per-lane (term, vote, commit) as persisted — an np array so the
-        # outputs pass can find changed lanes with one vectorized compare
-        # (-1 rows = absent lane: the first real triple always differs)
-        self._triple_np = np.full((capacity, 3), -1, np.int64)
-        # host mirrors of per-lane leader caches, same reason
-        self._lead_np = np.zeros((capacity,), np.int64)
-        self._lead_term_np = np.zeros((capacity,), np.int64)
+        # what the host last saw of each lane, one [capacity, 5] array the
+        # outputs pass compares a round's download with in one expression:
+        # (term, vote, commit) as persisted (-1 rows = absent lane: the
+        # first real triple always differs) and the leader caches
+        self._seen_np = np.zeros((capacity, 5), np.int64)
+        self._triple_np = self._seen_np[:, :3]
+        self._triple_np[:] = -1
+        self._lead_np = self._seen_np[:, 3]
+        self._lead_term_np = self._seen_np[:, 4]
         # lanes with possibly-pending host work (see mark_dirty); its
         # own tiny lock — NOT engine.mu (ingress holds node.mu and the
         # documented order is engine.mu -> node.mu)
         self._dirty: set[int] = set()
         self._dirty_mu = threading.Lock()
-        # occupancy vector for the output activity mask (absent lanes
-        # must not pass it — the -1 triple sentinel vs device term 0
-        # would make every empty lane "active" forever)
+        # occupancy for the output activity mask (absent lanes must not
+        # pass it — the -1 triple sentinel vs device term 0 would make
+        # every empty lane "active" forever), and the occupied lanes as an
+        # index array (None: stale, see _live_rows)
         self._occ_np = np.zeros((capacity,), bool)
+        self._live: np.ndarray | None = None
         # the applied cursor each lane's device state has been sent: the
         # device gates campaigns and compaction on it, so a lane whose RSM
         # has applied further is work for a round even when nothing else is
@@ -497,6 +612,12 @@ class KernelEngine:
         # both crossings' layouts at this geometry (kstate.py's table)
         self._cols = round_columns(kp)
         self._down_cols = {c.field: c for c in self._cols.down}
+        # where each field of the download starts in a row (the output
+        # pass reads a candidate row as a list, at these offsets)
+        self._at = {c.field: c.start for c in self._cols.down}
+        self._mask_cols = np.array(
+            [*range(len(FLAG_CLASSES)),
+             *(self._at[f] for f in _MASK_FIELDS)], np.intp)
         self._bufs = tuple(
             _RoundStaging(kp, capacity, mesh_replicas=mesh_r)
             for _ in range(2))
@@ -739,6 +860,7 @@ class KernelEngine:
         self._lead_np[lane] = 0
         self._lead_term_np[lane] = 0
         self._occ_np[lane] = True
+        self._live = None
         self._applied_sent_np[lane] = init.applied
         self._pending_inject[lane] = (node, init, pids, kinds, t0)
         self._inv_dirty.add(lane)
@@ -844,6 +966,7 @@ class KernelEngine:
             self._pid_np[lane] = 0
             self._triple_np[lane] = -1
             self._occ_np[lane] = False
+            self._live = None
             return
         self._write_cells((
             (lane, "kind", KP.K_ABSENT), (lane, "pid", 0),
@@ -855,6 +978,7 @@ class KernelEngine:
         self._pid_np[lane] = 0
         self._triple_np[lane] = -1
         self._occ_np[lane] = False
+        self._live = None
 
     def update_lane_membership(self, node: KernelNode) -> None:
         """Re-derive the lane's peer book from the RSM membership (host
@@ -1130,6 +1254,13 @@ class KernelEngine:
             reads_staged=self._reads_staged,
             lanes_staged=lanes_staged,
             lanes_processed=self._lanes_processed, keys=list(keys))
+
+    def _live_rows(self) -> np.ndarray:
+        """The occupied lanes, in order (rebuilt after an injection or a
+        cleared lane)."""
+        if self._live is None:
+            self._live = np.nonzero(self._occ_np)[0]
+        return self._live
 
     def _is_registered(self, n: KernelNode) -> bool:
         # identity, not membership: with a deferred (pipelined) output
@@ -1602,10 +1733,21 @@ class KernelEngine:
 
         The fetch is ONE download: the [G, Wd] int32 array the step's
         program ended by writing (core/round.py ``pack_round``: activity
-        flags, every StepOutput field, the save window's terms), read
-        through ``_RoundDown``.  Everything below is host work on that
-        array; the 20-40 per-field pulls and the per-count ``lt`` gather
-        this replaced were half of a round (PERF.md, PR 25)."""
+        flags, every StepOutput field, the save window's terms).
+        Everything below is host work on that array, and it reads the
+        array ONCE after the activity mask: the candidate rows are
+        gathered and turned into lists of Python ints (``_Retiring``), and
+        what follows indexes those lists at the column table's offsets
+        and does for a lane only what its row says happened (a class of
+        message whose flag is set, a save or apply window that is not
+        empty, a leader that moved).  Read a cell at a time, a round of
+        220 lanes made 9,000 numpy scalar reads and built 430 messages a
+        field at a time; read a numpy call a field, a round let the
+        interpreter go at every call (PERF.md section 6, PR 32).  The
+        rare classes (witness snapshots, ReadIndex completions and drops,
+        config changes, escalation, a save window past ``S``) keep
+        per-lane handlers on the numpy view of the same rows: a lane one
+        of them took counts ``per_lane`` in ``engine_retire_lanes``."""
         nodes = ctx.nodes
         rt = self._round
         for k in ctx.traced:
@@ -1615,87 +1757,54 @@ class KernelEngine:
         rt.enter("fetch")
         with _capacity.METER.sanctioned("round_down"):
             host = np.asarray(ctx.out)
-        o = _RoundDown(host, self._down_cols)
-        flags = o["flags"]
+        # lanes with anything to process, found VECTORIZED (per-lane
+        # Python here was 16 us/lane/step at 100k lanes) over the OCCUPIED
+        # rows' mask columns, gathered first: a numpy call over all
+        # [capacity] rows lets the interpreter go, and twenty of them a
+        # round were 28 ms of a 256-lane round's fetch (PERF.md section 6,
+        # PR 32).  The mask must cover every consumer below: emitted
+        # messages and snapshot needs (all eight flag columns), dropped
+        # reads (_complete_reads) and escalation flags, save/apply windows
+        # and quiet term/vote/commit changes (_build_updates persists a
+        # bump even when no message went out), leader-cache deltas
+        # (_leader_edge); staged proposal fates ride ctx.staged_rows below.
+        live = self._live_rows()
+        m = host[live[:, None], self._mask_cols]
         # the dispatch backend derives drain-pending from the same flags
         # (MeshDispatch dropped its per-step pending-scalar download)
-        self._dispatch.note_output_flags(flags)
-        pid = self._pid_np
-        kind = self._kind_np
-        # shards whose witness peer needs a snapshot but have no recorded
-        # snapshot to strip — they take the regular eviction slow path
-        self._wit_snap_fallback: set[int] = set()
-
-        updates: list[pb.Update] = []
-        replicates: list[pb.Message] = []
-        others: list[pb.Message] = []
-        # lanes with anything to process, found VECTORIZED — per-lane
-        # Python here was 16 us/lane/step at 100k lanes.  The mask must
-        # cover every consumer below: emitted messages and snapshot
-        # needs (all eight flag columns), save/apply windows and quiet
-        # term/vote/commit changes (_build_update persists a bump even
-        # when no message went out), dropped reads (_complete_reads),
-        # leader-cache deltas (_leader_edge), and escalation flags;
-        # staged proposal fates ride ctx.staged_rows below.
+        self._dispatch.note_output_flags(m[:, :len(FLAG_CLASSES)])
         active = (
-            flags.any(1)
-            | (o["save_last"] >= o["save_first"])
-            | (o["apply_last"] >= o["apply_first"])
-            | o["ri_dropped"]
-            | o["needs_host"]
-            | (o["term"] != self._triple_np[:, 0])
-            | (o["vote"] != self._triple_np[:, 1])
-            | (o["commit"] != self._triple_np[:, 2])
-            | (o["leader"] != self._lead_np)
-            | (o["leader_term"] != self._lead_term_np)
-        ) & self._occ_np
-        cand_ids = set(np.nonzero(active)[0].tolist())
+            m[:, :_M_BITS].any(1)
+            | (m[:, _M_SAVE_LAST] >= m[:, _M_SAVE_FIRST])
+            | (m[:, _M_APPLY_LAST] >= m[:, _M_APPLY_FIRST])
+            | (m[:, _M_SEEN:] != self._seen_np[live]).any(1))
+        cand_ids = set(live[active].tolist())
         cand_ids.update(ctx.staged_rows)
         cand_ids.difference_update(ctx.dead)
         # identity check, not membership: a row whose node was removed
         # (and possibly re-admitted) while the step was in flight must
         # not have stale outputs applied to the successor's books
-        cand = [(g, nodes[g]) for g in sorted(cand_ids)
-                if g in nodes and self.nodes.get(g) is nodes[g]]
+        lanes = [g for g in sorted(cand_ids)
+                 if g in nodes and self.nodes.get(g) is nodes[g]]
         # every processed lane re-stages once next step: multi-window
         # pipelines (apply batches, read books, ring compaction) advance
         # by re-examination, exactly as the full scan did
-        for g, _n in cand:
-            self._dirty.add(g)
-        self._lanes_processed += len(cand)
+        self._dirty.update(lanes)
+        self._lanes_processed += len(lanes)
+        # the candidate rows of the download, read once: everything below
+        # indexes these lists (never all [G] rows, never a numpy cell)
+        r = _Retiring(lanes, [nodes[g] for g in lanes], host,
+                      self._down_cols)
 
         rt.enter("resolve")
-        for g, n in cand:
-            # 1. proposal fates (origin holds the future's books — on a
-            # mesh engine forwarded proposals stage on the leader row)
-            fates = ctx.fates.get(g)
-            if fates:
-                for slot, (entry, origin) in enumerate(fates):
-                    if o["prop_accepted"][g, slot]:
-                        index = int(o["prop_index"][g, slot])
-                        term = int(o["prop_term"][g, slot])
-                        n.mirror[index] = _dc_replace(
-                            entry, index=index, term=term)
-                    else:
-                        if entry.is_config_change():
-                            origin.pending_config_change.done(
-                                entry.key, RequestResultCode.DROPPED)
-                        else:
-                            origin._rl_release(entry.key)
-                            origin.pending_proposals.dropped(entry.key)
-            if fates is not None and n._staged_props is fates:
-                # serial mode retires before the next staging rebinds
-                # the list; pipelined mode's rebind already happened
-                n._staged_props = []
-
-            # 2. outgoing messages, gated per class on the flag row
-            self._emit_messages(g, n, o, flags[g], pid, kind,
-                                replicates, others)
-
-            # 3. persistence batch
-            ud = self._build_update(g, n, o)
-            if ud is not None:
-                updates.append((n, ud))
+        # 1. proposal fates
+        self._resolve_fates(r, ctx.fates)
+        # 2. outgoing messages, of the classes a row's flags name
+        replicates: list = []
+        others: list = []
+        self._emit_messages(r, replicates, others)
+        # 3. persistence batch
+        updates = self._build_updates(r)
 
         # replicate-before-fsync (engine.go:1332-1343)
         self._send_all(replicates)
@@ -1706,191 +1815,300 @@ class KernelEngine:
             by_db: dict[int, tuple[object, list]] = {}
             for n, ud in updates:
                 by_db.setdefault(id(n.logdb), (n.logdb, []))[1].append(ud)
-                if lifecycle.TRACER.enabled:
-                    for e in ud.entries_to_save:
-                        if e.key:
-                            lifecycle.TRACER.stamp(
-                                e.key, lifecycle.STAGE_SAVE)
+                lifecycle.TRACER.stamp_all(
+                    [e.key for e in ud.entries_to_save],
+                    lifecycle.STAGE_SAVE)
             for db, uds in by_db.values():
                 db.save_raft_state(uds, worker_id=0)
             rt.enter("resolve")
         self._send_all(others)
 
         rt.enter("finish")
-        for g, n in cand:
-            # a whole-group eviction earlier in THIS loop (mesh engine)
-            # already handed the sibling rows to host-resident successor
-            # nodes — touching their SMs/books here would race them
-            if not self._is_registered(n):
-                continue
-            n._committed_cache = int(o["commit"][g])
-            # 4. ReadIndex results
-            self._complete_reads(g, n, o, flags[g], ctx.staged_ri.get(g))
-            # 5. apply released entries
-            self._apply(g, n, o)
-            # 6. leader edges
-            self._leader_edge(g, n, int(o["leader"][g]),
-                              int(o["leader_term"][g]))
-            self._lead_np[g] = int(o["leader"][g])
-            self._lead_term_np[g] = int(o["leader_term"][g])
-            # 7. escalation
-            if o["needs_host"][g]:
-                self._evict(n, reason="kernel escalation")
-            elif n.shard_id in self._wit_snap_fallback:
-                self._evict(n, reason="witness snapshot without record")
+        self._finish(r, ctx.staged_ri)
+        _RETIRED_PER_LANE.inc(len(r.per_lane))
+        _RETIRED_COLUMNAR.inc(len(lanes) - len(r.per_lane))
 
-    def _emit_messages(self, g, n, o, fl, pid, kind,
-                       replicates, others) -> None:
-        """Build this row's outgoing messages.  ``fl`` is the row of the
-        [G, C] class-activity matrix: a class whose bit is clear is
-        never indexed, so its per-slot Python loop never runs."""
-        E = self.kp.msg_entries
-        shard = n.shard_id
-        # response lanes
-        if fl[_F_RESP]:
-            for k in range(o["r_type"].shape[1]):
-                rt = int(o["r_type"][g, k])
-                if rt == 0:
-                    continue
-                others.append((n, pb.Message(
-                    type=pb.MessageType(rt), to=int(o["r_to"][g, k]),
-                    from_=n.replica_id, shard_id=shard,
-                    term=int(o["r_term"][g, k]),
-                    log_index=int(o["r_log_index"][g, k]),
-                    reject=bool(o["r_reject"][g, k]),
-                    hint=int(o["r_hint"][g, k]),
-                    hint_high=int(o["r_hint_high"][g, k]),
-                )))
-        rep, hb = bool(fl[_F_REP]), bool(fl[_F_HB])
-        vote, tnow = bool(fl[_F_VOTE]), bool(fl[_F_TIMEOUT])
-        wsnap = bool(fl[_F_WITSNAP])
-        if not (rep or hb or vote or tnow or wsnap):
+    def _resolve_fates(self, r: _Retiring, fates_of: dict) -> None:
+        """What became of the proposals staged into this step (the origin
+        holds the future's books: on a mesh engine forwarded proposals
+        stage on the leader row): an accepted one enters the payload
+        mirror at the index and term the kernel gave it, a refused one
+        fails its future now."""
+        if not fates_of:
             return
-        # per-peer lanes
-        for p in range(pid.shape[1]):
-            to = int(pid[g, p])
+        at = self._at
+        accepted, index_at, term_at = (
+            at["prop_accepted"], at["prop_index"], at["prop_term"])
+        where = {g: i for i, g in enumerate(r.lanes)}
+        for g, fates in fates_of.items():
+            i = where.get(g)
+            if i is None:
+                continue
+            row, n = r.cells[i], r.nodes[i]
+            mirror = n.mirror
+            for slot, fate in enumerate(fates):
+                if row[accepted + slot]:
+                    index = row[index_at + slot]
+                    mirror[index] = _entry_at(
+                        fate[0], index, row[term_at + slot])
+                else:
+                    if fate[0].is_config_change():
+                        r.per_lane.add(i)
+                    self._fail_fates((fate,))
+            if n._staged_props is fates:
+                # serial mode retires before the next staging rebinds
+                # the list; pipelined mode's rebind already happened
+                n._staged_props = []
+
+    def _link_mask(self, rows: list):
+        """Which links of the lanes ``rows`` the host transport carries,
+        as a ``[len(rows), R] bool`` array indexed by the target's replica
+        id less one; None where it carries them all (a seam: the mesh
+        engine answers with its cut mask, the rest rides the mesh)."""
+        return None
+
+    def _emit_messages(self, r: _Retiring, replicates: list,
+                       others: list) -> None:
+        """This round's outgoing messages, for the rows whose flags say
+        they have some: a class whose flag is clear is never looked at.
+        Replicates go to ``replicates`` (sent before the save), the rest
+        to ``others``; a (shard, target) pair gets them in the order
+        responses, witness snapshot, heartbeat, vote, timeout-now."""
+        links = self._link_mask(r.lanes)
+        # (a mask with no link in it: nothing is the host's to send but
+        # witness snapshots)
+        hushed = links is not None and not links.any()
+        links = None if links is None or hushed else links.tolist()
+        at = self._at
+        K, E = self.kp.inbox_cap, self.kp.msg_entries
+        term_at = at["term"]
+        r_type, r_to, r_term, r_index, r_reject, r_hint, r_high = (
+            at[f] for f in ("r_type", "r_to", "r_term", "r_log_index",
+                            "r_reject", "r_hint", "r_hint_high"))
+        s_rep, s_prev, s_prev_term, s_commit, s_count, s_terms = (
+            at[f] for f in ("s_rep", "s_prev_index", "s_prev_term",
+                            "s_commit", "s_n_ent", "s_ent_term"))
+        s_hb, s_hb_commit, s_hb_low, s_hb_high = (
+            at[f] for f in ("s_hb", "s_hb_commit", "s_hb_low", "s_hb_high"))
+        s_vote, s_vote_term, s_vote_index, s_vote_lterm, s_vote_hint = (
+            at[f] for f in ("s_vote", "s_vote_term", "s_vote_lindex",
+                            "s_vote_lterm", "s_vote_hint"))
+        s_timeout = at["s_timeout_now"]
+        pids = kinds = None         # the rows' peer books, once needed
+        for i, row in enumerate(r.cells):
+            resp, rep, hb, vote, timeout, wit_snap = (
+                row[_F_RESP], row[_F_REP], row[_F_HB], row[_F_VOTE],
+                row[_F_TIMEOUT], row[_F_WITSNAP])
+            if not (resp or rep or hb or vote or timeout or wit_snap):
+                continue
+            n = r.nodes[i]
+            if hushed:
+                resp = rep = hb = vote = timeout = 0
+            linked = None if links is None else links[i]
+            if resp:
+                for k in range(K):
+                    mt = row[r_type + k]
+                    if not mt:
+                        continue
+                    to = row[r_to + k]
+                    if linked is not None and not (
+                            1 <= to <= len(linked) and linked[to - 1]):
+                        continue
+                    others.append((n, _message(
+                        _MT_OF[mt], to, n, term=row[r_term + k],
+                        log_index=row[r_index + k],
+                        reject=bool(row[r_reject + k]),
+                        hint=row[r_hint + k], hint_high=row[r_high + k])))
+            if wit_snap:
+                r.per_lane.add(i)
+                self._witness_snapshot(r, i, others)
+            if not (rep or hb or vote or timeout):
+                continue
+            if pids is None:
+                pids = self._pid_np[r.lanes].tolist()
+            term = row[term_at]
+            # a leader's entries are built once per (prev, count) and
+            # shared by the peers they fit (a witness peer's are
+            # stripped: a form of its own)
+            built: dict = {}
+            for p, to in enumerate(pids[i]):
+                if to == 0 or to == n.replica_id:
+                    continue
+                if linked is not None and not (
+                        1 <= to <= len(linked) and linked[to - 1]):
+                    continue
+                if rep and row[s_rep + p]:
+                    if kinds is None:
+                        kinds = self._kind_np[r.lanes].tolist()
+                    prev, count = row[s_prev + p], row[s_count + p]
+                    key = (prev, count, kinds[i][p] == KP.K_WITNESS)
+                    entries = built.get(key)
+                    if entries is None:
+                        first = s_terms + p * E
+                        entries = built[key] = _replicate_entries(
+                            n.mirror, prev, row[first:first + count], key[2])
+                    replicates.append((n, _message(
+                        MT.REPLICATE, to, n, term=term,
+                        log_term=row[s_prev_term + p], log_index=prev,
+                        commit=row[s_commit + p], entries=entries)))
+                if hb and row[s_hb + p]:
+                    others.append((n, _message(
+                        MT.HEARTBEAT, to, n, term=term,
+                        commit=row[s_hb_commit + p], hint=row[s_hb_low + p],
+                        hint_high=row[s_hb_high + p])))
+                if vote and row[s_vote + p]:
+                    others.append((n, _message(
+                        MT.REQUEST_VOTE if row[s_vote + p] == 1
+                        else MT.REQUEST_PREVOTE, to, n,
+                        term=row[s_vote_term + p],
+                        log_term=row[s_vote_lterm + p],
+                        log_index=row[s_vote_index + p],
+                        hint=row[s_vote_hint + p])))
+                if timeout and row[s_timeout + p]:
+                    others.append((n, _message(
+                        MT.TIMEOUT_NOW, to, n, term=term)))
+
+    def _witness_snapshot(self, r: _Retiring, i: int, others: list) -> None:
+        """A witness peer of row ``i`` fell behind compaction: answer
+        with the stripped file-less snapshot built from the recorded
+        snapshot (raft.go:713-735) — no stream, no eviction.  The record
+        must cover the DEVICE compaction floor: the device paused the
+        peer at psnap = snap_index, and a stale older record would leave
+        a gap the witness can never bridge (re-sent forever) — the lane
+        takes the regular eviction slow path instead (a seam: on a mesh
+        engine it always does)."""
+        g, n = r.lanes[i], r.nodes[i]
+        for p in np.nonzero(r.view()["s_wit_snap"][i])[0].tolist():
+            to = int(self._pid_np[g, p])
             if to == 0 or to == n.replica_id:
                 continue
-            to_witness = int(kind[g, p]) == KP.K_WITNESS
-            if rep and o["s_rep"][g, p]:
-                prev = int(o["s_prev_index"][g, p])
-                cnt = int(o["s_n_ent"][g, p])
-                ents = []
-                for j in range(cnt):
-                    idx = prev + 1 + j
-                    e = n.mirror.get(idx)
-                    term = int(o["s_ent_term"][g, p, j])
-                    if e is None:
-                        e = pb.Entry(index=idx, term=term)
-                    elif e.term != term:
-                        e = _dc_replace(e, term=term)
-                    if to_witness and not e.is_config_change():
-                        # witnesses never see payloads (raft.go:770
-                        # makeMetadataEntries); CCs ship in full
-                        e = pb.Entry(index=idx, term=term,
-                                     type=pb.EntryType.METADATA)
-                    ents.append(e)
-                replicates.append((n, pb.Message(
-                    type=MT.REPLICATE, to=to, from_=n.replica_id,
-                    shard_id=shard, term=int(o["term"][g]),
-                    log_index=prev, log_term=int(o["s_prev_term"][g, p]),
-                    commit=int(o["s_commit"][g, p]),
-                    entries=tuple(ents),
-                )))
-            if wsnap and o["s_wit_snap"][g, p]:
-                # witness peer fell behind compaction: answer with the
-                # stripped file-less snapshot built from the recorded
-                # snapshot (raft.go:713-735) — no stream, no eviction.
-                # The record must cover the DEVICE compaction floor: the
-                # device paused the peer at psnap = snap_index, and a
-                # stale older record would leave a gap the witness can
-                # never bridge (re-sent forever) — evict instead.
-                ss = n.logdb.get_snapshot(n.shard_id, n.replica_id)
-                with _capacity.METER.sanctioned("wit_snap_floor"):
-                    floor = int(state_cell(           # wit_snap only
-                        self._resident.cols, np.int32(g), np.int32(
-                            self._state_cols["snap_index"].start)))
-                if ss is not None and not ss.is_empty() \
-                        and ss.index >= floor:
-                    others.append((n, pb.Message(
-                        type=MT.INSTALL_SNAPSHOT, to=to,
-                        from_=n.replica_id, shard_id=shard,
-                        term=int(o["term"][g]),
-                        snapshot=_dc_replace(
-                            ss, filepath="", file_size=0, files=(),
-                            witness=True, dummy=False),
-                    )))
-                else:
-                    # no record, or one below the device floor — the
-                    # regular escalation path recovers the shard
-                    self._wit_snap_fallback.add(n.shard_id)
-            if hb and o["s_hb"][g, p]:
+            ss = n.logdb.get_snapshot(n.shard_id, n.replica_id)
+            with _capacity.METER.sanctioned("wit_snap_floor"):
+                floor = int(state_cell(           # wit_snap only
+                    self._resident.cols, np.int32(g), np.int32(
+                        self._state_cols["snap_index"].start)))
+            if ss is not None and not ss.is_empty() and ss.index >= floor:
                 others.append((n, pb.Message(
-                    type=MT.HEARTBEAT, to=to, from_=n.replica_id,
-                    shard_id=shard, term=int(o["term"][g]),
-                    commit=int(o["s_hb_commit"][g, p]),
-                    hint=int(o["s_hb_low"][g, p]),
-                    hint_high=int(o["s_hb_high"][g, p]),
+                    type=MT.INSTALL_SNAPSHOT, to=to, from_=n.replica_id,
+                    shard_id=n.shard_id, term=r.cells[i][self._at["term"]],
+                    snapshot=_dc_replace(
+                        ss, filepath="", file_size=0, files=(),
+                        witness=True, dummy=False),
                 )))
-            sv = int(o["s_vote"][g, p]) if vote else 0
-            if sv:
-                others.append((n, pb.Message(
-                    type=(MT.REQUEST_VOTE if sv == 1
-                          else MT.REQUEST_PREVOTE),
-                    to=to, from_=n.replica_id, shard_id=shard,
-                    term=int(o["s_vote_term"][g, p]),
-                    log_index=int(o["s_vote_lindex"][g, p]),
-                    log_term=int(o["s_vote_lterm"][g, p]),
-                    hint=int(o["s_vote_hint"][g, p]),
-                )))
-            if tnow and o["s_timeout_now"][g, p]:
-                others.append((n, pb.Message(
-                    type=MT.TIMEOUT_NOW, to=to, from_=n.replica_id,
-                    shard_id=shard, term=int(o["term"][g]))))
+            elif n not in r.fallback:
+                # no record, or one below the device floor — the
+                # regular escalation path recovers the shard
+                r.fallback.append(n)
 
-    def _save_terms(self, g: int, first: int, last: int, o) -> np.ndarray:
-        """Terms of the ring entries ``first..last`` a step saved, indexed
-        from ``first``: the download's window, or for a lane whose window
-        is wider than its ``S`` entries (none expected; counted) one
-        fixed-shape fetch of the lane's whole ring row from the state
-        that step returned (still the resident one: a retire runs before
-        the next dispatch)."""
-        if last - first < self._cols.save_window:
-            return o["save_terms"][g]
+    def _save_terms(self, g: int, first: int, last: int) -> list:
+        """Terms of the ring entries ``first..last`` of a lane whose save
+        window is wider than the download's ``S`` entries (none expected;
+        counted): one fixed-shape fetch of the lane's whole ring row from
+        the state that step returned (still the resident one: a retire
+        runs before the next dispatch)."""
         _SAVE_WINDOW_OVERFLOW.inc()
         with _capacity.METER.sanctioned("save_window_row"):
             row = np.asarray(ring_row(self._resident.lt, np.int32(g)))
         return row[(first + np.arange(last - first + 1))
-                   & (self.kp.log_cap - 1)]
+                   & (self.kp.log_cap - 1)].tolist()
 
-    def _build_update(self, g, n, o) -> pb.Update | None:
-        first, last = int(o["save_first"][g]), int(o["save_last"][g])
-        triple = (int(o["term"][g]), int(o["vote"][g]), int(o["commit"][g]))
-        entries: list[pb.Entry] = []
-        if last >= first:
-            terms = self._save_terms(g, first, last, o)
-            for idx in range(first, last + 1):
-                term = int(terms[idx - first])
-                e = n.mirror.get(idx)
-                if e is None or e.term != term:
-                    e = (_dc_replace(e, term=term) if e is not None
-                         else pb.Entry(index=idx, term=term))
-                    n.mirror[idx] = e
-                entries.append(e)
-        state_changed = tuple(self._triple_np[n.lane]) != triple
-        if not entries and not state_changed:
-            return None
-        self._triple_np[n.lane] = triple
-        return pb.Update(
-            shard_id=n.shard_id, replica_id=n.replica_id,
-            state=pb.State(term=triple[0], vote=triple[1], commit=triple[2]),
-            entries_to_save=tuple(entries),
-        )
+    def _build_updates(self, r: _Retiring) -> list:
+        """-> [(node, pb.Update)] of the rows with entries to save or a
+        (term, vote, commit) that is not the persisted one (a quiet bump
+        is persisted too); the persisted triples are read once for the
+        rows and written once for those that moved."""
+        at = self._at
+        term_at, vote_at, commit_at = at["term"], at["vote"], at["commit"]
+        first_at, last_at, terms_at = (
+            at["save_first"], at["save_last"], at["save_terms"])
+        S = self._cols.save_window
+        persisted = self._triple_np[r.lanes].tolist()
+        moved: list = []            # lanes whose triple moved, and to what
+        moved_to: list = []
+        updates = []
+        for i, row in enumerate(r.cells):
+            lo, hi = row[first_at], row[last_at]
+            triple = [row[term_at], row[vote_at], row[commit_at]]
+            if triple != persisted[i]:
+                moved.append(r.lanes[i])
+                moved_to.append(triple)
+            elif hi < lo:
+                continue
+            n = r.nodes[i]
+            entries = []
+            if hi >= lo:
+                if hi - lo < S:
+                    terms = row[terms_at:terms_at + hi - lo + 1]
+                else:
+                    r.per_lane.add(i)
+                    terms = self._save_terms(r.lanes[i], lo, hi)
+                mirror = n.mirror
+                idx = lo
+                for t in terms:
+                    e = mirror.get(idx)
+                    if e is None:
+                        e = mirror[idx] = pb.Entry(t, idx)
+                    elif e.term != t:
+                        e = mirror[idx] = _entry_at(e, e.index, t)
+                    entries.append(e)
+                    idx += 1
+            updates.append((n, _update(n, pb.State(*triple), entries)))
+        if moved:
+            self._triple_np[moved] = moved_to
+        return updates
+
+    def _finish(self, r: _Retiring, staged_ri: dict) -> None:
+        """After the save, per row and only where it happened: complete
+        reads, apply released entries, fire the leader edge, escalate."""
+        at = self._at
+        commit_at, term_at = at["commit"], at["term"]
+        first_at, last_at = at["apply_first"], at["apply_last"]
+        leader_at, leader_term_at = at["leader"], at["leader_term"]
+        dropped_at, needs_host_at = at["ri_dropped"], at["needs_host"]
+        removed = len(self._removed_nodes)
+        led: list = []              # lanes whose leader moved, and to what
+        led_to: list = []
+        for i, row in enumerate(r.cells):
+            n = r.nodes[i]
+            # a whole-group eviction earlier in THIS loop (mesh engine)
+            # already handed the sibling rows to host-resident successor
+            # nodes — touching their SMs/books here would race them
+            # (a removal is logged: only then is a row checked again)
+            if len(self._removed_nodes) != removed \
+                    and not self._is_registered(n):
+                continue
+            n._committed_cache = row[commit_at]
+            # 4. ReadIndex results
+            if row[_F_RTR] or row[dropped_at]:
+                r.per_lane.add(i)
+                view = r.view()
+                self._complete_reads(i, n, view, view["flags"][i],
+                                     staged_ri.get(r.lanes[i]))
+            # 5. apply released entries
+            if row[last_at] >= row[first_at] and self._apply(
+                    n, row[first_at], row[last_at], row[term_at]):
+                r.per_lane.add(i)
+            # 6. leader edges
+            leader, term = row[leader_at], row[leader_term_at]
+            if leader != n._leader_cache or term != n._leader_term_cache:
+                self._leader_edge(n, leader, term)
+                led.append(r.lanes[i])
+                led_to.append((leader, term))
+            # 7. escalation
+            if row[needs_host_at]:
+                r.per_lane.add(i)
+                self._evict(n, reason="kernel escalation")
+        for n in r.fallback:
+            if self._is_registered(n):
+                self._evict(n, reason="witness snapshot without record")
+        if led:
+            self._seen_np[led, 3:] = led_to     # _lead_np, _lead_term_np
 
     def _complete_reads(self, g, n, o, fl, staged_ri) -> None:
-        """``staged_ri`` is the ReadIndex ctx staged into THIS step (from
-        the step ctx — staging for the next step rebinds ``n._staged_ri``
-        before a pipelined retire runs)."""
+        """ReadIndex results of row ``g`` of ``o`` (``fl``: its flag
+        row).  ``staged_ri`` is the ReadIndex ctx staged into THIS step
+        (from the step ctx — staging for the next step rebinds
+        ``n._staged_ri`` before a pipelined retire runs)."""
         if fl[_F_RTR]:
             rtr = o["rtr_valid"][g]
             for j in range(rtr.shape[0]):
@@ -1925,47 +2143,60 @@ class KernelEngine:
                 n._remote_reads.insert(0, (sender, staged_ri, monotonic_us()))
         n.pending_reads.applied(n.sm.get_last_applied())
 
-    def _apply(self, g, n, o) -> None:
-        first, last = int(o["apply_first"][g]), int(o["apply_last"][g])
-        if last < first:
-            return
+    def _apply(self, n: KernelNode, first: int, last: int,
+               term: int) -> bool:
+        """Hand the committed entries ``first..last`` to the RSM and
+        complete what waited on them; -> whether a config change was
+        among them (the rare class of this pass)."""
+        mirror = n.mirror
         entries = []
         for idx in range(first, last + 1):
-            e = n.mirror.get(idx)
+            e = mirror.get(idx)
             if e is None:
-                e = pb.Entry(index=idx, term=int(o["term"][g]))
-                n.mirror[idx] = e
+                e = mirror[idx] = pb.Entry(term, idx)
             entries.append(e)
-        for e in entries:
-            if e.key:
-                n._rl_release(e.key)
-        if n.notify_commit:
+        # a future lives only where its proposal entered: a replica whose
+        # book is empty (two of three, under writes to leaders) has none
+        # to commit or complete
+        book = n.pending_proposals
+        waited = not book.idle()
+        if n.rate_limiter.enabled():
             for e in entries:
                 if e.key:
-                    n.pending_proposals.committed(e.key)
+                    n._rl_release(e.key)
+        if n.notify_commit and waited:
+            for e in entries:
+                if e.key:
+                    book.committed(e.key)
         results = n.sm.handle(entries)
-        if lifecycle.TRACER.enabled:
-            for e in entries:
-                if e.key:
-                    lifecycle.TRACER.stamp(e.key, lifecycle.STAGE_APPLY)
+        lifecycle.TRACER.stamp_all(
+            [e.key for e in entries], lifecycle.STAGE_APPLY)
         cc_applied = False
-        for r in results:
-            entry = next(e for e in entries if e.index == r.index)
+        # ``handle`` answers for a subsequence of the entries, in order
+        # (it skips what an on-disk state machine already replayed): one
+        # forward cursor matches them
+        cursor = iter(entries)
+        for res in results:
+            for entry in cursor:
+                if entry.index == res.index:
+                    break
             if entry.is_config_change():
-                n._on_config_change_applied(entry, r)
+                n._on_config_change_applied(entry, res)
                 cc_applied = True
-            elif r.key:
-                n.pending_proposals.applied(
-                    r.key, r.client_id, r.series_id, r.result, r.rejected)
+            elif res.key and waited:
+                book.applied(res.key, res.client_id, res.series_id,
+                             res.result, res.rejected)
         if cc_applied:
             self.update_lane_membership(n)
         n.applied_since_snapshot += len(results)
-        n.pending_reads.applied(n.sm.get_last_applied())
+        if n.pending_reads.waiting:
+            n.pending_reads.applied(n.sm.get_last_applied())
         # auto snapshot + mirror pruning (node.go:694 saveSnapshotRequired)
         if (n.cfg.snapshot_entries > 0
                 and n.applied_since_snapshot >= n.cfg.snapshot_entries):
             self._take_lane_snapshot(n, _SnapshotRequest())
         self._prune_mirror(n)
+        return cc_applied
 
     def _mirror_floor(self, n: KernelNode) -> int:
         """Lowest applied cursor that still needs mirror payloads.  On a
@@ -2008,7 +2239,7 @@ class KernelEngine:
             error=0, first_index=avail_first, last_index=committed + 1,
             entries=entries))
 
-    def _leader_edge(self, g, n: KernelNode, leader: int, term: int) -> None:
+    def _leader_edge(self, n: KernelNode, leader: int, term: int) -> None:
         if (leader, term) == (n._leader_cache, n._leader_term_cache):
             return
         n._leader_cache, n._leader_term_cache = leader, term

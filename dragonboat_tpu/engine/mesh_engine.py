@@ -48,7 +48,6 @@ from dragonboat_tpu.engine.kernel_engine import (
     ADD_SHARD_LOCK_US,
     KernelEngine,
     KernelNode,
-    _F_WITSNAP,
     _KERNEL_MTYPES,
     _LaneInit,
 )
@@ -351,36 +350,23 @@ class MeshEngine(KernelEngine):
 
         return MeshDispatch(self.cluster)
 
-    def _emit_messages(self, g, n, o, fl, pid, kind,
-                       replicates, others) -> None:
+    def _link_mask(self, rows):
         # intra-group messages ride the mesh inside the step; the host
-        # sends ONLY the hub-fallback traffic of cut links (READ_INDEX
-        # forwarding and snapshot streams go through the per-node host
-        # path).  A witness peer needing a snapshot CANNOT be served
-        # over the mesh (witness replicas are host-resident, their mesh
-        # row is absent) — the group escalates to the host engines
-        if fl[_F_WITSNAP] and o["s_wit_snap"][g].any():
-            self._wit_snap_fallback.add(n.shard_id)
-        cut = self._dispatch.cut[g]
-        if not cut.any():
-            return
-        # hub fallback: rebuild EXACTLY the messages the mesh exchange
-        # masked out (sender-side per-link mask, parallel/ici.py
-        # _mask_outgoing reads the same unmasked output fields) and keep
-        # only the ones addressed over cut links.  The wit_snap branch is
-        # suppressed — it is host-escalation, handled above, not link
-        # traffic.
-        fl = fl.copy()
-        fl[_F_WITSNAP] = False
-        reps: list = []
-        oths: list = []
-        super()._emit_messages(g, n, o, fl, pid, kind, reps, oths)
-        R = self.spec.replicas
-        for built, dst in ((reps, replicates), (oths, others)):
-            for item in built:
-                to = item[1].to
-                if 1 <= to <= R and cut[to - 1]:
-                    dst.append(item)
+        # sends ONLY the hub-fallback traffic of cut links: EXACTLY the
+        # messages the mesh exchange masked out (sender-side per-link
+        # mask: parallel/ici.py _mask_outgoing reads the same unmasked
+        # output fields the download carries).  READ_INDEX forwarding and
+        # snapshot streams go through the per-node host path
+        return self._dispatch.cut[rows]
+
+    def _witness_snapshot(self, r, i: int, others: list) -> None:
+        # a witness peer needing a snapshot CANNOT be served over the
+        # mesh (witness replicas are host-resident, their mesh row is
+        # absent): host-escalation, not link traffic — the group leaves
+        # for the host engines
+        if r.view()["s_wit_snap"][i].any() \
+                and r.nodes[i] not in r.fallback:
+            r.fallback.append(r.nodes[i])
 
     def _send(self, n: KernelNode, m: pb.Message) -> None:
         # everything a mesh engine hands to the host transport passes here:

@@ -212,6 +212,16 @@ class LifecycleTracer:
             if sp is not None:
                 sp.stamps.append((stage, t))
 
+    def stamp_all(self, keys, stage: str) -> None:
+        """``stamp`` for each of ``keys`` (0: no key) that is sampled:
+        the engine's save and apply passes hand over a round's hundreds
+        of keys, of which one in ``sample_every`` has a span."""
+        every = self._every
+        if every > 0:
+            for key in keys:
+                if key and key % every == 0:
+                    self.stamp(key, stage)
+
     def finish(self, key: int) -> None:
         """Complete a span at future-ack time: stamp the closing stage
         (``ack`` for proposals, ``read_serve`` for reads), feed the

@@ -184,7 +184,7 @@ def _mask_outgoing(out: StepOutput, cut: jnp.ndarray) -> StepOutput:
     sends nothing on the mesh, but still ticks, persists and applies.
     A single column is the round-17 hub-fallback surface: traffic for
     that link leaves the mesh and rides the host hub instead
-    (MeshEngine._emit_messages)."""
+    (MeshEngine._link_mask)."""
     P = cut.shape[1]
 
     def zpeer(a):  # [G, P(, E)] peer-slot lanes: zero slot p where cut

@@ -173,6 +173,12 @@ class PendingProposal(_ClockedBook):
             out.update(d)
         return out
 
+    def idle(self) -> bool:
+        """No future is registered (an unlocked read, as ``gc``'s: a
+        key's future is registered before its entry is proposed, so a
+        book that reads empty holds none of an entry applied now)."""
+        return not any(self._shards)
+
     def propose(self, session, cmd: bytes, timeout_ticks: int
                 ) -> tuple[RequestState, pb.Entry]:
         key = next(self._seq)
@@ -220,7 +226,7 @@ class PendingProposal(_ClockedBook):
         # unlocked emptiness fast path: the amortized host sweep calls
         # gc on EVERY lane's books; an entry racing in is caught by the
         # next sweep (timeouts are tick-granular anyway)
-        if not any(self._shards):
+        if self.idle():
             return
         for i in range(self._n):
             with self._locks[i]:

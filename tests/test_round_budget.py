@@ -40,14 +40,14 @@ def _wait(cond, timeout):
     return cond()
 
 
-def _host(prefix, shards, depth=0, device=True, root=None):
+def _host(prefix, shards, depth=0, device=True, root=None, capacity=64):
     """One NodeHost, ``shards`` single-replica shards: a proposal is
     appended, committed, saved and applied in the round that stages it.
     With ``root`` the LogDB is the on-disk default under it."""
     nh = NodeHost(NodeHostConfig(
         raft_address=f"{prefix}-1", rtt_millisecond=5,
         node_host_dir=root or "",
-        expert=ExpertConfig(kernel_log_cap=64, kernel_capacity=64,
+        expert=ExpertConfig(kernel_log_cap=64, kernel_capacity=capacity,
                             fleet_stats_every=EVERY,
                             kernel_pipeline_depth=depth)))
     for sid in range(1, shards + 1):
@@ -163,6 +163,114 @@ def test_steady_round_is_one_write_and_one_fsync_of_one_log(tmp_path, depth):
                        for t in threading.enumerate())
         assert [c for _i, _t, c in _persisted(nh, SHARDS) if c] == \
             [f"k{i}={SHARDS}".encode() for i in range(12)]
+    finally:
+        nh.close()
+
+
+def _retired() -> dict:
+    snap = telemetry.GLOBAL.snapshot()
+    return {path: snap.get(f"engine_retire_lanes{{path={path}}}", 0)
+            for path in ("columnar", "per_lane")}
+
+
+#: what a steady round of single-replica shards, each proposed to once,
+#: reads of its download through numpy: no field of it is materialised over
+#: all [G] rows (``column_value``) and none is asked of a ``_RoundDown``
+#: (the per-lane pass asked 41 times a lane, each followed by a numpy scalar
+#: read; the rare classes' handlers still do): the activity mask is one
+#: gather of the occupied rows' mask columns, and the candidate rows are
+#: gathered and turned into lists ONCE
+STEADY_READS = {"column_value": 0, "getitem": 0, "tolist": 1}
+
+
+@pytest.mark.parametrize("shards", [1, 48, 256])
+def test_steady_round_reads_its_download_by_columns(shards, monkeypatch):
+    """The output pass reads the download once, never a cell at a time:
+    a steady round materialises the same fields of the activity mask and
+    turns its candidate rows into lists once at 1, 48 and 256 live lanes,
+    all of them retired on the columnar path."""
+    from dragonboat_tpu.engine import kernel_engine as ke
+
+    calls = dict.fromkeys(STEADY_READS, 0)
+    real_value, real_getitem = ke.column_value, ke._RoundDown.__getitem__
+
+    def column_value(c, packed):
+        calls["column_value"] += 1
+        return real_value(c, packed)
+
+    def getitem(self, f):
+        calls["getitem"] += 1
+        return real_getitem(self, f)
+
+    class Retiring(ke._Retiring):
+        def __init__(self, lanes, nodes, host, cols):
+            calls["tolist"] += 1
+            super().__init__(lanes, nodes, host, cols)
+
+    nh = _host(f"rb-cols{shards}", shards, capacity=max(64, shards))
+    try:
+        eng = nh.kernel_engine
+        sessions = [nh.get_noop_session(sid) for sid in range(1, shards + 1)]
+        with eng.mu:
+            _settle(eng)
+            monkeypatch.setattr(ke, "column_value", column_value)
+            monkeypatch.setattr(ke._RoundDown, "__getitem__", getitem)
+            monkeypatch.setattr(ke, "_Retiring", Retiring)
+            for i in range(3):
+                states = [nh.propose(s, f"k{i}=v".encode(), 30)
+                          for s in sessions]
+                calls.update(dict.fromkeys(calls, 0))
+                before = _retired()
+                assert eng.step_all()
+                assert calls == STEADY_READS, f"round {i} at {shards} lanes"
+                assert eng._lanes_processed == shards
+                after = _retired()
+                assert after["columnar"] - before["columnar"] == shards
+                assert after["per_lane"] == before["per_lane"]
+                _settle(eng)
+        for rs in states:
+            assert rs.get(30) is not None
+    finally:
+        nh.close()
+
+
+def test_retire_lanes_counts_per_lane_only_for_the_rare_classes():
+    """``engine_retire_lanes`` grows by the lanes the rounds processed;
+    writes are retired by columns, a ReadIndex completion takes its lane
+    through the per-lane handler, and so did the bootstrap config change
+    each shard applied when it started."""
+    start = _retired()
+    nh = _host("rb-retire", 4)
+    try:
+        eng = nh.kernel_engine
+        sessions = {sid: nh.get_noop_session(sid) for sid in range(1, 5)}
+        with eng.mu:
+            _settle(eng)
+            assert _retired()["per_lane"] - start["per_lane"] >= 4
+            before, processed, states = _retired(), 0, []
+            for i in range(5):
+                for sid, s in sessions.items():
+                    states.append(nh.propose(s, f"k{i}={sid}".encode(), 30))
+                assert eng.step_all()
+                processed += eng._lanes_processed
+            after = _retired()
+            assert processed >= 20
+            assert after["columnar"] - before["columnar"] == processed
+            assert after["per_lane"] == before["per_lane"]
+            _settle(eng)
+            before, processed = _retired(), 0
+            read = nh.read_index(2, 30)
+            for _ in range(20):
+                if not eng.step_all():
+                    break
+                processed += eng._lanes_processed
+            after = _retired()
+            assert after["per_lane"] - before["per_lane"] == 1
+            assert (after["columnar"] - before["columnar"]
+                    + after["per_lane"] - before["per_lane"]) == processed
+        assert read.get(30) is not None
+        for rs in states:
+            assert rs.get(30) is not None
     finally:
         nh.close()
 
