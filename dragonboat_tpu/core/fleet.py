@@ -48,6 +48,8 @@ CONTRACTS = {
         "leaderless": "[] i32 part=replicated collective=declared",
         "election_active": "[] i32 part=replicated collective=declared",
         "quiesced": "[] i32 part=replicated collective=declared",
+        "quiesced_by_word": "[] i32 part=replicated collective=declared",
+        "quiesce_wakes": "[] i32 part=replicated collective=declared",
         "term_max": "[] i32 part=replicated collective=declared",
         "term_min": "[] i32 part=replicated collective=declared",
         "lag_hist": "[LAGB] i32 part=replicated collective=declared",
@@ -68,6 +70,8 @@ class FleetStats(NamedTuple):
     leaderless: jnp.ndarray       # [] — occupied lanes with no known leader
     election_active: jnp.ndarray  # [] — candidates + pre-vote candidates
     quiesced: jnp.ndarray         # [] — occupied lanes masked-quiesced
+    quiesced_by_word: jnp.ndarray  # [] — of them, entered on a peer's word
+    quiesce_wakes: jnp.ndarray    # [] — quiesce_epoch over occupied lanes
     term_max: jnp.ndarray         # [] (0 when no lane is occupied)
     term_min: jnp.ndarray         # [] (0 when no lane is occupied)
     lag_hist: jnp.ndarray         # [len(LAG_BUCKETS)+1] cumulative counts
@@ -88,6 +92,14 @@ def _fleet_stats_impl(state, inbox_from) -> FleetStats:
                               | (state.role == P.PRE_VOTE_CANDIDATE))
                        ).astype(i32).sum()
     quiesced = (occ & state.quiesced).astype(i32).sum()
+    # entry leaves idle_tick where it found it: at the threshold for a lane
+    # that crossed on its own clock, under it for one that followed a peer
+    # (core/kernel.py 5b)
+    quiesced_by_word = (occ & state.quiesced
+                        & (state.idle_tick < state.e_timeout * 10)
+                        ).astype(i32).sum()
+    # monotone while the occupants stay: a lane's wakes so far, summed
+    quiesce_wakes = jnp.where(occ, state.quiesce_epoch, 0).sum()
     big = jnp.iinfo(jnp.int32).max
     term_max = jnp.where(occ, state.term, 0).max()
     term_min = jnp.where(occupied > 0,
@@ -105,6 +117,7 @@ def _fleet_stats_impl(state, inbox_from) -> FleetStats:
     return FleetStats(
         occupied=occupied, role_count=role_count, leaderless=leaderless,
         election_active=election_active, quiesced=quiesced,
+        quiesced_by_word=quiesced_by_word, quiesce_wakes=quiesce_wakes,
         term_max=term_max,
         term_min=term_min, lag_hist=lag_hist, inbox_hist=inbox_hist)
 
@@ -125,6 +138,8 @@ def stats_to_dict(stats: FleetStats) -> dict:
         "leaderless": int(s.leaderless),
         "election_active": int(s.election_active),
         "quiesced": int(s.quiesced),
+        "quiesced_by_word": int(s.quiesced_by_word),
+        "quiesce_wakes": int(s.quiesce_wakes),
         "term_max": int(s.term_max),
         "term_min": int(s.term_min),
         "lag_hist": {lab: int(s.lag_hist[i])

@@ -890,22 +890,39 @@ def _shard_step(kp: P.KernelParams, s: ShardState, box, inp):
     # 0. host-confirmed applied cursor
     s = s._replace(applied=jnp.maximum(s.applied, inp.applied))
 
-    # 0b. device quiesce wake (quiesce.go:60-77 record): any non-heartbeat
-    # inbound message or client activity (proposal, read, transfer) wakes
-    # the lane, resets its idle clock and bumps the wake epoch the quiesce
-    # invariants key on.  Heartbeats never count as activity: while awake
-    # they must not defer quiesce entry (quiesce.go:64), and while
-    # masked-quiesced the handlers below still process them, so —
-    # divergence from the reference's grace-window wake — no wake is
-    # needed for state parity.  e_tick resets so a lane whose election
-    # clock banked up across quiesced ticks cannot campaign the instant
-    # it wakes.
+    # 0b. device quiesce: a peer's word, and the wake (quiesce.go:60-104,
+    # with quiesce.py's QuiesceState as the plain reference).  A group
+    # enters together: the replica whose idle clock crosses first says so
+    # to its peers in its heartbeat lanes (step 5b; MT.QUIESCE on the
+    # wire), and the word is no raft message: its slots are blanked
+    # before the handlers run, it carries no term and is no activity.
+    # Any non-heartbeat inbound message or client activity (proposal,
+    # read, transfer) wakes the lane, resets its idle clock and bumps the
+    # wake epoch the quiesce invariants key on.  Heartbeats never count
+    # as activity while awake (they must not defer quiesce entry,
+    # quiesce.go:64); a quiesced lane answers them asleep during the
+    # grace window of e_timeout ticks after its entry (trailing
+    # heartbeats of peers not yet in, quiesce.go:84-89) and is woken by
+    # one that comes later, so a leader that woke alone brings its group
+    # out.  The grace is read off e_tick, which entry zeroes and a
+    # quiesced tick advances.  e_tick resets on a wake so a lane whose
+    # election clock banked up across quiesced ticks cannot campaign the
+    # instant it wakes.
+    is_word = (box.mtype == MT.QUIESCE) & (box.from_ != 0)      # [K]
+    word = jnp.any(is_word)
+    box = jax.tree_util.tree_map(
+        lambda x: jnp.where(
+            is_word.reshape(is_word.shape + (1,) * (x.ndim - 1)),
+            jnp.zeros_like(x), x),
+        box)
     hb_like = (box.mtype == MT.HEARTBEAT) | (box.mtype == MT.HEARTBEAT_RESP)
+    arrived = box.from_ != 0
     activity = (
-        jnp.any((box.from_ != 0) & ~hb_like)
+        jnp.any(arrived & ~hb_like)
         | jnp.any(inp.prop_valid) | inp.ri_valid | (inp.transfer_to != 0)
     )
-    wake = s.quiesced & activity
+    late_hb = jnp.any(arrived & hb_like) & (s.e_tick >= s.e_timeout)
+    wake = s.quiesced & (activity | late_hb)
     s = mrep(s, wake, quiesced=False, idle_tick=0, e_tick=0,
              quiesce_epoch=s.quiesce_epoch + 1)
 
@@ -1107,19 +1124,33 @@ def _shard_step(kp: P.KernelParams, s: ShardState, box, inp):
                     eff.hb_high),
     )
 
-    # 5b. device quiesce idle clock + entry (quiesce.go:43-54 tick): an
-    # enabled, awake lane idle for e_timeout*10 ticks (quiesce.py
-    # threshold) raises its quiesced mask; entry clears both protocol
-    # clocks so neither an election nor a heartbeat fires mid-quiesce.
+    # 5b. device quiesce idle clock + entry (quiesce.go:43-54 tick,
+    # :96-104 tryEnterQuiesce): an enabled, awake lane idle for
+    # e_timeout*10 ticks (quiesce.py threshold) raises its quiesced mask
+    # and tells its peers (the send phase puts the word in its heartbeat
+    # lanes); an awake lane that hears a peer's word follows it.  A tick
+    # is a round of the lane's OWN engine and a group's three engines
+    # step at different rates, so the receiver is not held to upstream's
+    # wall-clock rule (no entry within threshold ticks of its last exit:
+    # under clocks a few percent apart that turns every re-entry into an
+    # election, the follower refusing the word of a leader that then
+    # goes silent): it follows the word once its own idle clock is half
+    # way, which a lane that just left quiesce, or just served anything,
+    # is not.  Entry clears both protocol clocks so neither an election
+    # nor a heartbeat fires mid-quiesce, and idle_tick stays where entry
+    # found it (at the threshold: own clock; under it: a peer's word).
     # Entry is evaluated AFTER this step's tick work, so the step that
     # crosses the threshold still ran live — the mask only gates future
     # steps, and the kernel stays bitwise-identical with quiesce_on off.
     s = mrep(s, inp.tick & ~activity & ~s.quiesced,
              idle_tick=s.idle_tick + 1)
     s = mrep(s, activity, idle_tick=0)
-    enter_q = (s.quiesce_on & ~s.quiesced & inp.tick
-               & (s.idle_tick >= s.e_timeout * 10))
-    s = mrep(s, enter_q, quiesced=True, e_tick=0, h_tick=0)
+    q_threshold = s.e_timeout * 10
+    enter_own = (s.quiesce_on & ~s.quiesced & inp.tick
+                 & (s.idle_tick >= q_threshold))
+    enter_peer = (s.quiesce_on & ~s.quiesced & word
+                  & (s.idle_tick * 2 >= q_threshold))
+    s = mrep(s, enter_own | enter_peer, quiesced=True, e_tick=0, h_tick=0)
 
     # 6. send phase ------------------------------------------------------
     is_leader = s.role == P.LEADER
@@ -1164,6 +1195,12 @@ def _shard_step(kp: P.KernelParams, s: ShardState, box, inp):
     )
     send_hb = eff.need_hb & is_leader & hb_target
     hb_commit = jnp.minimum(s.match, s.committed)
+    # the word of a lane that entered quiesce on its own clock rides the
+    # same lanes, to every peer whatever its kind, marked by a commit no
+    # heartbeat carries (params.QUIESCE_WORD); it replaces a heartbeat
+    # due in the same step, which the entry makes moot
+    send_hb = sel(enter_own, present & not_self, send_hb)
+    hb_commit = sel(enter_own, P.QUIESCE_WORD, hb_commit)
 
     # vote-request lanes — masked by END-OF-STEP role: a campaign started
     # earlier in the step may have been cancelled by a later message (e.g.

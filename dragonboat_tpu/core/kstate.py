@@ -400,13 +400,19 @@ class ShardState(NamedTuple):
     # (e.g. a peer needs an InstallSnapshot stream) — host must intervene
     needs_host: jnp.ndarray     # [G] bool
 
-    # device quiesce (quiesce.go state machine folded into the step):
-    # an enabled lane idle for e_timeout*10 ticks raises its quiesced
-    # mask and stops taking live ticks (no elections, no heartbeats);
-    # any non-heartbeat inbox or client activity wakes it and bumps
-    # quiesce_epoch (the wake counter the quiesce invariants key on)
+    # device quiesce (quiesce.go state machine folded into the step,
+    # quiesce.py its plain reference): an enabled lane idle for
+    # e_timeout*10 ticks raises its quiesced mask, tells its peers (the
+    # word rides its heartbeat lanes, params.QUIESCE_WORD) and stops
+    # taking live ticks (no elections, no heartbeats); an awake lane
+    # whose own idle clock is half way follows a peer's word; any
+    # non-heartbeat inbox or client activity, or a heartbeat more than
+    # e_timeout ticks after the entry, wakes it and bumps quiesce_epoch
+    # (the wake counter the quiesce invariants key on)
     quiesce_on: jnp.ndarray     # [G] bool — per-lane enable (Config.quiesce)
-    idle_tick: jnp.ndarray      # [G] i32 — ticks since last activity
+    # [G] i32 — ticks since last activity; frozen while quiesced (at the
+    # threshold: entered on its own clock; under it: on a peer's word)
+    idle_tick: jnp.ndarray
     quiesced: jnp.ndarray       # [G] bool — device-resident quiesced mask
     quiesce_epoch: jnp.ndarray  # [G] i32 — wakes so far (monotone)
 

@@ -110,6 +110,14 @@ R_SNAPSHOT = 3
 
 NO_LEADER = 0
 
+# What a heartbeat lane's commit reads when the lane carries not a
+# heartbeat but the word of a replica that entered quiesce on its own
+# idle clock (upstream's Quiesce message, node.go sendEnterQuiesceMessages):
+# a real heartbeat's commit is never negative.  The host spells the lane
+# MT.QUIESCE on the wire (engine/kernel_engine.py), the device router in
+# the inbox (core/router.py), and the kernel reads it there (step 0b).
+QUIESCE_WORD = -1
+
 
 import numpy as np
 

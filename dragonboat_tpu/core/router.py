@@ -53,6 +53,7 @@ SLOT_RESP0, SLOT_RESP1, SLOT_REP, SLOT_HB, SLOT_VOTE = range(SLOTS_PER_PEER)
 SLOT_OFFSETS_OF_TYPE = {
     int(MT.REPLICATE): (SLOT_REP,),
     int(MT.HEARTBEAT): (SLOT_HB,),
+    int(MT.QUIESCE): (SLOT_HB,),
     int(MT.REQUEST_VOTE): (SLOT_VOTE,),
     int(MT.REQUEST_PREVOTE): (SLOT_VOTE,),
     int(MT.TIMEOUT_NOW): (SLOT_VOTE,),
@@ -256,8 +257,12 @@ def route(kp: KP.KernelParams, replicas: int, out: StepOutput) -> Inbox:
         # heartbeat
         v = take(hb_valid)
         k_slot = base + SLOT_HB
+        # a heartbeat lane whose commit is the quiesce word carries that
+        # word, not a heartbeat (params.QUIESCE_WORD; the kernel reads
+        # nothing but the slot's type and sender)
         fields["mtype"] = fields["mtype"].at[:, :, k_slot].set(
-            jnp.where(v, MT.HEARTBEAT, 0))
+            jnp.where(v, jnp.where(take(hb_commit) == KP.QUIESCE_WORD,
+                                   MT.QUIESCE, MT.HEARTBEAT), 0))
         fields["from_"] = fields["from_"].at[:, :, k_slot].set(
             jnp.where(v, take(src_rid), 0))
         fields["term"] = fields["term"].at[:, :, k_slot].set(

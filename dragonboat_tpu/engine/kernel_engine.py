@@ -103,6 +103,9 @@ _KERNEL_MTYPES = frozenset({
     MT.REQUEST_VOTE, MT.REQUEST_VOTE_RESP, MT.REQUEST_PREVOTE,
     MT.REQUEST_PREVOTE_RESP, MT.TIMEOUT_NOW, MT.UNREACHABLE,
     MT.SNAPSHOT_STATUS,
+    # a peer's word that it entered quiesce: no raft message, read by the
+    # step's quiesce block alone (core/kernel.py 0b), in a heartbeat slot
+    MT.QUIESCE,
 })
 
 # column per message class in the download's [G, C] activity flags
@@ -201,6 +204,29 @@ _RETIRE_LANES = telemetry.GLOBAL.counter(
     labelnames=("path",))
 _RETIRED_COLUMNAR = _RETIRE_LANES.labels("columnar")
 _RETIRED_PER_LANE = _RETIRE_LANES.labels("per_lane")
+# what the kernel's quiesce did, read off the fleet digest (every
+# ``fleet_stats_every`` rounds; the counts are the digest's, the growths
+# are since the engine's last one)
+_FLEET_LANES = telemetry.GLOBAL.counter(
+    "engine_fleet_lanes",
+    help="at every fleet digest, the lanes it counted: occupied, and of "
+         "them quiesced (a window's ratio is the share of held lanes "
+         "that were asleep while it ran)",
+    labelnames=("what",))
+_FLEET_OCCUPIED = _FLEET_LANES.labels("occupied")
+_FLEET_QUIESCED = _FLEET_LANES.labels("quiesced")
+_QUIESCE_WAKES = telemetry.GLOBAL.counter(
+    "engine_quiesce_wakes",
+    help="lanes that left quiesce: at every fleet digest, the growth of "
+         "the resident quiesce_epoch column summed over occupied lanes")
+_QUIESCE_ENTERS = telemetry.GLOBAL.counter(
+    "engine_quiesce_enters",
+    help="lanes a fleet digest newly counted quiesced, by how they "
+         "entered: on their own idle clock, or on a peer's word (a lane "
+         "that entered and woke between two digests is not counted)",
+    labelnames=("how",))
+_ENTERED_OWN_CLOCK = _QUIESCE_ENTERS.labels("own_clock")
+_ENTERED_ON_WORD = _QUIESCE_ENTERS.labels("peer")
 _LANES_LIVE = telemetry.GLOBAL.gauge(
     "engine_lanes_live",
     help="lanes holding a replica a round can see, per engine",
@@ -1253,7 +1279,9 @@ class KernelEngine:
             props_deferred=self._props_deferred,
             reads_staged=self._reads_staged,
             lanes_staged=lanes_staged,
-            lanes_processed=self._lanes_processed, keys=list(keys))
+            lanes_processed=self._lanes_processed,
+            lanes_quiesced=(self.last_fleet or {}).get("quiesced", 0),
+            keys=list(keys))
 
     def _live_rows(self) -> np.ndarray:
         """The occupied lanes, in order (rebuilt after an injection or a
@@ -1330,7 +1358,22 @@ class KernelEngine:
         with _capacity.METER.sanctioned("fleet_down"):
             stats = self._cap_entries["fleet_stats"](
                 self._resident, self._fleet_inbox_from())
-            self.last_fleet = _fleet.stats_to_dict(stats)
+            was, now = self.last_fleet or {}, _fleet.stats_to_dict(stats)
+        self.last_fleet = now
+        _FLEET_OCCUPIED.inc(now["occupied"])
+        _FLEET_QUIESCED.inc(now["quiesced"])
+
+        def tallies(d):
+            on_word = d.get("quiesced_by_word", 0)
+            return (d.get("quiesce_wakes", 0), on_word,
+                    d.get("quiesced", 0) - on_word)
+
+        # the growth since this engine's last digest; a lane vacated
+        # takes its counts with it: never count down
+        for counter, count, before in zip(
+                (_QUIESCE_WAKES, _ENTERED_ON_WORD, _ENTERED_OWN_CLOCK),
+                tallies(now), tallies(was)):
+            counter.inc(max(0, count - before))
 
     def _make_health_digest(self):
         """Fresh all-zero digest matching the engine's lane geometry,
@@ -1597,7 +1640,9 @@ class KernelEngine:
                 if not inbox.add(g, m, n):
                     requeue.append(m)
                 work = True
-            # other local/quiesce messages: ignored on the kernel path
+            # other local messages: ignored on the kernel path (a peer's
+            # QUIESCE word is a kernel type: the lane follows it on the
+            # device, as Node does through QuiesceState)
         if requeue:
             with n.mu:
                 n.incoming_msgs = requeue + n.incoming_msgs
@@ -1952,10 +1997,17 @@ class KernelEngine:
                         log_term=row[s_prev_term + p], log_index=prev,
                         commit=row[s_commit + p], entries=entries)))
                 if hb and row[s_hb + p]:
-                    others.append((n, _message(
-                        MT.HEARTBEAT, to, n, term=term,
-                        commit=row[s_hb_commit + p], hint=row[s_hb_low + p],
-                        hint_high=row[s_hb_high + p])))
+                    if row[s_hb_commit + p] == KP.QUIESCE_WORD:
+                        # the lane entered quiesce on its own idle clock
+                        # and tells its peers (node.go
+                        # sendEnterQuiesceMessages)
+                        others.append((n, _message(MT.QUIESCE, to, n)))
+                    else:
+                        others.append((n, _message(
+                            MT.HEARTBEAT, to, n, term=term,
+                            commit=row[s_hb_commit + p],
+                            hint=row[s_hb_low + p],
+                            hint_high=row[s_hb_high + p])))
                 if vote and row[s_vote + p]:
                     others.append((n, _message(
                         MT.REQUEST_VOTE if row[s_vote + p] == 1
@@ -2299,6 +2351,7 @@ class KernelEngine:
 _FAMILY_OF_TYPE = {
     int(pb.MessageType.REPLICATE): "rep",
     int(pb.MessageType.HEARTBEAT): "hb",
+    int(pb.MessageType.QUIESCE): "hb",
     int(pb.MessageType.REQUEST_VOTE): "vote",
     int(pb.MessageType.REQUEST_PREVOTE): "vote",
     int(pb.MessageType.TIMEOUT_NOW): "vote",

@@ -94,10 +94,13 @@ SCOPES = {
     # by kernel-source hash in analysis/safety.py)
     "fast": dict(depth=3, max_states=600),
     "deep": dict(depth=5, max_states=20000),
-    # quiesced=True seeds with a banked election clock: the natural
-    # entry path needs e_timeout*10 idle ticks — unreachable at these
-    # depths — so the scope seeds the mask directly and checks the
-    # quiesced_no_campaign / quiesced_no_vote invariants
+    # quiesced=True seeds with a banked election clock: e_timeout*10 idle
+    # ticks are unreachable at these depths, so two seeds raise the mask
+    # directly and check the quiesced_no_campaign / quiesced_no_vote
+    # invariants; a third stands one tick short of the threshold, so the
+    # entry itself is reached naturally: the leader crosses on its own
+    # clock, tells its peers, and they follow the word
+    # (quiesce_entry_tells_peers / quiesce_word_is_followed)
     "quiesce": dict(depth=3, max_states=600, quiesce=True),
 }
 
@@ -138,6 +141,14 @@ MUTATIONS = {
     "quiesce_campaigns": (
         "    q_any = inp.quiesced | s.quiesced\n",
         "    q_any = inp.quiesced\n",
+    ),
+    # a lane that enters quiesce on its own clock keeps it to itself:
+    # its followers are left awake beside a leader gone silent, and the
+    # first to time out moves the term (caught by
+    # quiesce_entry_tells_peers on the quiesce scope's near-entry seed)
+    "quiesce_word_unsent": (
+        "    send_hb = sel(enter_own, present & not_self, send_hb)\n",
+        "    send_hb = sel(enter_own, jnp.zeros_like(send_hb), send_hb)\n",
     ),
 }
 
@@ -249,7 +260,12 @@ def collect_messages(out, kp) -> list:
                              int(o["s_prev_term"][g, p]),
                              int(o["s_prev_index"][g, p]),
                              int(o["s_commit"][g, p]), 0, 0, 0, ents))
-            if bool(o["s_hb"][g, p]):
+            if bool(o["s_hb"][g, p]) and (
+                    int(o["s_hb_commit"][g, p]) == KP.QUIESCE_WORD):
+                # the row entered quiesce on its own clock and says so
+                msgs.append((int(MT.QUIESCE), my, to, 0, 0, 0, 0, 0, 0, 0,
+                             ()))
+            elif bool(o["s_hb"][g, p]):
                 msgs.append((int(MT.HEARTBEAT), my, to, int(o["term"][g]),
                              0, 0, int(o["s_hb_commit"][g, p]), 0,
                              int(o["s_hb_low"][g, p]),
@@ -445,6 +461,13 @@ class ModelChecker:
                             f"rows {r}/{q} disagree on committed entry {i}")
                         break
 
+        # a group enters quiesce together (core/kernel.py 0b / 5b): the
+        # row whose idle clock crosses tells every peer in the step that
+        # takes it in, and an awake row whose own clock is half way
+        # follows a word delivered to it alone
+        if prev is not None and self.scope.get("quiesce"):
+            self._check_quiesce_entry(node, prev, action)
+
         # declared INVARIANTS via the runtime probe's python oracle
         inv_fields = sorted({f for iv in inv_mod.PARSED.values()
                              for f in iv.fields})
@@ -465,6 +488,35 @@ class ModelChecker:
                     self._violate(
                         "invariant:" + iv.name, node,
                         f"row {r} violates {iv.name} ({action})")
+
+    def _check_quiesce_entry(self, node: Node, prev: Node,
+                             action: str) -> None:
+        a, pa = node.arrs, prev.arrs
+        for r in range(N_REP):
+            threshold = int(a["e_timeout"][r]) * 10
+            entered = bool(a["quiesced"][r]) and not bool(pa["quiesced"][r])
+            if (entered and int(a["idle_tick"][r]) >= threshold
+                    and r != node.isolated and len(node.net) < NET_CAP):
+                for q in range(N_REP):
+                    word = (int(MT.QUIESCE), r + 1, q + 1, 0, 0, 0, 0, 0,
+                            0, 0, ())
+                    if (q != r and q != node.isolated
+                            and word not in node.net):
+                        self._violate(
+                            "quiesce_entry_tells_peers", node,
+                            f"row {r} entered quiesce on its own clock "
+                            f"and sent row {q} no word ({action})")
+            if (action.startswith("deliver QUIESCE:")
+                    and action.endswith(f"->{r + 1}")):
+                due = (bool(pa["quiesce_on"][r]) and not bool(
+                    pa["quiesced"][r])
+                    and int(pa["idle_tick"][r]) * 2 >= threshold)
+                if due and not bool(a["quiesced"][r]):
+                    self._violate(
+                        "quiesce_word_is_followed", node,
+                        f"row {r}, awake and idle "
+                        f"{int(pa['idle_tick'][r])} ticks, did not follow "
+                        f"the word ({action})")
 
     # -- successor generation --------------------------------------------
     def successors(self, node: Node):
@@ -596,6 +648,19 @@ class ModelChecker:
                 arrs=arrs, net=base.net, isolated=-1, part_used=False,
                 depth=0, leaders=dict(base.leaders),
                 trail=(f"seed:quiesced{i}",)))
+        # one tick short of a natural entry: the settled group of the
+        # entry-committed seed, awake, the leader's idle clock at the
+        # threshold less one and the followers' (slower engines') two
+        # thirds of the way
+        base = seeds[-2]
+        arrs = {f: a.copy() for f, a in base.arrs.items()}
+        arrs["quiesce_on"][:] = True
+        leader = int(np.argmax(arrs["role"] == KP.LEADER))
+        arrs["idle_tick"][:] = ELECTION_TIMEOUT * 10 * 2 // 3
+        arrs["idle_tick"][leader] = ELECTION_TIMEOUT * 10 - 1
+        out.append(Node(
+            arrs=arrs, net=(), isolated=-1, part_used=False, depth=0,
+            leaders=dict(base.leaders), trail=("seed:near_entry",)))
         return out
 
     # -- BFS --------------------------------------------------------------
@@ -645,6 +710,8 @@ class ModelChecker:
             properties=["election_safety", "leader_append_only",
                         "log_matching", "leader_completeness",
                         "state_machine_safety"]
+            + (["quiesce_entry_tells_peers", "quiesce_word_is_followed"]
+               if self.scope.get("quiesce") else [])
             + ["invariant:" + n for n in inv_mod.INVARIANT_NAMES],
         )
 

@@ -86,6 +86,12 @@ _RETRY_MODULES = (
     # or with followers one entry behind for the whole 45 s convergence
     # window (PERF.md, Open questions).  It also runs last (below).
     "test_chaos_hotspot",
+    # added in PR 35 for its part B alone (three NodeHosts whose clocks
+    # differ by 30%, through both paths, asserting that NO term moves over
+    # ~1.5 s of wall time: beside busy workers a starved follower's
+    # election is load, not the subject); part A is the deterministic form
+    # of the same story and fails twice where it fails
+    "test_quiesce_group",
 )
 
 # module -> number of tests that needed the second attempt, THIS process.

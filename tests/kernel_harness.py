@@ -127,7 +127,11 @@ class KernelCluster:
                         log_index=int(o["s_prev_index"][g, p_]),
                         commit=int(o["s_commit"][g, p_]), ents=ents,
                     ))
-                if bool(o["s_hb"][g, p_]):
+                if bool(o["s_hb"][g, p_]) and (
+                        int(o["s_hb_commit"][g, p_]) == KP.QUIESCE_WORD):
+                    # the lane entered quiesce on its own clock and says so
+                    deliver(to_rid, Msg(MT.QUIESCE, my_rid, to_rid, 0))
+                elif bool(o["s_hb"][g, p_]):
                     deliver(to_rid, Msg(
                         MT.HEARTBEAT, my_rid, to_rid, int(o["term"][g]),
                         commit=int(o["s_hb_commit"][g, p_]),
@@ -187,13 +191,13 @@ class KernelCluster:
 
     def step(self, tick=False, proposals=None, reads=None, transfers=None,
              applied_sync=True):
-        """One kernel step. proposals: {row: n_entries or [(is_cc)...]},
+        """One kernel step. tick: every row's, or [G] bool (rows whose
+        engines tick at their own rates). proposals: {row: n_entries or [(is_cc)...]},
         reads: {row: (low, high)}, transfers: {row: target_rid}."""
         inp = empty_input(self.kp, self.G)
         d = {k: (np.asarray(v).copy() if v is not None else None)
              for k, v in inp._asdict().items()}
-        if tick:
-            d["tick"][:] = True
+        d["tick"][:] = tick         # one bool, or one a row
         if proposals:
             for row, spec in proposals.items():
                 if isinstance(spec, int):

@@ -10,7 +10,11 @@ import importlib.util
 import os
 import sys
 
+import numpy as np
 import pytest
+
+from dragonboat_tpu import raftpb as pb
+from dragonboat_tpu.core import params as KP
 
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
@@ -76,6 +80,39 @@ def test_quiesce_scope_clean_and_catches_masked_campaign():
     assert mut["violations"][0]["trail"]
 
 
+def test_quiesce_scope_reaches_the_entry_and_catches_an_unsent_word():
+    """The scope's third seed stands one tick short of the threshold, so
+    the entry is reached naturally: the leader crosses on its own clock
+    and tells both peers in that step, and a follower whose own clock is
+    two thirds of the way follows the word when it is delivered.  A
+    kernel whose entering lane keeps the word to itself is caught."""
+    res = mc.run_scope("quiesce")
+    assert res["violations"] == [], res["violations"]
+    assert {"quiesce_entry_tells_peers",
+            "quiesce_word_is_followed"} <= set(res["properties"])
+    checker = mc.ModelChecker(scope="quiesce")
+    near = next(s for s in checker.seeds() if s.trail == ("seed:near_entry",))
+    leader = int(np.argmax(near.arrs["role"] == KP.LEADER))
+    (_, entered), = [(a, n) for a, n in checker.successors(near)
+                     if a == "tick"]
+    assert entered.arrs["quiesced"].tolist() == [
+        r == leader for r in range(3)]
+    words = [m for m in entered.net if m[0] == int(pb.MessageType.QUIESCE)]
+    assert sorted(m[2] - 1 for m in words) == [
+        r for r in range(3) if r != leader]
+    follower = words[0][2] - 1
+    (_, followed), = [(a, n) for a, n in checker.successors(entered)
+                      if a.startswith("deliver QUIESCE")
+                      and a.endswith(f"->{follower + 1}")]
+    assert followed.arrs["quiesced"][follower]
+    assert followed.arrs["term"].tolist() == near.arrs["term"].tolist()
+
+    mut = mc.run_scope("quiesce", mutation="quiesce_word_unsent")
+    names = {v["property"] for v in mut["violations"]}
+    assert "quiesce_entry_tells_peers" in names, mut["violations"][:3]
+    assert mut["violations"][0]["trail"][0] == "seed:near_entry"
+
+
 def test_mutation_snippets_track_kernel_source():
     src = open(os.path.join(
         REPO, "dragonboat_tpu", "core", "kernel.py")).read()
@@ -97,5 +134,6 @@ def test_every_seeded_bug_is_caught_by_some_leg():
     fall through both legs."""
     from tests.test_safety import STATIC_OWNER
 
-    checker_owned = {"double_vote", "quiesce_campaigns"}
+    checker_owned = {"double_vote", "quiesce_campaigns",
+                     "quiesce_word_unsent"}
     assert set(mc.MUTATIONS) == checker_owned | set(STATIC_OWNER)
