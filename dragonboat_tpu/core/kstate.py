@@ -736,14 +736,14 @@ class StepOutput(NamedTuple):
 #
 # An engine round sends ONE [G, Wu] int32 array up (the staged Inbox and
 # StepInput) and reads ONE [G, Wd] int32 array down (the activity flags,
-# every StepOutput field, the save window's terms).  Both layouts are
-# derived here, from CONTRACTS and the geometry, so the host builders, the
-# jitted program's unpack/pack (core/round.py, parallel/round.py) and the
-# host view of the download cannot drift: a field is ``width`` consecutive
-# columns from ``start``, its trailing ``shape`` flattened row-major; a
-# bool rides as a 0/1 column.  Bytes are not the cost of a crossing, the
-# crossing is (PERF.md section 5): rows are not compacted, nothing is
-# bit-packed.
+# the ``active`` column, every StepOutput field, the save window's terms).
+# Both layouts are derived here, from CONTRACTS and the geometry, so the
+# host builders, the jitted program's unpack/pack (core/round.py,
+# parallel/round.py) and the host view of the download cannot drift: a
+# field is ``width`` consecutive columns from ``start``, its trailing
+# ``shape`` flattened row-major; a bool rides as a 0/1 column.  Bytes are
+# not the cost of a crossing, the crossing is (PERF.md section 5): rows are
+# not compacted, nothing is bit-packed.
 # ---------------------------------------------------------------------------
 
 # Message-class order of the download's leading flag columns (what
@@ -751,6 +751,15 @@ class StepOutput(NamedTuple):
 # to decide which of a row's message fields to read at all.
 FLAG_CLASSES = ("resp", "rep", "hb", "vote", "timeout_now",
                 "need_snapshot", "wit_snap", "rtr")
+
+# Bits of the download's ``active`` column (core/round.py ``row_activity``
+# writes it; the engine retires the rows where it is not 0): the row's
+# output holds something to do (a flag, a dropped read, an escalation, a
+# save or apply window that is not empty); its (term, vote, commit) moved
+# in this step (persisted even when no message goes out); its leader or
+# term moved (the leader edge).  A row nothing reaches (no tick, no
+# message) reads 0.
+ACTIVE_OUTPUT, ACTIVE_TRIPLE, ACTIVE_LEADER = 1, 2, 4
 
 #: symbolic contract axis -> the KernelParams field holding its extent
 #: (G is the free variable; the capacity model sizes by the same table)
@@ -769,7 +778,7 @@ class Column(NamedTuple):
 class RoundColumns(NamedTuple):
     up: tuple            # Inbox fields, then StepInput fields
     up_width: int
-    down: tuple          # "flags", StepOutput fields, "save_terms"
+    down: tuple          # "flags", "active", StepOutput fields, "save_terms"
     down_width: int
     save_window: int     # S
 
@@ -812,10 +821,11 @@ def round_columns(kp) -> RoundColumns:
     s = save_window(kp)
     flags = Column("flags", 0, len(FLAG_CLASSES), "bool",
                    (len(FLAG_CLASSES),))
-    out, w = _class_columns(StepOutput, kp, flags.width)
+    active = Column("active", flags.width, 1, "i32", ())
+    out, w = _class_columns(StepOutput, kp, flags.width + 1)
     terms = Column("save_terms", w, s, "i32", (s,))
     return RoundColumns(up=tuple(box + inp), up_width=wu,
-                        down=(flags, *out, terms),
+                        down=(flags, active, *out, terms),
                         down_width=w + s, save_window=s)
 
 
@@ -857,10 +867,11 @@ def unpack_upload(kp, up) -> tuple[Inbox, StepInput]:
             unpack_columns(StepInput, cols, up))
 
 
-def pack_download(kp, flags, out: StepOutput, save_terms):
+def pack_download(kp, flags, active, out: StepOutput, save_terms):
     return pack_columns(
         round_columns(kp).down,
-        {**out._asdict(), "flags": flags, "save_terms": save_terms})
+        {**out._asdict(), "flags": flags, "active": active,
+         "save_terms": save_terms})
 
 
 # ---------------------------------------------------------------------------

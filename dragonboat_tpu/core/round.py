@@ -23,6 +23,9 @@ import jax.numpy as jnp
 
 from dragonboat_tpu.core.kernel import output_row_flags
 from dragonboat_tpu.core.kstate import (
+    ACTIVE_LEADER,
+    ACTIVE_OUTPUT,
+    ACTIVE_TRIPLE,
     pack_download,
     pack_state,
     round_columns,
@@ -59,18 +62,47 @@ def ring_window(ring, first, size: int):
     return two[:, :size]
 
 
-def pack_round(kp, state, out):
-    """The round's download: the activity flags, every StepOutput field
-    and, from the state the step returned, the terms of the ``S`` ring
-    entries from ``save_first`` on (what ``_build_update`` persists)."""
+def row_activity(flags, out, was, now):
+    """[G] int32, the download's ``active`` column (kstate.py ``ACTIVE_*``):
+    which rows the host has to retire, and why.  ``was`` is the state the
+    step was given and ``now`` the one it returned: the host retires every
+    row whose triple or leader moved in the round it moved in, so "moved in
+    this step" is "differs from what the host last saw" and the host keeps
+    no copy to compare with (the one row no step accounts for, a replica
+    placed at a term > 0, the engine names itself: ``ctx.injected``).
+    Elementwise over [G] (shards along G with no collective).  Nothing
+    here asks whether a replica is placed in the row: a row's peer book
+    does not say (a replica that joins is placed with an empty one and
+    answers its leader all the same), a row that gets no tick and no
+    message moves nothing and reads 0, and the host drops a named row it
+    holds no replica in (a vacated mesh row a peer still writes to)."""
+    output = (jnp.any(flags, axis=1) | out.ri_dropped | out.needs_host
+              | (out.save_last >= out.save_first)
+              | (out.apply_last >= out.apply_first))
+    term = now.term != was.term
+    triple = term | (now.vote != was.vote) | (now.committed != was.committed)
+    leader = term | (now.leader != was.leader)
+    return (jnp.where(output, ACTIVE_OUTPUT, 0)
+            | jnp.where(triple, ACTIVE_TRIPLE, 0)
+            | jnp.where(leader, ACTIVE_LEADER, 0)).astype(I32)
+
+
+def pack_round(kp, was, state, out):
+    """The round's download: the activity flags, the ``active`` column
+    (``was``: the state the step was given), every StepOutput field and,
+    from the state the step returned, the terms of the ``S`` ring entries
+    from ``save_first`` on (what ``_build_update`` persists)."""
     terms = ring_window(state.lt, out.save_first, round_columns(kp).save_window)
-    return pack_download(kp, output_row_flags(out), out, terms)
+    flags = output_row_flags(out)
+    return pack_download(kp, flags, row_activity(flags, out, was, state),
+                         out, terms)
 
 
 def _round(kp, step_fn, state, up):
     inbox, inp = unpack_upload(kp, up)
-    s, out = step_fn(kp, unpack_state(kp, state), inbox, inp)
-    return pack_state(kp, s), pack_round(kp, s, out)
+    was = unpack_state(kp, state)
+    s, out = step_fn(kp, was, inbox, inp)
+    return pack_state(kp, s), pack_round(kp, was, s, out)
 
 
 @functools.partial(jax.jit, static_argnums=(0, 1))

@@ -390,7 +390,7 @@ class SerialDispatch:
         """No device-resident inbox: nothing carries between steps."""
         return False
 
-    def note_output_flags(self, flags) -> None:
+    def note_output_flags(self, rows) -> None:
         """No carried inbox, so retired activity flags carry no drain
         information here; MeshDispatch derives pending() from them."""
 
@@ -491,16 +491,18 @@ class MeshDispatch:
     def pending(self) -> bool:
         return self._pending_msgs
 
-    def note_output_flags(self, flags) -> None:
-        """Derive drain-pending from the retired step's [G, C] activity
-        flags (already host-side — no extra crossing): any messaging
-        class set means the exchange routed traffic into the carried
-        inbox (or the hub is about to carry it), so the next step has
-        work.  Conservative under cut links — flags are computed from
-        the unmasked output, so a fully-cut row costs at most one idle
-        step — and never an undercount: the carried inbox only ever
-        holds routed copies of flagged output lanes."""
-        self._pending_msgs = bool(flags[:, _MSG_FLAG_COLS].any())
+    def note_output_flags(self, rows) -> None:
+        """Derive drain-pending from the retired step's candidate rows
+        (``_Retiring.cells``: lists, already host-side, whose leading
+        cells are the activity flags; a row that is no candidate has no
+        flag set): any messaging class set means the exchange routed
+        traffic into the carried inbox (or the hub is about to carry it),
+        so the next step has work.  Conservative under cut links — flags
+        are computed from the unmasked output, so a fully-cut row costs
+        at most one idle step — and never an undercount: the carried
+        inbox only ever holds routed copies of flagged output lanes."""
+        self._pending_msgs = any(
+            row[c] for row in rows for c in _MSG_FLAG_COLS)
 
     def inbox_from(self, inbox_buf):
         # the mesh inbox is device-resident between steps; no host copy

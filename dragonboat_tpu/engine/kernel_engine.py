@@ -71,6 +71,7 @@ from dragonboat_tpu.core import params as KP
 from dragonboat_tpu.core.kernel import step as kernel_step
 from dragonboat_tpu.core import router as _router
 from dragonboat_tpu.core.kstate import (
+    ACTIVE_TRIPLE,
     FLAG_CLASSES,
     ShardState,
     column_value,
@@ -113,15 +114,6 @@ _KERNEL_MTYPES = frozenset({
 _F = {c: i for i, c in enumerate(FLAG_CLASSES)}
 _F_RESP, _F_REP, _F_HB, _F_VOTE = _F["resp"], _F["rep"], _F["hb"], _F["vote"]
 _F_TIMEOUT, _F_WITSNAP, _F_RTR = _F["timeout_now"], _F["wit_snap"], _F["rtr"]
-# the activity mask's columns (``KernelEngine._mask_cols``): the flags and
-# the two escalation bits (any of them set), the two windows' bounds, and
-# the five cells compared with what the host last saw (``_seen_np``)
-_MASK_FIELDS = ("ri_dropped", "needs_host", "save_first", "save_last",
-                "apply_first", "apply_last", "term", "vote", "commit",
-                "leader", "leader_term")
-_M_BITS = len(FLAG_CLASSES) + 2
-_M_SAVE_FIRST, _M_SAVE_LAST, _M_APPLY_FIRST, _M_APPLY_LAST, _M_SEEN = range(
-    _M_BITS, _M_BITS + 5)
 
 # admission at the staging boundary, all engines of the process
 # (telemetry.GLOBAL, where the round timer's histograms live)
@@ -204,6 +196,16 @@ _RETIRE_LANES = telemetry.GLOBAL.counter(
     labelnames=("path",))
 _RETIRED_COLUMNAR = _RETIRE_LANES.labels("columnar")
 _RETIRED_PER_LANE = _RETIRE_LANES.labels("per_lane")
+# and who named them: the download's ``active`` column, or only the host
+# (a row that staged proposals and came back with nothing else to do)
+_RETIRE_NAMED = telemetry.GLOBAL.counter(
+    "engine_retire_named",
+    help="lanes the output pass processed, by who named them: device "
+         "(the download's active column) or host (staged rows the column "
+         "left out); a falling device share says the host guesses again",
+    labelnames=("by",))
+_NAMED_BY_DEVICE = _RETIRE_NAMED.labels("device")
+_NAMED_BY_HOST = _RETIRE_NAMED.labels("host")
 # what the kernel's quiesce did, read off the fleet digest (every
 # ``fleet_stats_every`` rounds; the counts are the digest's, the growths
 # are since the engine's last one)
@@ -361,6 +363,8 @@ class _StepCtx:
     staged_rows: set[int]
     out: object = None                      # packed download, on device (async)
     dead: set[int] = field(default_factory=set)   # rows removed in flight
+    # rows placed at a term > 0 since the step before (``_injected``)
+    injected: set[int] = field(default_factory=set)
     # lifecycle-sampled proposal keys riding this step (dispatch/retire
     # stamps); keys of rows scrubbed in flight stay here harmlessly —
     # stamp() is a no-op once the book's dropped() scrubbed the span
@@ -562,26 +566,11 @@ class KernelEngine:
         self.nodes: dict[int, KernelNode] = {}     # lane -> node
         self.by_shard: dict[int, KernelNode] = {}
         self._free = list(range(capacity - 1, -1, -1))
-        # what the host last saw of each lane, one [capacity, 5] array the
-        # outputs pass compares a round's download with in one expression:
-        # (term, vote, commit) as persisted (-1 rows = absent lane: the
-        # first real triple always differs) and the leader caches
-        self._seen_np = np.zeros((capacity, 5), np.int64)
-        self._triple_np = self._seen_np[:, :3]
-        self._triple_np[:] = -1
-        self._lead_np = self._seen_np[:, 3]
-        self._lead_term_np = self._seen_np[:, 4]
         # lanes with possibly-pending host work (see mark_dirty); its
         # own tiny lock — NOT engine.mu (ingress holds node.mu and the
         # documented order is engine.mu -> node.mu)
         self._dirty: set[int] = set()
         self._dirty_mu = threading.Lock()
-        # occupancy for the output activity mask (absent lanes must not
-        # pass it — the -1 triple sentinel vs device term 0 would make
-        # every empty lane "active" forever), and the occupied lanes as an
-        # index array (None: stale, see _live_rows)
-        self._occ_np = np.zeros((capacity,), bool)
-        self._live: np.ndarray | None = None
         # the applied cursor each lane's device state has been sent: the
         # device gates campaigns and compaction on it, so a lane whose RSM
         # has applied further is work for a round even when nothing else is
@@ -613,6 +602,12 @@ class KernelEngine:
         # taken admissions awaiting this step's batched injection
         # (lane -> (node, init, pids, kinds, start)); see _flush_injections
         self._pending_inject: dict[int, tuple] = {}
+        # rows written into the state at a term > 0 (a founder's bootstrap
+        # term is 1; a replica that starts again has the term it saved)
+        # since the last dispatch: candidates of that step's output pass
+        # whatever its ``active`` column says, for the leader edge
+        # (0 -> term) that no step moves
+        self._injected: set[int] = set()
         self._inject_fn = None      # inject_rows jitted for this state
         # whole-engine tick rounds queued by the host ticker; each step
         # consumes ONE round as a vectorized [G]-bool broadcast (the
@@ -641,9 +636,6 @@ class KernelEngine:
         # where each field of the download starts in a row (the output
         # pass reads a candidate row as a list, at these offsets)
         self._at = {c.field: c.start for c in self._cols.down}
-        self._mask_cols = np.array(
-            [*range(len(FLAG_CLASSES)),
-             *(self._at[f] for f in _MASK_FIELDS)], np.intp)
         self._bufs = tuple(
             _RoundStaging(kp, capacity, mesh_replicas=mesh_r)
             for _ in range(2))
@@ -871,8 +863,8 @@ class KernelEngine:
         queued lane in ONE vectorized state update.  The eager form was
         ~30 full-[capacity] array copies PER admission — O(n·capacity)
         total, the first structure to fall over at 100k groups.  Host
-        bookkeeping (kind cache, payload mirror, writeback triple) is
-        done here so non-state readers see the shard immediately."""
+        bookkeeping (kind cache, payload mirror) is done here so non-state
+        readers see the shard immediately."""
         kp = self.kp
         pids = np.zeros((kp.num_peers,), np.int32)
         kinds = np.zeros((kp.num_peers,), np.int32)
@@ -882,11 +874,6 @@ class KernelEngine:
         self._pid_np[lane] = pids
         for e in init.entries:
             node.mirror[e.index] = e
-        self._triple_np[lane] = (init.term, init.vote, init.committed)
-        self._lead_np[lane] = 0
-        self._lead_term_np[lane] = 0
-        self._occ_np[lane] = True
-        self._live = None
         self._applied_sent_np[lane] = init.applied
         self._pending_inject[lane] = (node, init, pids, kinds, t0)
         self._inv_dirty.add(lane)
@@ -954,6 +941,8 @@ class KernelEngine:
             rows["snap_term"][j] = init.snap_term
             rows["last"][j] = last
             rows["committed"][j] = init.committed
+            if init.term:
+                self._injected.add(lane)
         # The batch is padded with copies of its last lane (a copy writes
         # the same values to the same row) to a power of two and at least
         # _INJECT_BATCH, so the program compiles once per size class and an
@@ -985,14 +974,12 @@ class KernelEngine:
 
     def _clear_lane(self, lane: int) -> None:
         self._inv_dirty.add(lane)
+        self._injected.discard(lane)
         if self._pending_inject.pop(lane, None) is not None:
             # evicted before its injection ever flushed: the lane state
             # was never written, so there is nothing to clear on device
             self._kind_np[lane] = KP.K_ABSENT
             self._pid_np[lane] = 0
-            self._triple_np[lane] = -1
-            self._occ_np[lane] = False
-            self._live = None
             return
         self._write_cells((
             (lane, "kind", KP.K_ABSENT), (lane, "pid", 0),
@@ -1002,9 +989,6 @@ class KernelEngine:
         ), "lane_clear_up")
         self._kind_np[lane] = KP.K_ABSENT
         self._pid_np[lane] = 0
-        self._triple_np[lane] = -1
-        self._occ_np[lane] = False
-        self._live = None
 
     def update_lane_membership(self, node: KernelNode) -> None:
         """Re-derive the lane's peer book from the RSM membership (host
@@ -1193,7 +1177,9 @@ class KernelEngine:
                 staged_ri={g: n._staged_ri for g, n in staged
                            if n._staged_ri is not None},
                 staged_rows=set(self._staged_rows),
+                injected=self._injected,
             )
+            self._injected = set()
             if lifecycle.TRACER.enabled:
                 ctx.traced = [e.key for fl in ctx.fates.values()
                               for e, _origin in fl
@@ -1282,13 +1268,6 @@ class KernelEngine:
             lanes_processed=self._lanes_processed,
             lanes_quiesced=(self.last_fleet or {}).get("quiesced", 0),
             keys=list(keys))
-
-    def _live_rows(self) -> np.ndarray:
-        """The occupied lanes, in order (rebuilt after an injection or a
-        cleared lane)."""
-        if self._live is None:
-            self._live = np.nonzero(self._occ_np)[0]
-        return self._live
 
     def _is_registered(self, n: KernelNode) -> bool:
         # identity, not membership: with a deferred (pipelined) output
@@ -1778,17 +1757,17 @@ class KernelEngine:
 
         The fetch is ONE download: the [G, Wd] int32 array the step's
         program ended by writing (core/round.py ``pack_round``: activity
-        flags, every StepOutput field, the save window's terms).
-        Everything below is host work on that array, and it reads the
-        array ONCE after the activity mask: the candidate rows are
-        gathered and turned into lists of Python ints (``_Retiring``), and
-        what follows indexes those lists at the column table's offsets
-        and does for a lane only what its row says happened (a class of
-        message whose flag is set, a save or apply window that is not
-        empty, a leader that moved).  Read a cell at a time, a round of
-        220 lanes made 9,000 numpy scalar reads and built 430 messages a
-        field at a time; read a numpy call a field, a round let the
-        interpreter go at every call (PERF.md section 6, PR 32).  The
+        flags, the ``active`` column, every StepOutput field, the save
+        window's terms).  Everything below is host work on that array,
+        and it reads the array ONCE after the ``active`` column: the
+        candidate rows are gathered and turned into lists of Python ints
+        (``_Retiring``), and what follows indexes those lists at the
+        column table's offsets and does for a lane only what its row says
+        happened (a class of message whose flag is set, a save or apply
+        window that is not empty, a leader that moved).  Read a cell at a
+        time, a round of 220 lanes made 9,000 numpy scalar reads and built
+        430 messages a field at a time; read a numpy call a field, a round
+        let the interpreter go at every call (PERF.md section 6, PR 32).  The
         rare classes (witness snapshots, ReadIndex completions and drops,
         config changes, escalation, a save window past ``S``) keep
         per-lane handlers on the numpy view of the same rows: a lane one
@@ -1798,33 +1777,31 @@ class KernelEngine:
         for k in ctx.traced:
             lifecycle.TRACER.stamp(k, lifecycle.STAGE_RETIRE)
         # fetch: the download (where the host waits for the device) and
-        # the activity mask built from it
+        # the rows it names
         rt.enter("fetch")
         with _capacity.METER.sanctioned("round_down"):
             host = np.asarray(ctx.out)
-        # lanes with anything to process, found VECTORIZED (per-lane
-        # Python here was 16 us/lane/step at 100k lanes) over the OCCUPIED
-        # rows' mask columns, gathered first: a numpy call over all
-        # [capacity] rows lets the interpreter go, and twenty of them a
-        # round were 28 ms of a 256-lane round's fetch (PERF.md section 6,
-        # PR 32).  The mask must cover every consumer below: emitted
+        # lanes with anything to process are the rows whose ``active``
+        # cell is not 0.  The round's program computed it (core/round.py
+        # ``row_activity``) and it covers every consumer below: emitted
         # messages and snapshot needs (all eight flag columns), dropped
         # reads (_complete_reads) and escalation flags, save/apply windows
         # and quiet term/vote/commit changes (_build_updates persists a
-        # bump even when no message went out), leader-cache deltas
-        # (_leader_edge); staged proposal fates ride ctx.staged_rows below.
-        live = self._live_rows()
-        m = host[live[:, None], self._mask_cols]
-        # the dispatch backend derives drain-pending from the same flags
-        # (MeshDispatch dropped its per-step pending-scalar download)
-        self._dispatch.note_output_flags(m[:, :len(FLAG_CLASSES)])
-        active = (
-            m[:, :_M_BITS].any(1)
-            | (m[:, _M_SAVE_LAST] >= m[:, _M_SAVE_FIRST])
-            | (m[:, _M_APPLY_LAST] >= m[:, _M_APPLY_FIRST])
-            | (m[:, _M_SEEN:] != self._seen_np[live]).any(1))
-        cand_ids = set(live[active].tolist())
+        # bump even when no message went out), leader moves
+        # (_leader_edge); staged proposal fates ride ctx.staged_rows
+        # below, and a replica placed at a term > 0 its first pass
+        # (ctx.injected: its leader edge is 0 -> that term, and no step
+        # moved it).
+        # ONE ``tolist`` of one column, the interpreter held: the host
+        # built this mask itself once, a dozen numpy calls over every row
+        # it held, each a point where the engine thread lost its turn
+        # (27 ms of a round that retired 79 of 1,024 rows: PERF.md
+        # section 6, PR 36)
+        active_at = self._at["active"]
+        cand_ids = {g for g, a in enumerate(host[:, active_at].tolist())
+                    if a}
         cand_ids.update(ctx.staged_rows)
+        cand_ids.update(ctx.injected)
         cand_ids.difference_update(ctx.dead)
         # identity check, not membership: a row whose node was removed
         # (and possibly re-admitted) while the step was in flight must
@@ -1840,6 +1817,12 @@ class KernelEngine:
         # indexes these lists (never all [G] rows, never a numpy cell)
         r = _Retiring(lanes, [nodes[g] for g in lanes], host,
                       self._down_cols)
+        named = sum(1 for row in r.cells if row[active_at])
+        _NAMED_BY_DEVICE.inc(named)
+        _NAMED_BY_HOST.inc(len(lanes) - named)
+        # the dispatch backend derives drain-pending from the rows' flags
+        # (a row that is no candidate has none set)
+        self._dispatch.note_output_flags(r.cells)
 
         rt.enter("resolve")
         # 1. proposal fates
@@ -2066,25 +2049,20 @@ class KernelEngine:
 
     def _build_updates(self, r: _Retiring) -> list:
         """-> [(node, pb.Update)] of the rows with entries to save or a
-        (term, vote, commit) that is not the persisted one (a quiet bump
-        is persisted too); the persisted triples are read once for the
-        rows and written once for those that moved."""
+        (term, vote, commit) that moved in this step (a quiet bump is
+        persisted too: the row's ``active`` cell says so, and every step
+        that moves a triple is retired, so what a step was given is what
+        was persisted last)."""
         at = self._at
         term_at, vote_at, commit_at = at["term"], at["vote"], at["commit"]
         first_at, last_at, terms_at = (
             at["save_first"], at["save_last"], at["save_terms"])
+        active_at = at["active"]
         S = self._cols.save_window
-        persisted = self._triple_np[r.lanes].tolist()
-        moved: list = []            # lanes whose triple moved, and to what
-        moved_to: list = []
         updates = []
         for i, row in enumerate(r.cells):
             lo, hi = row[first_at], row[last_at]
-            triple = [row[term_at], row[vote_at], row[commit_at]]
-            if triple != persisted[i]:
-                moved.append(r.lanes[i])
-                moved_to.append(triple)
-            elif hi < lo:
+            if hi < lo and not row[active_at] & ACTIVE_TRIPLE:
                 continue
             n = r.nodes[i]
             entries = []
@@ -2104,9 +2082,8 @@ class KernelEngine:
                         e = mirror[idx] = _entry_at(e, e.index, t)
                     entries.append(e)
                     idx += 1
-            updates.append((n, _update(n, pb.State(*triple), entries)))
-        if moved:
-            self._triple_np[moved] = moved_to
+            updates.append((n, _update(n, pb.State(
+                row[term_at], row[vote_at], row[commit_at]), entries)))
         return updates
 
     def _finish(self, r: _Retiring, staged_ri: dict) -> None:
@@ -2118,8 +2095,6 @@ class KernelEngine:
         leader_at, leader_term_at = at["leader"], at["leader_term"]
         dropped_at, needs_host_at = at["ri_dropped"], at["needs_host"]
         removed = len(self._removed_nodes)
-        led: list = []              # lanes whose leader moved, and to what
-        led_to: list = []
         for i, row in enumerate(r.cells):
             n = r.nodes[i]
             # a whole-group eviction earlier in THIS loop (mesh engine)
@@ -2144,8 +2119,6 @@ class KernelEngine:
             leader, term = row[leader_at], row[leader_term_at]
             if leader != n._leader_cache or term != n._leader_term_cache:
                 self._leader_edge(n, leader, term)
-                led.append(r.lanes[i])
-                led_to.append((leader, term))
             # 7. escalation
             if row[needs_host_at]:
                 r.per_lane.add(i)
@@ -2153,8 +2126,6 @@ class KernelEngine:
         for n in r.fallback:
             if self._is_registered(n):
                 self._evict(n, reason="witness snapshot without record")
-        if led:
-            self._seen_np[led, 3:] = led_to     # _lead_np, _lead_term_np
 
     def _complete_reads(self, g, n, o, fl, staged_ri) -> None:
         """ReadIndex results of row ``g`` of ``o`` (``fl``: its flag

@@ -46,10 +46,10 @@ def _round_body(kp, replicas, state, box, up, cut):
         lambda s, b: jnp.where(
             live.reshape(live.shape + (1,) * (s.ndim - 2)), s, b),
         staged, unpack_columns(Inbox, box_cols, box))
-    s, box, out = serve_body(kp, replicas, unpack_state(kp, state), box,
-                             inp, cut)
+    was = unpack_state(kp, state)
+    s, box, out = serve_body(kp, replicas, was, box, inp, cut)
     return (pack_state(kp, s), pack_columns(box_cols, box._asdict()),
-            pack_round(kp, s, out))
+            pack_round(kp, was, s, out))
 
 
 def _round(kp, cluster: IciCluster, state, box, up, cut):

@@ -191,7 +191,7 @@ def test_remove_shard_of_a_queued_admission_leaves_the_books_consistent():
             live = {n.lane for n in eng.by_shard.values()}
             assert set(eng.nodes) | set(eng._admitting) == live
             assert sorted(eng._free + list(live)) == list(range(4))
-            assert set(np.nonzero(eng._occ_np)[0]) == set(eng.nodes)
+            assert set(np.nonzero(eng._kind_np.any(1))[0]) == set(eng.nodes)
 
         assert 2 not in eng.by_shard and lane in eng._free
         assert not eng._admitting and not eng._pending_inject
@@ -206,10 +206,11 @@ def test_remove_shard_of_a_queued_admission_leaves_the_books_consistent():
         start(nh, prefix, 3)
         assert eng.by_shard[3].lane == lane
         eng.step_all()
-        assert eng.nodes[lane] is eng.by_shard[3] and eng._occ_np[lane]
+        assert eng.nodes[lane] is eng.by_shard[3]
+        assert eng._kind_np[lane].any()
         consistent()
         nh.stop_replica(3)
-        assert not eng._occ_np[lane] and lane not in eng.nodes
+        assert not eng._kind_np[lane].any() and lane not in eng.nodes
         consistent()
         # full: the fifth admission of four lanes falls back to the host
         for sid in (4, 5, 6, 7):

@@ -193,6 +193,26 @@ class Scene:
             self.v["flags"][g, FLAG[c]] = 1
         return self
 
+    def name_active(self):
+        """Fill the ``active`` column as the round's program does
+        (core/round.py ``row_activity``), a cell at a time: the lane's
+        ``triple`` and ``lead`` are the state the step was given."""
+        v = self.v
+        for g, spec in self.lanes.items():
+            output = (v["flags"][g].any() or v["ri_dropped"][g]
+                      or v["needs_host"][g]
+                      or v["save_last"][g] >= v["save_first"][g]
+                      or v["apply_last"][g] >= v["apply_first"][g])
+            triple = (v["term"][g], v["vote"][g],
+                      v["commit"][g]) != tuple(spec["triple"])
+            leader = (v["leader"][g],
+                      v["leader_term"][g]) != tuple(spec.get("lead", (0, 0)))
+            v["active"][g] = (
+                bool(output) * kstate.ACTIVE_OUTPUT
+                | triple * kstate.ACTIVE_TRIPLE
+                | leader * kstate.ACTIVE_LEADER)
+        return self
+
 
 def E(index, term, key=0, cmd=b"", type=pb.EntryType.APPLICATION):
     return pb.Entry(term=term, index=index, key=key, cmd=cmd, type=type,
@@ -595,8 +615,8 @@ def _reference(scene, nodes, ctx_nodes, log, state, cut=None, mesh=False):
     """The retire pass as the per-lane engine made it: for every candidate
     lane in order its fates, its messages and its update; the replicates,
     the save, the other messages; then per lane reads, apply, leader edge,
-    escalation.  ``state``: the engine's host arrays it keeps (triple,
-    lead, lead_term), as dicts by lane."""
+    escalation.  ``state``: what the per-lane engine kept on the host
+    of each lane (triple, lead, lead_term), as dicts by lane."""
     down = scene.down
     flag = lambda g, c: cell(down, "flags", g, FLAG[c])     # noqa: E731
     K, P = KPARAMS.inbox_cap, KPARAMS.num_peers
@@ -819,6 +839,7 @@ def _reference(scene, nodes, ctx_nodes, log, state, cut=None, mesh=False):
             registered.discard(id(n))
             log.append(("evict", (n.shard_id, n.replica_id),
                         "witness snapshot without record"))
+    state["retired"] = set(cand)
     return len(cand)
 
 
@@ -860,10 +881,6 @@ def _build(scene, log, engine=None):
             engine.nodes[g] = n
             engine.by_shard[(n.shard_id, n.replica_id) if mesh
                             else n.shard_id] = n
-            engine._occ_np[g] = True
-            engine._triple_np[g] = spec["triple"]
-            engine._lead_np[g], engine._lead_term_np[g] = spec.get(
-                "lead", (0, 0))
             for p, (rid, kind) in enumerate(spec["peers"]):
                 engine._pid_np[g, p], engine._kind_np[g, p] = rid, kind
         if mesh:
@@ -936,6 +953,7 @@ def _counters():
 def test_the_pass_retires_a_download_as_the_per_cell_reference_does(case):
     scene = Scene()
     case(scene)
+    scene.name_active()
     eng = _engine_for(case)
     try:
         log: list = []
@@ -973,9 +991,19 @@ def test_the_pass_retires_a_download_as_the_per_cell_reference_does(case):
             if g not in scene.dead:
                 assert nodes[g]._staged_props == []
             if g not in scene.dead and g not in scene.readmitted:
-                assert tuple(eng._triple_np[g]) == ref_state["triple"][g]
-                assert eng._lead_np[g] == ref_state["lead"][g]
-                assert eng._lead_term_np[g] == ref_state["lead_term"][g]
+                # what replaced the host's arrays: a triple that moved is
+                # in the ``pb.State`` of the lane's Update, the leader in
+                # the node's own caches
+                saved = [ud.state for ev in log if ev[0] == "save"
+                         for ud in ev[1] if (ud.shard_id, ud.replica_id) == (
+                             nodes[g].shard_id, nodes[g].replica_id)]
+                if ref_state["triple"][g] != tuple(scene.lanes[g]["triple"]):
+                    assert [(st.term, st.vote, st.commit)
+                            for st in saved] == [ref_state["triple"][g]]
+                if g in ref_state["retired"]:
+                    assert (nodes[g]._leader_cache,
+                            nodes[g]._leader_term_cache) == (
+                        ref_state["lead"][g], ref_state["lead_term"][g])
         assert eng._lanes_processed == processed
         assert after["per_lane"] - before["per_lane"] == PER_LANE.get(
             case.__name__, 0)
@@ -988,6 +1016,7 @@ def test_the_pass_retires_a_download_as_the_per_cell_reference_does(case):
 def test_a_leaders_entries_are_built_once_and_shared_by_the_peers_they_fit():
     scene = Scene()
     replicates_to_two_peers_at_the_same_prev(scene)
+    scene.name_active()
     eng = _engine_for(replicates_to_two_peers_at_the_same_prev)
     try:
         log: list = []

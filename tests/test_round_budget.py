@@ -275,6 +275,135 @@ def test_retire_lanes_counts_per_lane_only_for_the_rare_classes():
         nh.close()
 
 
+def _named() -> dict:
+    snap = telemetry.GLOBAL.snapshot()
+    return {by: snap.get(f"engine_retire_named{{by={by}}}", 0)
+            for by in ("device", "host")}
+
+
+@pytest.mark.parametrize("depth", [0, 1])
+def test_a_round_retires_the_rows_its_program_named_and_no_others(
+        depth, monkeypatch):
+    """An engine holding 1,024 rows, 8 of them written to: the download's
+    ``active`` column names those 8 (one replica a group: no peer's row
+    has anything to do), the round retires them and nothing else, and
+    ``engine_retire_named{by=device}`` counts them.  The 1,016 rows that
+    hold an idle replica cost the pass one cell of one ``tolist`` each: no
+    host array is compared with them (the parent's mask was a dozen numpy
+    calls over every row held, PERF.md section 6, PR 36)."""
+    from dragonboat_tpu.engine import kernel_engine as ke
+
+    retired = []
+
+    class Retiring(ke._Retiring):
+        def __init__(self, lanes, nodes, host, cols):
+            retired.append(list(lanes))
+            super().__init__(lanes, nodes, host, cols)
+
+    nh = _host(f"rb-named{depth}", 1024, depth=depth, capacity=1024)
+    try:
+        eng = nh.kernel_engine
+        assert len(eng.nodes) == eng.capacity == 1024
+        for gone in ("_seen_np", "_triple_np", "_lead_np", "_lead_term_np",
+                     "_mask_cols", "_occ_np", "_live_rows"):
+            assert not hasattr(eng, gone), gone
+        busy = list(range(100, 900, 100))
+        sessions = [nh.get_noop_session(sid) for sid in busy]
+        with eng.mu:
+            _settle(eng)
+            monkeypatch.setattr(ke, "_Retiring", Retiring)
+            for i in range(3):
+                states = [nh.propose(s, f"k{i}=v".encode(), 30)
+                          for s in sessions]
+                del retired[:]
+                before, processed = _named(), 0
+                assert eng.step_all()
+                processed += eng._lanes_processed
+                if depth:               # the retire comes a round late
+                    eng.step_all()
+                    processed += eng._lanes_processed
+                want = sorted(eng.by_shard[sid].lane for sid in busy)
+                assert [r for r in retired if r] == [want], f"round {i}"
+                after = _named()
+                assert after["device"] - before["device"] == processed == 8
+                assert after["host"] == before["host"]
+                _settle(eng)
+        for rs in states:
+            assert rs.get(30) is not None
+    finally:
+        nh.close()
+
+
+@pytest.mark.parametrize("depth", [0, 1])
+def test_a_row_with_an_empty_book_is_retired_and_one_placed_at_a_term_too(
+        depth, monkeypatch):
+    """A replica that joins is placed with NO peer in its book, and the
+    kernel answers its leader all the same: the ``active`` column names
+    that row (it asks for no occupancy), so the term it is told of is
+    saved.  Started again, the row is placed at that term with nothing to
+    save, apply or send: no step moves anything, the column reads 0, and
+    the host names the row itself for its first pass (``ctx.injected``),
+    where the leader edge 0 -> term fires as the parent's did (it compared
+    the download with leader cells it had set to 0 at the injection)."""
+    from dragonboat_tpu.engine import kernel_engine as ke
+
+    retired = []
+
+    class Retiring(ke._Retiring):
+        def __init__(self, lanes, nodes, host, cols):
+            retired.extend((g, int(host[g, eng._at["active"]]))
+                           for g in lanes)
+            super().__init__(lanes, nodes, host, cols)
+
+    def join():
+        nh.start_replica({}, True, KVStateMachine, Config(
+            shard_id=7, replica_id=2, election_rtt=10, heartbeat_rtt=2,
+            device_resident=True))
+        return nh.nodes[7]
+
+    def rounds():
+        for _ in range(2 + depth):
+            eng.step_all()
+
+    nh = _host(f"rb-join{depth}", 1, depth=depth)
+    try:
+        eng = nh.kernel_engine
+        monkeypatch.setattr(ke, "_Retiring", Retiring)
+        with eng.mu:
+            _settle(eng)
+            node = join()
+            rounds()
+            assert not eng._kind_np[node.lane].any(), "the book is not empty"
+            # its leader's heartbeat, from a later term
+            nh._handle_message_batch(pb.MessageBatch(
+                requests=(pb.Message(
+                    type=pb.MessageType.HEARTBEAT, to=2, from_=1,
+                    shard_id=7, term=7),),
+                source_address=f"rb-join{depth}-9"))
+            del retired[:]
+            rounds()
+            cells = [a for g, a in retired if g == node.lane]
+            assert cells and cells[0] & ke.ACTIVE_TRIPLE, retired
+            assert (node._leader_cache, node._leader_term_cache) == (1, 7)
+            assert nh.logdb.read_raft_state(7, 2, 0).state.term == 7
+            # ...and the replica starts again, at the term it saved
+            nh.stop_replica(7)
+            _settle(eng)
+            node = join()
+            del retired[:]
+            before = _named()
+            # (nothing is staged for the row: the round that first carries
+            # it is the next one a tick makes)
+            assert _wait(lambda: eng.step_all() and any(
+                g == node.lane for g, _a in retired), 30), "never retired"
+            rounds()
+            assert [a for g, a in retired if g == node.lane] == [0], retired
+            assert _named()["host"] - before["host"] == 1
+            assert (node._leader_cache, node._leader_term_cache) == (0, 7)
+    finally:
+        nh.close()
+
+
 def _entry_arrays() -> dict:
     snap = telemetry.GLOBAL.snapshot()
     return {d: snap.get(f"engine_entry_arrays{{dir={d}}}")

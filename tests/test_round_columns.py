@@ -109,8 +109,9 @@ def test_pack_then_unpack_returns_every_field(geometry, inline):
     # download: device pack -> device unpack, and the host view
     flags = rng.random((G, len(kstate.FLAG_CLASSES))) < 0.5
     terms = rng.integers(0, 2**31 - 1, (G, rc.save_window)).astype(np.int32)
+    active = rng.integers(0, 8, (G,)).astype(np.int32)
     down = kstate.pack_download(
-        kp, jnp.asarray(flags),
+        kp, jnp.asarray(flags), jnp.asarray(active),
         StepOutput(*(None if v is None else jnp.asarray(v) for v in out)),
         jnp.asarray(terms))
     assert down.shape == (G, rc.down_width) and down.dtype == jnp.int32
@@ -124,6 +125,7 @@ def test_pack_then_unpack_returns_every_field(geometry, inline):
             assert np.array_equal(o[f], want), f
             assert o[f] is o[f], "memoised"
     assert np.array_equal(o["flags"], flags) and o["flags"].dtype == bool
+    assert np.array_equal(o["active"], active) and o["active"].shape == (G,)
     assert np.array_equal(o["save_terms"], terms)
 
 
@@ -165,11 +167,11 @@ def _unpack(kp, resident, placement=None) -> ShardState:
 
 
 def test_served_geometry_widths():
-    """The sizes PERF.md quotes: 231 columns up, 344 down, S 64; 108
+    """The sizes PERF.md quotes: 231 columns up, 345 down, S 64; 108
     columns of resident state, 208 of carried mesh inbox."""
     served = KP.KernelParams(**GEOMETRIES["served"])
     rc = kstate.round_columns(served)
-    assert (rc.up_width, rc.down_width, rc.save_window) == (231, 344, 64)
+    assert (rc.up_width, rc.down_width, rc.save_window) == (231, 345, 64)
     assert kstate.state_columns(served)[1] == 108
     assert kstate.inbox_columns(served)[1] == 208
     assert kstate.save_window(KP.KernelParams(
