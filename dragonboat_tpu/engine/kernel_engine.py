@@ -155,8 +155,7 @@ _READ_STAGE_WAIT_US = telemetry.GLOBAL.histogram(
 # lane admission, beside nodehost_start_replica_us: the caller's wait for
 # the admission lock in add_shard (no round holds it; part of
 # start_replica's ``stage``), one _flush_injections batch on the engine
-# thread (inside a round's ``stage``), the replicas it wrote, and an
-# admission's time from add_shard's call to the end of that flush
+# thread (inside a round's ``stage``) and the replicas it wrote
 ADD_SHARD_LOCK_US = telemetry.GLOBAL.histogram(
     "engine_add_shard_lock_us",
     help="add_shard's wait for the admission lock, per call")
@@ -170,21 +169,14 @@ _INJECT_ROWS = telemetry.GLOBAL.counter(
     "engine_inject_rows",
     help="replicas written into the device state by _flush_injections "
          "(engine_inject_flush_us counts the batches)")
-_ADMIT_WAIT_US = telemetry.GLOBAL.histogram(
-    "engine_admit_wait_us",
-    help="one admission, from add_shard's call to the end of the flush "
-         "that injected its lane")
 # the width of a round, beside the round timer's histograms: per
-# committed round the lanes step_all staged and the lanes
-# _process_outputs retired (its candidate rows); and the lanes that hold a
-# replica, per engine
-_ROUND_LANES = telemetry.GLOBAL.counter(
+# committed round the lanes _process_outputs retired (its candidate rows;
+# the round record holds the lanes step_all staged too)
+_LANES_PROCESSED = telemetry.GLOBAL.counter(
     "engine_round_lanes",
-    help="lanes of committed rounds: staged by step_all, processed by "
-         "the output pass (its candidate rows)",
-    labelnames=("what",))
-_LANES_STAGED = _ROUND_LANES.labels("staged")
-_LANES_PROCESSED = _ROUND_LANES.labels("processed")
+    help="lanes of committed rounds that the output pass processed (its "
+         "candidate rows)",
+    labelnames=("what",)).labels("processed")
 # how the output pass retired them: by columns of the download, or through
 # the per-lane handler of a rare class (a witness snapshot, a ReadIndex
 # completion or drop, a config change, an escalation, a save window wider
@@ -229,10 +221,6 @@ _QUIESCE_ENTERS = telemetry.GLOBAL.counter(
     labelnames=("how",))
 _ENTERED_OWN_CLOCK = _QUIESCE_ENTERS.labels("own_clock")
 _ENTERED_ON_WORD = _QUIESCE_ENTERS.labels("peer")
-_LANES_LIVE = telemetry.GLOBAL.gauge(
-    "engine_lanes_live",
-    help="lanes holding a replica a round can see, per engine",
-    labelnames=("engine",))
 
 
 class _RoundDown:
@@ -600,7 +588,7 @@ class KernelEngine:
         self._admit_mu = threading.Lock()
         self._admitting: dict[int, tuple] = {}
         # taken admissions awaiting this step's batched injection
-        # (lane -> (node, init, pids, kinds, start)); see _flush_injections
+        # (lane -> (node, init, pids, kinds)); see _flush_injections
         self._pending_inject: dict[int, tuple] = {}
         # rows written into the state at a term > 0 (a founder's bootstrap
         # term is 1; a replica that starts again has the term it saved)
@@ -664,6 +652,9 @@ class KernelEngine:
         self._props_deferred = 0
         self._reads_staged = 0
         self._lanes_processed = 0
+        # what one ``_finish`` spent applying and acknowledging (ns; the
+        # timer's ``finish.apply`` and ``finish.ack``)
+        self._apply_ns = self._ack_ns = 0
         maybe_start_from_env()
         self.events.metrics.set("engine.pipeline.depth", self.pipeline_depth)
         # decimated device-side fleet telemetry (core/fleet.py): every N
@@ -769,7 +760,7 @@ class KernelEngine:
             node.lane = lane
             node.engine = self
             self.by_shard[node.shard_id] = node
-            self._admitting[lane] = (node, init, t0)
+            self._admitting[lane] = (node, init)
 
     def _take_admissions(self) -> None:
         """On the engine thread, holding ``mu``, as a round begins: every
@@ -779,13 +770,9 @@ class KernelEngine:
             return
         with self._admit_mu:
             taken, self._admitting = self._admitting, {}
-        for lane, (node, init, t0) in taken.items():
+        for lane, (node, init) in taken.items():
             self._register(lane, node)
-            self._inject(lane, node, init, t0)
-        self._note_lanes_live()
-
-    def _note_lanes_live(self) -> None:
-        _LANES_LIVE.labels(self._round.engine).set(len(self.nodes))
+            self._inject(lane, node, init)
 
     def _register(self, lane: int, node: KernelNode) -> None:
         """Make an admitted node visible to rounds (a seam: the mesh
@@ -804,7 +791,6 @@ class KernelEngine:
                 # (an admission no round took was never written anywhere)
                 self.nodes.pop(node.lane, None)
                 self._clear_lane(node.lane)
-                self._note_lanes_live()
             self._removed_nodes.append(node)
         return node
 
@@ -857,8 +843,8 @@ class KernelEngine:
         self._resident = res._replace(cols=write_cells_program(
             self._dispatch.placement())(res.cols, up))
 
-    def _inject(self, lane: int, node: KernelNode, init: _LaneInit,
-                t0: int) -> None:
+    def _inject(self, lane: int, node: KernelNode, init: _LaneInit
+                ) -> None:
         """Queue one lane injection; this ``step_all`` flushes every
         queued lane in ONE vectorized state update.  The eager form was
         ~30 full-[capacity] array copies PER admission — O(n·capacity)
@@ -875,7 +861,7 @@ class KernelEngine:
         for e in init.entries:
             node.mirror[e.index] = e
         self._applied_sent_np[lane] = init.applied
-        self._pending_inject[lane] = (node, init, pids, kinds, t0)
+        self._pending_inject[lane] = (node, init, pids, kinds)
         self._inv_dirty.add(lane)
         self.mark_dirty(lane)
 
@@ -902,7 +888,7 @@ class KernelEngine:
         rows["kind"] = np.zeros((n, kp.num_peers), np.int32)
         rows["lt"] = np.zeros((n, kp.log_cap), np.int32)
         rows["lcc"] = np.zeros((n, kp.log_cap), bool)
-        for j, (lane, (node, init, pids, kinds, _t0)) in enumerate(items):
+        for j, (lane, (node, init, pids, kinds)) in enumerate(items):
             rows["pid"][j], rows["kind"][j] = pids, kinds
             for e in init.entries:
                 rows["lt"][j, e.index & (kp.log_cap - 1)] = e.term
@@ -966,11 +952,8 @@ class KernelEngine:
             self._resident = self._inject_fn(
                 self._resident, jnp.asarray(lanes_np),
                 {k: jnp.asarray(v) for k, v in rows.items()})
-        now = monotonic_us()
-        _INJECT_FLUSH_US.observe(now - t0)
+        _INJECT_FLUSH_US.observe(monotonic_us() - t0)
         _INJECT_ROWS.inc(n)
-        for _lane, item in items:
-            _ADMIT_WAIT_US.observe(now - item[4])
 
     def _clear_lane(self, lane: int) -> None:
         self._inv_dirty.add(lane)
@@ -1104,7 +1087,8 @@ class KernelEngine:
             staging = self._bufs[self._buf_idx]
             inbox, inp = staging.inbox, staging.inp
             self._inbox_buf, self._input_buf = inbox, inp
-            staging.reset()
+            with rt.part("stage.reset"):
+                staging.reset()
             had_work = False
 
             # swap out the dirty set; arrivals during this step land in
@@ -1136,8 +1120,9 @@ class KernelEngine:
                     self._tick_rounds_pending -= 1
                 self._last_tick_us = monotonic_us()
             if tick_round:
-                lanes = np.fromiter(nodes.keys(), np.int64, len(nodes))
-                inp._tick[lanes] = True
+                with rt.part("stage.tick"):
+                    lanes = np.fromiter(nodes.keys(), np.int64, len(nodes))
+                    inp._tick[lanes] = True
                 had_work = True
             # an eviction while staging (InstallSnapshot; whole-GROUP on a
             # mesh engine) may remove rows staged EARLIER in this loop —
@@ -1207,10 +1192,12 @@ class KernelEngine:
                     resident, out = self._kernel_call(staging)
             # the previous resident arrays die here, three of them: each
             # one let go is a wait for the interpreter (kstate.py)
-            self._resident = resident
+            with rt.part("upload.release"):
+                self._resident = resident
             ctx.out = out
-            np.maximum(self._applied_sent_np, inp._applied,
-                       out=self._applied_sent_np)
+            with rt.part("upload.applied"):
+                np.maximum(self._applied_sent_np, inp._applied,
+                           out=self._applied_sent_np)
             for k in ctx.traced:
                 lifecycle.TRACER.stamp(k, lifecycle.STAGE_DISPATCH)
             self._pipe_steps += 1
@@ -1258,7 +1245,6 @@ class KernelEngine:
             _MAX_TICK_FLOOR_US,
             max(min(monotonic_us() - self._round_t0_us, 2 * floor + 5_000),
                 floor * 15 // 16))
-        _LANES_STAGED.inc(lanes_staged)
         _LANES_PROCESSED.inc(self._lanes_processed)
         self._round.commit(
             props_staged=self._props_staged,
@@ -1834,8 +1820,14 @@ class KernelEngine:
         # 3. persistence batch
         updates = self._build_updates(r)
 
-        # replicate-before-fsync (engine.go:1332-1343)
-        self._send_all(replicates)
+        # replicate-before-fsync (engine.go:1332-1343).  The timer's
+        # ``resolve.send`` is both sends as the sender pays them (the
+        # receivers' registries, ``_dirty_mu``, ``node.mu``); its two marks
+        # are when a round's messages have left, in a round that sent any
+        with rt.part("resolve.send"):
+            self._send_all(replicates)
+        if replicates:
+            rt.mark("replicates_out")
         if updates:
             rt.enter("save")
             # one batched fsync per LogDB (nodes of a shared mesh engine
@@ -1849,7 +1841,10 @@ class KernelEngine:
             for db, uds in by_db.values():
                 db.save_raft_state(uds, worker_id=0)
             rt.enter("resolve")
-        self._send_all(others)
+        with rt.part("resolve.send"):
+            self._send_all(others)
+        if others:
+            rt.mark("responses_out")
 
         rt.enter("finish")
         self._finish(r, ctx.staged_ri)
@@ -2095,6 +2090,9 @@ class KernelEngine:
         leader_at, leader_term_at = at["leader"], at["leader_term"]
         dropped_at, needs_host_at = at["ri_dropped"], at["needs_host"]
         removed = len(self._removed_nodes)
+        # what ``_apply`` spends applying and acknowledging, summed over
+        # the rows and handed to the round timer once each
+        self._apply_ns = self._ack_ns = 0
         for i, row in enumerate(r.cells):
             n = r.nodes[i]
             # a whole-group eviction earlier in THIS loop (mesh engine)
@@ -2126,6 +2124,9 @@ class KernelEngine:
         for n in r.fallback:
             if self._is_registered(n):
                 self._evict(n, reason="witness snapshot without record")
+        rt = self._round
+        rt.add("finish.apply", self._apply_ns)
+        rt.add("finish.ack", self._ack_ns)
 
     def _complete_reads(self, g, n, o, fl, staged_ri) -> None:
         """ReadIndex results of row ``g`` of ``o`` (``fl``: its flag
@@ -2170,7 +2171,17 @@ class KernelEngine:
                term: int) -> bool:
         """Hand the committed entries ``first..last`` to the RSM and
         complete what waited on them; -> whether a config change was
-        among them (the rare class of this pass)."""
+        among them (the rare class of this pass).
+
+        The round timer's two parts of ``finish`` are told apart here, a
+        row costing two or three reads of the host clock and no histogram:
+        ``finish.apply`` is the mirror walk and the state machine's
+        ``handle`` with its apply stamps; ``finish.ack`` the two loops that
+        answer the proposals' futures, each answer waking a client
+        thread, on a replica that holds any."""
+        clock = self._round.clock_ns
+        t_apply = clock()
+        ack_ns = 0
         mirror = n.mirror
         entries = []
         for idx in range(first, last + 1):
@@ -2188,12 +2199,16 @@ class KernelEngine:
                 if e.key:
                     n._rl_release(e.key)
         if n.notify_commit and waited:
+            t = clock()
             for e in entries:
                 if e.key:
                     book.committed(e.key)
+            ack_ns = clock() - t
         results = n.sm.handle(entries)
         lifecycle.TRACER.stamp_all(
             [e.key for e in entries], lifecycle.STAGE_APPLY)
+        t_ack = clock()
+        self._apply_ns += t_ack - t_apply - ack_ns
         cc_applied = False
         # ``handle`` answers for a subsequence of the entries, in order
         # (it skips what an on-disk state machine already replayed): one
@@ -2209,6 +2224,8 @@ class KernelEngine:
             elif res.key and waited:
                 book.applied(res.key, res.client_id, res.series_id,
                              res.result, res.rejected)
+        if waited:
+            self._ack_ns += ack_ns + clock() - t_ack
         if cc_applied:
             self.update_lane_membership(n)
         n.applied_since_snapshot += len(results)

@@ -198,7 +198,7 @@ class MeshEngine(KernelEngine):
             node.lane = row
             node.engine = self
             self.by_shard[key] = node
-            self._admitting[row] = (node, init, t0)
+            self._admitting[row] = (node, init)
 
     def _register(self, row: int, node: KernelNode) -> None:
         sid = node.shard_id
@@ -232,7 +232,6 @@ class MeshEngine(KernelEngine):
                 self.nodes.pop(node.lane, None)
                 self._clear_lane(node.lane)
                 self._dispatch.set_cut(node.lane, True)  # empty rows are cut
-                self._note_lanes_live()
             if last:
                 self._members.pop(sid, None)
                 self._mirrors.pop(sid, None)

@@ -14,12 +14,21 @@ TPU build adds a real trace path on top of the same metrics registry:
   trace (``jax.profiler.TraceAnnotation``), used around the kernel
   engine's step phases;
 - ``RoundTimer`` — the engine round, phase by phase (``ROUND_PHASES``),
-  on the host clock: ``engine_round_us{phase=...}`` /
-  ``engine_round_cpu_us`` histograms in ``telemetry.GLOBAL``, a
+  on the host clock and the engine thread's CPU clock:
+  ``engine_round_us{phase=...}`` / ``engine_round_cpu_us`` histograms in
+  ``telemetry.GLOBAL``; the CPU time by phase
+  (``engine_round_phase_cpu_us.sum{phase}``), the parts of its largest
+  phases (``ROUND_PARTS``: ``engine_round_part_us.sum{part}``) and the
+  moments a round's messages leave (``ROUND_MARKS``:
+  ``engine_round_mark_us.sum|count{mark}``) as plain sums on the timer,
+  published by callback gauges when a snapshot is taken; a
   ``kernel_engine.<phase>`` annotation per phase while a capture is
-  armed, a bounded ring of round records for ``/trace``, and the
+  armed, preceded by one ``tracing.clock_sync`` mark however the capture
+  was armed; a bounded ring of round records for ``/trace``, and the
   ``<prefix>.ewma_us`` gauge the load controller reads.  Always on (the
-  profiler itself is opt-in: capture costs memory).
+  profiler itself is opt-in: capture costs memory);
+- the callback gauge ``process_cpu_us``: the whole process's CPU time,
+  read when a snapshot is taken, beside the engine threads' own.
 
 Environment: ``DRAGONBOAT_TPU_TRACE_DIR`` arms profiler capture at import
 of the engine, for drive-by profiling without code changes.
@@ -31,11 +40,18 @@ import contextlib
 import os
 import threading
 import time
+import weakref
 from collections import deque
 
 from dragonboat_tpu import telemetry
 
 _active_trace_dir: str | None = None
+# the capture directory that holds a ``tracing.clock_sync`` mark already:
+# ``start_trace`` writes one, and the round timer writes one ahead of its
+# first annotation into a capture that was armed by hand (the benchmark
+# harness sets ``_active_trace_dir`` itself)
+_synced_dir: str | None = None
+_sync_mu = threading.Lock()     # guards _synced_dir and the mark it stands for
 # set while the ACTIVE capture was armed by DRAGONBOAT_TPU_TRACE_DIR
 # (maybe_start_from_env) rather than an explicit start_trace call —
 # engine close() stops env-armed captures, never user-started ones
@@ -59,6 +75,23 @@ def _clock_sync() -> None:
     """One instant of this module's clock, written into the capture."""
     with annotate(CLOCK_SYNC, monotonic_us=monotonic_us()):
         pass
+
+
+def _sync_capture(trace_dir: str | None) -> None:
+    """Write the capture's one ``tracing.clock_sync`` mark unless
+    ``trace_dir`` holds it already (None: the capture ended, so a later
+    one into the same directory gets its own).  Up to three engine
+    threads come here with their first annotation; the lock is held over
+    the mark, so none writes its annotation ahead of it.  Taken once a
+    capture by each thread that asks, never in a round without one."""
+    global _synced_dir
+    with _sync_mu:
+        if _synced_dir != trace_dir:
+            if trace_dir is not None:
+                _clock_sync()
+            # set after the mark: a thread that reads it without the lock
+            # and finds the capture synced finds the mark written
+            _synced_dir = trace_dir
 
 
 def start_trace(trace_dir: str, python_tracer_level: int | None = None,
@@ -90,7 +123,7 @@ def start_trace(trace_dir: str, python_tracer_level: int | None = None,
             options.host_tracer_level = host_tracer_level
         jax.profiler.start_trace(trace_dir, profiler_options=options)
     _active_trace_dir = trace_dir
-    _clock_sync()
+    _sync_capture(trace_dir)
 
 
 def stop_trace() -> str | None:
@@ -104,6 +137,7 @@ def stop_trace() -> str | None:
     jax.profiler.stop_trace()
     d, _active_trace_dir = _active_trace_dir, None
     _env_armed = False
+    _sync_capture(None)
     return d
 
 
@@ -222,7 +256,30 @@ class TraceRing:
 ROUND_PHASES = ("wait", "stage", "upload", "fetch", "resolve", "save",
                 "finish")
 ROUND_TOTAL = "total"
+#: what the largest phases are made of, ``<phase>.<part>``: a part's time
+#: stays inside its phase (a phase's self time is its time less its
+#: parts).  Each has a reader under ``benchmark/layer_metrics/``; a part
+#: nobody reads is not recorded.  kernel_engine.py names the extents
+ROUND_PARTS = ("stage.reset", "stage.tick", "upload.release",
+               "upload.applied", "resolve.send", "finish.apply",
+               "finish.ack")
+#: moments inside a round, from its start: the return of the send of a
+#: round's REPLICATEs, and of everything else it sends (after the save)
+ROUND_MARKS = ("replicates_out", "responses_out")
 DEFAULT_ROUND_RING = 4096
+#: the phase boundaries read the thread CPU clock in about one round an
+#: engine per this long: a round is read with probability (the engine's
+#: mean round) / (this), whatever the rounds before it were like.  On the
+#: chip's host the read is a 5.6 us system call made holding the
+#: interpreter lock, on a clock that ticks every 10 ms: read at every
+#: boundary of every round it cost a 10 ms round 3-6% of its throughput
+#: (PERF.md section 6, PR 37).  Where the mean round is this long or
+#: longer every round is read
+PHASE_CPU_EVERY_NS = 40_000_000
+#: 2**32 / the golden ratio: ``seq * _WEYL mod 2**32`` is spread evenly
+#: over any run of rounds AND over every n-th round of it (the engine
+#: collects its statistics every tenth), which a fixed stride is not
+_WEYL = 2654435769
 
 _thread = threading.local()
 
@@ -269,23 +326,122 @@ class RoundBook:
     def chrome_events(self) -> list[dict]:
         """The retained rounds as Chrome-trace events: one row per engine
         (``pid`` "engine", ``tid`` the engine's label), one complete
-        event per phase entry, on the clock the lifecycle spans use.  A
-        row's events are in clock order (an engine's rounds are recorded
-        in order), which the strict validator requires."""
+        event per phase entry and one instant event per mark, on the
+        clock the lifecycle spans use.  A row's events are in clock order
+        (an engine's rounds are recorded in order, a round's marks are
+        sorted in among its phases), which the strict validator
+        requires."""
         events = []
         for rec in self.rounds():
-            marks = rec["phases"]
+            entries = rec["phases"]
             args = {k: v for k, v in rec.items()
                     if k not in ("phases", "engine")}
-            for (phase, ts), (_next, end) in zip(marks, marks[1:]):
-                events.append({
-                    "name": phase, "cat": "round", "ph": "X", "ts": ts,
+            row = [{"name": phase, "cat": "round", "ph": "X", "ts": ts,
                     "dur": end - ts, "pid": "engine", "tid": rec["engine"],
-                    "args": args})
+                    "args": args}
+                   for (phase, ts), (_next, end) in zip(entries, entries[1:])]
+            row += [{"name": mark, "cat": "round", "ph": "i", "s": "t",
+                     "ts": rec["t0_us"] + us, "pid": "engine",
+                     "tid": rec["engine"]}
+                    for mark, us in rec.get("marks", {}).items()]
+            row.sort(key=lambda ev: ev["ts"])
+            events += row
         return events
 
 
 ROUNDS = RoundBook()
+
+
+class _RoundSums:
+    """What the timers of one registry summed over their committed rounds,
+    in plain integers: CPU nanoseconds by phase, host nanoseconds by part,
+    and by mark the nanoseconds from a round's start and the rounds that
+    made it.  A timer adds to a row of its own (the engine thread is the
+    row's one writer, so a round pays no lock, float or bucket search for
+    them); four callback gauges add the rows up, in microseconds, when a
+    snapshot is taken.  A timer that is collected leaves its row's sums
+    behind, so the gauges never fall: its finalizer, which may run inside
+    any allocation of any thread, takes no lock and only queues the row;
+    the next reader folds it into ``_retired``."""
+
+    FAMILIES = (
+        ("engine_round_phase_cpu_us.sum", "phase", ROUND_PHASES[1:], 1e-3,
+         "thread CPU time of the engine thread by phase of a round, "
+         "summed over the rounds that read it (about one an engine per "
+         "40 ms, drawn independently of the rounds before); the six sum "
+         "to those rounds' engine_round_cpu_us"),
+        ("engine_round_part_us.sum", "part", ROUND_PARTS, 1e-3,
+         "host time of a part of a phase (inside its phase's "
+         "engine_round_us), summed over the committed rounds "
+         "(engine_round_us{phase=total} counts them)"),
+        ("engine_round_mark_us.sum", "mark", ROUND_MARKS, 1e-3,
+         "from a round's start to the return of the send of its "
+         "REPLICATEs / of its other messages, summed over the rounds "
+         "that sent"),
+        ("engine_round_mark_us.count", "mark", ROUND_MARKS, 1,
+         "the rounds that sent REPLICATEs / other messages"),
+    )
+
+    _of_registry: "weakref.WeakKeyDictionary" = weakref.WeakKeyDictionary()
+    _mu = threading.Lock()      # rows come and go; no round takes it
+
+    def __init__(self, reg) -> None:
+        self._rows: list[tuple[dict, ...]] = []           # guarded-by: _mu
+        self._retired = self._new_row()                   # guarded-by: _mu
+        self._gone: deque = deque()     # rows of collected timers, to fold
+        for i, (name, label, _keys, scale, help) in enumerate(self.FAMILIES):
+            reg.gauge_fn(name, lambda i=i, scale=scale: self._read(i, scale),
+                         help=help, labelnames=(label,))
+
+    def _new_row(self) -> tuple[dict, ...]:
+        return tuple(dict.fromkeys(keys, 0)
+                     for _name, _label, keys, _scale, _help in self.FAMILIES)
+
+    @classmethod
+    def row_for(cls, timer, reg) -> tuple[dict, ...]:
+        """A fresh row for ``timer``, counted into ``reg``'s gauges: its
+        dicts of phase CPU, parts, mark sums and mark counts."""
+        with cls._mu:
+            sums = cls._of_registry.get(reg)
+            if sums is None:
+                sums = cls._of_registry[reg] = cls(reg)
+            row = sums._new_row()
+            sums._rows.append(row)
+        weakref.finalize(timer, sums._gone.append, row)
+        return row
+
+    def _read(self, i: int, scale) -> dict:
+        with self._mu:
+            while self._gone:
+                row = self._gone.popleft()
+                self._rows = [r for r in self._rows if r is not row]
+                for kept, gone in zip(self._retired, row):
+                    for k, v in gone.items():
+                        kept[k] += v
+            rows = [self._retired[i]] + [row[i] for row in self._rows]
+            return {k: scale * sum(r[k] for r in rows) for k in rows[0]}
+
+
+class _Part:
+    """The host time of one part of the open round, as a context manager
+    (``RoundTimer.part`` hands out the same object every round: a part is
+    entered by one thread, never inside itself)."""
+
+    __slots__ = ("_rt", "_name", "_t")
+
+    def __init__(self, rt: "RoundTimer", name: str) -> None:
+        self._rt, self._name = rt, name
+        self._t: int | None = None
+
+    def __enter__(self) -> "_Part":
+        rt = self._rt
+        self._t = None if rt._t0 is None else rt.clock_ns()
+        return self
+
+    def __exit__(self, *exc) -> bool:
+        if self._t is not None:
+            self._rt.add(self._name, self._rt.clock_ns() - self._t)
+        return False
 
 
 class RoundTimer:
@@ -301,10 +457,35 @@ class RoundTimer:
     Every phase is observed once per round, 0 where it was not entered,
     so the phases' means sum to the mean of ``total``.
 
+    The host clock is read at every boundary, and the thread CPU clock
+    with it in about one round per ``PHASE_CPU_EVERY_NS`` (the whole
+    round's CPU time, ``engine_round_cpu_us``, is read in every round, as
+    ever): a phase's thread CPU time beside its host time (host time less
+    CPU time is what the thread spent blocked in that phase: the device,
+    the disk, the interpreter lock), the six summing to that round's
+    ``engine_round_cpu_us``.  Which rounds are read is a draw on the
+    round's number and the engine's slow mean round alone (``begin``),
+    so a long round is as likely to be read as the short one after it.
+    Inside a phase, ``part(name)`` (a context manager) or ``add(name,
+    wall_ns)`` (for a part summed over rows in the caller's own
+    variables) feeds the host time of one of ``ROUND_PARTS`` (no CPU
+    time: the thread's CPU clock is a system call, 5.6 us a read on the
+    chip's host, which a part entered once a row cannot pay), and ``mark(name)`` notes one
+    of ``ROUND_MARKS`` at its distance from the round's start.  All
+    three do nothing outside an open round.  Phases are observed into
+    their histograms once a committed round, 0 where not entered; parts,
+    phase CPU times and marks are added to plain sums there
+    (``_RoundSums``: parts every committed round, phase CPU times in a
+    round that read them, a mark only in a round that made it), since
+    their readers take window means and nothing else.
+
     In a capture a pass is a ``kernel_engine.stage`` annotation before
     the engine knows whether it is a round; ``abandon`` marks the one of
     an idle pass with ``idle=1`` metadata, so a reader of the capture
-    counts the rounds the registry counts.
+    counts the rounds the registry counts.  Parts are no annotations (one
+    a row would swamp a capture): the first annotation written into a
+    capture is preceded by a ``tracing.clock_sync`` mark, which puts the
+    round records on the capture's clock.
 
     Called by the engine thread under the engine lock, so it holds no
     lock of its own.  Both clocks are injected (nanoseconds; tests pass
@@ -316,9 +497,9 @@ class RoundTimer:
         self.metrics = metrics
         self.prefix = prefix
         self.engine = engine
-        self._clock = clock_ns if clock_ns is not None else time.monotonic_ns
-        self._cpu_clock = (cpu_clock_ns if cpu_clock_ns is not None
-                           else time.thread_time_ns)
+        self.clock_ns = clock_ns if clock_ns is not None else time.monotonic_ns
+        self.cpu_clock_ns = (cpu_clock_ns if cpu_clock_ns is not None
+                             else time.thread_time_ns)
         reg = registry if registry is not None else telemetry.GLOBAL
         fam = reg.histogram(
             "engine_round_us",
@@ -330,6 +511,8 @@ class RoundTimer:
             "engine_round_cpu_us",
             help="thread CPU time of the engine thread over a round's "
                  "total (total minus this = blocked: device, disk, GIL)")
+        (self._phase_cpu_sum, self._part_sum, self._mark_sum,
+         self._mark_n) = _RoundSums.row_for(self, reg)
         self._book = book if book is not None else ROUNDS
         self._seq = 0
         self._ewma_us = 0.0
@@ -337,11 +520,22 @@ class RoundTimer:
         self._t0: int | None = None           # None: no round open
         self._cpu0 = 0
         self._cur = ""
-        self._cur_t = 0
-        # the open round's time per phase and its phase entries; commit
-        # and abandon leave both empty for the next pass
+        self._cur_t = self._cur_cpu = 0
+        # whether the open round reads the CPU clock at its boundaries,
+        # and the engine's mean round (ns, over its last ~64 rounds; slow,
+        # so that one long round hardly moves the chance of the next)
+        self._phase_cpu = False
+        self._mean_ns = PHASE_CPU_EVERY_NS
+        # the open round's host and CPU time per phase, its parts, its
+        # marks and its phase entries; commit and abandon leave all of
+        # them empty for the next pass
         self._acc = dict.fromkeys(ROUND_PHASES[1:], 0)
+        self._cpu_acc = dict.fromkeys(ROUND_PHASES[1:], 0)
+        self._part_acc = dict.fromkeys(ROUND_PARTS, 0)
+        self._sent: dict[str, int] = {}
         self._marks: list[tuple[str, int]] = []
+        self._dirty = False     # a phase or part of the open round has time
+        self._parts = {p: _Part(self, p) for p in ROUND_PARTS}
         self._ann = None
 
     # -- the round --------------------------------------------------------
@@ -350,7 +544,11 @@ class RoundTimer:
         ann, self._ann = self._ann, None
         if ann is not None:
             ann.__exit__(None, None, None)
-        if phase is not None and _active_trace_dir is not None:
+        trace_dir = _active_trace_dir
+        if _synced_dir != trace_dir:
+            # a capture began, or ended, however it was armed or stopped
+            _sync_capture(trace_dir)
+        if trace_dir is not None and phase is not None:
             self._ann = annotate(f"kernel_engine.{phase}",
                                  engine=self.engine)
             self._ann.__enter__()
@@ -364,9 +562,12 @@ class RoundTimer:
         return False
 
     def begin(self) -> None:
-        now = self._clock()
-        self._t0, self._cpu0 = now, self._cpu_clock()
-        self._cur, self._cur_t = "stage", now
+        now = self.clock_ns()
+        self._t0 = self._cur_t = now
+        self._cpu0 = self._cur_cpu = self.cpu_clock_ns()
+        self._phase_cpu = ((self._seq * _WEYL & 0xFFFFFFFF)
+                           * PHASE_CPU_EVERY_NS < self._mean_ns << 32)
+        self._cur = "stage"
         self._marks.append(("stage", now))
         _thread.phase = "stage"
         self._annotate("stage")
@@ -374,12 +575,34 @@ class RoundTimer:
     def enter(self, phase: str) -> None:
         if self._t0 is None:
             return
-        now = self._clock()
+        now = self.clock_ns()
         self._acc[self._cur] += now - self._cur_t
+        if self._phase_cpu:
+            cpu = self.cpu_clock_ns()
+            self._cpu_acc[self._cur] += cpu - self._cur_cpu
+            self._cur_cpu = cpu
+        self._dirty = True
         self._cur, self._cur_t = phase, now
         self._marks.append((phase, now))
         _thread.phase = phase
         self._annotate(phase)
+
+    def part(self, name: str) -> _Part:
+        """Time the body as part ``name`` of the open round (entered more
+        than once a round, its times add up)."""
+        return self._parts[name]
+
+    def add(self, name: str, wall_ns: int) -> None:
+        """Add to part ``name`` of the open round what the caller timed
+        itself (``clock_ns``)."""
+        if self._t0 is not None:
+            self._dirty = True
+            self._part_acc[name] += wall_ns
+
+    def mark(self, name: str) -> None:
+        """Note that the open round reached ``name`` now."""
+        if self._t0 is not None:
+            self._sent[name] = self.clock_ns() - self._t0
 
     @contextlib.contextmanager
     def within(self, name: str):
@@ -394,6 +617,20 @@ class RoundTimer:
             finally:
                 self._annotate(None)
 
+    def _clear(self) -> None:
+        """Close the open round: nothing of it is left for the next pass
+        (an idle pass, the common one, has entered nothing)."""
+        self._t0 = None
+        self._marks.clear()
+        if self._sent:
+            self._sent = {}
+        if self._dirty:
+            self._dirty = False
+            self._acc = dict.fromkeys(ROUND_PHASES[1:], 0)
+            self._cpu_acc = dict.fromkeys(ROUND_PHASES[1:], 0)
+            self._part_acc = dict.fromkeys(ROUND_PARTS, 0)
+        _thread.phase = "none"
+
     def abandon(self) -> None:
         """Drop the open round (a pass that found nothing to do, or
         raised): nothing is recorded, and its ``stage`` annotation, where
@@ -405,31 +642,43 @@ class RoundTimer:
             with contextlib.suppress(Exception):
                 ann.set_metadata(idle=1)
         self._annotate(None)
-        self._t0 = None
-        self._marks.clear()
-        for phase in self._acc:
-            self._acc[phase] = 0
-        _thread.phase = "none"
+        self._clear()
 
     def commit(self, **counts) -> None:
         """End the round and feed the three sinks; ``counts`` ride the
         round's record (staging counts, the sampled lifecycle keys)."""
         if self._t0 is None:
             return
-        now, cpu = self._clock(), self._cpu_clock()
+        now, cpu = self.clock_ns(), self.cpu_clock_ns()
         self._annotate(None)
-        _thread.phase = "none"
-        t0, self._t0 = self._t0, None
+        t0 = self._t0
         acc = self._acc
         acc[self._cur] += now - self._cur_t
+        self._dirty = True
         for phase, ns in acc.items():
             self._hist[phase].observe(ns / 1e3)
-            acc[phase] = 0
-        total_us = (now - t0) / 1e3
+        if self._phase_cpu:
+            cpu_acc, cpu_sum = self._cpu_acc, self._phase_cpu_sum
+            cpu_acc[self._cur] += cpu - self._cur_cpu
+            for phase, ns in cpu_acc.items():
+                cpu_sum[phase] += ns
+        total_ns = now - t0
+        total_us = total_ns / 1e3
         self._hist[ROUND_TOTAL].observe(total_us)
         cpu_us = (cpu - self._cpu0) / 1e3
         self._cpu_hist.observe(cpu_us)
-        marks, self._marks = self._marks, []
+        self._mean_ns += (min(total_ns, PHASE_CPU_EVERY_NS)
+                          - self._mean_ns) >> 6
+        parts, part_sum = {}, self._part_sum
+        for part, ns in self._part_acc.items():
+            part_sum[part] += ns
+            parts[part] = ns // 1000
+        sent, self._sent = self._sent, {}
+        for mark, ns in sent.items():
+            self._mark_sum[mark] += ns
+            self._mark_n[mark] += 1
+            sent[mark] = ns // 1000
+        marks = self._marks
         if self._last_end is not None:
             self._hist["wait"].observe((t0 - self._last_end) / 1e3)
             marks.insert(0, ("wait", self._last_end))
@@ -441,5 +690,14 @@ class RoundTimer:
         rec = {"engine": self.engine, "seq": self._seq, "t0_us": t0 // 1000,
                "phases": [(p, t // 1000) for p, t in marks]
                + [("end", now // 1000)],
-               "cpu_us": int(cpu_us), **counts}
+               "cpu_us": int(cpu_us), "parts": parts, "marks": sent,
+               **counts}
+        self._clear()
         self._book.record(rec)
+
+
+telemetry.GLOBAL.gauge_fn(
+    "process_cpu_us", lambda: time.process_time_ns() // 1000,
+    help="CPU time of the whole process, every thread's (beside the "
+         "engine threads' engine_round_cpu_us), read when a snapshot is "
+         "taken")

@@ -22,7 +22,7 @@ import jax
 import numpy as np
 import pytest
 
-from dragonboat_tpu import capacity, raftpb as pb, telemetry
+from dragonboat_tpu import capacity, raftpb as pb, telemetry, tracing
 from dragonboat_tpu.config import (
     Config, ExpertConfig, MeshSpec, NodeHostConfig,
 )
@@ -35,6 +35,10 @@ from test_mesh_engine import (  # noqa: E402
     close_all, make_cluster, propose_retry,
 )
 from test_nodehost import KVStateMachine, wait_leader  # noqa: E402
+from test_round_budget import (  # noqa: E402
+    check_round_families, check_round_records, part_us, phase_cluster,
+    records_of, round_families,
+)
 
 from benchmark import deployment, traffic as gen  # noqa: E402
 
@@ -311,3 +315,43 @@ def test_mesh_round_phases_crossings_and_counter():
     finally:
         close_all(hosts)
 
+
+
+@pytest.mark.parametrize("depth", [0, 1])
+def test_mesh_rounds_read_their_parts_and_mark_only_what_they_sent(depth):
+    """The mesh engine inherits the round timer's parts: one engine holds
+    the three hosts' futures, so ``finish.apply`` and ``finish.ack`` both
+    read above 0 on it and stay inside ``finish``; while every link is
+    resident a round hands the host transport nothing and makes no mark;
+    with one link cut its REPLICATEs leave by the hub and the round that
+    sent them marks ``replicates_out``."""
+    prefix = f"mshP{depth}x{time.monotonic_ns()}"
+    before = round_families()
+    hosts = phase_cluster(prefix, depth, mesh=MeshSpec(
+        name=prefix, g_size=2, replicas=3, n_local=4))
+    try:
+        lid = wait_leader(hosts, timeout=60)
+        nh, eng = hosts[lid], hosts[lid].mesh_engine
+        sess = nh.get_noop_session(1)
+        propose_retry(nh, sess, b"warm=up")
+        seq = eng._round._seq
+        for i in range(8):
+            propose_retry(nh, sess, f"k{i}=v{i}".encode())
+        resident = records_of(eng, seq)
+        frid = next(r for r in hosts if r != lid)
+        eng.set_link_hub_served(eng.by_shard[(1, lid)], frid, True)
+        seq = eng._round._seq
+        propose_retry(nh, sess, b"during=cut")
+        assert wait_for(lambda: hosts[frid].stale_read(1, "during") == "cut",
+                        30)
+        cut = records_of(eng, seq)
+    finally:
+        close_all(hosts)
+    grown = check_round_families(before, round_families())
+    for part in tracing.ROUND_PARTS:
+        assert grown[f"engine_round_part_us.sum{{part={part}}}"] > 0, part
+    check_round_records(resident + cut)
+    assert part_us(resident, "finish.apply") > 0
+    assert part_us(resident, "finish.ack") > 0
+    assert not any(r["marks"] for r in resident)
+    assert any("replicates_out" in r["marks"] for r in cut)

@@ -86,8 +86,8 @@ class Oracle:
     # -- what the parent wrote its arrays on ---------------------------------
 
     def _inject(self, inner):
-        def inject(lane, node, init, t0):
-            inner(lane, node, init, t0)
+        def inject(lane, node, init):
+            inner(lane, node, init)
             self.seen[lane] = (init.term, init.vote, init.committed, 0, 0)
             self.occ[lane] = True
         return inject

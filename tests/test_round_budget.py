@@ -18,7 +18,7 @@ import time
 
 import pytest
 
-from dragonboat_tpu import capacity, raftpb as pb, telemetry
+from dragonboat_tpu import capacity, raftpb as pb, telemetry, tracing
 from dragonboat_tpu.config import Config, ExpertConfig, NodeHostConfig
 from dragonboat_tpu.nodehost import NodeHost
 
@@ -165,6 +165,152 @@ def test_steady_round_is_one_write_and_one_fsync_of_one_log(tmp_path, depth):
             [f"k{i}={SHARDS}".encode() for i in range(12)]
     finally:
         nh.close()
+
+
+# -- what a round's largest phases are made of --------------------------------
+
+def phase_cluster(prefix, depth, mesh=None):
+    """Three NodeHosts with one three-replica shard: an engine each, or
+    one mesh engine for the three (``mesh``: its ``MeshSpec``)."""
+    addrs = {i: f"{prefix}-{i}" for i in (1, 2, 3)}
+    hosts = {}
+    try:
+        for rid, addr in addrs.items():
+            hosts[rid] = nh = NodeHost(NodeHostConfig(
+                raft_address=addr, rtt_millisecond=5,
+                expert=ExpertConfig(
+                    mesh=mesh, kernel_log_cap=256, kernel_capacity=8,
+                    kernel_apply_batch=16, kernel_compaction_overhead=16,
+                    kernel_pipeline_depth=depth)))
+            nh.start_replica(addrs, False, KVStateMachine, Config(
+                shard_id=1, replica_id=rid, election_rtt=10,
+                heartbeat_rtt=2, device_resident=mesh is None,
+                mesh_resident=mesh is not None))
+    except BaseException:
+        for nh in hosts.values():
+            nh.close()
+        raise
+    return hosts
+
+
+def round_families() -> dict:
+    return {k: v for k, v in telemetry.GLOBAL.snapshot().items()
+            if k.startswith(("engine_round_us.", "engine_round_cpu_us.",
+                             "engine_round_phase_cpu_us.",
+                             "engine_round_part_us.",
+                             "engine_round_mark_us."))}
+
+
+def records_of(eng, after_seq=0) -> list:
+    return [r for r in tracing.ROUNDS.rounds()
+            if r["engine"] == eng._round.engine and r["seq"] > after_seq]
+
+
+def check_round_families(before: dict, after: dict) -> dict:
+    """What the registry's round families grew by between two snapshots
+    taken while no engine ran: the six phase CPU times read in some rounds
+    (not in all: the timer reads them in about one round an engine per
+    40 ms) and never more than the rounds' CPU time, every part summed
+    (the rounds that ``engine_round_us{phase=total}`` counts are their
+    count), a part inside the phase that holds it, a mark at most once a
+    round; -> the growth."""
+    grown = {k: v - before.get(k, 0) for k, v in after.items()}
+    rounds = grown["engine_round_us.count{phase=total}"]
+    assert rounds > 0
+    assert grown["engine_round_cpu_us.count"] == rounds
+    phase_cpu = [grown[f"engine_round_phase_cpu_us.sum{{phase={p}}}"]
+                 for p in tracing.ROUND_PHASES[1:]]
+    assert 0 < sum(phase_cpu) <= grown["engine_round_cpu_us.sum"] * (1 + 1e-6)
+    assert not [k for k in grown
+                if k.startswith(("engine_round_phase_cpu_us.count",
+                                 "engine_round_part_us.count"))]
+    for part in tracing.ROUND_PARTS:
+        assert grown[f"engine_round_part_us.sum{{part={part}}}"] >= 0, part
+    for phase in ("stage", "upload", "resolve", "finish"):
+        inside = sum(grown[f"engine_round_part_us.sum{{part={p}}}"]
+                     for p in tracing.ROUND_PARTS
+                     if p.startswith(phase + "."))
+        assert 0 < inside <= grown[f"engine_round_us.sum{{phase={phase}}}"]
+    for mark in tracing.ROUND_MARKS:
+        assert grown.get(f"engine_round_mark_us.count{{mark={mark}}}", 0) \
+            <= rounds
+    return grown
+
+
+def check_round_records(recs: list) -> None:
+    """Every record: seven parts, each phase's parts inside the phase (a
+    record's times are whole microseconds, so to one a reading), and the
+    marks in a round's order."""
+    for rec in recs:
+        entries = rec["phases"]
+        us: dict = {}
+        for (phase, ts), (_next, end) in zip(entries, entries[1:]):
+            us[phase] = us.get(phase, 0) + end - ts
+        assert set(rec["parts"]) == set(tracing.ROUND_PARTS)
+        slack = len(entries) + len(rec["parts"])
+        for phase in ("stage", "upload", "resolve", "finish"):
+            inside = sum(wall for p, wall in rec["parts"].items()
+                         if p.startswith(phase + "."))
+            assert inside <= us.get(phase, 0) + slack, (phase, rec)
+        marks = rec["marks"]
+        total = entries[-1][1] - rec["t0_us"]
+        assert set(marks) <= set(tracing.ROUND_MARKS)
+        assert all(0 <= at <= total + 1 for at in marks.values()), rec
+        if len(marks) == 2:
+            assert marks["replicates_out"] <= marks["responses_out"]
+
+
+def part_us(recs: list, part: str) -> int:
+    return sum(r["parts"][part] for r in recs)
+
+
+@pytest.mark.parametrize("depth", [0, 1])
+def test_a_busy_round_reads_its_parts_where_their_work_ran(depth):
+    """Three serial engines under writes to one leader: every part reads
+    above 0 where its work ran; ``finish.ack`` only on the engine whose
+    host holds the futures; ``finish.apply`` on all three; a leader's
+    rounds mark ``replicates_out``, the followers' ``responses_out``; and
+    the registry's families close (``check_round_families``)."""
+    from test_mesh_engine import propose_retry
+    from test_nodehost import wait_leader
+
+    before = round_families()
+    hosts = phase_cluster(f"rb-parts{depth}-{time.monotonic_ns()}", depth)
+    try:
+        lid = wait_leader(hosts, timeout=60)
+        nh = hosts[lid]
+        sess = nh.get_noop_session(1)
+        propose_retry(nh, sess, b"warm=up")
+        seqs = {rid: h.kernel_engine._round._seq for rid, h in hosts.items()}
+        term = nh.nodes[1]._leader_term_cache
+        for i in range(12):
+            propose_retry(nh, sess, f"k{i}=v{i}".encode())
+        # (on a loaded box a 50 ms election timeout does move the leader:
+        # who sent what is held only where it stayed)
+        stayed = (wait_leader(hosts, timeout=60) == lid
+                  and nh.nodes[1]._leader_term_cache == term)
+        recs = {rid: records_of(h.kernel_engine, seqs[rid])
+                for rid, h in hosts.items()}
+    finally:
+        for h in hosts.values():
+            h.close()
+    grown = check_round_families(before, round_families())
+    for part in tracing.ROUND_PARTS:
+        assert grown[f"engine_round_part_us.sum{{part={part}}}"] > 0, part
+    for rid, mine in recs.items():
+        assert mine, rid
+        check_round_records(mine)
+        assert part_us(mine, "finish.apply") > 0
+        if rid == lid:
+            assert part_us(mine, "finish.ack") > 0
+            assert part_us(mine, "resolve.send") > 0
+            assert any("replicates_out" in r["marks"] for r in mine)
+        elif stayed:
+            assert part_us(mine, "finish.ack") == 0
+            assert any("responses_out" in r["marks"] for r in mine)
+            assert not any("replicates_out" in r["marks"] for r in mine)
+    assert grown["engine_round_mark_us.count{mark=replicates_out}"] > 0
+    assert grown["engine_round_mark_us.count{mark=responses_out}"] > 0
 
 
 def _retired() -> dict:

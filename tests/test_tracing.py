@@ -122,6 +122,297 @@ def test_round_timer_pipelined_order_and_chrome_row():
     assert book.counts()["recorded"] == 2
 
 
+def test_round_timer_reads_the_cpu_clock_at_the_phase_boundaries():
+    """``engine_round_phase_cpu_us.sum{phase}``: the thread CPU time of
+    each phase, a phase entered twice adding up, 0 where not entered, the
+    six summing to what the unlabelled ``engine_round_cpu_us`` observes
+    for the round (the same reads), in a round that was drawn; in a round
+    that was not, the clock is read at the round's two ends alone, as
+    ever.  (An engine's first rounds are all drawn; the draw itself is the
+    next test's.)"""
+    rt, wall, cpu, reg, book, _m = make_round_timer()
+    reads = []
+    counted = rt.cpu_clock_ns
+    rt.cpu_clock_ns = lambda: reads.append(1) or counted()
+    #: (phase, host us, CPU us) in a serial round's order
+    serial = (("stage", 30, 20), ("upload", 50, 10), ("fetch", 400, 5),
+              ("resolve", 70, 40), ("save", 200, 15), ("resolve", 10, 8),
+              ("finish", 40, 2))
+    short = (("stage", 10, 7), ("upload", 90, 3))
+    for schedule in (serial, short):
+        rt.begin()
+        for i, (phase, us, cpu_us) in enumerate(schedule):
+            if i:
+                rt.enter(phase)
+            wall.tick(us)
+            cpu.tick(cpu_us)
+        rt.commit()
+        with rt:                 # an idle pass between: nothing of it kept
+            wall.tick(3)
+            cpu.tick(3)
+    assert len(reads) == (1 + 6 + 1) + 1 + (1 + 1 + 1) + 1
+    rt._mean_ns = 0              # rounds of no length: none is drawn
+    run_round(rt, wall, cpu, cpu_us=40)
+    assert len(reads) == 13 + 2
+    snap = reg.snapshot()
+    want = {"stage": 27, "upload": 13, "fetch": 5, "resolve": 48,
+            "save": 15, "finish": 2}
+    for phase, us in want.items():
+        assert snap[f"engine_round_phase_cpu_us.sum{{phase={phase}}}"] \
+            == pytest.approx(us), phase
+    assert "engine_round_phase_cpu_us.sum{phase=wait}" not in snap
+    assert "engine_round_phase_cpu_us.sum{phase=total}" not in snap
+    # the whole round's family keeps its name, no label and its reading,
+    # in every round
+    assert snap["engine_round_cpu_us.count"] == 3
+    assert snap["engine_round_cpu_us.sum"] == pytest.approx(
+        sum(want.values()) + 40)
+    assert not [k for k in snap if k.startswith("engine_round_cpu_us")
+                and "{" in k]
+    assert [r["cpu_us"] for r in book.rounds()] == [100, 10, 40]
+
+
+def test_the_rounds_that_read_the_phases_are_drawn_evenly():
+    """Which rounds read the CPU clock at their boundaries depends on the
+    round's number and the engine's slow mean round, not on the round
+    before: with every tenth round three and a half times as long as the
+    others (the engine's collection) about a quarter of the rounds are
+    read (mean round 10 ms of ``PHASE_CPU_EVERY_NS`` 40), of the long
+    ones as of each other tenth, so ``finish`` reads its true share of
+    the CPU time."""
+    rt, wall, cpu, reg, _book, _m = make_round_timer()
+    reads = []
+    counted = rt.cpu_clock_ns
+    rt.cpu_clock_ns = lambda: reads.append(1) or counted()
+    drawn = []
+    for i in range(4_500):
+        long = i % 10 == 9
+        before = len(reads)
+        rt.begin()
+        wall.tick(6_000)
+        cpu.tick(3_000)
+        rt.enter("finish")
+        wall.tick(22_000 if long else 2_000)
+        cpu.tick(17_000 if long else 1_000)
+        rt.commit()
+        drawn.append(len(reads) - before == 3)
+        wall.tick(100)
+    settled = drawn[500:]        # (the first rounds are all read)
+    assert sum(settled) / len(settled) == pytest.approx(0.25, abs=0.02)
+    for tenth in range(10):
+        mine = settled[tenth::10]
+        assert sum(mine) / len(mine) == pytest.approx(0.25, abs=0.04), tenth
+    snap = reg.snapshot()
+    stage = snap["engine_round_phase_cpu_us.sum{phase=stage}"]
+    finish = snap["engine_round_phase_cpu_us.sum{phase=finish}"]
+    truth = (9 * 1 + 17) / (10 * 3 + 9 * 1 + 17)
+    assert finish / (stage + finish) == pytest.approx(truth, rel=0.03)
+    assert rt._mean_ns == pytest.approx(10_000_000, rel=0.2)
+    # an engine whose rounds are 40 ms or longer reads every one
+    rt, wall, cpu, reg, _book, _m = make_round_timer()
+    for _ in range(200):
+        run_round(rt, wall, cpu, schedule=(("stage", 45_000),), cpu_us=10)
+    assert reg.snapshot()["engine_round_phase_cpu_us.sum{phase=stage}"] \
+        == pytest.approx(2_000)
+
+
+def test_a_collected_timer_leaves_its_sums_in_the_gauges():
+    """The sums are the timers' own (no lock or histogram a round); the
+    callback gauges add up every live timer's and keep what a collected
+    one had summed, so a window's growth never reads negative."""
+    import gc
+
+    rt, wall, cpu, reg, book, m = make_round_timer()
+    other = RoundTimer(m, "engine.other", engine="e2", registry=reg,
+                       book=book, clock_ns=wall, cpu_clock_ns=cpu)
+    for timer in (rt, other):
+        timer.begin()
+        with timer.part("stage.reset"):
+            wall.tick(4)
+        timer.mark("responses_out")
+        timer.commit()
+    key = "engine_round_part_us.sum{part=stage.reset}"
+    assert reg.snapshot()[key] == pytest.approx(8)
+    assert reg.kind_of("engine_round_part_us.sum") == "gauge"
+    del rt, timer
+    gc.collect()
+    snap = reg.snapshot()
+    assert snap[key] == pytest.approx(8)
+    assert snap["engine_round_mark_us.count{mark=responses_out}"] == 2
+    other.begin()
+    with other.part("stage.reset"):
+        wall.tick(5)
+    other.commit()
+    assert reg.snapshot()[key] == pytest.approx(13)
+
+
+def test_round_timer_parts_stay_inside_their_phase():
+    """``part`` (entered once or twice a round) and ``add`` (what the
+    caller summed itself) feed ``engine_round_part_us.sum{part}``, host
+    time only (no part has a CPU family), summed over the committed
+    rounds (which ``engine_round_us{phase=total}`` counts); the phases'
+    own histograms are untouched; the record carries all seven, 0 where
+    not entered."""
+    rt, wall, cpu, reg, book, _m = make_round_timer()
+    rt.begin()
+    with rt.part("stage.reset"):
+        wall.tick(4)
+    wall.tick(6)
+    rt.enter("upload")
+    wall.tick(20)
+    with rt.part("upload.release"):
+        wall.tick(9)
+        cpu.tick(1)
+    rt.enter("resolve")
+    with rt.part("resolve.send"):
+        wall.tick(5)
+    rt.enter("save")
+    wall.tick(50)
+    rt.enter("resolve")
+    with rt.part("resolve.send"):
+        wall.tick(7)
+    rt.enter("finish")
+    wall.tick(30)
+    cpu.tick(12)
+    rt.add("finish.apply", 11_000)
+    rt.add("finish.ack", 8_000)
+    rt.add("finish.ack", 6_000)
+    rt.commit()
+    run_round(rt, wall, cpu)     # a round that entered no part
+    snap = reg.snapshot()
+    want = {"stage.reset": 4, "stage.tick": 0, "upload.release": 9,
+            "upload.applied": 0, "resolve.send": 12, "finish.apply": 11,
+            "finish.ack": 14}
+    assert set(want) == set(tracing.ROUND_PARTS)
+    for part, us in want.items():
+        assert snap[f"engine_round_part_us.sum{{part={part}}}"] \
+            == pytest.approx(us), part
+    assert snap["engine_round_us.count{phase=total}"] == 2
+    assert not [k for k in snap if k.startswith("engine_round_part_us.count")]
+    assert not [k for k in snap if k.startswith("engine_round_part_cpu_us")]
+    # a part's time is inside its phase's, which reads what it read
+    # without parts: 10 + 30, 29 + 50, 12 + 80, 30 + 40
+    phases = {"stage": 40, "upload": 79, "resolve": 92, "finish": 70}
+    for phase, us in phases.items():
+        assert snap[f"engine_round_us.sum{{phase={phase}}}"] \
+            == pytest.approx(us), phase
+        inside = sum(v for p, v in want.items() if p.startswith(phase))
+        assert inside <= us
+    first, second = book.rounds()
+    assert first["parts"] == want
+    assert second["parts"] == dict.fromkeys(want, 0)
+    assert first["marks"] == second["marks"] == {}
+
+
+def test_round_timer_marks_only_the_rounds_that_sent():
+    """``engine_round_mark_us{mark}`` holds the time from a round's start
+    to ``mark``: its count is the rounds that made the mark, and the
+    record and ``/trace`` carry it (an instant event in the engine's row,
+    in clock order)."""
+    from dragonboat_tpu.lifecycle import validate_chrome_trace
+
+    rt, wall, cpu, reg, book, _m = make_round_timer(engine="host-a")
+    rt.begin()
+    wall.tick(30)
+    rt.enter("resolve")
+    wall.tick(5)
+    rt.mark("replicates_out")
+    wall.tick(1)
+    rt.enter("save")
+    wall.tick(20)
+    rt.enter("resolve")
+    wall.tick(2)
+    rt.mark("responses_out")
+    wall.tick(1)
+    rt.enter("finish")
+    wall.tick(10)
+    rt.commit()
+    wall.tick(4)
+    rt.begin()                   # a follower's round: nothing to replicate
+    wall.tick(12)
+    rt.mark("responses_out")
+    wall.tick(1)
+    rt.commit()
+    run_round(rt, wall, cpu)     # and one that sent nothing at all
+    snap = reg.snapshot()
+    assert snap["engine_round_mark_us.count{mark=replicates_out}"] == 1
+    assert snap["engine_round_mark_us.sum{mark=replicates_out}"] \
+        == pytest.approx(35)
+    assert snap["engine_round_mark_us.count{mark=responses_out}"] == 2
+    assert snap["engine_round_mark_us.sum{mark=responses_out}"] \
+        == pytest.approx(58 + 12)
+    assert snap["engine_round_us.count{phase=total}"] == 3
+    recs = book.rounds()
+    assert [r["marks"] for r in recs] == [
+        {"replicates_out": 35, "responses_out": 58}, {"responses_out": 12},
+        {}]
+    events = book.chrome_events()
+    assert validate_chrome_trace({"traceEvents": events}) == len(events)
+    assert {(e["pid"], e["tid"]) for e in events} == {("engine", "host-a")}
+    instants = [(e["name"], e["ts"]) for e in events if e["ph"] == "i"]
+    t0, t1 = recs[0]["t0_us"], recs[1]["t0_us"]
+    assert instants == [("replicates_out", t0 + 35),
+                        ("responses_out", t0 + 58),
+                        ("responses_out", t1 + 12)]
+    assert [e["name"] for e in events[:7]] == [
+        "stage", "resolve", "replicates_out", "save", "resolve",
+        "responses_out", "finish"]
+    spans = [e for e in events if e["ph"] == "X"]
+    assert spans[0]["args"]["parts"] == recs[0]["parts"]
+    assert spans[0]["args"]["marks"] == recs[0]["marks"]
+
+
+@pytest.mark.parametrize("how", ["outside", "abandoned", "raised"])
+def test_parts_and_marks_of_no_round_are_dropped(how):
+    """``part``, ``add`` and ``mark`` outside an open round do nothing,
+    and those of a pass that is abandoned, or raises, are recorded
+    nowhere: the next round starts from nothing."""
+    rt, wall, cpu, reg, book, _m = make_round_timer()
+
+    def feed():
+        with rt.part("stage.reset"):
+            wall.tick(5)
+        rt.add("finish.ack", 7_000)
+        rt.mark("replicates_out")
+
+    if how == "outside":
+        feed()
+    elif how == "abandoned":
+        with rt:
+            feed()
+    else:
+        with pytest.raises(ZeroDivisionError):
+            with rt:
+                rt.enter("finish")
+                cpu.tick(9)
+                feed()
+                1 / 0
+    snap = reg.snapshot()
+    assert not [k for k, v in snap.items() if v
+                and k.startswith("engine_round_")]
+    assert book.rounds() == []
+    run_round(rt, wall, cpu)
+    snap = reg.snapshot()
+    assert snap["engine_round_us.count{phase=total}"] == 1
+    assert snap["engine_round_part_us.sum{part=stage.reset}"] == 0
+    assert snap["engine_round_part_us.sum{part=finish.ack}"] == 0
+    assert snap["engine_round_phase_cpu_us.sum{phase=finish}"] \
+        == pytest.approx(100)
+    assert snap.get("engine_round_mark_us.count{mark=replicates_out}", 0) == 0
+    (rec,) = book.rounds()
+    assert rec["marks"] == {} and not any(rec["parts"].values())
+
+
+def test_the_process_cpu_clock_is_a_callback_gauge():
+    """``process_cpu_us``: the whole process's CPU time, read when a
+    snapshot is taken (nothing on the hot path feeds it)."""
+    a = telemetry.GLOBAL.snapshot()["process_cpu_us"]
+    sum(i * i for i in range(200_000))
+    b = telemetry.GLOBAL.snapshot()["process_cpu_us"]
+    assert isinstance(a, int) and b > a >= 0
+    assert telemetry.GLOBAL.kind_of("process_cpu_us") == "gauge"
+
+
 def test_round_timer_sets_the_thread_phase():
     rt, wall, cpu, *_ = make_round_timer()
     assert tracing.current_phase() == "none"
@@ -373,6 +664,85 @@ def test_start_trace_passes_options_and_marks_the_clock(fake_profiler,
     tracing.start_trace(str(tmp_path))
     assert fake_profiler.options is None
     tracing.stop_trace()
+
+
+def test_a_capture_armed_by_hand_gets_one_clock_mark(fake_profiler,
+                                                      tmp_path):
+    """The benchmark harness starts the profiler itself and sets
+    ``_active_trace_dir`` by hand: the first annotation the round timer
+    writes into such a capture is preceded by ONE ``tracing.clock_sync``
+    mark (whichever engine's timer comes first), a later capture gets its
+    own, and ``start_trace``'s two marks stay two."""
+    rt, wall, cpu, *_ = make_round_timer(engine="h")
+    other, *_ = make_round_timer(engine="g")
+
+    def names():
+        return [(op, name) for op, name, _meta, _t in fake_profiler.log]
+
+    run_round(rt, wall, cpu)             # no capture: nothing written
+    assert names() == []
+    for d in ("a", "b", "b"):            # (the same directory twice, too)
+        del fake_profiler.log[:]
+        before = tracing.monotonic_us()
+        tracing._active_trace_dir = str(tmp_path / d)
+        run_round(rt, wall, cpu, schedule=(("stage", 10), ("upload", 20)))
+        with other:
+            pass
+        tracing._active_trace_dir = None
+        with rt:                         # the timer sees the capture end
+            pass
+        log = names()
+        sync = ("enter", tracing.CLOCK_SYNC), ("exit", tracing.CLOCK_SYNC)
+        assert tuple(log[:2]) == sync
+        assert log[2] == ("enter", "kernel_engine.stage")
+        assert log.count(sync[0]) == 1
+        meta = fake_profiler.log[0][2]
+        assert before <= meta["monotonic_us"] <= tracing.monotonic_us()
+    # a capture start_trace armed holds its own two marks and no third
+    del fake_profiler.log[:]
+    tracing.start_trace(str(tmp_path / "c"))
+    run_round(rt, wall, cpu)
+    tracing.stop_trace()
+    assert names().count(("enter", tracing.CLOCK_SYNC)) == 2
+    assert names()[:3] == [("enter", tracing.CLOCK_SYNC),
+                           ("exit", tracing.CLOCK_SYNC),
+                           ("enter", "kernel_engine.stage")]
+
+
+def test_three_engine_threads_write_one_mark_ahead_of_every_annotation(
+        fake_profiler, tmp_path, monkeypatch):
+    """Up to three engine threads meet a capture armed by hand at once:
+    one of them writes the mark, and the others wait for it before they
+    write their own first annotation (a mark that is slow to write shows
+    the order)."""
+    sync = tracing._clock_sync
+
+    def slow_sync():
+        time.sleep(0.05)
+        sync()
+
+    monkeypatch.setattr(tracing, "_clock_sync", slow_sync)
+    timers = [make_round_timer(engine=f"h{i}")[0] for i in range(3)]
+    gate = threading.Barrier(3)
+
+    def one_pass(rt):
+        gate.wait()
+        with rt:
+            pass
+
+    tracing._active_trace_dir = str(tmp_path / "raced")
+    threads = [threading.Thread(target=one_pass, args=(rt,))
+               for rt in timers]
+    for t in threads:
+        t.start()
+    for t in threads:
+        t.join()
+    tracing._active_trace_dir = None
+    log = [(op, name) for op, name, _meta, _t in fake_profiler.log]
+    assert log[:2] == [("enter", tracing.CLOCK_SYNC),
+                       ("exit", tracing.CLOCK_SYNC)]
+    assert log.count(("enter", tracing.CLOCK_SYNC)) == 1
+    assert log.count(("enter", "kernel_engine.stage")) == 3
 
 
 def one_shard_host(address):
