@@ -67,6 +67,7 @@ KERNEL_FILE = "dragonboat_tpu/core/kernel.py"
 CONTRACT_FILES = (
     "dragonboat_tpu/core/kstate.py",
     "dragonboat_tpu/core/kernel.py",
+    "dragonboat_tpu/core/fleet.py",
     "dragonboat_tpu/core/health.py",
     "dragonboat_tpu/core/invariants.py",
 )
@@ -1340,6 +1341,40 @@ def runtime_check(kp=None, num_shards: int = _CHECK_SHARDS,
         _invariants._check_invariants_impl, state, inv_digest)
     diff("InvariantReport", inv_report)
     diff("InvariantDigest", new_inv_digest)
+
+    # the collection (core/digest.py) packs the three reports into one
+    # int32 vector and carries the two digests as the columns of one
+    # [G, W] array: as long, and as wide, as the five classes declare
+    from math import prod
+
+    from dragonboat_tpu.core import digest as _digest
+    from dragonboat_tpu.core import fleet as _fleet
+
+    reports = ("FleetStats", "HealthReport", "InvariantReport")
+    digests = ("HealthDigest", "InvariantDigest")
+    if not all(cls in ctx.contracts for cls in reports + digests):
+        return findings         # a fixture tree without these modules
+    axis_env.update({"ROLES": _fleet.NUM_ROLES,
+                     "LAGB": len(_fleet.LAG_BUCKETS) + 1,
+                     "INBOXB": len(_fleet.INBOX_BUCKETS) + 1})
+    vec, carry = jax.eval_shape(
+        lambda st, bx, dg: _digest._fleet_digest_impl(
+            st, bx, dg, k=_health.DEFAULT_TOP_K),
+        state, box.from_, _digest.empty_carry(G))
+    declared = {
+        "packed reports": (vec, (sum(
+            prod(axis_env.get(a, -1) for a in fc.axes)
+            for cls in reports for fc in ctx.contracts[cls].values()),)),
+        "carried digests": (carry, (G, sum(
+            len(ctx.contracts[cls]) for cls in digests))),
+    }
+    for what, (got, shape) in declared.items():
+        if tuple(got.shape) != shape or _dtype_name(got.dtype) != "i32":
+            findings.append(Finding(
+                PASS, "dragonboat_tpu/core/digest.py", 1, "KC007",
+                f"the collection's {what}: the contracts declare i32 "
+                f"{shape} but the program gives {got.dtype} "
+                f"{tuple(got.shape)}"))
     return findings
 
 

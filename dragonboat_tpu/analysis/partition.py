@@ -36,7 +36,7 @@ discipline, driven by the ``part=``/``collective=`` tags on the
          ``jax.device_get`` inside the step_all/staging methods of
          ``kernel_engine.py``/``mesh_engine.py`` (the designated sync
          points — ``_process_outputs``, ``_device_pending``,
-         ``_collect_fleet_stats`` — are exempt by design)
+         ``_collect_digest`` — are exempt by design)
 
 Static scope: the abstract interpreter (subclassing the contracts
 pass's ``_Interp``) runs over ``core/fleet.py`` and ``parallel/ici.py``
@@ -143,7 +143,7 @@ _CALLBACKS = frozenset({"pure_callback", "io_callback", "host_callback"})
 # Methods on the engine step/staging path where a surprise sync stalls
 # every lane.  The designated sync points are exempt by design:
 # _process_outputs (the one fetch per step), _device_pending (the mesh
-# drain probe), _collect_fleet_stats / _fleet_inbox_from (decimated).
+# drain probe), _collect_digest (decimated).
 HOT_PATH_FUNCS = frozenset({
     "step_all", "mark_dirty", "_kernel_call", "_stage_lane",
     "_stage_props", "_prop_target", "dispatch",

@@ -107,6 +107,7 @@ CONTRACT_FILES = (
 
 #: every file any leg reads — scripts/lint.py --changed-only scope
 SCOPE = ENGINE_FILES + CONTRACT_FILES + (
+    "dragonboat_tpu/core/digest.py",
     "dragonboat_tpu/core/kernel.py",
     "dragonboat_tpu/core/params.py",
     "dragonboat_tpu/parallel/ici.py",
@@ -124,15 +125,13 @@ CACHE_SOURCES = SCOPE[:-1] + (
 #: telemetry reductions classified alongside DISPATCH_ENTRIES: the
 #: jitted impls whose signatures the TB001 parameter check reads
 TELEMETRY_ENTRIES = {
-    "fleet_stats": ("dragonboat_tpu/core/fleet.py", "_fleet_stats_impl"),
-    "fleet_health": ("dragonboat_tpu/core/health.py", "_fleet_health_impl"),
-    "check_invariants": ("dragonboat_tpu/core/invariants.py",
-                         "_check_invariants_impl"),
+    "fleet_digest": ("dragonboat_tpu/core/digest.py", "_fleet_digest_impl"),
 }
 
 #: entry parameters that are static/jit-metadata, never array crossings
 STATIC_PARAMS = frozenset({
-    "kp", "cluster", "cl", "replicas", "thresholds", "k", "step_fn",
+    "kp", "cluster", "cl", "replicas", "thresholds", "k", "probe",
+    "step_fn",
 })
 
 #: conventional parameter name -> contract class (the partition pass's
@@ -145,10 +144,8 @@ from dragonboat_tpu.analysis.partition import (  # noqa: E402
 from dragonboat_tpu.analysis import contracts as _ct  # noqa: E402
 
 #: engine-held device trees beyond the partition pass's set (the
-#: telemetry digest carries)
-_SELF_ATTRS = frozenset(_DEVICE_SELF_ATTRS) | {
-    "_health_digest", "_inv_digest",
-}
+#: collection's carried [G, 17] digest array)
+_SELF_ATTRS = frozenset(_DEVICE_SELF_ATTRS) | {"_digest"}
 
 #: geometry the budget/ledger sizes at when no budget file declares one
 #: (the bench sweet spot, bench_loop.bench_params(3) + 1024 groups)

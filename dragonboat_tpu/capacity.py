@@ -49,6 +49,7 @@ wrapped jitted call itself runs outside the lock.
 
 from __future__ import annotations
 
+import functools
 import threading
 from collections import deque
 
@@ -147,11 +148,19 @@ def predict_bytes(kp, num_groups: int, classes=MODEL_CLASSES,
             * int(num_groups))
 
 
+@functools.lru_cache(maxsize=None)
+def resident_bytes_per_group(kp, classes=RESIDENT_CLASSES) -> int:
+    """Analytic bytes a group of ``classes`` costs as an engine keeps it
+    (PACKED_RESIDENT): a constant of the geometry, walked once a process
+    (an engine asks at every collection)."""
+    return model_bytes_per_group(kp, tuple(classes), PACKED_RESIDENT)["total"]
+
+
 def max_g_for_budget(kp, budget_bytes: int,
                      classes=RESIDENT_CLASSES) -> int:
     """Largest G whose resident footprint, as an engine keeps it, fits
     ``budget_bytes``."""
-    per_group = model_bytes_per_group(kp, classes, PACKED_RESIDENT)["total"]
+    per_group = resident_bytes_per_group(kp, tuple(classes))
     if budget_bytes <= 0 or per_group <= 0:
         return 0
     return int(budget_bytes) // per_group
@@ -622,7 +631,7 @@ def engine_snapshot(kp, num_groups: int, live_bytes: int, peak_bytes: int,
         pressure = headroom < float(watermark_pct)
     else:
         headroom, pressure = 100.0, False
-    per_group = model_bytes_per_group(kp, classes, PACKED_RESIDENT)["total"]
+    per_group = resident_bytes_per_group(kp, tuple(classes))
     return {
         "ticks": int(ticks),
         "capacity": int(num_groups),

@@ -576,10 +576,8 @@ def _health_differential(eng) -> tuple[bool, dict, dict]:
     from dragonboat_tpu.core import health as _health
 
     with eng.mu:
-        if eng._health_digest is None:
-            eng._health_digest = eng._make_health_digest()
         state, inbox = eng.state, eng._fleet_inbox_from()
-        digest = eng._health_digest
+        digest = eng._health_digest     # a view of the carried columns
         report, _ = _health.fleet_health(
             state, inbox, digest, thresholds=eng.health_thresholds,
             k=eng.health_top_k)

@@ -157,9 +157,11 @@ class ExpertConfig:
     kernel_compaction_overhead: int = 64
     # max device-resident shards per NodeHost (lanes of the batched state)
     kernel_capacity: int = 1024
-    # device-side fleet telemetry decimation: the engines run the jitted
-    # fleet_stats reduction (core/fleet.py) every N steps and fetch one
-    # small struct to host; 0 disables the reduction entirely
+    # device-side fleet telemetry decimation: every N steps the engines
+    # run ONE jitted collection over the resident state (core/digest.py:
+    # the fleet_stats reduction of core/fleet.py and, where they are on,
+    # the health triage and the invariant probe below) and fetch ONE flat
+    # int32 vector to host; 0 disables the collection entirely
     fleet_stats_every: int = 10
     # engine software-pipeline depth (engine/kernel_engine.py): 0 runs
     # the serial stage->dispatch->fetch->process loop (the differential
@@ -167,9 +169,10 @@ class ExpertConfig:
     # step, dispatching through the donating jit entry
     kernel_pipeline_depth: int = 0
     # device-side health engine (core/health.py): rides the
-    # fleet_stats_every decimation, classifying every group into the
-    # anomaly taxonomy and fetching one O(K) triage report to host.
-    # health_top_k sizes the worst-offender list; 0 disables the pass
+    # fleet_stats_every decimation inside the same program, classifying
+    # every group into the anomaly taxonomy; its O(K) triage report is a
+    # block of the collection's one vector.  health_top_k sizes the
+    # worst-offender list; 0 leaves the pass and its block out
     health_top_k: int = 8
     # anomaly trip points, in health ticks (churn_trip is a leaky-bucket
     # level: each observed leadership handoff adds CHURN_INC=4, the
@@ -180,11 +183,12 @@ class ExpertConfig:
     health_churn_trip: int = 8
     health_runaway_ticks: int = 4
     # runtime protocol-invariant probe (core/invariants.py): rides the
-    # fleet_stats_every decimation, evaluating the declared
-    # core/kstate.py INVARIANTS over every group and fetching one O(1)
-    # verdict report.  Any violation is a BUG (kernel or declaration):
-    # it raises an invariant_violation flight event and degrades
-    # /healthz.  False disables the pass
+    # fleet_stats_every decimation inside the same program, evaluating
+    # the declared core/kstate.py INVARIANTS over every group; its O(1)
+    # verdict report is a block of the collection's one vector.  Any
+    # violation is a BUG (kernel or declaration): it raises an
+    # invariant_violation flight event and degrades /healthz.  False
+    # leaves the pass and its block out
     invariant_probe: bool = True
     # proposal-lifecycle tracing (lifecycle.py): every Nth proposal key
     # carries an end-to-end span stamped at each host hop (propose,

@@ -4,7 +4,9 @@ At 10^4–10^5 lanes, "how many shards are leaderless right now" must not
 be answered by iterating shards on host — one vectorized reduction over
 the resident ``ShardState`` produces a single small ``FleetStats``
 struct, and a decimation knob on the engines (``fleet_stats_every``)
-bounds the host transfer to one struct every N steps.
+bounds the host transfer to one every N steps: the struct's 11 fields
+ride the flat int32 vector of the engines' one collection program
+(core/digest.py), beside the health and invariant reports.
 
 ``fleet_stats`` is jitted and tracer-safe (pure jnp ops, no Python
 branching on traced values); the host-side helpers below turn a fetched
@@ -63,7 +65,8 @@ def bucket_labels(bounds) -> tuple:
 
 
 class FleetStats(NamedTuple):
-    """One host transfer's worth of fleet telemetry (all i32)."""
+    """The fleet telemetry of one collection (all i32): the first block of
+    the one vector an engine fetches (core/digest.py ``layout``)."""
 
     occupied: jnp.ndarray         # [] — lanes with >= 1 configured peer
     role_count: jnp.ndarray       # [NUM_ROLES]
@@ -128,7 +131,13 @@ fleet_stats = jax.jit(_fleet_stats_impl)
 def stats_to_dict(stats: FleetStats) -> dict:
     """Fetch to host and flatten into plain ints/dicts — the shape the
     callback gauges (and ``engine.last_fleet``) serve."""
-    s = jax.device_get(stats)
+    return host_dict(jax.device_get(stats))
+
+
+def host_dict(s: FleetStats) -> dict:
+    """``stats_to_dict`` of a struct already on the host: numpy values, or
+    the Python ints and lists the engines decode from their packed digest
+    (core/digest.py)."""
     lag_labels = bucket_labels(LAG_BUCKETS)
     inbox_labels = bucket_labels(INBOX_BUCKETS)
     return {

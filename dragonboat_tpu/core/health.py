@@ -31,9 +31,12 @@ Anomaly classes (bit ``c`` of a group's ``flags`` word):
 
 ``fleet_health`` is jitted and tracer-safe; the digest stays device
 resident (``part=G`` — the partition pass verifies no cross-G flow
-outside the declared reduction below), and the ``HealthReport`` is the
-single small host transfer, riding the same ``fleet_stats_every``
-decimation as FleetStats.  ``recount`` is the pure-python differential
+outside the declared reduction below), and the ``HealthReport`` is O(K)
+whatever the group count.  An engine runs ``_fleet_health_impl`` inside
+its one collection program (core/digest.py), on the same
+``fleet_stats_every`` decimation as FleetStats: the report is a block of
+that program's one vector, the digest ten columns of its one carried
+array.  ``recount`` is the pure-python differential
 oracle the tests and the chaos detector cross-check against.
 """
 
@@ -148,7 +151,7 @@ class HealthDigest(NamedTuple):
 
 
 class HealthReport(NamedTuple):
-    """One O(K) host transfer's worth of triage (all i32)."""
+    """One collection's O(K) triage (all i32)."""
 
     class_count: jnp.ndarray      # [NUM_CLASSES]
     anomalous: jnp.ndarray        # [] groups with any class tripped
@@ -327,7 +330,13 @@ def report_to_dict(report: HealthReport) -> dict:
     """Fetch to host and flatten into plain ints/dicts — the shape the
     callback gauges (and ``engine.last_health``) serve.  Healthy top-K
     padding (score 0) is dropped from ``worst``."""
-    r = jax.device_get(report)
+    return host_dict(jax.device_get(report))
+
+
+def host_dict(r: HealthReport) -> dict:
+    """``report_to_dict`` of a report already on the host: numpy values,
+    or the Python ints and lists the engines decode from their packed
+    digest (core/digest.py)."""
     worst = []
     for j in range(len(r.worst_idx)):
         sc = int(r.worst_score[j])

@@ -9,9 +9,11 @@ counter) between decimated probe ticks so STEP-scoped invariants
 (term/commit monotonicity, vote-at-most-once, quorum-backed commit
 advance) are checked over the transition between two observations —
 sound for the monotone/guarded forms kstate.py declares, at any
-decimation.  The ``InvariantReport`` is the single O(1) host transfer:
-a violation total, per-invariant counts, and the first-offender lane +
-its violation bitmask.
+decimation.  The ``InvariantReport`` is O(1): a violation total,
+per-invariant counts, and the first-offender lane + its violation
+bitmask.  An engine runs ``_check_invariants_impl`` inside its one
+collection program (core/digest.py): the report is a block of that
+program's one vector, the digest seven columns of its one carried array.
 
 A nonzero total is ALWAYS a bug — either in the kernel or in the
 declared invariant — never an operational condition: the engines raise
@@ -100,7 +102,7 @@ class InvariantDigest(NamedTuple):
 
 
 class InvariantReport(NamedTuple):
-    """One O(1) host transfer's worth of verdicts (all i32)."""
+    """One collection's O(1) verdicts (all i32)."""
 
     total: jnp.ndarray           # [] groups violating >= 1 invariant
     checked: jnp.ndarray         # [] occupied groups evaluated
@@ -220,7 +222,13 @@ def _decode_mask(mask: int) -> list[str]:
 def report_to_dict(report: InvariantReport) -> dict:
     """Fetch to host and flatten into plain ints/dicts — the shape the
     callback gauges (and ``engine.last_invariants``) serve."""
-    r = jax.device_get(report)
+    return host_dict(jax.device_get(report))
+
+
+def host_dict(r: InvariantReport) -> dict:
+    """``report_to_dict`` of a report already on the host: numpy values,
+    or the Python ints and lists the engines decode from their packed
+    digest (core/digest.py)."""
     d = {
         "total": int(r.total),
         "checked": int(r.checked),

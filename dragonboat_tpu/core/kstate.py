@@ -929,6 +929,13 @@ def inbox_columns(kp) -> tuple[tuple, int]:
     return cols, cols[-1].start + cols[-1].width
 
 
+def box_senders(kp, box):
+    """[G, K] sender ids of a carried [G, Wi] inbox (traced inside a
+    program: the mesh's ``box_from``, the engines' collection)."""
+    return column_value(
+        next(c for c in inbox_columns(kp)[0] if c.field == "from_"), box)
+
+
 @functools.lru_cache(maxsize=None)
 def resident_program(kp, fn, static_argnames=()):
     """``fn(state, ...)`` as a jitted program that takes the resident form

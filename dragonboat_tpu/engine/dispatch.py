@@ -40,6 +40,7 @@ engine-unity pass enforces (pure literals, parsed with
 from __future__ import annotations
 
 import jax
+import jax.numpy as jnp
 import numpy as np
 
 from dragonboat_tpu import capacity as _capacity
@@ -85,8 +86,8 @@ STEP_LOOP_METHODS = (
     "_fleet_inbox_from",
     "_capacity_trees",
     "_capacity_model_classes",
-    "_make_health_digest",
-    "_make_invariant_digest",
+    "_make_digest",
+    "_collect_digest",
 )
 
 #: sanctioned subclass seams: addressing, membership, escalation,
@@ -168,6 +169,12 @@ SYNC_POINTS = {
         "why": "the round's ONE download: the packed [G, Wd] array the "
                "jitted entry wrote (flags, StepOutput, save-window terms)",
     },
+    "KernelEngine._collect_digest": {
+        "tag": "digest_down",
+        "why": "the every-Nth-round collection's ONE download: the flat "
+               "int32 vector of the fleet, health and invariant reports "
+               "(core/digest.py), read by one tolist",
+    },
     "KernelEngine._save_terms": {
         "tag": "save_window_row",
         "why": "whole-ring-row fallback of a lane whose save window is "
@@ -201,6 +208,11 @@ SYNC_POINTS = {
 #: their resident form (kstate.py ``ResidentState``; the mesh backend's
 #: carried inbox one [G, Wi] array) and every program that reads or
 #: writes them converts inside itself.
+#: ``fleet_digest`` is the every-tenth-round collection (core/digest.py):
+#: the three reports ride ONE int32 vector down, the two digests stay
+#: resident as the columns of one carried [G, 17] array; its second upload
+#: is the rare reset of the lanes whose occupant changed (``[3, N]`` cells
+#: of that array, the invariant digest's age).
 #: ``_control`` rows are step-loop control-plane crossings (admissions,
 #: membership, lane clearing, telemetry) that belong to no single entry;
 #: a ``[3, N] i32`` value is ``_write_cells``' one upload (row, column,
@@ -276,43 +288,20 @@ TRANSFER_LEDGER = {
              "tag": "save_window_row", "per_step": False, "masked": True},
         ),
     },
-    "fleet_stats": {
-        "resident": ("ShardState",),
+    "fleet_digest": {
+        "resident": ("ShardState", "HealthDigest", "InvariantDigest"),
         "up": (
             {"value": "[G, K] i32", "param": "inbox_from",
-             "site": "KernelEngine._collect_fleet_stats",
-             "tag": "fleet_down", "per_step": False},
+             "site": "SerialDispatch.digest_inbox",
+             "tag": "digest_down", "per_step": False},
+            {"value": "[3, 16] i32",
+             "site": "KernelEngine._collect_digest",
+             "tag": "digest_down", "per_step": False},
         ),
         "down": (
-            {"value": "FleetStats",
-             "site": "KernelEngine._collect_fleet_stats",
-             "tag": "fleet_down", "per_step": False},
-        ),
-    },
-    "fleet_health": {
-        "resident": ("ShardState", "HealthDigest"),
-        "up": (
-            {"value": "[G, K] i32", "param": "inbox_from",
-             "site": "KernelEngine._collect_health",
-             "tag": "health_down", "per_step": False},
-        ),
-        "down": (
-            {"value": "HealthReport",
-             "site": "KernelEngine._collect_health",
-             "tag": "health_down", "per_step": False},
-        ),
-    },
-    "check_invariants": {
-        "resident": ("ShardState", "InvariantDigest"),
-        "up": (
-            {"value": "[G] i32",
-             "site": "KernelEngine._collect_invariants",
-             "tag": "invariants_down", "per_step": False},
-        ),
-        "down": (
-            {"value": "InvariantReport",
-             "site": "KernelEngine._collect_invariants",
-             "tag": "invariants_down", "per_step": False},
+            {"value": ("FleetStats", "HealthReport", "InvariantReport"),
+             "packed": True, "site": "KernelEngine._collect_digest",
+             "tag": "digest_down", "per_step": False},
         ),
     },
     "_control": (
@@ -398,6 +387,12 @@ class SerialDispatch:
         """[G, K] sender ids for the inbox-occupancy histogram — the
         host-staged builder is the inbox here."""
         return inbox_buf.from_
+
+    def digest_inbox(self, inbox_buf):
+        """The collection's inbox argument: the host-staged sender ids as
+        ONE explicit upload (handed over as a numpy array they went up
+        once for every program that took them)."""
+        return jnp.asarray(inbox_buf.from_)
 
     def shard(self, tree):
         """Single device: placement is a no-op."""
@@ -506,8 +501,13 @@ class MeshDispatch:
 
     def inbox_from(self, inbox_buf):
         # the mesh inbox is device-resident between steps; no host copy
-        # (one slice of the carried array, every tenth round)
+        # (one slice of the carried array, for callers outside a round)
         return box_from(self.cluster.kp, self._box)
+
+    def digest_inbox(self, inbox_buf):
+        """The collection's inbox argument: the carried [G, Wi] array as
+        it is; the program slices the sender ids out itself."""
+        return self._box
 
     def shard(self, tree):
         """Place a [G]-leading pytree onto the mesh (digests and the

@@ -160,7 +160,20 @@ ADD_SHARD_LOCK_US = telemetry.GLOBAL.histogram(
     "engine_add_shard_lock_us",
     help="add_shard's wait for the admission lock, per call")
 _INJECT_BATCH = 8       # least rows of one flush's program
-_CELL_BATCH = 16        # least cells of one _write_cells program
+_CELL_BATCH = 16        # least cells of one write_cells_program
+
+
+def _cell_batch(rows, cols, values) -> np.ndarray:
+    """The ``[3, N] int32`` host array ``write_cells_program`` takes: cell
+    ``i`` is ``(rows[i], cols[i], values[i])``; the batch is padded with
+    copies of its last cell to a power of two, so the program compiles once
+    per size class."""
+    n = len(rows)
+    size = max(_CELL_BATCH, 1 << (n - 1).bit_length())
+    cells = np.empty((3, size), np.int32)
+    cells[:, :n] = (rows, cols, values)
+    cells[:, n:] = cells[:, n - 1:n]
+    return cells
 _INJECT_FLUSH_US = telemetry.GLOBAL.histogram(
     "engine_inject_flush_us",
     help="one batch of queued lane injections written into the device "
@@ -657,11 +670,20 @@ class KernelEngine:
         self._apply_ns = self._ack_ns = 0
         maybe_start_from_env()
         self.events.metrics.set("engine.pipeline.depth", self.pipeline_depth)
-        # decimated device-side fleet telemetry (core/fleet.py): every N
-        # steps one jitted reduction over the resident state fetches ONE
-        # small struct to host; 0 disables
+        # the decimated collection (core/digest.py): every N steps ONE
+        # jitted program over the resident state runs the fleet statistics
+        # (core/fleet.py), the anomaly classification (core/health.py;
+        # health_top_k=0 leaves it out) and the protocol-invariant probe
+        # (core/invariants.py, the runtime leg of the safety verifier;
+        # invariant_probe off leaves it out), and ONE flat int32 vector
+        # crosses to the host; 0 disables.  Their per-group digests stay
+        # device resident between collections as ONE [G, 17] array
         self.fleet_stats_every = max(0, int(fleet_stats_every))
         self._fleet_countdown = self.fleet_stats_every
+        self._digest = None             # built lazily, at the first tick
+        #: (in, out) device arrays of the collection's program, from its
+        #: first call (like the dispatch backend's ``entry_arrays``)
+        self.digest_arrays: tuple[int, int] | None = None
         self.last_fleet: dict | None = None
         # standalone engines (no NodeHost) still expose the device-only
         # view; a NodeHost registers its merged host+device view over the
@@ -670,10 +692,6 @@ class KernelEngine:
 
         _fleet.register_exposition(self.events.metrics.registry,
                                    lambda: self.last_fleet)
-        # decimated device-side anomaly classification (core/health.py):
-        # rides the fleet countdown; the per-group digest carry stays
-        # device resident and only the O(K) report crosses to host.
-        # health_top_k=0 disables the pass entirely
         from dragonboat_tpu.core import health as _health
 
         self.health_top_k = max(0, int(health_top_k))
@@ -681,22 +699,16 @@ class KernelEngine:
             _health.HealthThresholds(*health_thresholds)
             if health_thresholds is not None
             else _health.DEFAULT_THRESHOLDS)
-        self._health_digest = None      # built lazily at the first tick
         self.last_health: dict | None = None
         self._health_seq = 0            # health ticks taken (flight stamp)
         _health.register_exposition(self.events.metrics.registry,
                                     lambda: self.last_health)
-        # decimated protocol-invariant probe (core/invariants.py): the
-        # runtime leg of the safety verifier, riding the same fleet
-        # countdown.  The prev-field digest carry stays device resident;
-        # one O(1) InvariantReport crosses to host.  A violation is
-        # ALWAYS a bug, so sightings are sticky (violations_seen) — a
-        # transient step-scope violation must not vanish from /healthz
-        # at the next clean window
+        # A violation is ALWAYS a bug, so sightings are sticky
+        # (violations_seen) — a transient step-scope violation must not
+        # vanish from /healthz at the next clean window
         from dragonboat_tpu.core import invariants as _invariants
 
         self.invariant_probe = bool(invariant_probe)
-        self._inv_digest = None         # built lazily at the first tick
         self.last_invariants: dict | None = None
         self._inv_seq = 0               # probe ticks taken (flight stamp)
         self._inv_violations_seen = 0   # sticky cumulative violation total
@@ -822,9 +834,8 @@ class KernelEngine:
     def _write_cells(self, writes, tag: str) -> None:
         """Set ``field[lane] = value`` on the device for every ``(lane,
         field, value)`` of ``writes`` (a [G] or [G, P] field of the
-        packed columns), by ONE small jitted program and one upload.  The
-        batch is padded with copies of its last cell to a power of two, so
-        the program compiles once per size class."""
+        packed columns), by ONE small jitted program and one upload
+        (``_cell_batch``)."""
         r, c, v = [], [], []
         for lane, field, value in writes:
             col = self._state_cols[field]
@@ -832,13 +843,8 @@ class KernelEngine:
             r += [lane] * col.width
             c += range(col.start, col.start + col.width)
             v += vals.ravel().tolist()
-        n = len(r)
-        size = max(_CELL_BATCH, 1 << (n - 1).bit_length())
-        cells = np.empty((3, size), np.int32)
-        cells[:, :n] = (r, c, v)
-        cells[:, n:] = cells[:, n - 1:n]
         with _capacity.METER.sanctioned(tag):
-            up = jnp.asarray(cells)
+            up = jnp.asarray(_cell_batch(r, c, v))
         res = self._resident
         self._resident = res._replace(cols=write_cells_program(
             self._dispatch.placement())(res.cols, up))
@@ -1223,12 +1229,9 @@ class KernelEngine:
                 if self._fleet_countdown <= 0:
                     self._fleet_countdown = self.fleet_stats_every
                     rt.enter("finish")
-                    self._collect_fleet_stats()
-                    if self.health_top_k > 0:
-                        self._collect_health()
-                    if self.invariant_probe:
-                        self._collect_invariants()
-                    self._collect_capacity()
+                    with rt.part("finish.collect"):
+                        self._collect_digest()
+                        self._collect_capacity()
             self._commit_round(len(staged), ctx.traced)
             return True
 
@@ -1309,22 +1312,89 @@ class KernelEngine:
         return self._dispatch.pending()
 
     def _fleet_inbox_from(self):
-        """[G, K] sender ids feeding the inbox-occupancy histogram: the
-        backend picks the host-staged builder or its carried box."""
+        """[G, K] sender ids feeding the inbox-occupancy histogram, for
+        callers outside a round (a lane's health row, the chaos oracle):
+        the backend picks the host-staged builder or its carried box."""
         return self._dispatch.inbox_from(self._inbox_buf)
 
-    def _collect_fleet_stats(self) -> None:
-        """Decimated fleet telemetry: one jitted reduction over the
-        resident state, one small struct fetched to host (core/fleet.py).
-        Runs under engine.mu right after a step, so the state it reads is
-        exactly the state the step produced."""
-        from dragonboat_tpu.core import fleet as _fleet
+    def _make_digest(self):
+        """Fresh all-zero ``[G, 17]`` carry of the collection (the health
+        digest's ten columns and the invariant digest's seven,
+        core/digest.py) at the engine's lane geometry, placed by the
+        dispatch backend (the mesh backend shards it along G like the
+        state it derives from)."""
+        from dragonboat_tpu.core import digest as _digest
 
-        with _capacity.METER.sanctioned("fleet_down"):
-            stats = self._cap_entries["fleet_stats"](
-                self._resident, self._fleet_inbox_from())
-            was, now = self.last_fleet or {}, _fleet.stats_to_dict(stats)
-        self.last_fleet = now
+        return self._dispatch.shard(_digest.empty_carry(self.capacity))
+
+    def _digest_views(self):
+        """``(HealthDigest, InvariantDigest)`` views of the carried array's
+        columns, unpacked on demand by one jitted program: for readers
+        outside a round.  Nothing inside ``step_all`` reads them (17
+        arrays to let go of)."""
+        from dragonboat_tpu.core import digest as _digest
+
+        with self.mu:
+            if self._digest is None:
+                self._digest = self._make_digest()
+            return _digest.carry_view_program(
+                self._dispatch.placement())(self._digest)
+
+    @property
+    def _health_digest(self):
+        return self._digest_views()[0]
+
+    @property
+    def _inv_digest(self):
+        return self._digest_views()[1]
+
+    def _collect_digest(self) -> None:
+        """The decimated collection (core/digest.py): ONE jitted program
+        over the resident state runs the fleet statistics, the anomaly
+        classification (where ``health_top_k`` > 0) and the invariant
+        probe (where ``invariant_probe``); ONE flat int32 vector is
+        fetched and read by one ``tolist``; the per-group digests stay on
+        the device as ONE carried array the program rewrites.  The sender
+        ids go in once: the serial backend's host array as one upload,
+        the mesh backend's carried box sliced inside the program.  Runs
+        under engine.mu right after a step, so the state it reads is
+        exactly the state the step produced, and blocks for its download,
+        so a reader of ``last_*`` sees this round's.  Lanes whose occupant
+        changed since the last collection have the invariant digest's age
+        zeroed first (one column of the carried array, by the program a
+        lane's clearing runs), so step-scoped invariants never compare
+        across occupants."""
+        from dragonboat_tpu.core import digest as _digest
+
+        with _capacity.METER.sanctioned("digest_down"):
+            carry = self._digest if self._digest is not None \
+                else self._make_digest()
+            if self.invariant_probe and self._inv_dirty:
+                lanes = sorted(self._inv_dirty)
+                self._inv_dirty.clear()
+                carry = write_cells_program(self._dispatch.placement())(
+                    carry, jnp.asarray(_cell_batch(
+                        lanes, [_digest.INV_TICKS_COL] * len(lanes),
+                        [0] * len(lanes))))
+            args = (self._resident,
+                    self._dispatch.digest_inbox(self._inbox_buf), carry)
+            res = self._cap_entries["fleet_digest"](*args)
+            if self.digest_arrays is None:
+                self.digest_arrays = (len(jax.tree.leaves(args)),
+                                      len(jax.tree.leaves(res)))
+            vec, self._digest = res
+            ints = np.asarray(vec).tolist()
+        fleet, health, invariants = _digest.decode(
+            ints, self.capacity, self.health_top_k, self.invariant_probe)
+        self._note_fleet(fleet)
+        if health is not None:
+            self._note_health(health)
+        if invariants is not None:
+            self._note_invariants(invariants)
+
+    def _note_fleet(self, now: dict) -> None:
+        """``last_fleet`` and the counters fed from it."""
+        was, self.last_fleet = self.last_fleet or {}, now
         _FLEET_OCCUPIED.inc(now["occupied"])
         _FLEET_QUIESCED.inc(now["quiesced"])
 
@@ -1340,32 +1410,13 @@ class KernelEngine:
                 tallies(now), tallies(was)):
             counter.inc(max(0, count - before))
 
-    def _make_health_digest(self):
-        """Fresh all-zero digest matching the engine's lane geometry,
-        placed by the dispatch backend (the mesh backend shards it
-        along G like the state it derives from)."""
-        from dragonboat_tpu.core import health as _health
-
-        return self._dispatch.shard(_health.empty_digest(self.capacity))
-
-    def _collect_health(self) -> None:
-        """Decimated anomaly classification (core/health.py), on the
-        same cadence (and under the same engine.mu post-step window) as
-        ``_collect_fleet_stats``.  The digest carry never leaves the
-        device; one O(K) HealthReport is fetched.  Class-count edges
-        (0 -> nonzero and back) are recorded as flight-recorder
-        anomaly_raised/anomaly_cleared events stamped with the engine's
-        health-tick sequence — never the wall clock."""
+    def _note_health(self, cur: dict) -> None:
+        """``last_health``; class-count edges (0 -> nonzero and back) are
+        recorded as flight-recorder anomaly_raised/anomaly_cleared events
+        stamped with the engine's health-tick sequence — never the wall
+        clock."""
         from dragonboat_tpu import flight
-        from dragonboat_tpu.core import health as _health
 
-        if self._health_digest is None:
-            self._health_digest = self._make_health_digest()
-        with _capacity.METER.sanctioned("health_down"):
-            report, self._health_digest = self._cap_entries["fleet_health"](
-                self._resident, self._fleet_inbox_from(), self._health_digest,
-                thresholds=self.health_thresholds, k=self.health_top_k)
-            cur = _health.report_to_dict(report)
         prev = self.last_health
         self._health_seq += 1
         self.last_health = cur
@@ -1379,40 +1430,12 @@ class KernelEngine:
                 flight.record(flight.ANOMALY_CLEARED, cls=cls,
                               tick=self._health_seq)
 
-    def _make_invariant_digest(self):
-        """Fresh all-zero invariant digest matching the engine's lane
-        geometry, placed by the dispatch backend (same sharding story
-        as the health digest)."""
-        from dragonboat_tpu.core import invariants as _invariants
-
-        return self._dispatch.shard(
-            _invariants.empty_digest(self.capacity))
-
-    def _collect_invariants(self) -> None:
-        """Decimated protocol-invariant probe (core/invariants.py), on
-        the same cadence (and under the same engine.mu post-step window)
-        as ``_collect_fleet_stats``.  Lanes whose occupant changed since
-        the last probe tick are re-seeded (ticks=0) so step-scoped
-        invariants never compare across occupants.  A 0 -> nonzero
-        violation edge is recorded as an ``invariant_violation`` flight
-        event stamped with the probe-tick sequence — never the wall
-        clock."""
+    def _note_invariants(self, cur: dict) -> None:
+        """``last_invariants``; a 0 -> nonzero violation edge is recorded
+        as an ``invariant_violation`` flight event stamped with the
+        probe-tick sequence — never the wall clock."""
         from dragonboat_tpu import flight
-        from dragonboat_tpu.core import invariants as _invariants
 
-        if self._inv_digest is None:
-            self._inv_digest = self._make_invariant_digest()
-        with _capacity.METER.sanctioned("invariants_down"):
-            if self._inv_dirty:
-                lanes = jnp.asarray(
-                    np.array(sorted(self._inv_dirty), np.int32))
-                self._inv_dirty.clear()
-                d = self._inv_digest
-                self._inv_digest = d._replace(
-                    ticks=d.ticks.at[lanes].set(0))
-            report, self._inv_digest = self._cap_entries[
-                "check_invariants"](self._resident, self._inv_digest)
-            cur = _invariants.report_to_dict(report)
         prev = self.last_invariants
         self._inv_seq += 1
         self._inv_violations_seen += cur["total"]
@@ -1430,42 +1453,39 @@ class KernelEngine:
     def _capacity_entries(self) -> dict:
         """Compile-telemetry wrappers for every jit entry this engine
         dispatches: the backend's step entries (serial step/step_donated
-        or the mesh serve pair) plus the shared telemetry reductions.
+        or the mesh serve pair) plus the collection's one program.
         Each engine wraps independently (own counters): a first compile
         at THIS engine's geometry is never mistaken for a retrace of
         another engine sharing the same jitted function."""
         from dragonboat_tpu import capacity as _capacity
-        from dragonboat_tpu.core import fleet as _fleet
-        from dragonboat_tpu.core import health as _health
-        from dragonboat_tpu.core import invariants as _invariants
+        from dragonboat_tpu.core import digest as _digest
 
-        # the reductions take the resident form and unpack it inside
-        # their own programs (kstate.py resident_program)
-        kp = self.kp
+        # the collection takes the resident form and unpacks it inside
+        # its own program (core/digest.py digest_program): one for this
+        # engine's thresholds, top-K and which of its parts are on; a
+        # backend that carries its Inbox hands that over (``digest_inbox``)
+        # and the program slices the sender ids out
         entries = dict(self._dispatch.entries)
-        entries.update({
-            "fleet_stats": _capacity.TRACKER.wrap(
-                "fleet_stats", resident_program(kp, _fleet.fleet_stats)),
-            "fleet_health": _capacity.TRACKER.wrap(
-                "fleet_health", resident_program(
-                    kp, _health.fleet_health, ("thresholds", "k"))),
-            "check_invariants": _capacity.TRACKER.wrap(
-                "check_invariants",
-                resident_program(kp, _invariants.check_invariants)),
-        })
+        entries["fleet_digest"] = _capacity.TRACKER.wrap(
+            "fleet_digest", _digest.digest_program(
+                self.kp, self.health_thresholds, self.health_top_k,
+                self.invariant_probe,
+                "Inbox" in self._dispatch.resident_classes(),
+                self._dispatch.placement()))
         return entries
 
     def _capacity_trees(self) -> tuple:
         """Device-resident trees this engine keeps alive between steps
         (the mesh backend adds its carried inbox)."""
-        return (self._resident, self._health_digest, self._inv_digest) \
+        return (self._resident, self._digest) \
             + self._dispatch.resident_trees()
 
     def _capacity_model_classes(self) -> tuple:
         """Contract classes resident on device for this engine's
         geometry: the serial backend re-stages its inbox from host each
-        step, so only state + digests persist; the mesh backend carries
-        its Inbox."""
+        step, so only state + the carried digests (one [G, 17] array,
+        both classes' columns) persist; the mesh backend carries its
+        Inbox."""
         return ("ShardState", "HealthDigest", "InvariantDigest") \
             + self._dispatch.resident_classes()
 
@@ -1474,7 +1494,9 @@ class KernelEngine:
         the same engine.mu post-step window: live bytes of the resident
         trees (shape-derived — no device sync), allocator stats where
         the backend reports them, the contracts capacity model at this
-        geometry, and the compile counters.  The memory_pressure
+        geometry (a constant, walked once a process:
+        capacity.resident_bytes_per_group), and the compile counters.
+        The memory_pressure
         watermark crossing is recorded as an edge-triggered flight event
         stamped with the capacity tick — never the wall clock."""
         from dragonboat_tpu import capacity as _capacity
@@ -1506,16 +1528,13 @@ class KernelEngine:
         never materialized on host."""
         from dragonboat_tpu.core import health as _health
 
-        with self.mu:
-            if self._health_digest is None:
-                self._health_digest = self._make_health_digest()
-            with _capacity.METER.sanctioned("health_row"):
-                row = resident_program(
-                    self.kp, _health.shard_row, ("thresholds",))(
-                    self._resident, self._fleet_inbox_from(),
-                    self._health_digest, np.int32(lane),
-                    thresholds=self.health_thresholds)
-                return _health.row_to_dict(row)
+        with self.mu, _capacity.METER.sanctioned("health_row"):
+            row = resident_program(
+                self.kp, _health.shard_row, ("thresholds",))(
+                self._resident, self._fleet_inbox_from(),
+                self._health_digest, np.int32(lane),
+                thresholds=self.health_thresholds)
+            return _health.row_to_dict(row)
 
     def _kernel_call(self, staging: _RoundStaging):
         # the round's device work, one upload and one jitted entry:

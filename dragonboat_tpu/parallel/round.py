@@ -24,7 +24,7 @@ from jax.sharding import PartitionSpec as PS
 
 from dragonboat_tpu.core.kstate import (
     Inbox,
-    column_value,
+    box_senders,
     inbox_columns,
     pack_columns,
     pack_state,
@@ -82,7 +82,7 @@ def jit_serve_step_donated(kp, cluster: IciCluster, state, box, up, cut):
 
 @functools.partial(jax.jit, static_argnums=(0,))
 def box_from(kp, box):
-    """[G, K] sender ids of the carried inbox (the fleet collections'
-    inbox-occupancy input, every tenth round)."""
-    cols, _ = inbox_columns(kp)
-    return column_value(next(c for c in cols if c.field == "from_"), box)
+    """[G, K] sender ids of the carried inbox, for callers outside a round
+    (a lane's health row, the chaos oracle; the every-tenth-round
+    collection slices them out inside its own program, core/digest.py)."""
+    return box_senders(kp, box)

@@ -262,7 +262,7 @@ ROUND_TOTAL = "total"
 #: nobody reads is not recorded.  kernel_engine.py names the extents
 ROUND_PARTS = ("stage.reset", "stage.tick", "upload.release",
                "upload.applied", "resolve.send", "finish.apply",
-               "finish.ack")
+               "finish.ack", "finish.collect")
 #: moments inside a round, from its start: the return of the send of a
 #: round's REPLICATEs, and of everything else it sends (after the save)
 ROUND_MARKS = ("replicates_out", "responses_out")

@@ -337,8 +337,11 @@ def _clear_jit_caches():
         clear = getattr(fn, "_clear_cache", None)
         if clear is not None:
             clear()
-    # an engine's reductions are programs over the resident form, one per
-    # (geometry, reduction) for the process: forget them too
+    # an engine's collection and a lane's health row are programs over the
+    # resident form, one per geometry for the process: forget them too
+    from dragonboat_tpu.core import digest
+
+    digest.digest_program.cache_clear()
     kstate.resident_program.cache_clear()
 
 
@@ -394,9 +397,11 @@ def test_steady_state_compiles_each_entry_once_per_geometry(depth):
         assert ent[active]["retraces"] == 0
         assert ent[active]["calls"] >= 50
         assert ent[idle]["calls"] == 0
-        for name in ("fleet_stats", "fleet_health"):
-            assert ent[name]["compiles"] == 1, (name, ent[name])
-            assert ent[name]["retraces"] == 0
+        # the collection: ONE program (core/digest.py), not three
+        assert ent["fleet_digest"]["compiles"] == 1, ent["fleet_digest"]
+        assert ent["fleet_digest"]["retraces"] == 0
+        assert ent["fleet_digest"]["calls"] == eng._capacity_seq
+        assert set(ent) == {"step", "step_donated", "fleet_digest"}
         assert snap["retrace_storm"] is False
         assert snap["ticks"] == eng._capacity_seq
         assert snap["bytes_in_use"] > 0

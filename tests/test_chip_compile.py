@@ -172,6 +172,46 @@ def test_mesh_round_compiles_for_v5e(topo, device_kp, entry):
     assert "all-gather" in hlo
 
 
+@pytest.mark.parametrize("where", ["one-chip", "mesh-1x3"])
+def test_the_collection_compiles_for_v5e(topo, one_chip, device_kp, where):
+    """The every-tenth-round collection (core/digest.py ``digest_program``:
+    fleet statistics, health triage with its top-K, the invariant probe with
+    its per-row sort) as an engine runs it: serial, the [G, K] sender ids
+    uploaded; on the mesh, sliced out of the carried [G, Wi] inbox inside the
+    program, state, inbox and carry sharded along G.  One int32 vector and
+    the carried [G, 17] array out, the carry placed as it came in."""
+    from dragonboat_tpu.core import digest, health
+
+    if where == "one-chip":
+        kp, rows, placement, boxed = device_kp(), ROWS, None, False
+        rows_sharding = one_chip
+    else:
+        kp = device_kp(min_inbox=10)
+        mesh = Mesh(np.array(topo.devices[:3]).reshape(1, 3), ("g", "r"))
+        cl = ici.IciCluster(kp=kp, mesh=mesh, replicas=3, n_local=48,
+                            num_groups=48)
+        rows, boxed = cl.total_rows, True
+        placement = rows_sharding = cl.sharding()
+    resident, box, _up = _round_args(kp, rows, rows_sharding)
+    mat = lambda w: jax.ShapeDtypeStruct(  # noqa: E731
+        (rows, w), jnp.int32, sharding=rows_sharding)
+    inbox = box if boxed else mat(kp.inbox_cap)
+    program = digest.digest_program(
+        kp, health.DEFAULT_THRESHOLDS, health.DEFAULT_TOP_K, True, boxed,
+        placement)
+    compiled = program.lower(
+        resident, inbox, mat(digest.CARRY_WIDTH)).compile()
+    vec, carry = jax.eval_shape(program, resident, inbox,
+                                mat(digest.CARRY_WIDTH))
+    assert vec.shape == (digest.layout(
+        rows, health.DEFAULT_TOP_K, True)[1],) and vec.dtype == jnp.int32
+    assert carry.shape == (rows, digest.CARRY_WIDTH)
+    if boxed:
+        assert compiled.output_shardings[1] == placement
+    mem = compiled.memory_analysis()
+    assert mem.temp_size_in_bytes + mem.argument_size_in_bytes < 1e9
+
+
 def _collective_result_bytes(hlo: str) -> int:
     """Bytes of every all-gather's and all-reduce's result in the text."""
     size = {"pred": 1, "s8": 1, "u8": 1, "s32": 4, "u32": 4, "f32": 4}

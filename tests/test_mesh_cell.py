@@ -34,6 +34,7 @@ sys.path.insert(0, os.path.dirname(os.path.abspath(__file__)))
 from test_mesh_engine import (  # noqa: E402
     close_all, make_cluster, propose_retry,
 )
+from test_digest import shadow_engines  # noqa: E402
 from test_nodehost import KVStateMachine, wait_leader  # noqa: E402
 from test_round_budget import (  # noqa: E402
     check_round_families, check_round_records, part_us, phase_cluster,
@@ -315,6 +316,68 @@ def test_mesh_round_phases_crossings_and_counter():
     finally:
         close_all(hosts)
 
+
+
+def test_mesh_collection_is_one_crossing_and_one_carried_array(monkeypatch):
+    """The mesh engine inherits the collection: every tenth round ONE
+    program over the sharded state (the carried box's sender ids sliced
+    inside it), ONE ``digest_down`` crossing, ONE carried ``[G, 17]`` array
+    sharded along G like the state; at most the resident arrays + 2 in and
+    2 out; ``finish.collect`` in every round record's parts, above 0 only in
+    the rounds that collected; and at every collection the reports and the
+    next carry equal the three programs' (``test_digest.Shadow``)."""
+    prefix = f"mshC{time.monotonic_ns()}"
+    shadows = shadow_engines(monkeypatch)
+    hosts = make_cluster(prefix)
+    try:
+        lid = wait_leader(hosts, timeout=60)
+        nh, eng = hosts[lid], hosts[lid].mesh_engine
+        sess = nh.get_noop_session(1)
+        propose_retry(nh, sess, b"warm=up")
+        assert wait_for(lambda: eng._health_seq >= 1, 30)
+
+        def snap():
+            with eng.mu:            # between rounds, not inside one
+                return (eng._round._seq, eng._health_seq, eng._inv_seq,
+                        capacity.METER.counts().get("digest_down", 0))
+
+        seq0, health0, inv0, down0 = snap()
+        for i in range(12):
+            propose_retry(nh, sess, f"k{i}=v{i}".encode())
+        assert wait_for(lambda: eng._health_seq >= health0 + 2, 30)
+        seq1, health1, inv1, down1 = snap()
+        recs = [r for r in records_of(eng, seq0) if r["seq"] <= seq1]
+        assert len(recs) == seq1 - seq0
+        assert all(set(r["parts"]) == set(tracing.ROUND_PARTS) for r in recs)
+        collected = sum(r["parts"]["finish.collect"] > 0 for r in recs)
+        # (the engines of the process share the meter: this one's are its
+        # health ticks)
+        assert collected == health1 - health0 == inv1 - inv0 >= 2
+        assert down1 - down0 >= collected
+        assert collected <= len(recs) // 10 + 1
+        check_round_records(recs)
+        with eng.mu:
+            placed = eng.cluster.sharding()
+            assert eng._digest.shape == (eng.capacity, 17)
+            assert eng._digest.sharding == placed
+            assert all(x.sharding == placed for x in jax.tree.leaves(
+                (eng._health_digest, eng._inv_digest)))
+            resident = len(jax.tree.leaves(eng._resident))
+            assert eng.digest_arrays == (resident + 2, 2)
+            stats = eng._cap_entries["fleet_digest"].stats()
+            assert stats["compiles"] <= 1 and stats["retraces"] == 0
+            fleet, health, inv = (eng.last_fleet, eng.last_health,
+                                  eng.last_invariants)
+            shadow = shadows[eng]
+            assert not shadow.mismatches, shadow.mismatches[:3]
+            assert shadow.compared == eng._health_seq >= 3
+        assert fleet["occupied"] == 3 and fleet["role_count"]["leader"] == 1
+        assert health["leaderless_now"] == 0 and inv["total"] == 0
+        assert inv["checked"] == 3
+        assert not {"fleet_down", "health_down", "invariants_down"} \
+            & set(capacity.METER.counts())
+    finally:
+        close_all(hosts)
 
 
 @pytest.mark.parametrize("depth", [0, 1])

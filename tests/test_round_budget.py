@@ -16,6 +16,7 @@ import sys
 import threading
 import time
 
+import jax
 import pytest
 
 from dragonboat_tpu import capacity, raftpb as pb, telemetry, tracing
@@ -28,7 +29,7 @@ from test_nodehost import KVStateMachine  # noqa: E402
 
 SHARDS = 48
 EVERY = 10      # fleet_stats_every: the every-tenth-round collections
-COLLECTIONS = {"fleet_down", "health_down", "invariants_down"}
+COLLECTIONS = {"digest_down"}   # fleet, health, invariants: ONE crossing
 
 
 def _wait(cond, timeout):
@@ -111,8 +112,58 @@ def test_round_crosses_the_boundary_once_each_way(depth):
                 for t, n in delta.items():
                     seen[t] = seen.get(t, 0) + n
             assert seen["round_up"] == rounds == 20
-            assert seen.get("fleet_down", 0) == rounds // EVERY
+            assert seen.get("digest_down", 0) == rounds // EVERY
             _settle(eng)                    # depth 1: retire the last step
+        for rs in states:
+            assert rs.get(30) is not None
+    finally:
+        nh.close()
+
+
+@pytest.mark.parametrize("depth", [0, 1])
+def test_a_collecting_round_is_one_program_one_array_down_one_carried(depth):
+    """Every tenth round the fleet statistics, the health triage and the
+    invariant probe run as ONE program: it takes the resident arrays, the
+    sender ids and the carried digests and returns one vector and the
+    digests (at most the resident arrays + 2 in, 2 out), compiles once, and
+    rewrites ONE carried ``[G, 17]`` array.  ``finish.collect`` is in every
+    round record's parts: above 0 in the collecting rounds, 0 in the other
+    nine of ten."""
+    nh = _host(f"rb-collect{depth}", 4, depth=depth)
+    try:
+        eng = nh.kernel_engine
+        sessions = {sid: nh.get_noop_session(sid) for sid in range(1, 5)}
+        with eng.mu:
+            _settle(eng)
+            seq0 = eng._round._seq
+            health0, inv0 = eng._health_seq, eng._inv_seq
+            states, collecting = [], []
+            for i in range(20):
+                for sid, s in sessions.items():
+                    states.append(nh.propose(s, f"c{i}={sid}".encode(), 30))
+                collecting.append(eng._fleet_countdown == 1)
+                carried = eng._digest
+                assert eng.step_all(), f"round {i} found nothing to do"
+                assert (eng._digest is not carried) == collecting[-1]
+            recs = records_of(eng, seq0)
+            assert len(recs) == 20 and sum(collecting) == 20 // EVERY
+            for rec, collected in zip(recs, collecting):
+                assert set(rec["parts"]) == set(tracing.ROUND_PARTS)
+                assert (rec["parts"]["finish.collect"] > 0) == collected, rec
+            check_round_records(recs)
+            assert eng._health_seq - health0 == eng._inv_seq - inv0 \
+                == 20 // EVERY
+            resident = len(jax.tree.leaves(eng._resident))
+            assert eng.digest_arrays == (resident + 2, 2)
+            assert eng._digest.shape == (eng.capacity, 17)
+            assert str(eng._digest.dtype) == "int32"
+            stats = eng._cap_entries["fleet_digest"].stats()
+            assert stats["compiles"] <= 1 and stats["retraces"] == 0
+            assert not {"fleet_stats", "fleet_health", "check_invariants"} \
+                & set(eng._cap_entries)
+            _settle(eng)
+        assert not {"fleet_down", "health_down", "invariants_down"} \
+            & set(capacity.METER.counts())
         for rs in states:
             assert rs.get(30) is not None
     finally:
@@ -593,13 +644,13 @@ def test_state_property_is_not_read_inside_a_round(monkeypatch):
         with eng.mu:
             _settle(eng)
             reads.clear()
-            collections0 = capacity.METER.counts().get("fleet_down", 0)
+            collections0 = capacity.METER.counts().get("digest_down", 0)
             states = []
             for i in range(20):
                 for sid, s in sessions.items():
                     states.append(nh.propose(s, f"p{i}={sid}".encode(), 30))
                 assert eng.step_all()
-            assert capacity.METER.counts()["fleet_down"] \
+            assert capacity.METER.counts()["digest_down"] \
                 == collections0 + 20 // EVERY
             assert reads == [], "step_all read engine.state"
             state = eng.state

@@ -282,7 +282,7 @@ def test_round_timer_parts_stay_inside_their_phase():
     snap = reg.snapshot()
     want = {"stage.reset": 4, "stage.tick": 0, "upload.release": 9,
             "upload.applied": 0, "resolve.send": 12, "finish.apply": 11,
-            "finish.ack": 14}
+            "finish.ack": 14, "finish.collect": 0}
     assert set(want) == set(tracing.ROUND_PARTS)
     for part, us in want.items():
         assert snap[f"engine_round_part_us.sum{{part={part}}}"] \

@@ -490,9 +490,13 @@ def test_emit_ledger_artifact(tmp_path):
     with open(out, encoding="utf-8") as f:
         ledger = json.load(f)
     for entry in ("step", "step_donated", "serve_step",
-                  "serve_step_donated", "fleet_stats", "fleet_health",
-                  "check_invariants"):
+                  "serve_step_donated", "fleet_digest"):
         assert entry in ledger["entries"], entry
+    # the collection's three reports ride ONE packed row down
+    (down,) = ledger["entries"]["fleet_digest"]["down"]
+    assert down["packed"] and down["tag"] == "digest_down"
+    assert down["value"] == ["FleetStats", "HealthReport",
+                             "InvariantReport"]
     for _entry, section in ledger["entries"].items():
         for dirn in ("up", "down"):
             for row in section[dirn]:
