@@ -172,14 +172,19 @@ def test_mesh_round_compiles_for_v5e(topo, device_kp, entry):
     assert "all-gather" in hlo
 
 
-@pytest.mark.parametrize("where", ["one-chip", "mesh-1x3"])
+#: rows a chip of the two mesh geometries the benchmark serves
+MESH_N_LOCAL = {"mesh-1x3": 48, "mesh-1x3-1024": 1024}
+
+
+@pytest.mark.parametrize("where", ["one-chip", *MESH_N_LOCAL])
 def test_the_collection_compiles_for_v5e(topo, one_chip, device_kp, where):
     """The every-tenth-round collection (core/digest.py ``digest_program``:
     fleet statistics, health triage with its top-K, the invariant probe with
     its per-row sort) as an engine runs it: serial, the [G, K] sender ids
     uploaded; on the mesh, sliced out of the carried [G, Wi] inbox inside the
     program, state, inbox and carry sharded along G.  One int32 vector and
-    the carried [G, 17] array out, the carry placed as it came in."""
+    the carried [G, 17] array out, the carry placed as it came in.  The
+    third case is the width ``fleet-1k-mesh4`` serves: 1,024 rows a chip."""
     from dragonboat_tpu.core import digest, health
 
     if where == "one-chip":
@@ -188,8 +193,9 @@ def test_the_collection_compiles_for_v5e(topo, one_chip, device_kp, where):
     else:
         kp = device_kp(min_inbox=10)
         mesh = Mesh(np.array(topo.devices[:3]).reshape(1, 3), ("g", "r"))
-        cl = ici.IciCluster(kp=kp, mesh=mesh, replicas=3, n_local=48,
-                            num_groups=48)
+        n_local = MESH_N_LOCAL[where]
+        cl = ici.IciCluster(kp=kp, mesh=mesh, replicas=3, n_local=n_local,
+                            num_groups=n_local)
         rows, boxed = cl.total_rows, True
         placement = rows_sharding = cl.sharding()
     resident, box, _up = _round_args(kp, rows, rows_sharding)
@@ -225,25 +231,30 @@ def _collective_result_bytes(hlo: str) -> int:
     return total
 
 
-def test_collective_bytes_cover_what_the_compiler_moves(topo, device_kp):
+@pytest.mark.parametrize("n_local", MESH_N_LOCAL.values())
+def test_collective_bytes_cover_what_the_compiler_moves(topo, device_kp,
+                                                        n_local):
     """``benchmark/collective_bytes.py`` counts the out-lanes ``route``
     reads; the compiler moves no more than that for the served mesh round
     (an all-gather brings a chip two thirds of its result; the one
     all-reduce is a gather of [G] lanes written as update-slice and sum),
-    and not much less: a field ``route`` starts or stops reading shows."""
+    and not much less: a field ``route`` starts or stops reading shows.
+    At 48 rows a chip (``upstream-48-mesh4``) and at the 1,024 that
+    ``fleet-1k-mesh4`` serves, where the round is compiled for the first
+    time at its real width (25 all-gathers there, no all-reduce)."""
     from benchmark import collective_bytes
 
     kp = device_kp(min_inbox=10)
     mesh = Mesh(np.array(topo.devices[:3]).reshape(1, 3), ("g", "r"))
-    cl = ici.IciCluster(kp=kp, mesh=mesh, replicas=3, n_local=48,
-                        num_groups=48)
+    cl = ici.IciCluster(kp=kp, mesh=mesh, replicas=3, n_local=n_local,
+                        num_groups=n_local)
     cut = jax.ShapeDtypeStruct((cl.total_rows, kp.num_peers), bool,
                                sharding=cl.sharding())
     hlo = pround.jit_serve_step.lower(
         kp, cl, *_round_args(kp, cl.total_rows, cl.sharding()), cut,
     ).compile().as_text()
     moved = _collective_result_bytes(hlo) * 2 // 3
-    counted = 2 * collective_bytes.exchange_bytes_per_chip(kp, 48)
+    counted = 2 * collective_bytes.exchange_bytes_per_chip(kp, n_local)
     assert 0 < moved <= counted <= moved * 4 // 3, (moved, counted)
 
 

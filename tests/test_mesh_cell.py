@@ -50,6 +50,15 @@ def hub_msgs() -> dict:
             if k.startswith("engine_mesh_hub_msgs{")}
 
 
+def durability() -> list:
+    """Rounds, fsyncs, saves, partitions saved and shared fsyncs so far."""
+    snap = telemetry.GLOBAL.snapshot()
+    return [snap.get(k, 0) for k in (
+        "engine_round_us.count{phase=total}", "logdb.fsync_us.count",
+        "logdb.save_parts.count", "logdb.save_parts.sum",
+        "logdb.sync_shared")]
+
+
 def wait_for(cond, timeout_s):
     deadline = time.time() + timeout_s
     while time.time() < deadline:
@@ -178,13 +187,6 @@ def test_mesh_cell_reads_back_after_leader_placement(cell):
     traffic = deployment.load_json("traffic", "write16")
     gen.validate(traffic)
     hub0 = hub_msgs()
-
-    def durability():
-        snap = telemetry.GLOBAL.snapshot()
-        return [snap.get(k, 0) for k in (
-            "engine_round_us.count{phase=total}", "logdb.fsync_us.count",
-            "logdb.save_parts.count", "logdb.save_parts.sum",
-            "logdb.sync_shared")]
 
     assert {h.logdb.name() for h in dep.hosts.values()} == {"sharded-tan-1"}
     d0 = durability()
@@ -418,3 +420,92 @@ def test_mesh_rounds_read_their_parts_and_mark_only_what_they_sent(depth):
     assert part_us(resident, "finish.ack") > 0
     assert not any(r["marks"] for r in resident)
     assert any("replicates_out" in r["marks"] for r in cut)
+
+
+# -- the mesh with quiesce on: ``fleet-1k-mesh4`` at its rehearsal's size ------
+
+FLEET_SHARDS, FLEET_BUSY = 12, 3
+
+
+def test_fleet_mesh_with_quiesce_holds_what_the_mesh_cell_holds(tmp_path):
+    """``fleet-1k-mesh4`` as ``benchmark/deployment.py`` builds it, at the
+    rehearsal's size (12 groups, 3 written to): while every group sleeps,
+    while three are written to and while an idle one is woken by a write,
+    what the 48-group mesh holds is held: at most one fsync a LogDB a
+    round, the entry's arrays 6 / 5, one upload and one download a round,
+    and NOTHING over any ``way`` of ``engine_mesh_hub_msgs``: the quiesce
+    word rides the heartbeat lanes of the exchange, and a word on a
+    resident link must not also go to the hub."""
+    cfg = dict(deployment.load_json("configs", "fleet-1k-mesh4"),
+               name=f"fleetcell{time.monotonic_ns()}")
+    assert cfg["shard"] == {"quiesce": True} and cfg["engine"] == "mesh"
+    mix = deployment.load_json("traffic", "write16-hot96")
+    mix = {**mix, **mix["rehearsal"]}
+    gen.validate(mix, FLEET_SHARDS)
+    busy = gen.active_shards(mix, 2**31 + 39, deployment.wanted_leaders(
+        range(1, FLEET_SHARDS + 1), 3))
+    assert len(busy) == FLEET_BUSY
+    dep = deployment.Deployment(cfg, jax.devices(), str(tmp_path),
+                                FLEET_SHARDS, None, lambda **kw: None, busy)
+    try:
+        eng, = dep.engines
+        hub0 = hub_msgs()
+
+        def asleep(n):
+            return wait_for(lambda: dep.quiesced_lanes() == n, 90)
+
+        def counts():
+            with eng.mu:            # between rounds, not inside one
+                meter = capacity.METER.counts()
+                return durability(), [meter.get(tag, 0)
+                                      for tag in ("round_up", "round_down")]
+
+        # every group asleep: 36 of 36 rows by the one engine's digest
+        assert asleep(3 * FLEET_SHARDS), eng.last_fleet
+        assert hub_msgs() == hub0, "a quiesce word went to the hub"
+        terms = {sid: dep.hosts[1].nodes[sid].node_term()
+                 for sid in dep.shards}
+
+        # three groups written to: they wake, the other 27 rows sleep on
+        d0, m0 = counts()
+        load = gen.Load(dep, mix, gen.client_streams(mix, 2**31 + 39,
+                                                     dep.active, 0))
+        load.start()
+        time.sleep(WRITE_S)
+        during = dep.quiesced_lanes()
+        records = load.join(30.0)
+        d1, m1 = counts()
+        rounds, fsyncs, saves, parts, shared = (b - a for a, b in zip(d0, d1))
+        assert records and all(r.status == gen.OK for r in records)
+        assert {r.shard for r in records} == set(dep.active)
+        assert during == 3 * (FLEET_SHARDS - FLEET_BUSY), during
+        assert 0 < fsyncs <= 3 * rounds, (fsyncs, rounds)
+        assert saves == parts == fsyncs and shared == 0
+        assert [b - a for a, b in zip(m0, m1)] == [rounds, rounds]
+        assert eng._dispatch.entry_arrays == (6, 5)
+        assert hub_msgs() == hub0, "writes to waking groups met the hub"
+
+        # the busy groups idle back to sleep; one idle group is woken by a
+        # write after the drain (the benchmark's check (f)) and serves
+        assert asleep(3 * FLEET_SHARDS), eng.last_fleet
+        sid = dep.idle[0]
+        lead = dep.hosts[dep.leader_host(sid)]
+        lead.sync_propose(lead.get_noop_session(sid), b"woken=yes",
+                          timeout_s=10)
+        assert wait_for(lambda: all(
+            dep.replica_value(rid, sid, "woken") == "yes"
+            for rid in dep.hosts), 15)
+        assert hub_msgs() == hub0, "a wake met the hub"
+        assert asleep(3 * FLEET_SHARDS), eng.last_fleet
+        # nobody campaigned through any of it, and no leader moved
+        assert {s: dep.hosts[1].nodes[s].node_term()
+                for s in dep.shards} == terms
+        assert all(dep.leader_host(s) == dep.wanted[s] for s in dep.shards)
+        assert wait_for(lambda: all(len(set(dep.sm_hashes(s))) == 1
+                                    for s in dep.shards), 15)
+        assert capacity.TRACKER.snapshot()["serve_step"]["retraces"] == 0
+    finally:
+        dep.close()
+        # (its records count up to 36 lanes asleep: not for a later reader
+        # of the process's ring, tests/benchmark/test_benchmark_fleet1k_cell)
+        tracing.ROUNDS.reset()

@@ -7,7 +7,10 @@ device-resident replica takes).  What a group SHOWS has to agree: all three
 replicas asleep within ``election_rtt`` ticks of the first, no term and no
 leader moved across the entry, a proposal / a read / a transfer wakes the
 group and it commits, a replica that just left quiesce is not pulled back
-in by a peer's stale word.
+in by a peer's stale word.  A third form of the same block since PR 39: the
+mesh engine (``engine/mesh_engine.py``), where the three replicas are rows of
+ONE engine on three devices, the word rides the step's collectives and all
+three rows tick on one clock.
 
 Part A drives the kernel alone on seeded schedules whose three rows tick at
 rates up to 30% apart (a tick is a round of the row's own engine, and a
@@ -15,18 +18,23 @@ group's three engines do not step alike).  On the parent's kernel, where a
 lane entered alone on its own clock, the no-election case fails: a follower
 at 70% of its leader's rate is 30 ticks short when the leader goes silent,
 and campaigns 10-19 ticks later.  Part B runs the same story through three
-NodeHosts whose ``rtt_millisecond`` differ by 30%, once a path.
+NodeHosts whose ``rtt_millisecond`` differ by 30%, once a path: the host
+path, the kernel path (an engine a host) and the mesh path (one engine for
+the three hosts, on forced host devices).
 """
 
 import random
 import time
+import zlib
 
 import jax.numpy as jnp
 import numpy as np
 import pytest
 
 from dragonboat_tpu import raftpb as pb
-from dragonboat_tpu.config import Config, ExpertConfig, NodeHostConfig
+from dragonboat_tpu.config import (
+    Config, ExpertConfig, MeshSpec, NodeHostConfig,
+)
 from dragonboat_tpu.core import params as KP
 from dragonboat_tpu.nodehost import NodeHost
 from dragonboat_tpu.request import RequestError
@@ -251,39 +259,63 @@ def test_with_quiesce_off_nothing_enters_and_the_word_is_nothing():
 
 
 # ---------------------------------------------------------------------------
-# Part B: the host path and the kernel path, through NodeHosts whose clocks
-# differ by 30%
+# Part B: the host path, the kernel path and the mesh path, through NodeHosts
+# whose clocks differ by 30%
 # ---------------------------------------------------------------------------
 
 RTT_MS = {1: 10, 2: 12, 3: 13}          # host 1's clock is the fastest
+PATHS = ["host-path", "kernel-path", "mesh-path"]
 
 
-def make_hosts(prefix, device_resident):
+class HashedKV(KVStateMachine):
+    """``KVStateMachine`` with the hash ``NodeHost.get_sm_hash`` asks for."""
+
+    def get_hash(self) -> int:
+        return table_hash(self.kv)
+
+
+def table_hash(kv: dict) -> int:
+    return zlib.crc32("\n".join(
+        f"{k}={v}" for k, v in sorted(kv.items())).encode())
+
+
+def make_hosts(prefix, path):
+    """Three NodeHosts with one replica of shard 1 each.  On the mesh path
+    they share one ``MeshEngine`` (a ``MeshSpec`` of their own name): the
+    group's three replicas are rows on three of the forced host devices."""
     addrs = {i: f"{prefix}-{i}" for i in RTT_MS}
+    geometry = dict(kernel_log_cap=256, kernel_capacity=8,
+                    kernel_apply_batch=16, kernel_compaction_overhead=16)
+    if path == "mesh-path":
+        geometry["mesh"] = MeshSpec(name=prefix, g_size=1, replicas=3,
+                                    n_local=4)
     hosts = {}
     for rid, addr in addrs.items():
         nh = NodeHost(NodeHostConfig(
             raft_address=addr, rtt_millisecond=RTT_MS[rid],
-            expert=ExpertConfig(kernel_log_cap=256, kernel_capacity=8,
-                                kernel_apply_batch=16,
-                                kernel_compaction_overhead=16)))
-        nh.start_replica(addrs, False, KVStateMachine, Config(
+            expert=ExpertConfig(**geometry)))
+        nh.start_replica(addrs, False, HashedKV, Config(
             shard_id=1, replica_id=rid, election_rtt=ELECTION,
-            heartbeat_rtt=2, quiesce=True, device_resident=device_resident))
+            heartbeat_rtt=2, quiesce=True,
+            device_resident=path == "kernel-path",
+            mesh_resident=path == "mesh-path"))
         hosts[rid] = nh
     return hosts
 
 
 def shows(nh):
-    """-> (quiesced, term) of host ``nh``'s replica, whichever path."""
+    """-> (quiesced, term, wakes) of host ``nh``'s replica, whichever path;
+    ``wakes`` moves every time the replica leaves quiesce."""
     node = nh.nodes[1]
     if node.peer is not None:           # host path: QuiesceState in node.py
-        return node.qs.quiesced(), node.peer.raft.term
-    eng = nh.kernel_engine
+        return (node.qs.quiesced(), node.peer.raft.term,
+                node.qs.exit_quiesce_tick)
+    eng = node.engine                   # its host's own, or the shared mesh
     with eng.mu:
         s, lane = eng.state, node.lane
         return (bool(np.asarray(s.quiesced)[lane]),
-                int(np.asarray(s.term)[lane]))
+                int(np.asarray(s.term)[lane]),
+                int(np.asarray(s.quiesce_epoch)[lane]))
 
 
 def wait_all(hosts, asleep, seconds=20.0):
@@ -315,9 +347,15 @@ def at_the_leader(hosts, call, seconds=30.0):
             time.sleep(0.1)
 
 
-def propose(hosts, cmd):
-    return at_the_leader(hosts, lambda nh: nh.sync_propose(
+def propose(hosts, cmd, written=None):
+    """Write ``cmd`` through the leader's host; ``written`` is the plain
+    reference, a dict that takes what was acknowledged."""
+    value = at_the_leader(hosts, lambda nh: nh.sync_propose(
         nh.get_noop_session(1), cmd, timeout_s=5))[1]
+    if written is not None:
+        k, v = cmd.decode().split("=", 1)
+        written[k] = v
+    return value
 
 
 def read(hosts, key):
@@ -339,7 +377,7 @@ def lead_from(hosts, rid, seconds=20.0):
         assert time.time() < deadline, "the leader did not move"
 
 
-def settle(hosts, rid, seconds=30.0):
+def settle(hosts, rid, written, seconds=30.0):
     """Host ``rid`` leads, a write went through it, and all three replicas
     show one term -> {replica: term}.  (Beside busy workers a starved
     follower times out now and then; what is read before the entry has to
@@ -347,7 +385,7 @@ def settle(hosts, rid, seconds=30.0):
     deadline = time.time() + seconds
     while True:
         lead_from(hosts, rid)
-        propose(hosts, b"k0=v0")
+        propose(hosts, b"k0=v0", written)
         terms = {r: shows(nh)[1] for r, nh in hosts.items()}
         if len(set(terms.values())) == 1 and (
                 wait_leader(hosts, timeout=30) == rid):
@@ -355,15 +393,41 @@ def settle(hosts, rid, seconds=30.0):
         assert time.time() < deadline, f"the group did not settle: {terms}"
 
 
+def late_heartbeat(hosts, leader, follower):
+    """A heartbeat of the leader's reaches ``follower`` long after the
+    group entered (its grace window, ``election_rtt`` ticks, is over): by
+    ``quiesce.go:60-77`` it wakes the replica, whose answer brings the
+    group out, and nobody campaigns.  Handed to the replica's own node as a
+    transport would hand it over (on the mesh path past the gate that
+    turns hub copies of resident links away: this one stands for a
+    heartbeat the exchange carried late)."""
+    _, term, wakes = shows(hosts[follower])
+    hosts[follower].nodes[1].handle_message(pb.Message(
+        type=MT.HEARTBEAT, shard_id=1, from_=leader, to=follower, term=term))
+    hosts[follower]._kick()
+    deadline = time.time() + 10.0
+    while shows(hosts[follower])[2] == wakes:
+        assert time.time() < deadline, "the late heartbeat woke nobody"
+        time.sleep(0.01)
+
+
 @pytest.mark.parametrize("leader_host", [1, 3],
                          ids=["leader-fastest", "leader-slowest"])
-@pytest.mark.parametrize("device_resident", [False, True],
-                         ids=["host-path", "kernel-path"])
-def test_both_paths_show_the_same_group(device_resident, leader_host,
-                                        one_core):
-    hosts = make_hosts(
-        f"qg{int(device_resident)}{leader_host}", device_resident)
+@pytest.mark.parametrize("path", PATHS)
+def test_both_paths_show_the_same_group(path, leader_host, one_core):
+    """The same story on every path, and what it fixes is the same on
+    every path: asleep or awake per replica, no term and no leader moved
+    across an entry, the same applied values (the plain dict ``written``)
+    and one ``get_sm_hash`` on all three replicas."""
+    hosts = make_hosts(f"qg{PATHS.index(path)}{leader_host}", path)
+    written: dict[str, str] = {}
     try:
+        if path == "mesh-path":
+            eng = hosts[1].mesh_engine
+            assert eng is not None and all(
+                nh.mesh_engine is eng and nh.nodes[1].peer is None
+                for nh in hosts.values()), "not one mesh engine"
+            assert len(eng.state.term.sharding.device_set) == 3
         # busy for more than a threshold first: upstream's QuiesceState
         # counts a replica's first ``threshold`` ticks as "just exited"
         # (its exit tick starts at 0), and would refuse a peer's word then
@@ -371,10 +435,10 @@ def test_both_paths_show_the_same_group(device_resident, leader_host,
         end = time.time() + 1.5 * THRESHOLD * RTT_MS[3] / 1e3
         i = 0
         while time.time() < end:
-            propose(hosts, f"w{i}=v{i}".encode())
+            propose(hosts, f"w{i}=v{i}".encode(), written)
             i += 1
             time.sleep(0.05)
-        terms = settle(hosts, leader_host)
+        terms = settle(hosts, leader_host, written)
         # all three asleep, soon after the first: the fastest clock needs
         # 1.0 s from the last write and the slowest alone 1.3 s
         wait_all(hosts, asleep=True)
@@ -384,19 +448,41 @@ def test_both_paths_show_the_same_group(device_resident, leader_host,
             "a term moved across the entry")
         assert wait_leader(hosts, timeout=30) == leader_host
         # a proposal wakes the group and commits; so does a read
-        propose(hosts, b"k1=v1")
+        propose(hosts, b"k1=v1", written)
         assert not shows(hosts[leader_host])[0]
         assert hosts[leader_host].stale_read(1, "k1") == "v1"
         wait_all(hosts, asleep=True)
         lead, value = read(hosts, "k1")
         assert value == "v1"
         assert not shows(hosts[lead])[0]
-        # and a transfer: the target leads, the group commits again
+        # a heartbeat that arrives late wakes its replica, the group comes
+        # out and goes back to sleep; on the device paths nobody campaigned
+        # (the host path re-enters by upstream's rule, under which a
+        # follower refuses the word of a leader whose clock ran ahead of
+        # its own since the wake and campaigns beside it: ``at_the_leader``)
         wait_all(hosts, asleep=True)
+        time.sleep(2 * ELECTION * RTT_MS[3] / 1e3)    # the grace window
+        lead = wait_leader(hosts, timeout=30)
+        terms = {r: shows(nh)[1] for r, nh in hosts.items()}
+        late_heartbeat(hosts, lead, next(r for r in hosts if r != lead))
+        wait_all(hosts, asleep=True)
+        if path != "host-path":
+            assert {r: shows(nh)[1] for r, nh in hosts.items()} == terms, (
+                "a term moved after a late heartbeat")
+            assert wait_leader(hosts, timeout=30) == lead
+        # and a transfer: the target leads, the group commits again
         target = 2
         lead_from(hosts, target)
-        propose(hosts, b"k2=v2")
+        propose(hosts, b"k2=v2", written)
         assert hosts[target].stale_read(1, "k2") == "v2"
+        # what was applied is what was acknowledged, on all three replicas
+        deadline = time.time() + 10.0
+        while {nh.get_sm_hash(1) for nh in hosts.values()} != {
+                table_hash(written)}:
+            assert time.time() < deadline, "the replicas did not converge"
+            time.sleep(0.05)
+        for nh in hosts.values():
+            assert nh.nodes[1].sm.sm.kv == written
     finally:
         for nh in hosts.values():
             nh.close()
