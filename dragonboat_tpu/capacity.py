@@ -483,17 +483,24 @@ class _SanctionedCrossing:
     """One declared boundary crossing: counts its tag, times its own
     extent into ``device_crossing_us{tag=...}`` (a download blocks until
     the device has the value, so this is where an engine round's wait
-    for the device shows, crossing by crossing), and — only while a
-    disallow guard is active — re-allows transfers for its extent so
-    everything OUTSIDE a sanctioned scope keeps raising."""
+    for the device shows, crossing by crossing), adds the bytes its site
+    says it ``moved`` to ``device_crossing_bytes{tag=...}`` and — only
+    while a disallow guard is active — re-allows transfers for its extent
+    so everything OUTSIDE a sanctioned scope keeps raising."""
 
-    __slots__ = ("_meter", "_tag", "_cm", "_t0")
+    __slots__ = ("_meter", "_tag", "_cm", "_t0", "_nbytes")
 
     def __init__(self, meter: "TransferMeter", tag: str) -> None:
         self._meter = meter
         self._tag = tag
         self._cm = None
         self._t0 = 0
+        self._nbytes = 0
+
+    def moved(self, *arrays) -> None:
+        """The arrays that cross inside this scope, on either side of the
+        boundary: their ``nbytes`` (shape-derived, no sync)."""
+        self._nbytes += sum(int(a.nbytes) for a in arrays)
 
     def __enter__(self) -> "_SanctionedCrossing":
         m = self._meter
@@ -509,6 +516,8 @@ class _SanctionedCrossing:
     def __exit__(self, *exc) -> bool:
         m = self._meter
         m._hist.labels(self._tag).observe(m._clock() - self._t0)
+        if self._nbytes:
+            m._bytes.labels(self._tag).inc(self._nbytes)
         cm, self._cm = self._cm, None
         if cm is not None:
             return bool(cm.__exit__(*exc))
@@ -557,11 +566,17 @@ class TransferMeter:
         self._guard_depth = 0      # guarded-by: mu
         # injected microsecond clock, as the tracker's
         self._clock = clock if clock is not None else monotonic_us
-        self._hist = (registry if registry is not None
-                      else _telemetry.GLOBAL).histogram(
+        reg = registry if registry is not None else _telemetry.GLOBAL
+        self._hist = reg.histogram(
             "device_crossing_us",
             help="host time inside one sanctioned host<->device "
                  "crossing, by its declared tag",
+            labelnames=("tag",))
+        self._bytes = reg.counter(
+            "device_crossing_bytes",
+            help="bytes of the arrays that crossed between host and "
+                 "device inside sanctioned crossings, by declared tag "
+                 "(their nbytes: shape-derived, no sync)",
             labelnames=("tag",))
 
     def sanctioned(self, tag: str) -> _SanctionedCrossing:

@@ -464,7 +464,8 @@ class MeshDispatch:
         cl = self.cluster
         up = staging.to_device(cl.sharding())
         if self._cut_dev is None:
-            with _capacity.METER.sanctioned("cut_up"):
+            with _capacity.METER.sanctioned("cut_up") as crossing:
+                crossing.moved(self.cut)
                 self._cut_dev = jax.device_put(self.cut, cl.sharding())
         entry = self.entries["serve_step_donated" if donate
                              else "serve_step"]

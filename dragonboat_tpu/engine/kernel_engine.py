@@ -220,6 +220,14 @@ _FLEET_LANES = telemetry.GLOBAL.counter(
          "them quiesced (a window's ratio is the share of held lanes "
          "that were asleep while it ran)",
     labelnames=("what",))
+# the rows an engine's programs run over, whatever they hold: the step,
+# the round's upload and download and the sweeps are priced by it (an
+# engine states its own under its label and takes it back when it closes)
+_ENGINE_LANES = telemetry.GLOBAL.gauge(
+    "engine_lanes",
+    help="lanes of an engine's batched state by what they are: capacity "
+         "(the rows every device program of that engine runs over)",
+    labelnames=("what", "engine"))
 _FLEET_OCCUPIED = _FLEET_LANES.labels("occupied")
 _FLEET_QUIESCED = _FLEET_LANES.labels("quiesced")
 _QUIESCE_WAKES = telemetry.GLOBAL.counter(
@@ -561,6 +569,8 @@ class KernelEngine:
                  label: str = "") -> None:
         self.kp = kp
         self.capacity = capacity
+        self.label = label or f"engine-{id(self):x}"
+        _ENGINE_LANES.labels("capacity", self.label).set(capacity)
         self.send_message = send_message
         self.events = events or EventHub()
         self.mu = threading.RLock()
@@ -610,6 +620,12 @@ class KernelEngine:
         # (0 -> term) that no step moves
         self._injected: set[int] = set()
         self._inject_fn = None      # inject_rows jitted for this state
+        # peer-book writes (``update_lane_membership``) of the rows one
+        # ``_finish`` retires, held for ONE upload and one program behind
+        # its loop: 4,096 groups elected at once apply their bootstrap
+        # config changes in a few rounds, and a program, an upload and two
+        # arrays let go of for each replica was most of those rounds
+        self._held_cells: list = []
         # whole-engine tick rounds queued by the host ticker; each step
         # consumes ONE round as a vectorized [G]-bool broadcast (the
         # per-lane Python tick walk was ~25 s/round at 100k lanes).
@@ -658,7 +674,7 @@ class KernelEngine:
         # engine in its records and annotations: the owning host's id) +
         # opt-in jax.profiler capture
         self._round = RoundTimer(self.events.metrics, "engine.kernel_step",
-                                 engine=label or f"engine-{id(self):x}")
+                                 engine=self.label)
         # staging counts of the round being staged (the round's record),
         # and the lanes its output pass took into the per-lane loops
         self._props_staged = 0
@@ -767,7 +783,9 @@ class KernelEngine:
         with self._admit_mu:
             ADD_SHARD_LOCK_US.observe(monotonic_us() - t0)
             if not self._free:
-                raise RuntimeError("kernel engine is at capacity")
+                raise RuntimeError(
+                    f"kernel engine is at capacity ({self.capacity} "
+                    "lanes, ExpertConfig.kernel_capacity)")
             lane = self._free.pop()
             node.lane = lane
             node.engine = self
@@ -812,6 +830,7 @@ class KernelEngine:
         relying on atexit for it races interpreter/backend shutdown and
         can leave the trace dir empty (a user-started ``start_trace``
         capture is deliberately left to its owner)."""
+        _ENGINE_LANES.labels("capacity", self.label).set(0)
         stop_env_trace()
 
     @property
@@ -843,11 +862,23 @@ class KernelEngine:
             r += [lane] * col.width
             c += range(col.start, col.start + col.width)
             v += vals.ravel().tolist()
-        with _capacity.METER.sanctioned(tag):
+        with _capacity.METER.sanctioned(tag) as crossing:
             up = jnp.asarray(_cell_batch(r, c, v))
+            crossing.moved(up)
         res = self._resident
         self._resident = res._replace(cols=write_cells_program(
             self._dispatch.placement())(res.cols, up))
+
+    def _write_held_cells(self) -> None:
+        """The peer-book writes a ``_finish`` held back, as ONE batch.  A
+        cell's last write wins, as it did at one program a replica (a mesh
+        group's members write its shared books in turn); a lane vacated
+        since (an eviction clears it at once) is left alone."""
+        held, self._held_cells = self._held_cells, []
+        last = {(lane, field): (lane, field, value)
+                for lane, field, value in held if lane in self.nodes}
+        if last:
+            self._write_cells(last.values(), "membership_up")
 
     def _inject(self, lane: int, node: KernelNode, init: _LaneInit
                 ) -> None:
@@ -954,7 +985,8 @@ class KernelEngine:
             self._inject_fn = _capacity.TRACKER.wrap(
                 "inject_rows", inject_program(
                     self.kp, self._dispatch.placement()))
-        with _capacity.METER.sanctioned("inject_up"):
+        with _capacity.METER.sanctioned("inject_up") as crossing:
+            crossing.moved(lanes_np, *rows.values())
             self._resident = self._inject_fn(
                 self._resident, jnp.asarray(lanes_np),
                 {k: jnp.asarray(v) for k, v in rows.items()})
@@ -984,7 +1016,9 @@ class KernelEngine:
         applies config changes; the device book follows).  A membership
         larger than the fixed [P] peer book cannot be modeled on device —
         quorum over a truncated book would be unsafe — so the shard is
-        evicted to the host engine instead."""
+        evicted to the host engine instead.  The book's cells are held
+        (``_held_cells``) and go up behind the ``_finish`` that applied the
+        change, with those of every other row it retired."""
         m = node.sm.get_membership()
         kp = self.kp
         total = len(m.addresses) + len(m.non_votings) + len(m.witnesses)
@@ -1008,14 +1042,14 @@ class KernelEngine:
                 pids[i], kinds[i] = rid, KP.K_WITNESS
                 i += 1
         g = node.lane
-        self._write_cells((
+        self._held_cells += (
             (g, "pid", pids), (g, "kind", kinds),
             # the applied CC releases the one-in-flight gate (pycore
             # add_node/add_non_voting/... clear pending_config_change on
             # apply; without this a lane accepts exactly ONE config
             # change in its lifetime and drops every later one)
             (g, "pending_cc", False),
-        ), "membership_up")
+        )
         self._kind_np[g] = kinds
         self._pid_np[g] = pids
 
@@ -1255,6 +1289,7 @@ class KernelEngine:
             reads_staged=self._reads_staged,
             lanes_staged=lanes_staged,
             lanes_processed=self._lanes_processed,
+            lanes_held=len(self.nodes),
             lanes_quiesced=(self.last_fleet or {}).get("quiesced", 0),
             keys=list(keys))
 
@@ -1366,23 +1401,28 @@ class KernelEngine:
         across occupants."""
         from dragonboat_tpu.core import digest as _digest
 
-        with _capacity.METER.sanctioned("digest_down"):
+        with _capacity.METER.sanctioned("digest_down") as crossing:
             carry = self._digest if self._digest is not None \
                 else self._make_digest()
             if self.invariant_probe and self._inv_dirty:
                 lanes = sorted(self._inv_dirty)
                 self._inv_dirty.clear()
+                cells = jnp.asarray(_cell_batch(
+                    lanes, [_digest.INV_TICKS_COL] * len(lanes),
+                    [0] * len(lanes)))
+                crossing.moved(cells)
                 carry = write_cells_program(self._dispatch.placement())(
-                    carry, jnp.asarray(_cell_batch(
-                        lanes, [_digest.INV_TICKS_COL] * len(lanes),
-                        [0] * len(lanes))))
-            args = (self._resident,
-                    self._dispatch.digest_inbox(self._inbox_buf), carry)
+                    carry, cells)
+            inbox = self._dispatch.digest_inbox(self._inbox_buf)
+            if "Inbox" not in self._dispatch.resident_classes():
+                crossing.moved(inbox)       # host-staged: it went up
+            args = (self._resident, inbox, carry)
             res = self._cap_entries["fleet_digest"](*args)
             if self.digest_arrays is None:
                 self.digest_arrays = (len(jax.tree.leaves(args)),
                                       len(jax.tree.leaves(res)))
             vec, self._digest = res
+            crossing.moved(vec)
             ints = np.asarray(vec).tolist()
         fleet, health, invariants = _digest.decode(
             ints, self.capacity, self.health_top_k, self.invariant_probe)
@@ -1528,12 +1568,13 @@ class KernelEngine:
         never materialized on host."""
         from dragonboat_tpu.core import health as _health
 
-        with self.mu, _capacity.METER.sanctioned("health_row"):
+        with self.mu, _capacity.METER.sanctioned("health_row") as crossing:
             row = resident_program(
                 self.kp, _health.shard_row, ("thresholds",))(
                 self._resident, self._fleet_inbox_from(),
                 self._health_digest, np.int32(lane),
                 thresholds=self.health_thresholds)
+            crossing.moved(*jax.tree.leaves(row))
             return _health.row_to_dict(row)
 
     def _kernel_call(self, staging: _RoundStaging):
@@ -1784,8 +1825,9 @@ class KernelEngine:
         # fetch: the download (where the host waits for the device) and
         # the rows it names
         rt.enter("fetch")
-        with _capacity.METER.sanctioned("round_down"):
+        with _capacity.METER.sanctioned("round_down") as crossing:
             host = np.asarray(ctx.out)
+            crossing.moved(host)
         # lanes with anything to process are the rows whose ``active``
         # cell is not 0.  The round's program computed it (core/round.py
         # ``row_activity``) and it covers every consumer below: emitted
@@ -1867,6 +1909,7 @@ class KernelEngine:
 
         rt.enter("finish")
         self._finish(r, ctx.staged_ri)
+        self._write_held_cells()
         _RETIRED_PER_LANE.inc(len(r.per_lane))
         _RETIRED_COLUMNAR.inc(len(lanes) - len(r.per_lane))
 
@@ -2032,10 +2075,12 @@ class KernelEngine:
             if to == 0 or to == n.replica_id:
                 continue
             ss = n.logdb.get_snapshot(n.shard_id, n.replica_id)
-            with _capacity.METER.sanctioned("wit_snap_floor"):
-                floor = int(state_cell(           # wit_snap only
+            with _capacity.METER.sanctioned("wit_snap_floor") as crossing:
+                cell = state_cell(                # wit_snap only
                     self._resident.cols, np.int32(g), np.int32(
-                        self._state_cols["snap_index"].start)))
+                        self._state_cols["snap_index"].start))
+                crossing.moved(cell)
+                floor = int(cell)
             if ss is not None and not ss.is_empty() and ss.index >= floor:
                 others.append((n, pb.Message(
                     type=MT.INSTALL_SNAPSHOT, to=to, from_=n.replica_id,
@@ -2056,8 +2101,9 @@ class KernelEngine:
         the state that step returned (still the resident one: a retire
         runs before the next dispatch)."""
         _SAVE_WINDOW_OVERFLOW.inc()
-        with _capacity.METER.sanctioned("save_window_row"):
+        with _capacity.METER.sanctioned("save_window_row") as crossing:
             row = np.asarray(ring_row(self._resident.lt, np.int32(g)))
+            crossing.moved(row)
         return row[(first + np.arange(last - first + 1))
                    & (self.kp.log_cap - 1)].tolist()
 
@@ -2407,7 +2453,8 @@ class _RoundStaging:
     def to_device(self, sharding=None):
         """The round's one upload (``sharding``: the mesh backend's
         placement along G)."""
-        with _capacity.METER.sanctioned("round_up"):
+        with _capacity.METER.sanctioned("round_up") as crossing:
+            crossing.moved(self.up)
             return jax.device_put(self.up, sharding)
 
 

@@ -450,7 +450,7 @@ class MeshEngine(KernelEngine):
                            (member.lane, "kind", kinds)]
                 self._kind_np[member.lane] = kinds
                 self._pid_np[member.lane] = pids
-        self._write_cells(writes, "membership_up")
+        self._held_cells += writes
 
     def _evict(self, n: KernelNode, reason: str, carry=None) -> None:
         """Whole-group escalation: every member leaves the mesh and is

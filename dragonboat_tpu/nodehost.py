@@ -21,7 +21,7 @@ from dataclasses import dataclass, field
 
 from dragonboat_tpu import raftpb as pb
 from dragonboat_tpu.client import Session
-from dragonboat_tpu.config import Config, NodeHostConfig
+from dragonboat_tpu.config import Config, ConfigError, NodeHostConfig
 from dragonboat_tpu.events import EventHub
 from dragonboat_tpu.logdb.memdb import MemLogDB
 from dragonboat_tpu.logdb.sharded import ShardedLogDB
@@ -109,6 +109,7 @@ class NodeHost:
                  auto_run: bool = True) -> None:
         nhconfig.validate()
         self.config = nhconfig
+        self._check_kernel_capacity()
         from dragonboat_tpu.vfs import default_fs
 
         self.fs = (nhconfig.expert.fs if nhconfig.expert.fs is not None
@@ -726,7 +727,7 @@ class NodeHost:
             for rid, addr in {**m.addresses, **m.non_votings, **m.witnesses}.items():
                 self.registry.add(cfg.shard_id, rid, addr)
             self.nodes[cfg.shard_id] = node
-            self._nodes_version += 1
+            self._views_add(cfg.shard_id, node)
             self._replica_specs[cfg.shard_id] = (
                 dict(initial_members), join, create_sm, cfg)
         t_build = monotonic_us()
@@ -847,6 +848,34 @@ class NodeHost:
             lag_ticks=ex.health_lag_ticks,
             churn_trip=ex.health_churn_trip,
             runaway_ticks=ex.health_runaway_ticks)
+
+    def _check_kernel_capacity(self) -> None:
+        """``ExpertConfig.kernel_capacity`` is what a deployment states:
+        the lanes of this host's kernel engine, the height of every device
+        program it runs.  Refused here, by the field's name, where it is
+        no positive whole number or where the resident state of that many
+        lanes (``capacity.resident_bytes_per_group`` a lane) is over the
+        device budget the same config states; left to itself either fails
+        at the first ``start_replica``, as an allocation or a shape."""
+        from dragonboat_tpu import capacity as _capacity
+
+        ex = self.config.expert
+        lanes = ex.kernel_capacity
+        if isinstance(lanes, bool) or not isinstance(lanes, int) or lanes <= 0:
+            raise ConfigError(
+                "ExpertConfig.kernel_capacity must be a positive whole "
+                f"number of lanes, got {lanes!r}")
+        budget = ex.capacity_device_budget_bytes
+        if budget <= 0:
+            return      # the backend's own limit is not known before it is
+        need = lanes * _capacity.resident_bytes_per_group(
+            self._kernel_params())
+        if need > budget:
+            raise ConfigError(
+                f"ExpertConfig.kernel_capacity {lanes} needs {need} bytes "
+                "of resident state, over capacity_device_budget_bytes "
+                f"{budget} (at most "
+                f"{budget * lanes // need} lanes fit)")
 
     def _kernel_params(self, min_inbox: int = 0):
         import jax
@@ -1043,6 +1072,21 @@ class NodeHost:
         workers the ticker wakes after its ``clear``."""
         if not self._work.is_set():
             self._work.set()
+
+    def _views_add(self, shard_id: int, node) -> None:
+        """``self.nodes`` gained ``node`` (under ``self.mu``): where the
+        views are current the node joins them in place, so a host that is
+        handed thousands of replicas in a row does not relist all it holds
+        after each (a rebuild a ``start_replica``, by every thread that
+        walks the views, was work in the square of a host's replicas; a
+        walker in mid-list sees the newcomer now or at its next pass)."""
+        version, shares, driven = self._views
+        current = version == self._nodes_version
+        self._nodes_version += 1
+        if current:
+            (driven if node.engine_driven
+             else shares[shard_id % self._num_workers]).append(node)
+            self._views = (self._nodes_version, shares, driven)
 
     def _node_views(self) -> tuple[list[list], list]:
         """-> (per step worker, the host-resident nodes hashed to it by

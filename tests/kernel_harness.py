@@ -218,13 +218,16 @@ class KernelCluster:
         from dragonboat_tpu.core.kstate import StepInput
 
         box = self._build_inbox()
-        self.state, out = step(
-            self.kp, self.state, box,
-            StepInput(**{k: (np.asarray(v) if v is not None else None)
-                         for k, v in d.items()}))
+        self.state, out = self._kernel_step(
+            box, StepInput(**{k: (np.asarray(v) if v is not None else None)
+                              for k, v in d.items()}))
         self.last_out = out
         self._route(out)
         return out
+
+    def _kernel_step(self, box, inp):
+        """-> (state, out) of this cluster's rows after one kernel step."""
+        return step(self.kp, self.state, box, inp)
 
     def run_until_leader(self, group: int = 0, max_steps: int = 200):
         for i in range(max_steps):
@@ -249,3 +252,47 @@ class KernelCluster:
 
     def field(self, name: str):
         return np.asarray(getattr(self.state, name))
+
+
+class TallCluster(KernelCluster):
+    """A KernelCluster whose rows are the LAST rows of a state ``height``
+    rows tall (4,096: an engine as wide as ``fleet-4k`` states it): every
+    step runs the kernel over the whole height, the rows below are live
+    lanes of their own (fresh three-replica members that tick, campaign and
+    are never answered), and everything the harness reads (``state``, the
+    step's output) is the cluster's own rows, sliced out.  Seeds are the
+    short cluster's, so a schedule elects whom it elects there."""
+
+    def __init__(self, *args, height: int = 4096, **kw) -> None:
+        super().__init__(*args, **kw)
+        self.height, self.base = height, height - self.G
+        assert self.base >= 0
+        below = init_state(
+            self.kp, self.base,
+            np.arange(self.base, dtype=np.int32) % 3 + 1,
+            np.asarray([1, 2, 3] + [0] * (self.kp.num_peers - 3), np.int32))
+        self.tall = self._stack(below, self.state)
+        self._no_mail = empty_inbox(self.kp, self.base)
+
+    @staticmethod
+    def _stack(below, top):
+        import jax
+
+        return jax.tree.map(
+            lambda b, t: np.concatenate([np.asarray(b), np.asarray(t)]),
+            below, top)
+
+    def _kernel_step(self, box, inp):
+        import jax
+
+        ticking = bool(np.asarray(inp.tick).any())
+        fill_inp = empty_input(self.kp, self.base)._replace(
+            tick=np.full((self.base,), ticking),
+            applied=np.asarray(self.tall.processed)[:self.base])
+        self.tall, out = step(
+            self.kp, self.tall,
+            self._stack(self._no_mail, box),
+            self._stack(fill_inp, inp))
+        mine = lambda tree: jax.tree.map(  # noqa: E731
+            lambda x: x[self.base:], tree)
+        return mine(self.tall), mine(out)

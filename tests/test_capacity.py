@@ -745,3 +745,99 @@ def test_compile_listener_ignores_other_events():
     snap = reg.snapshot()
     assert snap["xla_compiles{phase=upload}"] == 1
     assert snap["xla_compile_us.sum{phase=upload}"] == 250000.0
+
+
+# ---------------------------------------------------------------------
+# the bytes a crossing moved; the capacity a deployment states
+
+def test_sanctioned_crossing_counts_the_bytes_its_site_names():
+    """``device_crossing_bytes{tag=...}``: the arrays the site hands the
+    scope's ``moved``, host or device side, from ``nbytes`` alone; a scope
+    that names none adds no sample."""
+    import jax.numpy as jnp
+    import numpy as np
+
+    reg = telemetry.Registry()
+    meter = capacity.TransferMeter(registry=reg)
+    up = np.zeros((1024, 231), np.int32)
+    with meter.sanctioned("round_up") as crossing:
+        crossing.moved(up)
+    with meter.sanctioned("round_down") as crossing:
+        crossing.moved(jnp.zeros((1024, 345), jnp.int32))
+    with meter.sanctioned("digest_down") as crossing:
+        crossing.moved(np.zeros((16,), np.int32))
+        crossing.moved(np.zeros((8,), np.int32), np.zeros((2, 4), bool))
+    with meter.sanctioned("health_row"):
+        pass
+    snap = reg.snapshot()
+    assert snap["device_crossing_bytes{tag=round_up}"] == 1024 * 231 * 4
+    assert snap["device_crossing_bytes{tag=round_down}"] == 1024 * 345 * 4
+    assert snap["device_crossing_bytes{tag=digest_down}"] == 64 + 32 + 8
+    assert "device_crossing_bytes{tag=health_row}" not in snap
+    assert snap["device_crossing_us.count{tag=health_row}"] == 1
+
+
+def _host_config(**expert):
+    from dragonboat_tpu.config import ExpertConfig, NodeHostConfig
+
+    return NodeHostConfig(raft_address="capacity-stated", rtt_millisecond=5,
+                          expert=ExpertConfig(**expert))
+
+
+@pytest.mark.parametrize("lanes", [0, -1, 2.5, True, None, "4096"])
+def test_a_kernel_capacity_that_is_no_positive_whole_number_is_refused(lanes):
+    from dragonboat_tpu.config import ConfigError
+    from dragonboat_tpu.nodehost import NodeHost
+
+    with pytest.raises(ConfigError, match="kernel_capacity"):
+        NodeHost(_host_config(kernel_capacity=lanes), auto_run=False)
+
+
+def test_a_kernel_capacity_over_the_stated_device_budget_is_refused():
+    """By the field's name, with what fits: 4,096 lanes keep 26.4 MB
+    resident (6,452 B a lane at the default geometry)."""
+    from dragonboat_tpu.config import ConfigError
+    from dragonboat_tpu.nodehost import NodeHost
+
+    per_lane = capacity.resident_bytes_per_group(KernelParams(
+        num_peers=5, log_cap=1024, inbox_cap=8, msg_entries=8,
+        proposal_cap=8, readindex_cap=4))
+    assert per_lane == 6452
+    with pytest.raises(ConfigError) as e:
+        NodeHost(_host_config(kernel_capacity=4096,
+                              capacity_device_budget_bytes=4095 * per_lane),
+                 auto_run=False)
+    assert "kernel_capacity 4096" in str(e.value)
+    assert "capacity_device_budget_bytes" in str(e.value)
+    assert "4095 lanes fit" in str(e.value)
+
+
+@pytest.mark.parametrize("budget", [0, 4096 * 6452, int(16e9)])
+def test_a_kernel_capacity_of_4096_is_accepted(budget):
+    """With no budget stated (0: the backend's is not known before the
+    backend is), with exactly its footprint, and with a chip's 16 GB."""
+    from dragonboat_tpu.nodehost import NodeHost
+
+    nh = NodeHost(_host_config(kernel_capacity=4096,
+                               capacity_device_budget_bytes=budget),
+                  auto_run=False)
+    try:
+        assert nh.config.expert.kernel_capacity == 4096
+    finally:
+        nh.close()
+
+
+def test_a_full_engine_names_its_capacity_and_the_field():
+    from dragonboat_tpu.engine.kernel_engine import KernelEngine
+
+    eng = KernelEngine(KernelParams(log_cap=64), 2, send_message=None)
+    eng._free.clear()
+    with pytest.raises(RuntimeError,
+                       match=r"at capacity \(2 lanes, "
+                             r"ExpertConfig\.kernel_capacity\)"):
+        eng.add_shard(object(), None)
+    # an engine states its height under its own label, and takes it back
+    gauge = f"engine_lanes{{what=capacity,engine={eng.label}}}"
+    assert telemetry.GLOBAL.snapshot()[gauge] == 2
+    eng.close()
+    assert telemetry.GLOBAL.snapshot()[gauge] == 0

@@ -40,6 +40,8 @@ from dragonboat_tpu.rsm import device_kv_pallas
 from dragonboat_tpu.rsm.device_kv import DeviceKV
 
 ROWS = 3072          # 1024 groups x 3 replicas
+WIDE = 4096          # an engine as ``fleet-4k`` states it: one program over
+                     # 4,096 rows (past the ~3k rows of README "The TPU design")
 
 
 @pytest.fixture(scope="module")
@@ -97,7 +99,7 @@ def _shapes(tree, sharding_of):
 
 
 def _step_args(kp, rows):
-    rids = np.tile(np.arange(1, 4, dtype=np.int32), rows // 3)
+    rids = np.arange(rows, dtype=np.int32) % 3 + 1
     pids = np.zeros((rows, kp.num_peers), np.int32)
     return jax.eval_shape(lambda: (init_state(kp, rows, rids, pids),
                                    empty_inbox(kp, rows),
@@ -114,36 +116,82 @@ def _round_args(kp, rows, sharding):
                     mat(round_columns(kp).up_width)), lambda x: sharding)
 
 
-@pytest.mark.parametrize("entry", ["step", "step_donated"])
-def test_kernel_step_compiles_for_v5e(one_chip, device_kp, entry):
+#: the unpacked step's program text by height: the round's cases hold
+#: their gathers against it, and it is compiled once a height
+_PLAIN_STEP: dict[int, str] = {}
+
+
+def _plain_step(kp, rows, one_chip) -> str:
+    if rows not in _PLAIN_STEP:
+        compiled = kernel.step.lower(
+            kp, *_shapes(_step_args(kp, rows), lambda x: one_chip)).compile()
+        mem = compiled.memory_analysis()
+        assert mem.temp_size_in_bytes + mem.argument_size_in_bytes < 16e9
+        _PLAIN_STEP[rows] = compiled.as_text()
+    return _PLAIN_STEP[rows]
+
+
+@pytest.mark.parametrize("entry,rows", [
+    ("step", ROWS), ("step_donated", ROWS), ("step", WIDE)])
+def test_kernel_step_compiles_for_v5e(one_chip, device_kp, entry, rows):
     kp = device_kp()
-    args = _shapes(_step_args(kp, ROWS), lambda x: one_chip)
+    if entry == "step":
+        assert _plain_step(kp, rows, one_chip)
+        return
+    args = _shapes(_step_args(kp, rows), lambda x: one_chip)
     compiled = getattr(kernel, entry).lower(kp, *args).compile()
     mem = compiled.memory_analysis()
     assert mem.temp_size_in_bytes + mem.argument_size_in_bytes < 16e9
 
 
-@pytest.mark.parametrize("entry", ["step", "step_donated"])
-def test_round_step_compiles_for_v5e(one_chip, device_kp, entry):
+@pytest.mark.parametrize("entry,rows", [
+    ("step", ROWS), ("step_donated", ROWS), ("step", WIDE)])
+def test_round_step_compiles_for_v5e(one_chip, device_kp, entry, rows):
     """The serial round as served: the resident state and one [G, Wu]
     upload in (4 arrays), the resident state and one [G, Wd] download out
     (4), and no gather added around the step (the save window is read by
-    block select, core/round.py)."""
+    block select, core/round.py).  Again at the 4,096 rows ``fleet-4k``'s
+    engines hold (depth 0, as every cell serves)."""
     kp = device_kp()
     rc = round_columns(kp)
-    state, _box, up = _round_args(kp, ROWS, one_chip)
+    state, _box, up = _round_args(kp, rows, one_chip)
     assert len(jax.tree.leaves((state, up))) == 4
     compiled = getattr(cround, entry).lower(
         kp, kernel.step, state, up).compile()
     assert jax.eval_shape(
         lambda s, u: getattr(cround, entry)(kp, kernel.step, s, u)[1],
-        state, up).shape == (ROWS, rc.down_width)
+        state, up).shape == (rows, rc.down_width)
     mem = compiled.memory_analysis()
     assert mem.temp_size_in_bytes + mem.argument_size_in_bytes < 16e9
-    plain = kernel.step.lower(
-        kp, *_shapes(_step_args(kp, ROWS), lambda x: one_chip)
-    ).compile().as_text()
-    assert compiled.as_text().count(" gather(") <= plain.count(" gather(")
+    assert compiled.as_text().count(" gather(") <= _plain_step(
+        kp, rows, one_chip).count(" gather(")
+
+
+@pytest.mark.parametrize("batch", [8, 1024])
+def test_the_admission_program_compiles_for_v5e(one_chip, device_kp, batch):
+    """``kstate.inject_program`` as ``_flush_injections`` calls it on an
+    engine of 4,096 lanes: the resident state, a batch of lanes and their
+    rows in, the resident state out; at the least batch and at the size
+    class 12,288 ``start_replica`` calls fill."""
+    from dragonboat_tpu.core.kstate import inject_program
+
+    kp = device_kp()
+    resident, _box, _up = _round_args(kp, WIDE, one_chip)
+    one = lambda *shape, dtype=jnp.int32: jax.ShapeDtypeStruct(  # noqa: E731
+        (batch, *shape), dtype, sharding=one_chip)
+    rows = {f: one() for f in (
+        "replica_id", "seed", "rand_timeout", "e_timeout", "h_timeout",
+        "role", "term", "vote", "applied", "snap_index", "snap_term",
+        "last", "committed")}
+    rows.update({f: one(dtype=bool)
+                 for f in ("check_quorum", "pre_vote", "quiesce_on")})
+    rows.update(pid=one(kp.num_peers), kind=one(kp.num_peers),
+                lt=one(kp.log_cap), lcc=one(kp.log_cap, dtype=bool))
+    compiled = inject_program(kp).lower(resident, one(), rows).compile()
+    out = jax.eval_shape(inject_program(kp), resident, one(), rows)
+    assert out.cols.shape == resident.cols.shape
+    mem = compiled.memory_analysis()
+    assert mem.temp_size_in_bytes + mem.argument_size_in_bytes < 1e9
 
 
 @pytest.mark.parametrize("entry", ["jit_serve_step",
@@ -176,7 +224,8 @@ def test_mesh_round_compiles_for_v5e(topo, device_kp, entry):
 MESH_N_LOCAL = {"mesh-1x3": 48, "mesh-1x3-1024": 1024}
 
 
-@pytest.mark.parametrize("where", ["one-chip", *MESH_N_LOCAL])
+@pytest.mark.parametrize("where", ["one-chip", "one-chip-4096",
+                                   *MESH_N_LOCAL])
 def test_the_collection_compiles_for_v5e(topo, one_chip, device_kp, where):
     """The every-tenth-round collection (core/digest.py ``digest_program``:
     fleet statistics, health triage with its top-K, the invariant probe with
@@ -184,11 +233,13 @@ def test_the_collection_compiles_for_v5e(topo, one_chip, device_kp, where):
     uploaded; on the mesh, sliced out of the carried [G, Wi] inbox inside the
     program, state, inbox and carry sharded along G.  One int32 vector and
     the carried [G, 17] array out, the carry placed as it came in.  The
-    third case is the width ``fleet-1k-mesh4`` serves: 1,024 rows a chip."""
+    last case is the width ``fleet-1k-mesh4`` serves: 1,024 rows a chip;
+    the second the 4,096 rows of ONE program that ``fleet-4k`` serves."""
     from dragonboat_tpu.core import digest, health
 
-    if where == "one-chip":
-        kp, rows, placement, boxed = device_kp(), ROWS, None, False
+    if where.startswith("one-chip"):
+        kp, placement, boxed = device_kp(), None, False
+        rows = WIDE if where.endswith("4096") else ROWS
         rows_sharding = one_chip
     else:
         kp = device_kp(min_inbox=10)

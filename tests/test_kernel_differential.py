@@ -27,7 +27,7 @@ from dragonboat_tpu.core import params as KP
 from dragonboat_tpu.core.logentry import InMemoryLogDB
 from dragonboat_tpu.core.pycore import CoreConfig, Raft
 
-from tests.kernel_harness import KernelCluster
+from tests.kernel_harness import KernelCluster, TallCluster
 
 MT = pb.MessageType
 
@@ -149,8 +149,8 @@ class DiffCluster:
 
     def __init__(self, groups=2, replicas=3, election=10, heartbeat=1,
                  check_quorum=False, pre_vote=False, witnesses=frozenset(),
-                 kp=None):
-        self.kc = KernelCluster(groups, replicas, election=election,
+                 kp=None, cluster=KernelCluster):
+        self.kc = cluster(groups, replicas, election=election,
                                 heartbeat=heartbeat,
                                 check_quorum=check_quorum, pre_vote=pre_vote,
                                 witnesses=witnesses, kp=kp)
@@ -438,6 +438,23 @@ def test_diff_randomized_trace(seed):
         _random_schedule(d, rng, step_no, partitions=False)
     d.settle()
     d.compare("random-trace")
+
+
+@pytest.mark.parametrize("seed", [2024])
+def test_diff_randomized_trace_in_the_top_rows_of_4096(seed):
+    """The seeded random schedule again with the kernel's lanes sitting in
+    the last six rows of a state 4,096 rows tall (rows 4,090-4,095, live
+    lanes below them): against pycore, stepped in lockstep, the converged
+    state must match bitwise there as it does at six rows."""
+    rng = np.random.default_rng(seed)
+    d = DiffCluster(groups=2, replicas=3, cluster=TallCluster)
+    assert d.kc.base == 4090 and d.kc.tall.term.shape == (4096,)
+    d.tick_until_leader()
+    for step_no in range(60):
+        _random_schedule(d, rng, step_no, partitions=False)
+    d.settle()
+    d.compare("random-trace, rows 4090-4095 of 4096")
+    assert int(np.asarray(d.kc.tall.term)[:4090].max()) >= 2   # live below
 
 
 @pytest.mark.parametrize("cfg", [
