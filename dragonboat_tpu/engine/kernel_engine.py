@@ -228,6 +228,14 @@ _ENGINE_LANES = telemetry.GLOBAL.gauge(
     help="lanes of an engine's batched state by what they are: capacity "
          "(the rows every device program of that engine runs over)",
     labelnames=("what", "engine"))
+# what a round's reset of its staging array costs follows this, not the
+# capacity: the rows the round before it wrote (PR 43)
+_SWEPT_ROWS = telemetry.GLOBAL.counter(
+    "engine_round_swept_rows",
+    help="rows of its staging array an engine's rounds zeroed as they "
+         "began: the rows the rounds before them wrote (staged lanes and "
+         "forwarded proposals' target rows), whatever the engine holds",
+    labelnames=("engine",))
 _FLEET_OCCUPIED = _FLEET_LANES.labels("occupied")
 _FLEET_QUIESCED = _FLEET_LANES.labels("quiesced")
 _QUIESCE_WAKES = telemetry.GLOBAL.counter(
@@ -571,6 +579,7 @@ class KernelEngine:
         self.capacity = capacity
         self.label = label or f"engine-{id(self):x}"
         _ENGINE_LANES.labels("capacity", self.label).set(capacity)
+        self._swept_rows = _SWEPT_ROWS.labels(self.label)
         self.send_message = send_message
         self.events = events or EventHub()
         self.mu = threading.RLock()
@@ -639,11 +648,12 @@ class KernelEngine:
         # persistent staging buffers, zeroed per step (the jitted step
         # needs fixed [capacity] shapes anyway; reallocating every engine
         # iteration would cost ~G*K*E ints of fresh numpy per step).
-        # TWO of them: at pipeline depth 1 staging for step N writes the
-        # alternate one while step N-1 (whose device upload may alias
-        # its numpy staging on CPU backends) is still in flight; a
-        # buffer is only rewritten after the step that used it has
-        # retired
+        # A slot is a pair (``_RoundStaging``: one for the rounds no tick
+        # is due in, one for the tick rounds), and at pipeline depth 1
+        # there are TWO slots: staging for step N writes the alternate
+        # one while step N-1 (whose device upload may alias its numpy
+        # staging on CPU backends) is still in flight; a buffer is only
+        # rewritten after the step that used it has retired
         # mesh subclasses set _slot_exact_replicas BEFORE super().__init__
         # so hub-fallback staging lands at route()'s exact slot layout
         mesh_r = getattr(self, "_slot_exact_replicas", None)
@@ -653,18 +663,19 @@ class KernelEngine:
         # where each field of the download starts in a row (the output
         # pass reads a candidate row as a list, at these offsets)
         self._at = {c.field: c.start for c in self._cols.down}
-        self._bufs = tuple(
-            _RoundStaging(kp, capacity, mesh_replicas=mesh_r)
-            for _ in range(2))
-        self._buf_idx = 0
-        # aliases to the builders of the most recent dispatch (fleet
-        # stats and tests read the staged inbox through these)
-        self._inbox_buf = self._bufs[0].inbox
-        self._input_buf = self._bufs[0].inp
         # software pipeline: 0 = serial oracle (stage, dispatch, fetch,
         # process in one pass), 1 = retire step N-1 while N is staged,
         # dispatching N through the donating jit entry
         self.pipeline_depth = max(0, min(1, int(pipeline_depth)))
+        self._bufs = tuple(
+            tuple(_RoundStaging(kp, capacity, mesh_replicas=mesh_r)
+                  for _tick_due in (False, True))
+            for _ in range(self.pipeline_depth + 1))
+        self._buf_idx = 0
+        # aliases to the builders of the most recent dispatch (fleet
+        # stats and tests read the staged inbox through these)
+        self._inbox_buf = self._bufs[0][0].inbox
+        self._input_buf = self._bufs[0][0].inp
         self._pending_ctx: _StepCtx | None = None
         # pipeline occupancy accounting: a dispatch is "overlapped" when
         # a previous step was still unretired at its staging
@@ -1124,11 +1135,11 @@ class KernelEngine:
                     or self._device_pending()):
                 return False
             self._flush_injections()
-            staging = self._bufs[self._buf_idx]
+            staging = self._bufs[self._buf_idx][tick_due]
             inbox, inp = staging.inbox, staging.inp
             self._inbox_buf, self._input_buf = inbox, inp
             with rt.part("stage.reset"):
-                staging.reset()
+                self._swept_rows.inc(staging.reset())
             had_work = False
 
             # swap out the dirty set; arrivals during this step land in
@@ -1161,8 +1172,7 @@ class KernelEngine:
                 self._last_tick_us = monotonic_us()
             if tick_round:
                 with rt.part("stage.tick"):
-                    lanes = np.fromiter(nodes.keys(), np.int64, len(nodes))
-                    inp._tick[lanes] = True
+                    staging.tick_all(nodes)
                 had_work = True
             # an eviction while staging (InstallSnapshot; whole-GROUP on a
             # mesh engine) may remove rows staged EARLIER in this loop —
@@ -1236,8 +1246,10 @@ class KernelEngine:
                 self._resident = resident
             ctx.out = out
             with rt.part("upload.applied"):
-                np.maximum(self._applied_sent_np, inp._applied,
-                           out=self._applied_sent_np)
+                sent, applied = self._applied_sent_np, inp._applied
+                for g in staging.rows:
+                    if applied[g] > sent[g]:
+                        sent[g] = applied[g]
             for k in ctx.traced:
                 lifecycle.TRACER.stamp(k, lifecycle.STAGE_DISPATCH)
             self._pipe_steps += 1
@@ -2436,19 +2448,60 @@ def _newest_heartbeats(msgs: list) -> list:
 class _RoundStaging:
     """One round's upload: a host array ``[G, Wu] int32`` laid out by
     kstate.py's column table, written through the two builders (whose
-    fields are views of its columns) and sent up in ONE transfer."""
+    fields are views of its columns) and sent up in ONE transfer.
+
+    What a round costs here follows the rows it STAGED, not the lanes the
+    engine holds: the builders note every row they write (``rows``) and
+    ``reset`` clears those alone; the full zero-fill it replaces was a
+    pass over ``[capacity]`` a round, i.e. one more place where the engine
+    thread gave the interpreter up (PERF.md section 6, PR 43).  An engine
+    keeps a pair of these a buffer slot: one serves the rounds every lane
+    ticks in and keeps its tick column set for the registered lanes
+    between them (``tick_all``), the other serves the rest and is never
+    asked to, so neither a tick round nor the one after it writes a
+    column whole."""
 
     def __init__(self, kp: KP.KernelParams, G: int,
                  mesh_replicas: int | None = None) -> None:
         cols = round_columns(kp)
         self.up = np.zeros((G, cols.up_width), np.int32)
         views = column_views(cols.up, self.up)
+        #: rows written since the last ``reset`` (the builders add)
+        self.rows: set[int] = set()
         self.inbox = _InboxBuilder(views, kp.inbox_cap, kp.msg_entries,
-                                   mesh_replicas=mesh_replicas)
-        self.inp = _InputBuilder(views, kp.proposal_cap)
+                                   self.rows, mesh_replicas=mesh_replicas)
+        self.inp = _InputBuilder(views, kp.proposal_cap, self.rows)
+        # the lanes whose tick cell stays set, as the keys ``tick_all``
+        # was last handed and as a [G] mask (none until it is called)
+        self._ticked: set[int] = set()
+        self._ticked_np = np.zeros((G,), bool)
 
-    def reset(self) -> None:
-        self.up.fill(0)
+    def reset(self) -> int:
+        """Zero the rows written since the last reset (every column of
+        them; their tick cells back to what ``tick_all`` keeps set) ->
+        how many rows that was.  A row at a time, by plain indexing: one
+        assignment through an index ARRAY gives the interpreter up inside
+        numpy whatever its size (0.17-0.5 ms a call in a served engine
+        where a row's plain write takes microseconds: PERF.md, PR 43)."""
+        n = len(self.rows)
+        if n:
+            up, tick, kept = self.up, self.inp._tick, self._ticked_np
+            for g in self.rows:
+                up[g] = 0
+                if kept[g]:
+                    tick[g] = 1
+            self.rows.clear()
+        return n
+
+    def tick_all(self, nodes: dict) -> None:
+        """Every lane of ``nodes`` ticks this round: the column is
+        rewritten where the registered lanes moved since this array's last
+        tick round and is left as it stands otherwise."""
+        if nodes.keys() != self._ticked:
+            self._ticked = set(nodes)
+            self._ticked_np.fill(False)
+            self._ticked_np[np.fromiter(nodes, np.intp, len(nodes))] = True
+            self.inp._tick[:] = self._ticked_np
 
     def to_device(self, sharding=None):
         """The round's one upload (``sharding``: the mesh backend's
@@ -2463,9 +2516,10 @@ class _InboxBuilder:
     ``_RoundStaging``'s field -> [G, ...] int32 view; a bool field is a
     0/1 column)."""
 
-    def __init__(self, views: dict, K: int, E: int,
+    def __init__(self, views: dict, K: int, E: int, rows: set,
                  mesh_replicas: int | None = None) -> None:
         self.K, self.E = K, E
+        self._rows = rows       # the staging's: every row written here
         # typed slot layout (params.slot_families): a message may only be
         # staged into a slot whose family accepts its type ('any' accepts
         # all) — the kernel compiles family-specialized handlers per slot
@@ -2511,6 +2565,7 @@ class _InboxBuilder:
                 break
         if k < 0:
             return False  # family full this step; host requeues the message
+        self._rows.add(g)
         self.mtype[g, k] = int(m.type)
         self.from_[g, k] = m.from_
         self.term[g, k] = m.term
@@ -2535,8 +2590,9 @@ class _InputBuilder:
     """Writes the StepInput columns of a round's upload (``quiesced``
     stays 0: the device quiesces itself)."""
 
-    def __init__(self, views: dict, B: int) -> None:
+    def __init__(self, views: dict, B: int, rows: set) -> None:
         self.B = B
+        self._rows = rows       # the staging's: every row written here
         self.prop_valid = views["prop_valid"]
         self.prop_cc = views["prop_cc"]
         self.ri_valid = views["ri_valid"]
@@ -2547,19 +2603,24 @@ class _InputBuilder:
         self._applied = views["applied"]
 
     def prop(self, g: int, slot: int, is_cc: bool) -> None:
+        self._rows.add(g)
         self.prop_valid[g, slot] = True
         self.prop_cc[g, slot] = is_cc
 
     def read(self, g: int, ctx: pb.SystemCtx) -> None:
+        self._rows.add(g)
         self.ri_valid[g] = True
         self.ri_low[g] = ctx.low & 0x7FFFFFFF
         self.ri_high[g] = ctx.high & 0x7FFFFFFF
 
     def transfer(self, g: int, target: int) -> None:
+        self._rows.add(g)
         self.transfer_to[g] = target
 
     def tick(self, g: int) -> None:
+        self._rows.add(g)
         self._tick[g] = True
 
     def applied(self, g: int, v: int) -> None:
+        self._rows.add(g)
         self._applied[g] = v

@@ -776,7 +776,7 @@ def test_round_call_beside_three_busy_threads():
         eng = nh.kernel_engine
         with eng.mu:
             _settle(eng)
-            staging = eng._bufs[eng._buf_idx]
+            staging = eng._bufs[eng._buf_idx][0]
             staging.reset()
             for t in spinners:
                 t.start()

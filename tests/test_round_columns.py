@@ -103,8 +103,12 @@ def test_pack_then_unpack_returns_every_field(geometry, inline):
     got_box, got_inp = kstate.unpack_upload(kp, jnp.asarray(staging.up))
     _assert_same("staged Inbox", got_box, box)
     _assert_same("staged StepInput", got_inp, inp)
-    staging.reset()
-    assert not staging.up.any()
+    # a reset clears the rows the BUILDERS wrote and no other (the views
+    # above wrote behind their backs: PR 43, tests/test_staged_rows.py)
+    assert staging.reset() == 0 and staging.up.any()
+    staging.rows.update(range(G))
+    assert staging.reset() == G
+    assert not staging.up.any() and not staging.rows
 
     # download: device pack -> device unpack, and the host view
     flags = rng.random((G, len(kstate.FLAG_CLASSES))) < 0.5
